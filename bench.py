@@ -72,7 +72,7 @@ ORDER BY l_returnflag, l_linestatus
 """
 
 
-def make_lineitem():
+def make_lineitem(n: int = N_ROWS):
     """Seeded SF1-shaped lineitem with the REAL TPC-H schema: the money
     columns are decimal(15,2) (dbgen 4.2.2.13 domains), generated as
     unscaled int64 directly."""
@@ -81,7 +81,6 @@ def make_lineitem():
 
     DEC = T.DecimalType(15, 2)
     rng = np.random.default_rng(20260730)
-    n = N_ROWS
     quantity = rng.integers(1, 51, n) * 100          # 1.00 .. 50.00
     extendedprice = rng.integers(90100, 10494951, n)  # 901.00..104949.50
     discount = rng.integers(0, 11, n)                 # 0.00 .. 0.10
@@ -109,15 +108,21 @@ def make_lineitem():
     return HostBatch(schema, cols, n)
 
 
+def write_lineitem(spark, path: str, n: int = N_ROWS) -> None:
+    """Generate lineitem from the seed and write it as N_PARTITIONS
+    Parquet files through the engine's own writer."""
+    df = spark.createDataFrame(make_lineitem(n),
+                               num_partitions=N_PARTITIONS)
+    df.write.mode("overwrite").parquet(path)
+
+
 def ensure_data(spark) -> str:
     marker = os.path.join(DATA_DIR, "_SUCCESS.bench")
     if os.path.exists(marker):
         return DATA_DIR
     if os.path.exists(DATA_DIR):
         shutil.rmtree(DATA_DIR)
-    batch = make_lineitem()
-    df = spark.createDataFrame(batch, num_partitions=N_PARTITIONS)
-    df.write.mode("overwrite").parquet(DATA_DIR)
+    write_lineitem(spark, DATA_DIR)
     with open(marker, "w") as f:
         f.write("ok\n")
     return DATA_DIR
@@ -164,13 +169,21 @@ TPCDS_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
 
 
 def ensure_tpcds_data(spark) -> None:
-    """Synthetic TPC-DS star-schema slice for q3 (BASELINE config 2):
-    store_sales fact + item/date_dim dimensions, decimal money."""
     marker = os.path.join(TPCDS_DIR, "_SUCCESS.bench")
     if os.path.exists(marker):
         return
     if os.path.exists(TPCDS_DIR):
         shutil.rmtree(TPCDS_DIR)
+    write_tpcds(spark, TPCDS_DIR)
+    with open(marker, "w") as f:
+        f.write("ok\n")
+
+
+def write_tpcds(spark, path: str, n: int = TPCDS_ROWS) -> None:
+    """Synthetic TPC-DS star-schema slice for q3 (BASELINE config 2),
+    generated from the seed: store_sales fact (``n`` rows) +
+    item/date_dim dimensions, decimal money, one Parquet directory per
+    table under ``path``."""
     from spark_rapids_tpu.columnar.host import HostBatch, HostColumn
     from spark_rapids_tpu.sql import types as T
     rng = np.random.default_rng(20260731)
@@ -208,7 +221,6 @@ def ensure_tpcds_data(spark) -> None:
             T.IntegerT),
     ], n_date)
 
-    n = TPCDS_ROWS
     store_sales = HostBatch(T.StructType([
         T.StructField("ss_sold_date_sk", T.LongT),
         T.StructField("ss_item_sk", T.LongT),
@@ -222,9 +234,7 @@ def ensure_tpcds_data(spark) -> None:
     for name, batch, parts in (("item", item, 1), ("date_dim", date_dim, 1),
                                ("store_sales", store_sales, 8)):
         spark.createDataFrame(batch, num_partitions=parts).write \
-            .mode("overwrite").parquet(os.path.join(TPCDS_DIR, name))
-    with open(marker, "w") as f:
-        f.write("ok\n")
+            .mode("overwrite").parquet(os.path.join(path, name))
 
 
 def run_tpcds_q3(spark, capture=False):
@@ -1064,8 +1074,11 @@ def run_serving(clean_wall: float, cpu_rows, q3_cpu_rows) -> dict:
                 t.join()
             wall = time.perf_counter() - t0
             if errors:
-                legs[f"c{concurrency}"] = {"errors": errors[:3]}
-                continue
+                # a served request that failed (or returned rows that
+                # differ from the oracle) fails the leg, not one cell
+                raise RuntimeError(
+                    f"serving c={concurrency}: {len(errors)} of {total} "
+                    f"requests failed: {errors[:3]}")
             hits = PLAN_CACHE.hits - h0
             misses = PLAN_CACHE.misses - m0
             legs[f"c{concurrency}"] = {
@@ -2073,14 +2086,46 @@ def run_bench_diff(current: dict) -> dict:
     }
 
 
+def run_leg(failed: list, label: str, fn, *args) -> dict:
+    """Fault-isolated detail leg: a leg that raises must not discard
+    the measured primary results, so its failure rides in the JSON —
+    but it is a failure, not a skip: its label joins ``failed`` and
+    main() exits non-zero."""
+    t0 = time.perf_counter()
+    try:
+        out = fn(*args)
+    except Exception as e:  # noqa: BLE001 - reported and exit code set
+        import traceback
+        traceback.print_exc()
+        failed.append(label)
+        out = {"failed": True, "reason": f"{label} failed: {e!r}"}
+    # progress on stderr: a run that is cut short still says how far it got
+    state = ("FAILED" if out.get("failed") else
+             "skipped" if out.get("skipped") else "ok")
+    print(f"[bench] {label}: {state} in {time.perf_counter() - t0:.1f}s",
+          file=sys.stderr, flush=True)
+    return out
+
+
 def main():
+    import jax
+
     from spark_rapids_tpu.metrics import registry_snapshot
     from spark_rapids_tpu.sql.session import TpuSparkSession
+
+    t_start = time.perf_counter()
+    failed_legs: list = []
+
+    def progress(phase: str) -> None:
+        print(f"[bench] {phase} done at "
+              f"{time.perf_counter() - t_start:.1f}s",
+              file=sys.stderr, flush=True)
 
     gen = TpuSparkSession({"spark.rapids.sql.enabled": "false"})
     ensure_data(gen)
     ensure_tpcds_data(gen)
     gen.stop()
+    progress("data")
 
     cpu = TpuSparkSession({"spark.rapids.sql.enabled": "false"})
     q_cpu = build_query(cpu)
@@ -2091,11 +2136,14 @@ def main():
         cpu_times.append(dt)
     q3_cpu_t, q3_cpu_rows, _, _ = run_tpcds_q3(cpu)
     cpu.stop()
+    progress("CPU engine passes")
 
     # unfused FIRST (its compile misses don't warm fused-stage
     # programs; the fused pass compiles its own)
     unfused = run_tpu(fusion_enabled=False)
+    progress("unfused device pass")
     fused = run_tpu(fusion_enabled=True)
+    progress("fused device pass")
 
     assert_rows_match(cpu_rows, fused["rows"])
     assert_rows_match(cpu_rows, unfused["rows"])
@@ -2104,115 +2152,73 @@ def main():
 
     # decode A/B legs (host decode / unpipelined), fault-isolated like
     # every other detail leg
-    try:
-        decode_ab = run_decode_ab(fused["wall_s"], cpu_rows)
-    except Exception as e:  # noqa: BLE001 - reported, not swallowed
-        decode_ab = {"skipped": True,
-                     "reason": f"decode A/B leg failed: {e!r}"}
+    decode_ab = run_leg(failed_legs, "decode A/B leg", run_decode_ab,
+                        fused["wall_s"], cpu_rows)
 
     # AFTER the primary asserts, and fault-isolated: a multichip-leg
     # failure must not discard the measured single-chip results
-    try:
-        multichip = run_multichip(fused["wall_s"], cpu_rows)
-    except Exception as e:  # noqa: BLE001 - reported, not swallowed
-        multichip = {"skipped": True,
-                     "reason": f"multichip leg failed: {e!r}"}
+    multichip = run_leg(failed_legs, "multichip leg", run_multichip,
+                        fused["wall_s"], cpu_rows)
 
     # robustness sweep, equally fault-isolated
-    try:
-        robustness = run_robustness(fused["wall_s"], cpu_rows)
-    except Exception as e:  # noqa: BLE001 - reported, not swallowed
-        robustness = {"skipped": True,
-                      "reason": f"robustness leg failed: {e!r}"}
+    robustness = run_leg(failed_legs, "robustness leg", run_robustness,
+                         fused["wall_s"], cpu_rows)
 
     # planned out-of-core sweep (docs/out_of_core.md): 1x/4x/10x over
     # budget, gated on the planned path staying retry-free
-    try:
-        out_of_core_leg = run_out_of_core(fused["wall_s"], cpu_rows)
-    except Exception as e:  # noqa: BLE001 - reported, not swallowed
-        out_of_core_leg = {"skipped": True,
-                           "reason": f"out-of-core leg failed: {e!r}"}
+    out_of_core_leg = run_leg(failed_legs, "out-of-core leg", run_out_of_core,
+                              fused["wall_s"], cpu_rows)
 
     # span-tracing leg (docs/observability.md), equally fault-isolated
-    try:
-        trace_leg = run_trace(fused["wall_s"], cpu_rows)
-    except Exception as e:  # noqa: BLE001 - reported, not swallowed
-        trace_leg = {"skipped": True,
-                     "reason": f"trace leg failed: {e!r}"}
+    trace_leg = run_leg(failed_legs, "trace leg", run_trace, fused["wall_s"],
+                        cpu_rows)
 
     # query-profile leg (per-op peak HBM + explain coverage)
-    try:
-        profile_leg = run_profile(fused["wall_s"], cpu_rows)
-    except Exception as e:  # noqa: BLE001 - reported, not swallowed
-        profile_leg = {"skipped": True,
-                       "reason": f"profile leg failed: {e!r}"}
+    profile_leg = run_leg(failed_legs, "profile leg", run_profile,
+                          fused["wall_s"], cpu_rows)
 
     # Pallas kernel tier A/B (docs/kernels.md), equally fault-isolated
-    try:
-        kernels_leg = run_kernels(fused["wall_s"], cpu_rows)
-    except Exception as e:  # noqa: BLE001 - reported, not swallowed
-        kernels_leg = {"skipped": True,
-                       "reason": f"kernels leg failed: {e!r}"}
+    kernels_leg = run_leg(failed_legs, "kernels leg", run_kernels,
+                          fused["wall_s"], cpu_rows)
 
     # serving leg (docs/serving.md): QPS/latency through the query
     # server at concurrency 1/4/16, equally fault-isolated
-    try:
-        serving = run_serving(fused["wall_s"], cpu_rows, q3_cpu_rows)
-    except Exception as e:  # noqa: BLE001 - reported, not swallowed
-        serving = {"skipped": True,
-                   "reason": f"serving leg failed: {e!r}"}
+    serving = run_leg(failed_legs, "serving leg", run_serving, fused["wall_s"],
+                      cpu_rows, q3_cpu_rows)
 
     # live-telemetry leg (docs/observability.md "Live telemetry"):
     # ring-recorder overhead, endpoint scrape-under-load latency, one
     # forced slow-query bundle round trip — equally fault-isolated
-    try:
-        telemetry_leg = run_telemetry(fused["wall_s"], cpu_rows)
-    except Exception as e:  # noqa: BLE001 - reported, not swallowed
-        telemetry_leg = {"skipped": True,
-                         "reason": f"telemetry leg failed: {e!r}"}
+    telemetry_leg = run_leg(failed_legs, "telemetry leg", run_telemetry,
+                            fused["wall_s"], cpu_rows)
 
     # query-lifecycle leg (docs/serving.md "Query lifecycle"): cancel
     # latency, deadline bound, drain wall, quarantine fail-fast
-    try:
-        lifecycle_leg = run_lifecycle(fused["wall_s"], cpu_rows)
-    except Exception as e:  # noqa: BLE001 - reported, not swallowed
-        lifecycle_leg = {"skipped": True,
-                         "reason": f"lifecycle leg failed: {e!r}"}
+    lifecycle_leg = run_leg(failed_legs, "lifecycle leg", run_lifecycle,
+                            fused["wall_s"], cpu_rows)
 
     # query-history leg (docs/observability.md "Query history"):
     # append overhead, doctor round trip on a forced slow query,
     # warm-start watchdog availability — equally fault-isolated
-    try:
-        history_leg = run_history(fused["wall_s"], cpu_rows)
-    except Exception as e:  # noqa: BLE001 - reported, not swallowed
-        history_leg = {"skipped": True,
-                       "reason": f"history leg failed: {e!r}"}
+    history_leg = run_leg(failed_legs, "history leg", run_history,
+                          fused["wall_s"], cpu_rows)
 
     # self-tuning leg (docs/tuning.md): forced compileStorm pre-warm
     # hit on restart, forced kernelFallback conf flip, injected
     # harmful action auto-reverted by the guardrail
-    try:
-        tuning_leg = run_tuning(fused["wall_s"], cpu_rows)
-    except Exception as e:  # noqa: BLE001 - reported, not swallowed
-        tuning_leg = {"skipped": True,
-                      "reason": f"tuning leg failed: {e!r}"}
+    tuning_leg = run_leg(failed_legs, "tuning leg", run_tuning,
+                         fused["wall_s"], cpu_rows)
 
     # adaptive-execution leg (docs/adaptive.md): skewed-join replan
     # A/B, coalesce dispatch delta, same-signature batch-fusion QPS
-    try:
-        adaptive_leg = run_adaptive(fused["wall_s"])
-    except Exception as e:  # noqa: BLE001 - reported, not swallowed
-        adaptive_leg = {"skipped": True,
-                        "reason": f"adaptive leg failed: {e!r}"}
+    adaptive_leg = run_leg(failed_legs, "adaptive leg", run_adaptive,
+                           fused["wall_s"])
 
     # result + subplan cache leg (docs/caching.md): dashboard-replay
     # warm-vs-cold QPS at c=16, hit rates, join build reuse delta
-    try:
-        result_cache_leg = run_result_cache(fused["wall_s"], cpu_rows,
-                                            q3_cpu_rows)
-    except Exception as e:  # noqa: BLE001 - reported, not swallowed
-        result_cache_leg = {"skipped": True,
-                            "reason": f"result-cache leg failed: {e!r}"}
+    result_cache_leg = run_leg(failed_legs, "result-cache leg",
+                               run_result_cache, fused["wall_s"], cpu_rows,
+                               q3_cpu_rows)
 
     cpu_t = min(cpu_times)
     tpu_t = fused["wall_s"]
@@ -2227,7 +2233,9 @@ def main():
             "device_wall_s": round(tpu_t, 4),
             "cpu_engine_wall_s": round(cpu_t, 4),
             "speedup_vs_cpu_engine": round(speedup, 4),
-            "backend": __import__("jax").default_backend(),
+            "backend": jax.default_backend(),
+            "device_kind": jax.devices()[0].device_kind,
+            "device_count": len(jax.devices()),
             "rows": N_ROWS,
             "stages": fused["stages"],
             "decode": {**fused["decode"], "ab": decode_ab,
@@ -2273,12 +2281,12 @@ def main():
     }
     # regression verdict vs the previous round rides IN the output
     # (fault-isolated: a differ failure must not discard the results)
-    try:
-        telemetry_leg["benchDiff"] = run_bench_diff(result)
-    except Exception as e:  # noqa: BLE001 - reported, not swallowed
-        telemetry_leg["benchDiff"] = {
-            "skipped": True, "reason": f"bench-diff failed: {e!r}"}
+    telemetry_leg["benchDiff"] = run_leg(failed_legs, "bench-diff",
+                                         run_bench_diff, result)
+    result["detail"]["failedLegs"] = failed_legs
     print(json.dumps(result))
+    if failed_legs:
+        sys.exit(1)
 
 
 if __name__ == "__main__":

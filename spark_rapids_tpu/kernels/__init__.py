@@ -16,22 +16,27 @@ Model (docs/kernels.md):
 - per-kernel enable confs ``spark.rapids.sql.kernel.<name>.enabled``
   plus a master ``spark.rapids.sql.kernel.enabled``; with everything
   off the oracle path is byte-for-byte what shipped before this tier.
-- ``device_caps.pallas_mode()`` picks real lowering on TPU or
-  ``interpret=True`` emulation on CPU, so tier-1 exercises every
-  kernel path without hardware.
+- ``device_caps.pallas_mode()`` picks real lowering on an accelerator
+  or ``interpret=True`` emulation on the CPU platform, so tier-1
+  exercises every kernel path without hardware. A kernel the native
+  compiler refuses is listed in ``NATIVE_REFUSED`` with the compiler's
+  message and gated off there, so the stock path never attempts a
+  compile that is known to fail.
 - **fallback**: a kernel program that fails to lower/compile/execute
   (anything that is not the retry protocol's OOM/chip-failure
-  traffic) poisons its structural key and the call re-runs on the
-  oracle — counted as ``kernelFallbacks.<name>``. The group-by kernel
-  additionally reports hash-table overflow as a device flag; the exec
-  re-runs overflowed batches on the oracle (same counter).
+  traffic) poisons its structural key — the reason is logged and kept
+  (``poisoned()``) — and the call re-runs on the oracle, counted as
+  ``kernelFallbacks.<name>``. The group-by kernel additionally reports
+  hash-table overflow as a device flag; the exec re-runs overflowed
+  batches on the oracle (same counter, no poison).
 """
 
 from __future__ import annotations
 
 import contextlib as _contextlib
+import logging
 import threading
-from typing import Dict, Optional, Tuple
+from typing import Dict
 
 from spark_rapids_tpu import metrics as M
 
@@ -56,6 +61,28 @@ _CONF_OF = {
 }
 
 
+# Kernels the native (Mosaic) compiler refuses: name -> the first line
+# of what the lowering raised, as chip_smoke.py's kernel leg printed it
+# on a TPU v5 lite (jax 0.9.0, libtpu 0.0.34; docs/kernels.md has the
+# shapes and where each error comes from). All four carry 64-bit lanes,
+# which Pallas's TPU lowering has no rules for — the design itself is
+# refused, so ROADMAP S4/D1/D2 decide redesign or removal. The gate
+# below answers False for these wherever Pallas lowers natively, so the
+# stock path performs no failed kernel compile and poisons no key;
+# interpret mode (CPU) still runs them. A kernel that is made to lower
+# leaves this table (chip_smoke.py fails while the two disagree).
+NATIVE_REFUSED: Dict[str, str] = {
+    "groupbyHash": "ZeroDivisionError: integer modulo by zero",
+    "joinProbe": "RecursionError: maximum recursion depth exceeded",
+    "murmur3": "RecursionError: maximum recursion depth exceeded",
+    "decodeFused": "ValueError: Only arrays with 32-bit element types "
+                   "can be converted to scalars, but got: int64. Try "
+                   "casting the input before squeezing the scalar.",
+}
+
+_log = logging.getLogger("spark_rapids_tpu.kernels")
+
+
 class KernelDispatchError(RuntimeError):
     """Injected kernel failure (tests): routed to the oracle fallback
     exactly like a real lowering/compile failure."""
@@ -66,7 +93,7 @@ class KernelDispatchError(RuntimeError):
 # process lifetime; a conf flip or restart clears the set). Bounded:
 # distinct plan structures, not per-batch.
 _POISON_LOCK = threading.Lock()
-_POISONED: set = set()
+_POISONED: Dict[tuple, str] = {}  # (name, key) -> first line of the error
 _POISON_CAP = 4096
 
 # test hook: kernel names whose next dispatches raise (FaultInjector
@@ -75,10 +102,21 @@ _POISON_CAP = 4096
 _FAIL_INJECT: set = set()
 
 
-def poison(name: str, key) -> None:
+def first_line(exc: BaseException) -> str:
+    """``Type: first line of the message`` — how a refusal is recorded
+    (poison reasons, ``NATIVE_REFUSED``, chip_smoke.py's verdicts)."""
+    lines = str(exc).strip().splitlines()
+    return f"{type(exc).__name__}: {lines[0] if lines else ''}"
+
+
+def poison(name: str, key, exc: BaseException) -> None:
+    reason = first_line(exc)
+    _log.warning("kernel %s failed and is off for this structure "
+                 "(oracle composition takes over): %s", name, reason,
+                 exc_info=exc)
     with _POISON_LOCK:
         if len(_POISONED) < _POISON_CAP:
-            _POISONED.add((name, key))
+            _POISONED[(name, key)] = reason
 
 
 def is_poisoned(name: str, key) -> bool:
@@ -89,6 +127,12 @@ def is_poisoned(name: str, key) -> bool:
 def clear_poison() -> None:
     with _POISON_LOCK:
         _POISONED.clear()
+
+
+def poisoned() -> Dict[tuple, str]:
+    """Snapshot of the poisoned ``(name, key) -> reason`` entries."""
+    with _POISON_LOCK:
+        return dict(_POISONED)
 
 
 def inject_failure(name: str, on: bool = True) -> None:
@@ -115,7 +159,10 @@ def kernel_enabled(conf, name: str) -> bool:
         return False
     if not conf.is_op_enabled(_CONF_OF[name], default=True):
         return False
-    return DC.pallas_mode() is not None
+    mode = DC.pallas_mode()
+    if mode == "native":
+        return name not in NATIVE_REFUSED
+    return mode is not None
 
 
 def interpret() -> bool:
@@ -128,14 +175,14 @@ def is_oracle_fallback_error(exc: BaseException) -> bool:
     composition; False for the retry protocol's own traffic (OOM /
     split / chip failure must keep riding PR 4's state machine)."""
     from spark_rapids_tpu.retry import (TpuChipFailure, TpuRetryOOM,
-                                        _OOM_MARKERS)
+                                        is_oom_error)
     if isinstance(exc, (TpuRetryOOM, TpuChipFailure, KeyboardInterrupt,
                         SystemExit)):
         return False
-    msg = str(exc)
-    if any(m in msg for m in _OOM_MARKERS):
-        return False  # raw backend OOM: the retry wrappers translate it
-    return True
+    # raw backend HBM OOM: the retry wrappers translate it. A compile
+    # error that names VMEM is not one (retry.is_vmem_refusal): the
+    # kernel does not fit the core, and the oracle takes over
+    return not is_oom_error(exc)
 
 
 def count_dispatch(metrics, name: str) -> None:

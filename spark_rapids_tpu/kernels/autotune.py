@@ -61,12 +61,8 @@ _GRIDS: Dict[str, List[dict]] = {
 
 
 def _device_kind() -> str:
-    try:
-        import jax
-        d = jax.devices()[0]
-        return getattr(d, "device_kind", None) or d.platform
-    except Exception:
-        return "unknown"
+    import jax
+    return jax.devices()[0].device_kind
 
 
 def _bucket(cap: int) -> int:

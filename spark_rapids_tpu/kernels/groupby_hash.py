@@ -606,15 +606,13 @@ def _build_kernel_tiled(cap: int, K: int, n_add: int, n_min: int,
     out_specs.append(pl.BlockSpec((1, 1), lambda g, b: (g, 0)))
     out_shape.append(jax.ShapeDtypeStruct((LG, 1), jnp.bool_))
     scratch.append(pltpu.VMEM((1,), jnp.bool_))
-    kwargs = {}
-    if not interpret:
-        kwargs["compiler_params"] = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "arbitrary"))
     call = pl.pallas_call(kern, grid=(LG, nb), in_specs=in_specs,
                           out_specs=out_specs,
                           out_shape=tuple(out_shape),
                           scratch_shapes=scratch, interpret=interpret,
-                          **kwargs)
+                          compiler_params=pltpu.CompilerParams(
+                              dimension_semantics=("parallel",
+                                                   "arbitrary")))
 
     def wrapper(kw, h, valid, *lanes):
         args = [kw, h, valid]

@@ -30,10 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.4.35 re-exports shard_map at the top level
-    from jax import shard_map
-except ImportError:  # older jax: experimental home
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from spark_rapids_tpu.columnar.device import (
     AnyDeviceColumn, DeviceBatch, DeviceColumn, DeviceStringColumn,

@@ -72,6 +72,17 @@ _OOM_MARKERS = ("RESOURCE_EXHAUSTED", "Resource exhausted",
                 "Failed to allocate", "OOM")
 
 
+def is_vmem_refusal(e: BaseException) -> bool:
+    """A compile-time ``RESOURCE_EXHAUSTED`` that names VMEM: the
+    program (a Pallas kernel, in practice) does not fit the chip's
+    on-core vector memory. Spilling HBM or splitting the batch cannot
+    change a static kernel footprint, so this is a kernel refusal for
+    the oracle fallback (kernels.is_oracle_fallback_error), never
+    traffic for the retry ladder."""
+    s = str(e)
+    return "vmem" in s.lower() and any(m in s for m in _OOM_MARKERS)
+
+
 def is_oom_error(e: BaseException) -> bool:
     """Heuristic: does a raw backend error look like an HBM allocation
     failure (XLA surfaces RESOURCE_EXHAUSTED through generic
@@ -79,7 +90,7 @@ def is_oom_error(e: BaseException) -> bool:
     if isinstance(e, TpuRetryOOM):
         return True
     s = str(e)
-    return any(m in s for m in _OOM_MARKERS)
+    return any(m in s for m in _OOM_MARKERS) and not is_vmem_refusal(e)
 
 
 # ---------------------------------------------------------------------------

@@ -46,10 +46,8 @@ class TpuSparkSession:
         self.conf_obj = TpuConf(conf)
         self._owns_mesh = False
         if self.conf_obj.sql_enabled:
-            import spark_rapids_tpu
             from spark_rapids_tpu import device_manager
-            device_manager.initialize(self.conf_obj)
-            spark_rapids_tpu._enable_compile_cache()
+            device_manager.initialize()
             from spark_rapids_tpu.conf import (HAS_NANS,
                                                SHUFFLE_ICI_DEVICES,
                                                SHUFFLE_MODE)
