@@ -31,7 +31,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from spark_rapids_tpu import trace as _trace
 from spark_rapids_tpu.columnar.host import HostBatch, HostColumn
+from spark_rapids_tpu.jit_cache import JitCache, named_jit
 from spark_rapids_tpu.sql import types as T
 
 # Minimum capacity bucket: small enough for tests, large enough that op
@@ -40,9 +42,9 @@ MIN_CAPACITY = 64
 DEFAULT_CHAR_CAP = 32
 
 
-@jax.jit
-def _count_active(active: jax.Array) -> jax.Array:
-    return jnp.sum(active)
+# tpu-lint: disable=jit-direct(single fixed count program — jax's own signature cache bounds it by capacity bucket)
+_count_active = named_jit("srt_count_active",
+                          lambda active: jnp.sum(active))
 
 
 def bucket_capacity(n: int) -> int:
@@ -302,11 +304,14 @@ class DeviceBatch:
 
     def row_count(self) -> int:
         if self._num_rows is None:
-            if self._num_rows_dev is not None:
-                self._num_rows = int(np.asarray(self._num_rows_dev))
-            else:
-                # jitted: an EAGER jnp.sum pays a per-op dispatch
-                self._num_rows = int(_count_active(self.active))
+            # the host blocks here until the device has the value:
+            # counted as deviceSyncTime (site=rowCount)
+            with _trace.device_sync("rowCount"):
+                if self._num_rows_dev is not None:
+                    self._num_rows = int(np.asarray(self._num_rows_dev))
+                else:
+                    # jitted: an EAGER jnp.sum pays a per-op dispatch
+                    self._num_rows = int(_count_active(self.active))
         return self._num_rows
 
     def with_columns(self, schema: T.StructType,
@@ -390,8 +395,6 @@ def finish_to_host(token) -> HostBatch:
     return HostBatch(batch.schema, cols, len(idx))
 
 
-from spark_rapids_tpu.jit_cache import JitCache
-
 _FETCH_PACK_CACHE = JitCache("fetchPack")
 
 
@@ -415,7 +418,8 @@ def start_fetch(arrays: List[jax.Array]):
                 jnp.concatenate([arrs[i].reshape(-1) for i in idxs])
                 if len(idxs) > 1 else arrs[idxs[0]].reshape(-1)
                 for _dt, idxs in order)
-        cached = _FETCH_PACK_CACHE.put(key, (jax.jit(_fn), order))
+        cached = _FETCH_PACK_CACHE.put(
+            key, (named_jit("srt_fetch_pack", _fn), order))
     jfn, order = cached
     packed = jfn(*arrays)
     _prefetch_host(list(packed))
@@ -424,12 +428,15 @@ def start_fetch(arrays: List[jax.Array]):
 
 def finish_fetch(token) -> List[np.ndarray]:
     kind, arrays, extra = token
+    # blocking device->host reads: deviceSyncTime (site=fetch)
     if kind == "raw":
-        return [np.asarray(a) for a in arrays]
+        with _trace.device_sync("fetch"):
+            return [np.asarray(a) for a in arrays]
     order, packed = extra
+    with _trace.device_sync("fetch"):
+        bufs = [np.asarray(buf) for buf in packed]
     out: List[Optional[np.ndarray]] = [None] * len(arrays)
-    for (_dt, idxs), buf in zip(order, packed):
-        b = np.asarray(buf)
+    for (_dt, idxs), b in zip(order, bufs):
         off = 0
         for i in idxs:
             shape = arrays[i].shape
@@ -623,7 +630,7 @@ def concat_device(batches: Sequence[DeviceBatch]) -> DeviceBatch:
             total_t = offs[len(flats)]
             active = jnp.arange(cap) < total_t
             return active, tuple(outs)
-        fn = _CONCAT_CACHE.put(key, jax.jit(_fn))
+        fn = _CONCAT_CACHE.put(key, named_jit("srt_concat", _fn))
     counts_arr = jnp.asarray(np.asarray(counts, dtype=np.int64))
     all_flat = [a for flat in flats for a in flat]
     active, outs = fn(counts_arr, *all_flat)
@@ -801,6 +808,7 @@ def take_columns(columns: Sequence[AnyDeviceColumn], idx: jax.Array,
     return out
 
 
+@jax.named_scope("compact")
 def _compact_body(active: jax.Array, flat):
     """Stable compaction (active rows to the front): ONE 2-operand sort
     pass for the permutation + ONE fused lane-matrix gather for all
@@ -823,9 +831,9 @@ def _compact_body(active: jax.Array, flat):
     return new_active, tuple(outs)
 
 
-@jax.jit
-def _compact_arrays(active: jax.Array, *flat: jax.Array):
-    return _compact_body(active, flat)
+# tpu-lint: disable=jit-direct(single fixed compaction program — jax's own signature cache bounds it by shape set)
+_compact_arrays = named_jit(
+    "srt_compact", lambda active, *flat: _compact_body(active, flat))
 
 
 def flatten_columns(columns: Sequence[AnyDeviceColumn]
@@ -887,7 +895,7 @@ def _shrink_impl(batch: DeviceBatch, n: int, compact_first: bool
                 active, arrs = _compact_body(active, arrs)
             return active[:cap], tuple(
                 (a[:cap] if a.ndim == 1 else a[:cap, :]) for a in arrs)
-        fn = _SHRINK_CACHE.put(key, jax.jit(_fn))
+        fn = _SHRINK_CACHE.put(key, named_jit("srt_shrink", _fn))
     new_active, outs = fn(batch.active, *flat)
     return DeviceBatch(batch.schema, rebuild_columns(spec, outs),
                        new_active, n)

@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from spark_rapids_tpu import trace as _trace
 from spark_rapids_tpu.sql import types as T
 
 # layout entry kinds
@@ -246,7 +247,7 @@ def pack_batch(batch) -> Tuple[np.ndarray, List[np.ndarray], Tuple]:
 # Bounded LRU: every distinct (layout, n, cap, nbytes) compiles its own
 # decode program; long sessions with varying batch sizes must not retain
 # them all.
-from spark_rapids_tpu.jit_cache import JitCache
+from spark_rapids_tpu.jit_cache import JitCache, named_jit
 
 _DECODE_CACHE = JitCache("uploadDecode", capacity=64)
 
@@ -336,7 +337,7 @@ def _build_decode(layout: Tuple, n: int, cap: int) -> Callable:
                 outs.extend([_pad_cap(data, n, cap), validity])
         return active, tuple(outs)
 
-    return jax.jit(fn)
+    return named_jit("srt_upload_decode", fn)
 
 
 # Below this row count the packed codec's per-(layout, n, cap) decode
@@ -465,7 +466,6 @@ def finish_upload(staged, device: Optional[jax.Device] = None):
     """Device-side half: one device_put (+ one decode program on the
     packed and encoded paths). Traced per staging mode with the target
     chip, nested inside the R2C transition's copyToDeviceTime span."""
-    from spark_rapids_tpu import trace as _trace
     with _trace.span("finishUpload", mode=staged[0],
                      chip=(device.id if device is not None else None)):
         return finish_started(start_upload(staged, device))
@@ -513,6 +513,7 @@ def finish_started(token):
     fn = _DECODE_CACHE.get(key)
     if fn is None:
         fn = _DECODE_CACHE.put(key, _build_decode(layout, n, cap))
+    _trace.first_dispatch(None, fn)
     active, outs = fn(dev[0], *dev[1:])
     spec = [(f.data_type,
              3 if (D.is_string_like(f.data_type)
@@ -833,7 +834,7 @@ def _build_encoded_decode(layout: Tuple, cap: int) -> Callable:
     def fn(words, n_arr, *extras):
         return _encoded_decode_body(layout, cap, words, n_arr, extras)
 
-    return jax.jit(fn)
+    return named_jit("srt_decode", fn)
 
 
 def _chain_fn(layout, cap: int, nbytes: int):
@@ -868,6 +869,7 @@ def _finish_encoded_upload(token):
                     layout, cap, interpret=KR.interpret(),
                     char_chunk=char_chunk))
             KR.count_dispatch(metrics, "decodeFused")
+            _trace.first_dispatch(metrics, fn)
             with KR.dispatch_span("decodeFused", bucket=cap,
                                   tuned=bool(fuse.get("tuned"))):
                 active, outs = fn(dev[0], dev[1], *dev[2:])
@@ -883,6 +885,7 @@ def _finish_encoded_upload(token):
             active = outs = None
     if outs is None:
         fn = _chain_fn(layout, cap, nbytes)
+        _trace.first_dispatch(metrics, fn)
         active, outs = fn(dev[0], dev[1], *dev[2:])
     if metrics is not None:
         # programs-per-batch attribution for the fused A/B: the chain

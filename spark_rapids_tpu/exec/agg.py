@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from spark_rapids_tpu import metrics as M
+from spark_rapids_tpu import trace as TR
 from spark_rapids_tpu.columnar.device import (
     AnyDeviceColumn, DeviceBatch, DeviceColumn, concat_device, mask_col,
     shrink_to_bucket, slice_compacted_to_bucket, take_columns)
@@ -186,12 +187,15 @@ def is_device_agg(grouping: List[E.AttributeReference],
 # Compiled aggregation programs cached on structure so re-planned queries
 # (every collect() builds fresh exec instances) reuse XLA executables;
 # bounded LRU so long-running sessions can't grow it without limit.
-from spark_rapids_tpu.jit_cache import JitCache, mirror_to_metrics
+from spark_rapids_tpu.jit_cache import (JitCache, mirror_to_metrics,
+                                        named_jit, program_name,
+                                        program_of)
 
 _AGG_FN_CACHE = JitCache("agg")
 
 # tpu-lint: disable=jit-direct(single fixed count-stack program — one executable, bounded by construction)
-_stack_counts = jax.jit(lambda cs: jnp.stack(cs))
+_stack_counts = named_jit("srt_agg_stack_counts",
+                          lambda cs: jnp.stack(cs))
 
 
 class TpuHashAggregateExec(TpuExec):
@@ -300,7 +304,8 @@ class TpuHashAggregateExec(TpuExec):
                     prelude_steps, cols, active, prelude_lits)
             cap = active.shape[0]
             ctx = X.Ctx(cols, cap, all_exprs, lit_vals)
-            key_cols = [X.dev_eval(e, ctx) for e in key_bound]
+            with jax.named_scope("agg_inputs"):
+                key_cols = [X.dev_eval(e, ctx) for e in key_bound]
             # dedupe slot sources (sum(x) + avg(x) share x): each unique
             # expression is evaluated, sorted, and lane-packed ONCE
             uniq_srcs: List[E.Expression] = []
@@ -312,7 +317,8 @@ class TpuHashAggregateExec(TpuExec):
                     uniq_of[k] = len(uniq_srcs)
                     uniq_srcs.append(e)
                 src_map.append(uniq_of[k])
-            slot_vals = [X.dev_eval(e, ctx) for e in uniq_srcs]
+            with jax.named_scope("agg_inputs"):
+                slot_vals = [X.dev_eval(e, ctx) for e in uniq_srcs]
             if kernel_slots is not None:
                 # Pallas hash-table kernel (docs/kernels.md): one
                 # open-addressed insert/combine pass replaces the
@@ -396,8 +402,10 @@ class TpuHashAggregateExec(TpuExec):
             for e in aggregates:
                 if isinstance(e, E.Alias) and isinstance(
                         e.child, E.AggregateExpression):
-                    out_cols.append(dev_evaluate(
-                        e.child.func, by_alias[e.expr_id], out_active))
+                    with jax.named_scope("agg_result"):
+                        out_cols.append(dev_evaluate(
+                            e.child.func, by_alias[e.expr_id],
+                            out_active))
                 elif isinstance(e, E.AttributeReference):
                     out_cols.append(key_by_attr[e.expr_id])
                 elif isinstance(e, E.Alias) and isinstance(
@@ -406,7 +414,10 @@ class TpuHashAggregateExec(TpuExec):
                 else:
                     raise X.DeviceUnsupported(f"agg result expr {e!r}")
             return out_cols, out_active
-        return jax.jit(fn, donate_argnums=(0, 1) if donate else ())
+        return named_jit(
+            program_name("agg", mode,
+                         "kernel" if kernel_slots is not None else None),
+            fn, donate_argnums=(0, 1) if donate else ())
 
     def _out_desc(self) -> Tuple:
         """Structural descriptor of the result-column layout (what the
@@ -512,10 +523,8 @@ class TpuHashAggregateExec(TpuExec):
             lit_vals = (X.stage_literal_values(prelude_steps), lit_vals)
         cnt = None
         self.metrics.create(M.DISPATCH_COUNT, M.ESSENTIAL).add(1)
-        from spark_rapids_tpu import trace as TR
         from spark_rapids_tpu.parallel.mesh import record_chip_dispatch
         record_chip_dispatch(self.metrics, batch)
-        qt = TR._ACTIVE
         chip = TR.chip_of(batch)  # None (no device query) when untraced
         import time as _time
 
@@ -540,6 +549,14 @@ class TpuHashAggregateExec(TpuExec):
         fn, was_miss = _get_fn(kern_slots)
         mirror_to_metrics(_AGG_FN_CACHE, self.metrics, was_miss)
         ovf = None
+        TR.first_dispatch(self.metrics, fn)
+        # the ENQUEUE interval (jax dispatch is asynchronous). The
+        # annotation is opened by hand: it covers a failed kernel
+        # attempt too, while the host interval restarts at the fallback
+        ann = TR.annotation(
+            "TpuHashAggregateExec.dispatch", TR.scope_of(self.metrics),
+            attrs={"mode": mode, "program": program_of(fn)})
+        ann.__enter__()
         t0 = _time.perf_counter_ns()
         try:
             if kern_slots is not None:
@@ -566,15 +583,21 @@ class TpuHashAggregateExec(TpuExec):
             t0 = _time.perf_counter_ns()
             out_cols, out_active, cnt = fn(batch.columns, batch.active,
                                            lit_vals)
+        finally:
+            ann.__exit__(None, None, None)
         elapsed = _time.perf_counter_ns() - t0
+        qt = TR._ACTIVE
         if qt is not None:
             # the same measurement feeds computeAggTime/stageCompileTime
             # below — trace and metrics agree (docs/observability.md)
-            qt.add("TpuHashAggregateExec.dispatch", t0, t0 + elapsed,
-                   chip=chip, mode=mode, compile=bool(was_miss),
-                   **({"kernel": "groupbyHash",
-                       "bucket": batch.capacity, "tuned": kern_tuned}
-                      if kern_slots is not None else {}))
+            attrs = {"mode": mode, "compile": bool(was_miss),
+                     "program": program_of(fn)}
+            if kern_slots is not None:
+                attrs.update(kernel="groupbyHash", bucket=batch.capacity,
+                             tuned=kern_tuned)
+            TR.record(qt, "TpuHashAggregateExec.dispatch", t0,
+                      t0 + elapsed, TR.scope_of(self.metrics),
+                      chip=chip, attrs=attrs)
         if was_miss:
             # first call after a compile miss carries trace+XLA compile
             self.metrics.create(M.STAGE_COMPILE_TIME,
@@ -646,7 +669,8 @@ class TpuHashAggregateExec(TpuExec):
                 out, cnt, _ovf = R.with_retry(
                     lambda w=whole: self._aggregate_batch(w, mode="merge"),
                     self.conf, self.metrics)
-                out._num_rows = int(cnt)  # sizes the bucket slice
+                with TR.device_sync("aggMerge", self.metrics):
+                    out._num_rows = int(cnt)  # sizes the bucket slice
                 out = slice_compacted_to_bucket(out)
                 for h in chunk:
                     h.close()
@@ -715,7 +739,6 @@ class TpuHashAggregateExec(TpuExec):
         DOUBLED modulus, bounded by outOfCore.maxRecursion; past the
         bound the OOM-retry protocol is the backstop."""
         from spark_rapids_tpu import retry as R
-        from spark_rapids_tpu import trace as TR
         TR.instant("oocAggPlan", modulus=modulus, depth=depth)
         child_out = self.child.output
         bound = [E.bind_references(g, child_out) for g in self.grouping]
@@ -884,7 +907,8 @@ class TpuHashAggregateExec(TpuExec):
         # timed_wall: with taskParallelism > 1, several pool threads
         # drain concurrently; interval-union keeps the metric <= query
         # wall so the bench stage breakdown sums sensibly
-        with self.metrics.timed_wall("pipelineDrainTime"):
+        with self.metrics.timed_wall("pipelineDrainTime"), \
+                TR.device_sync("aggCounts", self.metrics):
             if prefetched:
                 counts = [int(np.asarray(c)) for _h, c, _o, _i in pending]
                 overflows = [o is not None and bool(np.asarray(o))
@@ -923,7 +947,8 @@ class TpuHashAggregateExec(TpuExec):
                         lambda piece: self._aggregate_batch(
                             piece, force_oracle=True),
                         self.conf, self.metrics):
-                    b2._num_rows = int(np.asarray(cnt2))
+                    with TR.device_sync("aggCounts", self.metrics):
+                        b2._num_rows = int(np.asarray(cnt2))
                     b2 = slice_compacted_to_bucket(b2)
                     shrunk.append(self.register_spillable(store, b2))
                 continue

@@ -178,6 +178,7 @@ class TpuRowToColumnarExec(TpuExec):
 
             q: "_q.Queue" = _q.Queue(maxsize=depth)
             stop = threading.Event()
+            scope = _trace.scope_of(metrics)
 
             def put_bounded(item) -> bool:
                 while not stop.is_set():
@@ -198,18 +199,15 @@ class TpuRowToColumnarExec(TpuExec):
                         # decodeTime>wall audit applies to these
                         # threads too), mirrored as a scanPrefetch span
                         m = metrics.create("scanPrefetchTime")
-                        qt = _trace._ACTIVE
-                        t0 = _time.perf_counter_ns()
-                        m.enter_wall()
-                        try:
-                            prep = self._prepare(payload, metrics)
-                        finally:
-                            m.exit_wall()
-                            if qt is not None:
-                                qt.add("scanPrefetch", t0,
-                                       _time.perf_counter_ns(),
-                                       chip=(device.id if device
-                                             is not None else None))
+                        with _trace.span(
+                                "scanPrefetch", scope=scope,
+                                chip=(device.id if device is not None
+                                      else None)):
+                            m.enter_wall()
+                            try:
+                                prep = self._prepare(payload, metrics)
+                            finally:
+                                m.exit_wall()
                         return put_bounded(("batch", prep))
 
                     pending: List[HostBatch] = []
@@ -251,7 +249,11 @@ class TpuRowToColumnarExec(TpuExec):
                     put_bounded(("error", err) if err is not None
                                 else ("done",))
 
-            t = threading.Thread(target=producer, daemon=True,
+            def producer_scoped() -> None:
+                with _trace.attach(scope):
+                    producer()
+
+            t = threading.Thread(target=producer_scoped, daemon=True,
                                  name="srt-scan-prefetch")
             t.start()
             ring: List = []

@@ -25,11 +25,14 @@ from spark_rapids_tpu.sql import types as T
 
 import jax
 
+from spark_rapids_tpu.jit_cache import named_jit
+
 # row counters are DEVICE int64 scalars created via T.device_long —
 # a bare jnp.int64 would silently truncate to int32 without x64 and
 # wrap past 2^31 rows; the explicit dtype= keeps the jitted sum wide
 # tpu-lint: disable=jit-direct(single fixed row-counter program — one executable, bounded by construction)
-_advance_rows = jax.jit(
+_advance_rows = named_jit(
+    "srt_advance_rows",
     lambda start, active: start + jnp.sum(active, dtype=jnp.int64))
 
 
@@ -132,21 +135,23 @@ class TpuFilterExec(TpuExec):
         return f"TpuFilter {self.condition!r}"
 
 
-from functools import partial
-
-
-@partial(jax.jit, static_argnums=(4,))
-def _range_chunk(start, off, step, n, cap):
+def _range_chunk_body(start, off, step, n, cap):
     idx = jnp.arange(cap, dtype=jnp.int64)
     data = start + (off + idx) * step
     active = idx < n
     return jnp.where(active, data, jnp.int64(0)), active
 
 
-@jax.jit
-def _limit_mask(active, remaining):
+def _limit_mask_body(active, remaining):
     rank = jnp.cumsum(active.astype(jnp.int32))
     return active & (rank <= remaining)
+
+
+# tpu-lint: disable=jit-direct(two fixed helper programs — jax's own signature cache bounds them by capacity bucket)
+_range_chunk = named_jit("srt_range_chunk", _range_chunk_body,
+                         static_argnums=(4,))
+# tpu-lint: disable=jit-direct(two fixed helper programs — jax's own signature cache bounds them by capacity bucket)
+_limit_mask = named_jit("srt_limit_mask", _limit_mask_body)
 
 
 class TpuRangeExec(TpuExec):

@@ -17,7 +17,6 @@ from __future__ import annotations
 
 import os
 import threading
-from functools import partial
 from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
 import jax
@@ -36,7 +35,7 @@ from spark_rapids_tpu.sql import expressions as E
 from spark_rapids_tpu.sql import physical as P
 from spark_rapids_tpu.sql import types as T
 
-from spark_rapids_tpu.jit_cache import JitCache
+from spark_rapids_tpu.jit_cache import JitCache, named_jit
 
 _PID_CACHE = JitCache("exchangePid")
 _SORT_CACHE = JitCache("exchangeSort")
@@ -72,7 +71,9 @@ def hash_partition_ids(exprs: List[E.Expression], batch: DeviceBatch,
                 return hashing.traced_partition_ids(
                     exprs, cols, active, lit_vals, num_partitions,
                     use_kernel=kernel_on)
-            fn = _PID_CACHE.put(key, jax.jit(_fn))
+            fn = _PID_CACHE.put(key, named_jit(
+                "srt_exchange_pid_kernel" if kernel_on
+                else "srt_exchange_pid", _fn))
         return fn
 
     lits = X.literal_values(exprs)
@@ -91,11 +92,15 @@ def hash_partition_ids(exprs: List[E.Expression], batch: DeviceBatch,
     return _get(False)(batch.columns, batch.active, lits)
 
 
-@partial(jax.jit, static_argnums=(2,))
-def _round_robin_pids(active: jax.Array, start: jax.Array,
+def _round_robin_body(active: jax.Array, start: jax.Array,
                       n: int) -> jax.Array:
     rank = jnp.cumsum(active.astype(jnp.int32)) - 1
     return jnp.mod(rank + start, n).astype(jnp.int32)
+
+
+# tpu-lint: disable=jit-direct(single fixed round-robin program — jax's own signature cache bounds it by capacity bucket)
+_round_robin_pids = named_jit("srt_exchange_round_robin",
+                              _round_robin_body, static_argnums=(2,))
 
 
 def range_key_columns(order: List[E.Expression],
@@ -115,7 +120,8 @@ def range_key_columns(order: List[E.Expression],
             cap = active.shape[0]
             ctx = X.Ctx(cols, cap, bound_t, lit_vals)
             return tuple(X.dev_eval(e, ctx).arrays() for e in bound_t)
-        fn = _RANGE_PID_CACHE.put(key, jax.jit(_fn))
+        fn = _RANGE_PID_CACHE.put(
+            key, named_jit("srt_exchange_range_keys", _fn))
     arrs = fn(batch.columns, batch.active, X.literal_values(bound))
     return [make_column(e.data_type, a) for e, a in zip(bound, arrs)]
 
@@ -182,7 +188,8 @@ def global_range_pids(order: List[E.Expression],
                 outs.append(pids[off:off + a.shape[0]])
                 off += a.shape[0]
             return tuple(outs)
-        fn = _RANGE_RANK_CACHE.put(key, jax.jit(_fn))
+        fn = _RANGE_RANK_CACHE.put(
+            key, named_jit("srt_exchange_range_rank", _fn))
     return list(fn(tuple(tuple(kc) for kc in keycols_per_batch),
                    tuple(actives)))
 
@@ -211,9 +218,12 @@ def split_by_pid(batch: DeviceBatch, pids: jax.Array, n: int
                                      side="left")
             counts = edges[1:] - edges[:-1]
             return counts, tuple(sorted_arrs)
-        sort_fn = _SORT_CACHE.put(skey, jax.jit(_sort))
+        sort_fn = _SORT_CACHE.put(
+            skey, named_jit("srt_exchange_split_sort", _sort))
     counts_d, sorted_flat = sort_fn(pids, batch.active, *flat)
-    counts = np.asarray(counts_d)
+    from spark_rapids_tpu import trace as TR
+    with TR.device_sync("exchangeSplit"):
+        counts = np.asarray(counts_d)
     offsets = np.concatenate([[0], np.cumsum(counts)])
 
     out: List[Optional[DeviceBatch]] = []
@@ -240,7 +250,8 @@ def split_by_pid(batch: DeviceBatch, pids: jax.Array, n: int
                                       jnp.zeros((), dtype=g.dtype))
                     outs.append(g)
                 return new_active, tuple(outs)
-            ext_fn = _EXTRACT_CACHE.put(ekey, jax.jit(_extract))
+            ext_fn = _EXTRACT_CACHE.put(
+                ekey, named_jit("srt_exchange_extract", _extract))
         new_active, outs = ext_fn(
             T.device_long(offsets[pid]), T.device_long(cnt), *sorted_flat)
         out.append(DeviceBatch(batch.schema, rebuild_columns(spec, outs),
@@ -362,6 +373,9 @@ class TpuShuffleExchangeExec(TpuExec):
         n_threads = self._task_threads()
         sem = get_semaphore(self.conf)
 
+        from spark_rapids_tpu import trace as TR
+        scope = TR.scope_of(self.metrics)
+
         def pull(thunk):
             try:
                 # bill the drain thread's permit wait to the EXCHANGE
@@ -369,7 +383,8 @@ class TpuShuffleExchangeExec(TpuExec):
                 # inside the child's R2C books it against the upload,
                 # hiding exchange-drain contention from the breakdown
                 sem.acquire_if_necessary(self.metrics)
-                return [split_one(b) for b in thunk()]
+                with TR.attach(scope):
+                    return [split_one(b) for b in thunk()]
             finally:
                 # pool threads acquire the TpuSemaphore inside the child
                 # pipeline (R2C upload) but never reach a root C2R —

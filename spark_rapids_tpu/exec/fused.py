@@ -49,7 +49,8 @@ from spark_rapids_tpu.exec.base import (DevicePartitionThunk, TpuExec,
                                         device_channel)
 from spark_rapids_tpu.exec.basic import (TpuFilterExec, TpuProjectExec,
                                          TpuRangeExec)
-from spark_rapids_tpu.jit_cache import JitCache, mirror_to_metrics
+from spark_rapids_tpu.jit_cache import (JitCache, mirror_to_metrics,
+                                        program_of)
 from spark_rapids_tpu.ops import exprs as X
 from spark_rapids_tpu.sql import expressions as E
 from spark_rapids_tpu.sql import physical as P
@@ -167,8 +168,6 @@ class TpuFusedStageExec(TpuExec):
         bseq = itertools.count()  # thread-safe-enough batch ids (GIL)
 
         def run_one(b: DeviceBatch) -> DeviceBatch:
-            import time as _time
-
             from spark_rapids_tpu import trace as TR
             # per-batch: a batch whose pytree repeats a buffer (range
             # validity aliasing active) must use the non-donating
@@ -179,7 +178,6 @@ class TpuFusedStageExec(TpuExec):
             # read their placement
             from spark_rapids_tpu.parallel.mesh import record_chip_dispatch
             record_chip_dispatch(metrics, b)
-            qt = TR._ACTIVE
             chip = TR.chip_of(b)  # None (no device query) when untraced
             fn, was_miss = _STAGE_CACHE.get_or_build(
                 (skey, donate), lambda: X.build_stage_fn(steps, donate))
@@ -187,16 +185,18 @@ class TpuFusedStageExec(TpuExec):
             lits = stage_lits
             nrows = None if has_filter else b._num_rows
             nrows_dev = None if has_filter else b._num_rows_dev
-            t0 = _time.perf_counter_ns()
-            cols, active, err = fn(b.columns, b.active, lits)
-            t1 = _time.perf_counter_ns()
-            elapsed = t1 - t0
-            # the SAME measurement feeds the metric channel and the
-            # trace span — one set of numbers (docs/observability.md)
-            if qt is not None:
-                qt.add("TpuFusedStageExec.dispatch", t0, t1,
-                       batch=next(bseq), chip=chip, stage=stage_label,
-                       compile=bool(was_miss))
+            TR.first_dispatch(metrics, fn)
+            # the ENQUEUE of the program (jax dispatch is asynchronous:
+            # the device's own time is in the profiler's device planes,
+            # under the program's name). The SAME measurement feeds the
+            # metric channel, the trace span and the profiler
+            # annotation — one set of numbers (docs/observability.md)
+            with TR.span("TpuFusedStageExec.dispatch", metrics=metrics,
+                         batch=next(bseq), chip=chip, stage=stage_label,
+                         compile=bool(was_miss),
+                         program=program_of(fn)) as sp:
+                cols, active, err = fn(b.columns, b.active, lits)
+            elapsed = sp.t1 - sp.t0
             # a miss's first call carries trace+XLA-compile on top of
             # the dispatch: book it as compile wall; otherwise the wall
             # is fanned back to the constituents ONLY (the fused node

@@ -34,7 +34,8 @@ from spark_rapids_tpu.sql import types as T
 # bounded LRU like every other structural jit cache (jit_cache.py);
 # the raw module dict it replaces grew one pinned XLA executable per
 # distinct (shape-set, flags) forever
-from spark_rapids_tpu.jit_cache import JitCache, mirror_to_metrics
+from spark_rapids_tpu.jit_cache import (JitCache, mirror_to_metrics,
+                                        named_jit)
 
 _GEN_CACHE = JitCache("generate")
 
@@ -90,7 +91,7 @@ class TpuGenerateExec(TpuExec):
             key = (shapes, tuple(repr(dt) for dt, _ in spec), ordinal,
                    position, outer)
             fn, was_miss = _GEN_CACHE.get_or_build(
-                key, lambda: jax.jit(self._build_fn(
+                key, lambda: named_jit("srt_generate", self._build_fn(
                     spec, ordinal, position, outer)))
             mirror_to_metrics(_GEN_CACHE, metrics, was_miss)
             active_out, outs = fn(b.active, *flat)

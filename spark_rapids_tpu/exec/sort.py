@@ -37,7 +37,7 @@ from spark_rapids_tpu.ops import sort as S
 from spark_rapids_tpu.sql import expressions as E
 from spark_rapids_tpu.sql import physical as P
 
-from spark_rapids_tpu.jit_cache import JitCache
+from spark_rapids_tpu.jit_cache import JitCache, named_jit, program_name
 
 _SORT_FN_CACHE = JitCache("sort")
 
@@ -95,7 +95,10 @@ def sorted_batch(order: List[E.SortOrder], bound: List[E.Expression],
             out = [mask_col(c, new_active).arrays()
                    for c in rebuild_columns(spec, sorted_flat)]
             return out, new_active
-        fn = _SORT_FN_CACHE.put(key, jax.jit(_fn))
+        fn = _SORT_FN_CACHE.put(key, named_jit(
+            "srt_topn" if limit >= 0 else "srt_sort", _fn))
+    from spark_rapids_tpu import trace as TR
+    TR.first_dispatch(None, fn)
     arrs, new_active = fn(batch.columns, batch.active,
                           X.literal_values(bound))
     from spark_rapids_tpu.columnar.device import make_column

@@ -44,7 +44,7 @@ from spark_rapids_tpu.sql import expressions as E
 from spark_rapids_tpu.sql import physical as P
 from spark_rapids_tpu.sql import types as T
 
-from spark_rapids_tpu.jit_cache import JitCache
+from spark_rapids_tpu.jit_cache import JitCache, named_jit
 
 _WINDOW_FN_CACHE = JitCache("window")
 
@@ -755,7 +755,7 @@ def _build_window_fn(part_bound: Tuple[E.Expression, ...],
                 outs.append(((_to_orig(inv, d),),
                              _to_orig(inv, v)))
         return outs
-    return jax.jit(fn)
+    return named_jit("srt_window", fn)
 
 
 class TpuWindowExec(TpuExec):

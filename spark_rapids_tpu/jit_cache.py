@@ -18,6 +18,7 @@ returns the whole registry for the bench's JSON detail.
 from __future__ import annotations
 
 import os
+import re
 import threading
 from collections import OrderedDict
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -27,6 +28,62 @@ from typing import Any, Callable, Dict, Optional, Tuple
 # of distinct plan shapes cannot pin unbounded executables.
 DEFAULT_CAPACITY = int(os.environ.get(
     "SPARK_RAPIDS_TPU_JIT_CACHE_CAPACITY", "256"))
+
+PROGRAM_NAME_MAX = 48
+_NAME_OK = re.compile(r"[A-Za-z0-9_]+\Z")
+
+
+def program_name(family: str, *tags) -> str:
+    """``srt_<family>[_<tag>...]``: the name a device program carries
+    in XLA (module ``jit_srt_...``), in profiler traces and in compile
+    spans. At most 48 characters of ``[A-Za-z0-9_]``. Every tag must be
+    a function of the program's STRUCTURAL key and nothing else — no
+    literal value, capacity, hash(), counter or address: JAX's
+    persistent compilation cache keys on the module name, so a name
+    that varies from process to process misses there every time."""
+    parts = [family] + [str(t) for t in tags if t not in (None, "")]
+    name = "srt_" + re.sub(r"[^A-Za-z0-9]+", "_", "_".join(parts))
+    return name[:PROGRAM_NAME_MAX].rstrip("_")
+
+
+def named_jit(name: str, fn: Callable, **jit_kwargs) -> Callable:
+    """``jax.jit(fn, **jit_kwargs)`` under a stable program name: sets
+    ``fn.__name__``/``__qualname__`` (what XLA names the module after)
+    and returns the jitted function itself — no wrapper around the
+    call, so a dispatch costs what ``jax.jit`` costs. The one way the
+    package builds a device program (the ``jit-direct`` lint rule
+    holds every other ``jax.jit`` to a reasoned suppression). The name
+    is readable back as ``program_of(jitted)``."""
+    if len(name) > PROGRAM_NAME_MAX or not name.startswith("srt_") \
+            or _NAME_OK.match(name) is None:
+        raise ValueError(
+            f"program name {name!r}: want srt_<family>[_<tag>], at most "
+            f"{PROGRAM_NAME_MAX} characters of [A-Za-z0-9_]")
+    import jax
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn, **jit_kwargs)
+
+
+def program_of(jitted) -> Optional[str]:
+    """The ``named_jit`` name of a jitted callable (None for anything
+    else): what dispatch spans and ``firstDispatch`` record as
+    ``program=``."""
+    name = getattr(jitted, "__name__", None)
+    return name if isinstance(name, str) and name.startswith("srt_") \
+        else None
+
+
+def program_in(value) -> Optional[str]:
+    """The program name of a cache value: a jitted callable, or a
+    tuple holding one (fetchPack keeps ``(fn, order)``)."""
+    if isinstance(value, tuple):
+        for v in value:
+            name = program_of(v)
+            if name is not None:
+                return name
+        return None
+    return program_of(value)
+
 
 _CACHES: Dict[str, "JitCache"] = {}
 # non-JitCache stat sources (the kernel autotuner's warm-table) that
@@ -123,9 +180,14 @@ class JitCache:
             from spark_rapids_tpu import trace as _trace
             qt = _trace._ACTIVE
             if qt is not None:
+                # host stream only: the interval ran get -> put with
+                # no block to annotate (get_or_build's does both)
                 import time
-                qt.add("compile", pending[0], time.perf_counter_ns(),
-                       cache=self.name)
+                _trace.record(qt, "compile", pending[0],
+                              time.perf_counter_ns(),
+                              _trace.current_scope(),
+                              attrs={"cache": self.name,
+                                     "program": program_in(value)})
         with self._lock:
             self._data[key] = value
             self._data.move_to_end(key)
@@ -143,8 +205,6 @@ class JitCache:
         OUTSIDE the lock (tracing can be slow and may re-enter other
         caches). If a build raises, its waiters re-race: one becomes
         the new builder, so a transient failure never wedges the key."""
-        import time
-
         from spark_rapids_tpu import trace as _trace
         while True:
             wait_ev = None
@@ -167,17 +227,14 @@ class JitCache:
             # of waiting the build out (the builder is unaffected)
             from spark_rapids_tpu.lifecycle import cancellable_wait
             cancellable_wait(wait_ev, site="jitWait")
-        t0 = time.perf_counter_ns()
         try:
-            val = build()
+            with _trace.span("compile", cache=self.name) as sp:
+                val = build()
+                sp.attrs["program"] = program_in(val)
             with self._lock:
                 self._data[key] = val
                 self._data.move_to_end(key)
                 self._evict_locked()
-            qt = _trace._ACTIVE
-            if qt is not None:
-                qt.add("compile", t0, time.perf_counter_ns(),
-                       cache=self.name)
             return val, True
         finally:
             with self._lock:

@@ -148,4 +148,5 @@ def build_fused_decode(layout: Tuple, cap: int, *, interpret: bool,
                 di += step[1]
         return active, tuple(outs)
 
-    return jax.jit(fn)
+    from spark_rapids_tpu.jit_cache import named_jit
+    return named_jit("srt_decode_fused", fn)

@@ -214,6 +214,20 @@ def resolve_path(fctx: FileCtx, expr: ast.AST) -> Optional[str]:
     return f"{base}.{rest}" if rest else base
 
 
+def jit_traced_arg(fctx: FileCtx, call: ast.Call
+                   ) -> Tuple[bool, Optional[ast.AST]]:
+    """``(is_jit, traced function expr)`` for a call that builds a
+    device program: ``jax.jit(fn, ...)`` or the package's
+    ``jit_cache.named_jit(name, fn, ...)`` (the same jit under a
+    stable program name — every jit rule treats the two alike)."""
+    p = resolve_path(fctx, call.func)
+    if p == "jax.jit":
+        return True, (call.args[0] if call.args else None)
+    if p is not None and (p == "named_jit" or p.endswith(".named_jit")):
+        return True, (call.args[1] if len(call.args) > 1 else None)
+    return False, None
+
+
 def call_args(call: ast.Call) -> List[ast.AST]:
     return list(call.args) + [kw.value for kw in call.keywords]
 

@@ -281,7 +281,7 @@ def donated_positions(fctx: A.FileCtx,
     the donating instantiation); pallas donation is the keys of an
     ``input_output_aliases`` dict."""
     p = A.resolve_path(fctx, call.func)
-    if p == "jax.jit":
+    if A.jit_traced_arg(fctx, call)[0]:
         for kw in call.keywords:
             if kw.arg == "donate_argnums":
                 ints = {n.value for n in ast.walk(kw.value)
@@ -631,14 +631,16 @@ def traced_roots(pctx, cg: CallGraph
                 assigns[node.targets[0].id] = node.value
         for call in A.file_calls(fctx):
             p = A.resolve_path(fctx, call.func)
-            is_jit = p == "jax.jit"
+            is_jit, traced = A.jit_traced_arg(fctx, call)
             is_pallas = p is not None and (p == "pallas_call"
                                            or p.endswith(".pallas_call"))
-            if not (is_jit or is_pallas) or not call.args:
+            if is_pallas and call.args:
+                traced = call.args[0]
+            if not (is_jit or is_pallas) or traced is None:
                 continue
             what = "pl.pallas_call" if is_pallas else "jax.jit"
             for fctx2, node in _resolve_traced_arg(fctx, cg, assigns,
-                                                   call.args[0], 0):
+                                                   traced, 0):
                 yield fctx2, node, what
 
 

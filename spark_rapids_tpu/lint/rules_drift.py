@@ -16,7 +16,8 @@ runtime lints; docs/observability.md).
                   expression of a ``with`` (an unclosed span corrupts
                   the B/E nesting of the whole lane).
 ``span-kind``   — every LITERAL span/instant kind recorded in the
-                  package (``trace.span``/``trace.instant`` calls, and
+                  package (``trace.span``/``trace.instant``/
+                  ``trace.annotation``/``trace.record`` calls, and
                   the ``qt.add``/``qt.mark`` convention over the
                   active trace) must appear in trace.py's
                   ``SPAN_CATALOG``/``INSTANT_CATALOG``, so flight-
@@ -259,10 +260,11 @@ def check_span_kinds(pctx):
     if span_kinds is None or instant_kinds is None:
         return  # no catalogs in this tree (fixture runs)
 
-    def _literal(call) -> Optional[str]:
-        if call.args and isinstance(call.args[0], ast.Constant) \
-                and isinstance(call.args[0].value, str):
-            return call.args[0].value
+    def _literal(call, at: int = 0) -> Optional[str]:
+        if len(call.args) > at \
+                and isinstance(call.args[at], ast.Constant) \
+                and isinstance(call.args[at].value, str):
+            return call.args[at].value
         return None
 
     for fctx in pctx.files:
@@ -270,11 +272,17 @@ def check_span_kinds(pctx):
             continue
         for call in A.file_calls(fctx):
             tail = A.call_tail(call)
-            if tail in ("span", "instant"):
+            at = 0  # position of the kind among the arguments
+            if tail in ("span", "instant", "annotation", "record"):
+                # trace.span / trace.instant, the profiler-only
+                # trace.annotation, and trace.record(qt, kind, ...)
+                # for an interval that is already over
                 if not isinstance(call.func, ast.Attribute) or \
                         A.resolve_path(fctx, call.func.value) != trace_mod:
                     continue
-                catalog = span_kinds if tail == "span" else instant_kinds
+                catalog = (instant_kinds if tail == "instant"
+                           else span_kinds)
+                at = 1 if tail == "record" else 0
             elif tail in ("add", "mark"):
                 # the package convention: `qt = trace._ACTIVE` (or the
                 # metrics-module mirror) — literal kinds recorded
@@ -287,7 +295,7 @@ def check_span_kinds(pctx):
                 catalog = span_kinds if tail == "add" else instant_kinds
             else:
                 continue
-            kind = _literal(call)
+            kind = _literal(call, at)
             if kind is None or kind in catalog:
                 continue
             which = ("SPAN_CATALOG" if catalog is span_kinds

@@ -1,6 +1,9 @@
 """Rule family 2 — compile discipline (docs/fusion.md, PR 2/7).
 
-``jit-direct``: every ``jax.jit(...)`` outside ``jit_cache.py`` must be
+``jit-direct``: every ``jax.jit(...)`` — and every
+``jit_cache.named_jit(name, fn, ...)``, the same jit under a stable
+``srt_`` program name, which is how the package builds its programs —
+outside ``jit_cache.py`` must be
 routed through a bounded single-flight ``JitCache`` — either lexically
 inside the value argument of ``<cache>.put(key, ...)``, or inside a
 builder reachable from a ``get_or_build`` / ``.put`` call (closed
@@ -31,7 +34,7 @@ from spark_rapids_tpu.lint.engine import Finding, rule
 
 
 def _is_jax_jit(fctx: A.FileCtx, call: ast.Call) -> bool:
-    return A.resolve_path(fctx, call.func) == "jax.jit"
+    return A.jit_traced_arg(fctx, call)[0]
 
 
 def _is_pallas_call(fctx: A.FileCtx, call: ast.Call) -> bool:
@@ -158,13 +161,13 @@ def check_jit_direct(pctx):
                      for a in [call] + list(A.ancestors(call)))
             if ok:
                 continue
-            what = "pl.pallas_call" if is_pallas else "jax.jit"
+            what = "pl.pallas_call" if is_pallas else "jax.jit/named_jit"
             yield Finding(
                 "jit-direct", fctx.rel, call.lineno,
                 call.col_offset + 1,
                 f"direct {what} outside the JitCache path — compile "
                 "via a bounded JitCache (get_or_build or "
-                "cache.put(key, jax.jit(fn)))"
+                "cache.put(key, named_jit(name, fn)))"
                 + (", or move the kernel into the kernels/ registry "
                    "package" if is_pallas else "")
                 + ", or suppress with a reason if the program is "

@@ -112,18 +112,9 @@ def stamp_plan_tenant(physical, tenant: Optional[str]) -> None:
     if tenant is None:
         return
 
-    def walk(p) -> None:
-        m = getattr(p, "metrics", None)
-        if m is not None:
-            m._tenant = tenant
-        for op in getattr(p, "fused_ops", []):
-            fm = getattr(op, "metrics", None)
-            if fm is not None:
-                fm._tenant = tenant
-        for c in getattr(p, "children", []):
-            walk(c)
-
-    walk(physical)
+    from spark_rapids_tpu.metrics import plan_registries
+    for reg in plan_registries(physical):
+        reg._tenant = tenant
 
 
 class _State:

@@ -5,11 +5,12 @@ Three verbosity levels (ESSENTIAL/MODERATE/DEBUG) gated by
 surfaced by ``TpuExec.metrics``. Timers are wall-clock nanoseconds.
 
 Every ``timed``/``timed_wall`` scope also mirrors its interval into the
-active span tracer (spark_rapids_tpu/trace.py) as a span named
-``<owner>.<metric>`` — the trace, the event log, and the profiler read
-the SAME measurement, so the three can never disagree
-(docs/observability.md). When tracing is off the mirror is a single
-module-global None check.
+span tracer (spark_rapids_tpu/trace.py) as a span named
+``<owner>.<metric>`` under the registry's query id — the trace, the
+event log, the profile artifact and a ``jax.profiler`` session read the
+SAME measurement, so they can never disagree (docs/observability.md).
+With no sink and no profiler on, the mirror is a ``TraceAnnotation``'s
+flag test and one module-global None check.
 """
 
 from __future__ import annotations
@@ -68,6 +69,11 @@ DEVICE_DECODE_OOM_FALLBACKS = "deviceDecodeOomFallbacks"  # encoded-upload
 PLANNED_PARTITIONS = "plannedPartitions"  # spill-backed partitions planned
 BUDGET_PRESSURE_PEAK = "budgetPressurePeak"  # worst estimate/share ratio
 PLANNED_OOC_ESCALATIONS = "plannedOutOfCoreEscalations"  # re-plans
+# per-query host intervals no operator timer covers (trace.QueryScope's
+# own registry, owner "Query"; docs/observability.md)
+PLAN_TIME = "planTime"                    # parse + rewrite, calling thread
+FIRST_DISPATCH_TIME = "firstDispatchTime"  # query begin -> first enqueue
+DEVICE_SYNC_TIME = "deviceSyncTime"       # blocked reading device values
 
 
 # ---------------------------------------------------------------------------
@@ -124,6 +130,15 @@ METRIC_DESCRIPTIONS: Dict[str, str] = {
                              "escalated (re-partitioned at a doubled "
                              "modulus) after a partition still "
                              "overflowed its budget share",
+    PLAN_TIME: "host planning wall on the calling thread (ns): SQL "
+               "parse, analysis, overrides, plan cache, fingerprints, up "
+               "to execute_collect — once per query",
+    FIRST_DISPATCH_TIME: "ns from the query's begin to its first "
+                         "enqueue of any device program — once per query",
+    DEVICE_SYNC_TIME: "thread-ns the host spent blocked reading a "
+                      "device value back (row counts, fetches, "
+                      "aggregate counts, join sizes); summed over task "
+                      "threads",
     # ad-hoc keys registered inline by individual operators
     "pipelineDrainTime": "wall where the partial agg drained the async "
                          "upstream pipeline (interval union)",
@@ -330,6 +345,9 @@ class MetricRegistry:
         self.owner = owner
         self.epoch = _EPOCH
         self._lock = threading.Lock()
+        # the executing query (trace.QueryScope), stamped by
+        # execute_plan on every registry of the plan (trace.stamp_plan)
+        self._query = None
         _REGISTRIES.add(self)
         weakref.finalize(self, _retire_metrics, self.metrics)
 
@@ -345,6 +363,7 @@ class MetricRegistry:
         r.owner = self.owner
         r.epoch = _EPOCH
         r._lock = threading.Lock()
+        r._query = None
         _REGISTRIES.add(r)
         weakref.finalize(r, _retire_metrics, r.metrics)
         for k, m in self.metrics.items():
@@ -370,19 +389,15 @@ class MetricRegistry:
     def _span_kind(self, name: str) -> str:
         return f"{self.owner}.{name}" if self.owner else name
 
-    @contextlib.contextmanager
     def timed(self, name: str, level: int = MODERATE,
-              **attrs) -> Iterator[None]:
-        m = self.create(name, level)
-        qt = _trace._ACTIVE
-        t0 = time.perf_counter_ns()
-        try:
-            yield
-        finally:
-            t1 = time.perf_counter_ns()
-            m.add(t1 - t0)
-            if qt is not None:
-                qt.add(self._span_kind(name), t0, t1, **attrs)
+              **attrs) -> "_trace.span":
+        """``with metrics.timed(name):`` — the interval goes to the
+        metric, to a ``<owner>.<name>`` span of the open trace sink and
+        to the profiler's trace (trace.span), under this registry's
+        query."""
+        # tpu-lint: disable=span-scope(factory — every caller opens the returned span in its own with-statement)
+        return _trace.span(self._span_kind(name), metrics=self,
+                           timer=self.create(name, level), **attrs)
 
     @contextlib.contextmanager
     def timed_wall(self, name: str, level: int = MODERATE,
@@ -395,24 +410,58 @@ class MetricRegistry:
         THREAD's interval — the trace shows per-thread lanes, the
         metric their union."""
         m = self.create(name, level)
-        qt = _trace._ACTIVE
-        t0 = time.perf_counter_ns()
-        m.enter_wall()
-        try:
-            yield
-        finally:
-            m.exit_wall()
-            if qt is not None:
-                qt.add(self._span_kind(name), t0,
-                       time.perf_counter_ns(), **attrs)
+        with _trace.span(self._span_kind(name), metrics=self, **attrs):
+            m.enter_wall()
+            try:
+                yield
+            finally:
+                m.exit_wall()
 
     def snapshot(self) -> Dict[str, int]:
         return {k: m.value for k, m in self.metrics.items()}
 
 
+def plan_registries(physical) -> Iterator["MetricRegistry"]:
+    """Every metric registry of a physical plan: each node's, its
+    fused constituents' and its children's (the walk the tenant and
+    query stamps share)."""
+    ms = getattr(physical, "metrics", None)
+    if ms is not None:
+        yield ms
+    for op in getattr(physical, "fused_ops", []):
+        fm = getattr(op, "metrics", None)
+        if fm is not None:
+            yield fm
+    for c in getattr(physical, "children", []):
+        yield from plan_registries(c)
+
+
+_QUERY_REGISTRY = MetricRegistry(owner="Query")
+
+
+def query_registry() -> "MetricRegistry":
+    """The process-lifetime registry (owner ``Query``) of the host
+    intervals no operator owns — planTime, firstDispatchTime,
+    deviceSyncTime. ONE registry for every query, not one each: the
+    process totals are what is read (the benchmark, the Prometheus
+    endpoint), the per-query view is the spans' ``q``; and a registry
+    that died with each query would leave the totals a gap between its
+    death and its finalizer (a scrape in between reads low). A sync
+    outside any query (a tool reading a batch back) lands here too."""
+    return _QUERY_REGISTRY
+
+
 def live_registries() -> list:
     """Every live MetricRegistry in the process (a stable list copy of
     the weak set) — the telemetry aggregator's iteration surface."""
+    # a WeakSet guards its iteration against removals, not against
+    # another thread ADDING a registry (one per query, plus every
+    # plan's) mid-walk: retry the copy
+    for _ in range(8):
+        try:
+            return list(_REGISTRIES)
+        except RuntimeError:
+            continue
     return list(_REGISTRIES)
 
 

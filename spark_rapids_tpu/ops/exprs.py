@@ -1617,7 +1617,8 @@ def _cast_to_string_device(c: AnyDeviceColumn, ctx: Ctx
 # Jitted entry points + structural compile cache
 # ---------------------------------------------------------------------------
 
-from spark_rapids_tpu.jit_cache import JitCache  # noqa: E402
+from spark_rapids_tpu.jit_cache import (JitCache, named_jit,  # noqa: E402
+                                        program_name)
 
 _PROJECT_CACHE = JitCache("project")
 
@@ -1638,11 +1639,16 @@ def _build_project(exprs: Tuple[E.Expression, ...]) -> Callable:
                                   for f, _m in ctx.errors]))
                if ctx.errors else None)
         return outs, err
-    return jax.jit(fn)
+    return named_jit("srt_project", fn)
 
 
 def _raise_if_errors(err) -> None:
-    if err is not None and bool(err):
+    if err is None:
+        return
+    from spark_rapids_tpu import trace as TR
+    with TR.device_sync("ansiError"):
+        failed = bool(err)
+    if failed:
         raise ArithmeticError("Cast overflow in ANSI mode")
 
 
@@ -1693,7 +1699,7 @@ def run_filter(cond: E.Expression, batch: DeviceBatch,
                                       for f, _m in ctx.errors]))
                    if ctx.errors else None)
             return active & p.validity & _as_bool(p), err
-        fn = _FILTER_CACHE.put(key, jax.jit(_fn))
+        fn = _FILTER_CACHE.put(key, named_jit("srt_filter", _fn))
     if part_ctx is not None:
         new_active, err = fn(batch.columns, batch.active,
                              literal_values([cond]), part_ctx)
@@ -1721,6 +1727,14 @@ def stage_structural_key(steps: StageSteps) -> Tuple:
                  for kind, exprs in steps)
 
 
+def stage_program_name(steps: StageSteps) -> str:
+    """``srt_stage_Filter_Project``: the fused chain's operator kinds
+    in order — structure only, so every process gives one chain one
+    name (jit_cache.program_name)."""
+    return program_name("stage", *(kind.capitalize()
+                                   for kind, _exprs in steps))
+
+
 def stage_literal_values(steps: StageSteps) -> Tuple[list, ...]:
     """Per-step traced-literal inputs, in step order (the pytree the
     compiled stage program takes alongside columns+active)."""
@@ -1739,13 +1753,18 @@ def trace_stage_steps(steps: StageSteps, cols, active, lits_per_step):
     for (kind, exprs), lv in zip(steps, lits_per_step):
         ctx = Ctx(cols, active.shape[0], exprs, lv)
         ctx.active_hint = active
-        if kind == "filter":
-            p = dev_eval(exprs[0], ctx)
-            errors.extend(f & active for f, _m in ctx.errors)
-            active = active & p.validity & _as_bool(p)
-        else:
-            cols = [mask_col(dev_eval(e, ctx), active) for e in exprs]
-            errors.extend(f & active for f, _m in ctx.errors)
+        # one named scope per constituent operator: every HLO op's
+        # op_name then says which operator of the fused program it
+        # belongs to (metadata only — no compiled code changes)
+        with jax.named_scope(kind.capitalize()):
+            if kind == "filter":
+                p = dev_eval(exprs[0], ctx)
+                errors.extend(f & active for f, _m in ctx.errors)
+                active = active & p.validity & _as_bool(p)
+            else:
+                cols = [mask_col(dev_eval(e, ctx), active)
+                        for e in exprs]
+                errors.extend(f & active for f, _m in ctx.errors)
     return cols, active, errors
 
 
@@ -1764,7 +1783,8 @@ def build_stage_fn(steps: StageSteps, donate: bool = False) -> Callable:
         err = (jnp.any(jnp.stack([jnp.any(f) for f in errors]))
                if errors else None)
         return cols, active, err
-    return jax.jit(fn, donate_argnums=(0, 1) if donate else ())
+    return named_jit(stage_program_name(steps_t), fn,
+                     donate_argnums=(0, 1) if donate else ())
 
 
 # ---------------------------------------------------------------------------

@@ -286,6 +286,13 @@ def _boundaries_from_words(sorted_keys: Sequence[jax.Array],
     return boundary, is_end
 
 
+# Named scopes (jax.named_scope, metadata only): every HLO operation of
+# an aggregate program says in its op_name which STEP it belongs to —
+# groupby_sort (the segment sort + payload gather), groupby_reduce (the
+# segmented scans) — so a device trace totals by step
+# (tools trace <profile dir>; columnar.device._compact_body is
+# "compact").
+@jax.named_scope("groupby_sort")
 def build_segments(key_cols: Sequence[AnyDeviceColumn],
                    active: jax.Array,
                    payload: Sequence[jax.Array] = (),
@@ -345,6 +352,7 @@ def hash_subkey_words(words: Sequence[jax.Array]) -> jax.Array:
     return h
 
 
+@jax.named_scope("groupby_sort")
 def build_segments_hashed(key_cols: Sequence[AnyDeviceColumn],
                           active: jax.Array,
                           payload: Sequence[jax.Array] = (),
@@ -419,6 +427,7 @@ def prefix_total(seg: Segments, x: jax.Array) -> jax.Array:
     return pp - base
 
 
+@jax.named_scope("groupby_reduce")
 def seg_sum(seg: Segments, col_s: AnyDeviceColumn, out_type: T.DataType,
             null_when_empty: bool):
     """sum / sum_nonnull primitive. ``col_s`` is ALREADY in sorted row
@@ -484,6 +493,7 @@ def _seg_sum_limb(seg: Segments, col_s: AnyDeviceColumn, valid_s,
     return DeviceDecimal128Column(out_type, rhi, rlo, validity)
 
 
+@jax.named_scope("groupby_reduce")
 def seg_sums_batched(seg: Segments, entries, has_nans=None):
     """All of a program's sum/count-family aggregates in ONE pass: every
     slot contributes int64 lanes to a single ``(cap, P)`` matrix (one
@@ -621,6 +631,7 @@ def seg_sums_batched(seg: Segments, entries, has_nans=None):
     return out
 
 
+@jax.named_scope("groupby_reduce")
 def seg_count(seg: Segments, col_s: AnyDeviceColumn) -> DeviceColumn:
     valid_s = col_s.validity & seg.active_sorted
     run = prefix_total(seg, valid_s.astype(jnp.int64))
@@ -638,6 +649,7 @@ def _winner_gather(seg: Segments, col_s: AnyDeviceColumn,
     return take_columns([col_s], safe, valid_at=won)[0]
 
 
+@jax.named_scope("groupby_reduce")
 def seg_extreme(seg: Segments, col_s: AnyDeviceColumn, is_min: bool,
                 has_nans: Optional[bool] = None) -> AnyDeviceColumn:
     """min/max by winning-row-position so values round-trip untouched."""
@@ -648,6 +660,7 @@ def seg_extreme(seg: Segments, col_s: AnyDeviceColumn, is_min: bool,
     return _winner_gather(seg, col_s, win, won)
 
 
+@jax.named_scope("groupby_reduce")
 def seg_first_last(seg: Segments, col_s: AnyDeviceColumn, is_first: bool,
                    ignore_nulls: bool) -> AnyDeviceColumn:
     """first/last by original row order (Spark First/Last semantics).

@@ -47,14 +47,15 @@ import numpy as np
 
 # bounded LRUs (jit_cache.py): long sessions planning many distinct
 # join shapes must not pin unbounded XLA executables
-from spark_rapids_tpu.jit_cache import JitCache
+from spark_rapids_tpu.jit_cache import JitCache, named_jit, program_name
 
 _COUNT_CACHE = JitCache("joinCount")
 _GATHER_CACHE = JitCache("joinGather")
 _MASK_CACHE = JitCache("joinMask")
 
 # tpu-lint: disable=jit-direct(single fixed 3-scalar stack program — one executable, bounded by construction)
-_stack3 = jax.jit(lambda a, b, c: jnp.stack([a, b, c]))
+_stack3 = named_jit("srt_join_stack3",
+                    lambda a, b, c: jnp.stack([a, b, c]))
 
 # join types that expand to (left, right) pairs
 PAIR_JOINS = ("inner", "cross", "left", "leftouter", "right", "rightouter",
@@ -220,7 +221,7 @@ def _build_count_fn(lkeys: Tuple[E.Expression, ...],
             extra_order = jnp.zeros(cap_r, dtype=jnp.int32)
         return (total_pairs, n_extra, max_m, m, offsets, base, order_r,
                 extra_order, matched_r)
-    return jax.jit(fn)
+    return named_jit("srt_join_probe", fn)
 
 
 def _build_fast_gather_fn(join_type: str) -> Callable:
@@ -240,7 +241,7 @@ def _build_fast_gather_fn(join_type: str) -> Callable:
         out_r = take_columns(cols_r, jnp.where(has, ri, 0), valid_at=has)
         active = (active_l & has) if inner else active_l
         return out_r, active, jnp.sum(active.astype(jnp.int64))
-    return jax.jit(fn)
+    return named_jit(program_name("join_gather_fast", join_type), fn)
 
 
 def _build_gather_fn(out_cap: int, join_type: str) -> Callable:
@@ -304,7 +305,8 @@ def _build_gather_fn(out_cap: int, join_type: str) -> Callable:
         active = active | is_extra
         return out_l, merged, active, lv, rv | is_extra
 
-    return jax.jit(fn_right if right_outer else fn)
+    return named_jit(program_name("join_gather", join_type),
+                     fn_right if right_outer else fn)
 
 
 def _build_mask_fn(lkeys: Tuple[E.Expression, ...],
@@ -324,7 +326,7 @@ def _build_mask_fn(lkeys: Tuple[E.Expression, ...],
         if is_semi:
             return active_l & (m > 0)
         return active_l & (m == 0)
-    return jax.jit(fn)
+    return named_jit("srt_join_mask", fn)
 
 
 def _align_string_caps(kl: Sequence[AnyDeviceColumn],
@@ -415,7 +417,7 @@ def _build_mask_kernel_fn(lkeys: Tuple[E.Expression, ...],
         if is_semi:
             return active_l & matched
         return active_l & ~matched
-    return jax.jit(fn)
+    return named_jit("srt_join_mask_kernel", fn)
 
 
 def _build_fast_probe_fn(lkeys: Tuple[E.Expression, ...],
@@ -436,7 +438,7 @@ def _build_fast_probe_fn(lkeys: Tuple[E.Expression, ...],
                              valid_at=matched)
         active = (active_l & matched) if inner else active_l
         return out_r, active, jnp.sum(active.astype(jnp.int64))
-    return jax.jit(fn)
+    return named_jit("srt_join_probe_fast", fn)
 
 
 _MULT_CACHE = JitCache("joinMult")
@@ -471,18 +473,22 @@ def build_key_max_multiplicity(right: DeviceBatch,
                 _key_words(kr, ns), valid, cap_r)
             length = jnp.where(active_s, end - start + 1, 0)
             return jnp.max(length)
-        return jax.jit(_fn)
+        return named_jit("srt_join_build", _fn)
     fn, _ = _MULT_CACHE.get_or_build(key, _build_mult)
     with G.nan_scope(salt[0]):
         out = fn(right.columns, right.active, X.literal_values(list(rk)))
     from spark_rapids_tpu.columnar.device import _prefetch_host
     _prefetch_host([out])  # overlap the fetch with the stream-side scan
-    return lambda: int(np.asarray(out))
+    def resolve() -> int:
+        from spark_rapids_tpu import trace as TR
+        with TR.device_sync("joinBuild"):
+            return int(np.asarray(out))
+    return resolve
 
 
 _EXTRAS_CACHE = JitCache("joinExtras")
 # tpu-lint: disable=jit-direct(single fixed boolean-OR program — one executable, bounded by construction)
-_OR = jax.jit(lambda a, b: a | b)
+_OR = named_jit("srt_join_or", lambda a, b: a | b)
 
 
 def or_masks(a, b):
@@ -536,7 +542,7 @@ def right_extras_batch(right: DeviceBatch, matched_any: jax.Array,
                     lefts += [jnp.zeros(cap_r,
                                         dtype=storage_jnp_dtype(dt)), fv]
             return tuple(lefts), tuple(outs), keep
-        return jax.jit(build)
+        return named_jit("srt_join_extras", build)
     fn, _ = _EXTRAS_CACHE.get_or_build(key, _build_extras)
     lefts, routs, keep = fn(matched_any, right.active, *flat)
     from spark_rapids_tpu.columnar.device import column_arity, make_column
@@ -643,6 +649,8 @@ def device_join(left: DeviceBatch, right: DeviceBatch,
     ckey = (struct, join_type)
     count_fn, _ = _COUNT_CACHE.get_or_build(
         ckey, lambda: _build_count_fn(lk, rk, join_type, nst))
+    from spark_rapids_tpu import trace as TR
+    TR.first_dispatch(metrics, count_fn)
     with G.nan_scope(salt[0]):
         (total_pairs, n_extra, max_m, m, offsets, base, order_r,
          extra_order, matched_r) = count_fn(
@@ -677,7 +685,8 @@ def device_join(left: DeviceBatch, right: DeviceBatch,
 
     # ONE host sync for sizing: all scalars ride one stacked fetch
     # (each D2H read is a device sync)
-    sc = np.asarray(_stack3(total_pairs, n_extra, max_m))
+    with TR.device_sync("joinSize", metrics):
+        sc = np.asarray(_stack3(total_pairs, n_extra, max_m))
     total = int(sc[0]) + int(sc[1])
     out_cap = bucket_capacity(max(1, total))
 

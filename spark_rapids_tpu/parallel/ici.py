@@ -89,7 +89,8 @@ def all_to_all_rows(arrs: Sequence[jax.Array], active: jax.Array,
 
 # bounded LRU like every other structural jit cache: mesh programs show
 # up in compileCacheHits/Misses and the bench's detail.jitCaches
-from spark_rapids_tpu.jit_cache import JitCache, mirror_to_metrics
+from spark_rapids_tpu.jit_cache import (JitCache, mirror_to_metrics,
+                                        named_jit)
 
 _EXCHANGE_CACHE = JitCache("iciExchange")
 
@@ -123,7 +124,7 @@ def _build_exchange(mesh: Mesh, exprs: Tuple[E.Expression, ...],
                    in_specs=(P(SHUFFLE_AXIS), P(SHUFFLE_AXIS), P()),
                    out_specs=(P(SHUFFLE_AXIS), P(SHUFFLE_AXIS),
                               P(SHUFFLE_AXIS)))
-    return jax.jit(sm)
+    return named_jit("srt_ici_exchange", sm)
 
 
 def exchange_fn(mesh: Mesh, exprs: Sequence[E.Expression],
@@ -169,7 +170,7 @@ def _dest_counts_fn(mesh: Mesh, exprs: Tuple[E.Expression, ...],
         sm = shard_map(per_shard, mesh=mesh,
                        in_specs=(P(SHUFFLE_AXIS), P(SHUFFLE_AXIS), P()),
                        out_specs=P(SHUFFLE_AXIS))
-        return jax.jit(sm)
+        return named_jit("srt_ici_sizes", sm)
 
     fn, was_miss = _EXCHANGE_CACHE.get_or_build(key, build)
     if metrics is not None:
@@ -276,7 +277,8 @@ def mesh_exchange(slots: Sequence[DeviceBatch],
     # without it every block is worst-case cap and staging grows
     # n_dev x cap per chip (VERDICT r3 weak #6)
     from spark_rapids_tpu import trace as _trace
-    with _trace.span("meshSizeExchange"):
+    with _trace.span("meshSizeExchange", metrics=metrics), \
+            _trace.device_sync("iciSizes", metrics):
         counts = np.asarray(_dest_counts_fn(
             mesh, tuple(bound_exprs), n_parts, metrics)(
             stacked_cols, stacked_active, lit_vals))

@@ -32,7 +32,7 @@ from spark_rapids_tpu.sql import types as T
 # bounded LRU like every other structural jit cache: mesh step programs
 # count in cache_stats() (bench detail.jitCaches) instead of living in
 # an invisible module dict
-from spark_rapids_tpu.jit_cache import JitCache
+from spark_rapids_tpu.jit_cache import JitCache, named_jit
 
 _STEP_CACHE = JitCache("meshStep")
 
@@ -95,7 +95,7 @@ def sum_count_step(mesh: Mesh) -> Callable:
                        in_specs=(P(SHUFFLE_AXIS), P(SHUFFLE_AXIS),
                                  P(SHUFFLE_AXIS)),
                        out_specs=(P(SHUFFLE_AXIS),) * 4)
-        return jax.jit(sm)
+        return named_jit("srt_mesh_agg_step", sm)
 
     # single-flight get_or_build (not raw get/put): two concurrent
     # queries racing the first mesh-step compile would otherwise both

@@ -73,11 +73,14 @@ class TpuSemaphore:
         per-task collect path, the broadcast build, and the exchange
         drain all pass their registry) and as a span in the active
         trace."""
-        import time
         if getattr(self._held, "count", 0) > 0:
             return
-        t0 = time.perf_counter_ns()
-        with self._cv:
+        from spark_rapids_tpu import metrics as M
+        from spark_rapids_tpu import trace as _trace
+        timer = (metrics.create(M.SEMAPHORE_WAIT_TIME)
+                 if metrics is not None else None)
+        with _trace.span("semaphoreWait", metrics=metrics, timer=timer), \
+                self._cv:
             while self._in_use >= self.permits:
                 # bounded wait + lifecycle checkpoint: a cancelled /
                 # timed-out query must not park on the semaphore
@@ -88,14 +91,6 @@ class TpuSemaphore:
                     from spark_rapids_tpu.lifecycle import checkpoint
                     checkpoint("semaphore")
             self._in_use += 1
-        t1 = time.perf_counter_ns()
-        if metrics is not None:
-            from spark_rapids_tpu import metrics as M
-            metrics.create(M.SEMAPHORE_WAIT_TIME).add(t1 - t0)
-        from spark_rapids_tpu import trace as _trace
-        qt = _trace._ACTIVE
-        if qt is not None:
-            qt.add("semaphoreWait", t0, t1)
         self._held.count = 1
 
     def release_if_necessary(self) -> None:

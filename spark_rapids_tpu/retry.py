@@ -451,9 +451,9 @@ def _recover(conf, metrics, attempt: int, backoff_ms: int,
     # storm surfaces while it is happening (one boolean check when the
     # engine is unarmed; docs/observability.md "Live telemetry")
     TEL.on_retry()
-    t0 = time.perf_counter_ns()
     freed = 0
-    with suppress_injection():
+    with TR.span("retryBlock", metrics=metrics,
+                 attempt=attempt) as sp, suppress_injection():
         if conf is not None:
             from spark_rapids_tpu.memory import get_device_store
             store = get_device_store(conf)
@@ -477,15 +477,12 @@ def _recover(conf, metrics, attempt: int, backoff_ms: int,
             # through its deadline inside the retry protocol
             from spark_rapids_tpu.lifecycle import cancellable_sleep
             cancellable_sleep(delay / 1000.0, site="retryBackoff")
-    t1 = time.perf_counter_ns()
-    qt = TR._ACTIVE
-    if qt is not None:
-        qt.add("retryBlock", t0, t1, attempt=attempt, freedBytes=freed)
+        sp.attrs["freedBytes"] = freed
     if metrics is not None:
         metrics.create(M.RETRY_COUNT, M.ESSENTIAL).add(1)
         if freed:
             metrics.create(M.SPILL_BYTES_ON_RETRY, M.ESSENTIAL).add(freed)
-        metrics.create(M.RETRY_BLOCK_TIME).add(t1 - t0)
+        metrics.create(M.RETRY_BLOCK_TIME).add(sp.t1 - sp.t0)
 
 
 def with_retry(fn: Callable[[], T], conf=None, metrics=None, *,
@@ -686,6 +683,7 @@ def _half_pids():
             rank = jnp.cumsum(active.astype(jnp.int64)) - 1
             total = jnp.sum(active.astype(jnp.int64))
             return jnp.where(rank * 2 < total, 0, 1).astype(jnp.int32)
+        from spark_rapids_tpu.jit_cache import named_jit
         # tpu-lint: disable=jit-direct(one lazily-built fixed split program — bounded by construction)
-        _HALF_PIDS = jax.jit(_fn)
+        _HALF_PIDS = named_jit("srt_retry_half_pids", _fn)
     return _HALF_PIDS

@@ -208,7 +208,13 @@ class AdmissionController:
         deadlines are enforced from admission time (docs/serving.md
         "Query lifecycle"). ``signature`` is the learned digest hint
         the tuning signature caps key on; release() must receive the
-        same hint."""
+        same hint. The wait is one ``serveQueueWait`` span (host
+        stream and profiler), under the request's query id."""
+        from spark_rapids_tpu import trace as _trace
+        with _trace.span("serveQueueWait", tenant=tenant):
+            return self._acquire(tenant, token, signature)
+
+    def _acquire(self, tenant: str, token, signature) -> float:
         t0 = time.perf_counter()
         throttled = False
         with self._cv:
@@ -275,12 +281,6 @@ class AdmissionController:
             waits = self._tenant_waits.setdefault(tenant, [])
             waits.append(wait)
             del waits[:-_RESERVOIR]
-        from spark_rapids_tpu import trace as _trace
-        qt = _trace._ACTIVE
-        if qt is not None:
-            now = time.perf_counter_ns()
-            qt.add("serveQueueWait", now - int(wait * 1e9), now,
-                   tenant=tenant)
         return wait
 
     def bill_fused_member(self, tenant: str, wait_s: float) -> None:
@@ -302,8 +302,11 @@ class AdmissionController:
         qt = _trace._ACTIVE
         if qt is not None:
             now = time.perf_counter_ns()
-            qt.add("serveQueueWait", now - int(max(0.0, wait_s) * 1e9),
-                   now, tenant=tenant)
+            # host stream only: the member's wait is already over
+            _trace.record(qt, "serveQueueWait",
+                          now - int(max(0.0, wait_s) * 1e9), now,
+                          _trace.current_scope(),
+                          attrs={"tenant": tenant})
 
     def bill_cache_hit(self, tenant: str) -> None:
         """Result-cache-hit accounting (docs/caching.md): a hit is

@@ -588,6 +588,17 @@ class QueryServer:
             protocol.send_msg(conn, {"status": "error", "error": str(e)})
 
     def _handle_sql(self, conn: socket.socket, header: Dict) -> None:
+        # the request's query record opens HERE, pending, so the
+        # admission wait and a result-cache hit carry the id that
+        # session.sql()/execute_plan then adopt (trace.QueryScope)
+        from spark_rapids_tpu import trace as TR
+        scope = TR.QueryScope(
+            tenant=str(header.get("tenant") or "default"), pending=True)
+        with TR.attach(scope):
+            self._handle_sql_scoped(conn, header)
+
+    def _handle_sql_scoped(self, conn: socket.socket,
+                           header: Dict) -> None:
         from spark_rapids_tpu import lifecycle as LC
         from spark_rapids_tpu import trace as TR
         from spark_rapids_tpu import plan_cache as PC

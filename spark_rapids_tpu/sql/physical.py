@@ -107,6 +107,10 @@ class PhysicalPlan:
         # checkpointing per drained batch is the cooperative batch-loop
         # cancellation point (docs/serving.md "Query lifecycle")
         token = LC.current_token()
+        # ... and so does the query's record, for the spans and timers
+        # of sites that hold no stamped registry (trace.attach)
+        from spark_rapids_tpu import trace as TR
+        scope = TR.current_scope()
 
         def drain(t) -> list:
             # per-task try/finally: an injected/real fault mid-drain
@@ -114,7 +118,7 @@ class PhysicalPlan:
             # threads are discarded with the pool, so a leaked permit
             # would shrink the semaphore for the process lifetime
             try:
-                with LC.token_scope(token):
+                with LC.token_scope(token), TR.attach(scope):
                     out = []
                     for b in t():
                         LC.checkpoint("batch")

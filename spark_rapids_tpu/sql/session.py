@@ -161,8 +161,23 @@ class TpuSparkSession:
             L.SubqueryAlias(name, self.catalog_views[name.lower()]), self)
 
     def sql(self, query: str) -> DataFrame:
+        from spark_rapids_tpu import metrics as M
+        from spark_rapids_tpu import trace as TR
         from spark_rapids_tpu.sql.parser import parse_sql
-        return parse_sql(query, self)
+        # the query's record opens HERE, pending, so the parse is the
+        # first part of its planTime and its `plan` span carries its
+        # id (the server's request scope, when there is one);
+        # execute_plan adopts it when this very plan is collected next
+        # on this thread (any other use leaves the id unused)
+        cur = TR.current_scope()
+        scope = (cur if cur is not None and cur.pending
+                 else TR.QueryScope(tenant=self.tenant, pending=True))
+        with TR.span("plan", scope=scope, phase="parse",
+                     timer=M.query_registry().create(M.PLAN_TIME,
+                                                     M.ESSENTIAL)):
+            df = parse_sql(query, self)
+        self._tls.pending_scope = (df.plan, scope)
+        return df
 
     # -- execution ---------------------------------------------------------
     def plan_physical(self, plan: L.LogicalPlan,
@@ -213,12 +228,14 @@ class TpuSparkSession:
                 conf_obj=self.conf_obj)
             self.last_rewrite_report = report
             self._tls.rewrite_report = report
+            self._tls.plan_cache_hit = not was_miss
             if not was_miss and report is not None:
                 # sql.explain output replays from the cached report
                 # (the building thread printed inside apply_overrides)
                 report.print_explain(self.conf_obj)
         else:
             self._tls.plan_signature = None
+            self._tls.plan_cache_hit = None
             template, report = self._rewrite_fresh(plan)
             physical = template
             self.last_rewrite_report = report
@@ -243,9 +260,45 @@ class TpuSparkSession:
         physical = _reuse_broadcast_exchanges(physical)
         return physical, report
 
-    def execute_plan(self, plan: L.LogicalPlan) -> HostBatch:
+    def _open_scope(self, plan):
+        """This execution's per-query record (trace.QueryScope): the
+        pending one ``sql()`` opened for this plan on this thread, else
+        the pending one the server opened before admission, else a new
+        one (a scalar subquery's carries its parent's id)."""
         import time as _time
 
+        from spark_rapids_tpu import trace as TR
+        pend = getattr(self._tls, "pending_scope", None)
+        self._tls.pending_scope = None
+        scope = pend[1] if pend is not None and pend[0] is plan else None
+        if scope is None:
+            cur = TR.current_scope()
+            scope = cur if cur is not None and cur.pending else None
+        if scope is None:
+            return TR.QueryScope(tenant=self.tenant)
+        scope.pending = False
+        scope.t_begin = _time.perf_counter_ns()
+        return scope
+
+    def execute_plan(self, plan: L.LogicalPlan) -> HostBatch:
+        from spark_rapids_tpu import trace as TR
+        scope = self._open_scope(plan)
+        # one root annotation per query in the profiler's trace; the
+        # host stream's root span is recorded where the sink closes
+        scope.running = True
+        try:
+            with TR.attach(scope), TR.annotation(
+                    "srt.query", scope,
+                    attrs={"tenant": scope.tenant,
+                           "parent": scope.parent}):
+                return self._execute_scoped(plan, scope)
+        finally:
+            scope.running = False
+
+    def _execute_scoped(self, plan: L.LogicalPlan, scope) -> HostBatch:
+        import time as _time
+
+        from spark_rapids_tpu import metrics as M
         from spark_rapids_tpu import trace as TR
         from spark_rapids_tpu.conf import EVENT_LOG_DIR, TASK_PARALLELISM
         if self.conf_obj.sql_enabled:
@@ -282,48 +335,74 @@ class TpuSparkSession:
         physical = None
         t_begin = _time.perf_counter()
         tok = TR.begin_query(self.conf_obj)
+
+        def end_trace(**kw):
+            # the host stream's root span goes in BEFORE the sink
+            # closes (file mode writes the file at end_query)
+            qt = TR._ACTIVE
+            if qt is not None:
+                TR.record(qt, "srt.query", scope.t_begin,
+                          _time.perf_counter_ns(), scope,
+                          attrs={"tenant": scope.tenant})
+            return TR.end_query(self.conf_obj, tok, **kw)
+
         try:
-            physical = self.plan_physical(plan)
-            sig = getattr(self._tls, "plan_signature", None)
-            if quar_thr > 0 and sig is not None \
-                    and LC.is_quarantined(sig):
-                # poison-query quarantine: fail fast BEFORE touching
-                # the device — the signature already wedged the
-                # runtime quar_thr consecutive times
-                raise LC.TpuQueryQuarantined(
-                    sig, LC.quarantined_failures(sig))
-            # THIS thread's rewrite report: a concurrent query on the
-            # same session may overwrite last_rewrite_report before the
-            # profile/event-log writes below run
-            report = getattr(self._tls, "rewrite_report",
-                             self.last_rewrite_report)
-            # serving tenancy (docs/serving.md): stamp every registry of
-            # THIS execution's plan with the session tenant so store
-            # registrations from any pool thread bill the right ledger
-            from spark_rapids_tpu import memory as _mem
-            _mem.stamp_plan_tenant(physical, self.tenant)
-            # serve-tier caching (docs/caching.md): fingerprint every
-            # file-scan input BEFORE execution reads it — a file
-            # mutated mid-query then mismatches at lookup time instead
-            # of going stale. Captured on this thread for the server's
-            # result-cache population and the join build-reuse hooks;
-            # skipped (and cleared) when neither cache is on.
-            from spark_rapids_tpu.conf import (RESULT_CACHE_ENABLED,
-                                               SUBPLAN_CACHE_ENABLED)
-            from spark_rapids_tpu.serve import result_cache as _RC
-            if (bool(self.conf_obj.get(RESULT_CACHE_ENABLED))
-                    or bool(self.conf_obj.get(SUBPLAN_CACHE_ENABLED))):
-                _RC.set_execution_fingerprints(
-                    _RC.capture_fingerprints(physical))
-            else:
-                _RC.set_execution_fingerprints(None)
+            # planTime / the `plan` span: everything the calling thread
+            # does between the query's begin and execute_collect (a
+            # scalar subquery's whole execution included: it runs
+            # inside plan_physical)
+            with TR.span("plan", scope=scope, phase="rewrite",
+                         timer=M.query_registry().create(
+                             M.PLAN_TIME, M.ESSENTIAL)) as plan_span:
+                physical = self.plan_physical(plan)
+                plan_span.attrs["cacheHit"] = getattr(
+                    self._tls, "plan_cache_hit", None)
+                sig = getattr(self._tls, "plan_signature", None)
+                if quar_thr > 0 and sig is not None \
+                        and LC.is_quarantined(sig):
+                    # poison-query quarantine: fail fast BEFORE
+                    # touching the device — the signature already
+                    # wedged the runtime quar_thr consecutive times
+                    raise LC.TpuQueryQuarantined(
+                        sig, LC.quarantined_failures(sig))
+                # THIS thread's rewrite report: a concurrent query on
+                # the same session may overwrite last_rewrite_report
+                # before the profile/event-log writes below run
+                report = getattr(self._tls, "rewrite_report",
+                                 self.last_rewrite_report)
+                # serving tenancy (docs/serving.md): stamp every
+                # registry of THIS execution's plan with the session
+                # tenant so store registrations from any pool thread
+                # bill the right ledger — and with the query's record,
+                # so every span and timer from any pool thread says
+                # whose it is (docs/observability.md)
+                from spark_rapids_tpu import memory as _mem
+                _mem.stamp_plan_tenant(physical, self.tenant)
+                TR.stamp_plan(physical, scope)
+                # serve-tier caching (docs/caching.md): fingerprint
+                # every file-scan input BEFORE execution reads it — a
+                # file mutated mid-query then mismatches at lookup
+                # time instead of going stale. Captured on this thread
+                # for the server's result-cache population and the
+                # join build-reuse hooks; skipped (and cleared) when
+                # neither cache is on.
+                from spark_rapids_tpu.conf import (RESULT_CACHE_ENABLED,
+                                                   SUBPLAN_CACHE_ENABLED)
+                from spark_rapids_tpu.serve import result_cache as _RC
+                if (bool(self.conf_obj.get(RESULT_CACHE_ENABLED))
+                        or bool(self.conf_obj.get(
+                            SUBPLAN_CACHE_ENABLED))):
+                    _RC.set_execution_fingerprints(
+                        _RC.capture_fingerprints(physical))
+                else:
+                    _RC.set_execution_fingerprints(None)
             t0 = _time.perf_counter()
             with _mem.tenant_scope(self.tenant):
                 result = physical.execute_collect(
                     int(self.conf_obj.get(TASK_PARALLELISM)))
             wall_s = _time.perf_counter() - t0
         except LC.TpuQueryCancelled as e:
-            TR.end_query(self.conf_obj, tok, error=True)
+            end_trace(error=True)
             # a cancelled/timed-out query's HBM frees NOW: close the
             # dead plan's spillable handles deterministically instead
             # of waiting for plan GC (cancellation never counts toward
@@ -336,21 +415,20 @@ class TpuSparkSession:
                 _time.perf_counter() - t_begin)
             raise
         except LC.TpuQueryQuarantined:
-            TR.end_query(self.conf_obj, tok, error=True)
+            end_trace(error=True)
             self._record_terminal(
                 "quarantined", None, physical, sig,
                 _time.perf_counter() - t_begin)
             raise  # never ran: neither a failure nor a success
         except BaseException:
-            TR.end_query(self.conf_obj, tok, error=True)
+            end_trace(error=True)
             if quar_thr > 0 and sig is not None:
                 LC.record_runtime_failure(sig, quar_thr)
             self._record_terminal(
                 "failed", None, physical, sig,
                 _time.perf_counter() - t_begin)
             raise
-        trace_path = TR.end_query(self.conf_obj, tok, wall_s=wall_s,
-                                  rows=result.num_rows)
+        trace_path = end_trace(wall_s=wall_s, rows=result.num_rows)
         if sig is not None:
             # the watchdog's per-signature p99 history; one success
             # also clears the signature's quarantine streak

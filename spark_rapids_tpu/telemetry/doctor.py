@@ -151,9 +151,9 @@ def _trace_self_times(trace_path: str) -> Dict[str, float]:
     file — corroborating evidence next to the profile-based stage
     diff."""
     try:
-        from spark_rapids_tpu.tools import exclusive_times
+        from spark_rapids_tpu.tools import exclusive_times, work_spans
         from spark_rapids_tpu.trace import load_trace
-        spans = load_trace(trace_path)["spans"]
+        spans = work_spans(load_trace(trace_path)["spans"])
         return {name: d["exclusive"] / 1e6
                 for name, d in exclusive_times(spans).items()}
     except Exception:

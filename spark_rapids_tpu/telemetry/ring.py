@@ -25,7 +25,7 @@ import time
 from collections import deque
 from typing import Dict, Optional
 
-from spark_rapids_tpu.trace import (QueryTrace, _clean,
+from spark_rapids_tpu.trace import (QueryTrace, _clean, _with_q,
                                     write_chrome_trace)
 
 
@@ -68,12 +68,13 @@ class RingTrace(QueryTrace):
             **attrs) -> None:
         ident = self._thread()
         self._ring(self._span_rings, ident).append(
-            (kind, t0, t1, ident, batch, chip, _clean(attrs)))
+            (kind, t0, t1, ident, batch, chip, _clean(_with_q(attrs))))
 
     def mark(self, kind: str, **attrs) -> None:
         ident = self._thread()
         self._ring(self._instant_rings, ident).append(
-            (kind, time.perf_counter_ns(), ident, _clean(attrs)))
+            (kind, time.perf_counter_ns(), ident,
+             _clean(_with_q(attrs))))
 
     def count(self, series: str, value) -> None:
         self._counter_ring.append((series, time.perf_counter_ns(),

@@ -15,7 +15,7 @@ CLI:
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 
 @dataclass
@@ -292,10 +292,14 @@ def exclusive_times(spans: List[dict]) -> Dict[str, Dict[str, float]]:
     return out
 
 
-def chip_occupancy(spans: List[dict]) -> Dict[int, Dict]:
-    """Busy/idle per chip from chip-attributed spans (uploads,
-    dispatches): merged busy intervals, occupancy over the trace
-    window, and the top idle gaps (mesh skew shows up here)."""
+def enqueue_occupancy(spans: List[dict]) -> Dict[int, Dict]:
+    """Per chip, the union of the HOST intervals of chip-attributed
+    spans (uploads, dispatches) over the trace window, and the top
+    gaps between them. jax dispatch is asynchronous, so this is when
+    programs were ENQUEUED for a chip, not when the chip ran them:
+    true device occupancy comes from a profiler trace's device planes
+    (``device_occupancy`` / ``tools trace <profile dir>``). Mesh skew
+    in the enqueue order still shows up here."""
     t_begin, t_end = _trace_bounds(spans) if spans else (0.0, 1.0)
     per: Dict[int, List[Tuple[float, float]]] = {}
     for s in spans:
@@ -337,15 +341,55 @@ def top_spans(spans: List[dict], n: int = 10) -> List[dict]:
              "args": s.get("args", {})} for s in ranked]
 
 
-def analyze_trace(path: str) -> Dict:
+ROOT_SPAN = "srt.query"  # the query's extent, not a piece of its work
+
+
+def work_spans(spans: List[dict]) -> List[dict]:
+    """Spans without the per-query roots: a root covers its whole
+    query, so on a critical path or in a self-time table it would
+    swallow everything under it."""
+    return [s for s in spans if s["name"] != ROOT_SPAN]
+
+
+def _of_query(records: List[dict], query) -> List[dict]:
+    """The records of one query id (all of them for None)."""
+    if query is None:
+        return records
+    return [r for r in records if r.get("args", {}).get("q") == query]
+
+
+def per_query(spans: List[dict]) -> Dict:
+    """Per query id (``args.q``; None = recorded outside any query):
+    span count, first start to last end, and the three longest span
+    kinds by summed duration (microseconds)."""
+    out: Dict = {}
+    for s in spans:
+        d = out.setdefault(s.get("args", {}).get("q"),
+                           {"spans": 0, "t0": s["t0"], "t1": s["t1"],
+                            "kinds": {}})
+        d["spans"] += 1
+        d["t0"] = min(d["t0"], s["t0"])
+        d["t1"] = max(d["t1"], s["t1"])
+        d["kinds"][s["name"]] = d["kinds"].get(s["name"], 0.0) \
+            + s["t1"] - s["t0"]
+    for d in out.values():
+        d["top"] = sorted(d.pop("kinds").items(),
+                          key=lambda kv: -kv[1])[:3]
+    return out
+
+
+def analyze_trace(path: str, query=None) -> Dict:
     """Machine-readable analysis of one trace file (bench detail.trace
-    consumes this)."""
+    consumes this); ``query`` restricts it to one query id."""
     from spark_rapids_tpu.trace import load_trace
     tr = load_trace(path)
-    spans = tr["spans"]
+    spans = _of_query(tr["spans"], query)
     out: Dict = {"file": path, "meta": tr["meta"],
                  "spanCount": len(spans),
                  "instantCount": len(tr["instants"])}
+    out["queries"] = sorted({s["args"]["q"] for s in spans
+                             if "q" in s.get("args", {})})
+    spans = work_spans(spans)
     if not spans:
         return out
     cp, idle = critical_path(spans)
@@ -355,17 +399,21 @@ def analyze_trace(path: str) -> Dict:
         for k, v in sorted(cp.items(), key=lambda kv: -kv[1])}
     out["criticalPathIdle_s"] = round(idle / 1e6, 4)
     out["criticalPathSpan_s"] = round(total / 1e6, 4)
-    out["occupancy"] = chip_occupancy(spans)
+    out["enqueueOccupancy"] = enqueue_occupancy(spans)
     out["topSpans"] = top_spans(spans, 5)
     return out
 
 
-def format_trace_report(path: str, top: int = 10) -> str:
-    """Human-readable trace report (the `tools trace` CLI output)."""
+def format_trace_report(path: str, top: int = 10, query=None) -> str:
+    """Human-readable trace report (the `tools trace` CLI output);
+    ``query`` restricts every section to one query id."""
     from spark_rapids_tpu.trace import load_trace
     tr = load_trace(path)
-    spans, instants, meta = tr["spans"], tr["instants"], tr["meta"]
-    lines = ["=== TPU Trace Report ===", f"trace: {path}",
+    meta = tr["meta"]
+    spans = _of_query(tr["spans"], query)
+    instants = _of_query(tr["instants"], query)
+    lines = ["=== TPU Trace Report ===", f"trace: {path}"
+             + (f" (query {query} only)" if query is not None else ""),
              f"query {meta.get('queryId')}: "
              f"{meta.get('wallSeconds', 0):.3f}s wall, "
              f"{meta.get('outputRows', 0)} rows, "
@@ -375,6 +423,15 @@ def format_trace_report(path: str, top: int = 10) -> str:
         return "\n".join(lines)
     t_begin, t_end = _trace_bounds(spans)
     window = t_end - t_begin
+    lines.append("queries (every record carries its query's id; "
+                 "--query <id> restricts the report to one):")
+    for q, d in sorted(per_query(spans).items(),
+                       key=lambda kv: (kv[0] is None, kv[0] or 0)):
+        tops = ", ".join(f"{k} {us / 1e6:.3f}s" for k, us in d["top"])
+        lines.append(f"  q={q}: {d['spans']} spans over "
+                     f"{(d['t1'] - d['t0']) / 1e6:.3f}s; {tops}")
+    lines.append("")
+    spans = work_spans(spans)
     cp, idle = critical_path(spans)
     lines.append(f"critical path ({window / 1e6:.3f}s traced window):")
     for name, us in sorted(cp.items(), key=lambda kv: -kv[1]):
@@ -390,9 +447,11 @@ def format_trace_report(path: str, top: int = 10) -> str:
         lines.append(f"  {name:44s} {d['count']:6d} "
                      f"{d['total'] / 1e6:9.3f} "
                      f"{d['exclusive'] / 1e6:9.3f}")
-    occ = chip_occupancy(spans)
-    lines += ["", "per-chip occupancy (chip-attributed spans over the "
-              "traced window):"]
+    occ = enqueue_occupancy(spans)
+    lines += ["", "per-chip enqueue occupancy (host intervals of "
+              "chip-attributed spans: when programs were ENQUEUED; the "
+              "device's own busy time is in a profiler trace, `tools "
+              "trace <profile dir>`):"]
     if occ:
         for chip, d in occ.items():
             gaps = ", ".join(f"{g / 1e3:.1f}ms"
@@ -419,6 +478,418 @@ def format_trace_report(path: str, top: int = 10) -> str:
     return "\n".join(lines)
 
 
+# -- profiler-trace analysis -------------------------------------------------
+# `tools trace <profile dir>`: a jax.profiler trace holds the engine's
+# spans (TraceAnnotations, trace.py) on the host planes and what the
+# chip ran on the device planes, on ONE clock. load_profile turns the
+# xplane into plain lists; everything after it is arithmetic on lists
+# (as benchmarks/harness/profiler.reduce_trace is), tested without a
+# chip. Times are microseconds from the trace's first event.
+
+GAP_MIN_US = 1000.0     # idle gaps shorter than 1 ms are not attributed
+NO_SPAN = "no_span"
+_DEVICE_PLANE = "/device:TPU:"
+_OPS_LINE, _MODULES_LINE = "XLA Ops", "XLA Modules"
+# the stat of an op's EVENT METADATA that carries the HLO op_name (named
+# scopes), as "jit(srt_agg_partial)/groupby_sort/scatter:"
+_OP_NAME_STAT = "tf_op"
+
+
+def profile_files(path: str) -> List[str]:
+    """The ``.xplane.pb`` files of a profile directory (``<dir>/plugins/
+    profile/<time>/*.xplane.pb``), or the file itself."""
+    import glob
+    import os
+    if os.path.isfile(path):
+        return [path] if path.endswith(".xplane.pb") else []
+    return sorted(glob.glob(os.path.join(
+        path, "plugins", "profile", "*", "*.xplane.pb")))
+
+
+def scope_of_op(op_name: str) -> str:
+    """``jit(srt_agg_partial)/jit(main)/groupby_sort/sort`` ->
+    ``groupby_sort``: the named-scope path of an HLO op_name, without
+    the jit wrappers, the primitive's own name and the ``:type`` tail
+    the profiler appends."""
+    parts = [p for p in str(op_name or "").split(":", 1)[0].split("/")
+             if p and not p.startswith(("jit(", "pjit("))]
+    return "/".join(parts[:-1]) or "(no scope)"
+
+
+def _pb_fields(buf: bytes):
+    """Protobuf wire format, one level: ``(field, wire type, value)``
+    with a length-delimited value as bytes and a varint as int (64-
+    and 32-bit fixed fields are skipped as bytes). Enough to read the
+    few xplane fields ``jax.profiler.ProfileData`` does not expose."""
+    def varint(i: int) -> Tuple[int, int]:
+        v = shift = 0
+        while True:
+            b = buf[i]
+            i += 1
+            v |= (b & 0x7F) << shift
+            shift += 7
+            if b < 0x80:
+                return v, i
+
+    i, n = 0, len(buf)
+    while i < n:
+        key, i = varint(i)
+        field, wt = key >> 3, key & 7
+        if wt == 0:
+            v, i = varint(i)
+            yield field, wt, v
+        elif wt == 2:
+            ln, i = varint(i)
+            yield field, wt, buf[i:i + ln]
+            i += ln
+        elif wt in (1, 5):
+            size = 8 if wt == 1 else 4
+            yield field, wt, buf[i:i + size]
+            i += size
+        else:
+            raise ValueError(f"xplane: unexpected wire type {wt}")
+
+
+def xplane_op_names(path: str) -> Dict[str, Dict[str, str]]:
+    """``{device plane: {event name: op_name}}`` from the EVENT METADATA
+    of an xplane file: the device's op events carry their HLO op_name
+    (with the ``jax.named_scope`` path) as the ``tf_op`` stat of their
+    metadata, which ``ProfileData`` does not hand out. Schema
+    (tsl/profiler/protobuf/xplane.proto): XSpace.planes=1;
+    XPlane.name=2, event_metadata=4 and stat_metadata=5 (maps: key=1,
+    value=2); XEventMetadata.name=2, stats=5; XStat.metadata_id=1,
+    str_value=5, ref_value=7 (a stat_metadata id); XStatMetadata
+    name=2."""
+    with open(path, "rb") as f:
+        space = f.read()
+    out: Dict[str, Dict[str, str]] = {}
+    for field, wt, plane in _pb_fields(space):
+        if field != 1 or wt != 2:
+            continue
+        name, ev_meta, stat_names = "", [], {}
+        for f2, w2, v in _pb_fields(plane):
+            if f2 == 2 and w2 == 2:
+                name = v.decode("utf-8", "replace")
+            elif f2 == 4 and w2 == 2:
+                ev_meta.append(v)
+            elif f2 == 5 and w2 == 2:
+                key, sname = None, ""
+                for f3, w3, v3 in _pb_fields(v):
+                    if f3 == 1 and w3 == 0:
+                        key = v3
+                    elif f3 == 2 and w3 == 2:
+                        for f4, w4, v4 in _pb_fields(v3):
+                            if f4 == 2 and w4 == 2:
+                                sname = v4.decode("utf-8", "replace")
+                if key is not None:
+                    stat_names[key] = sname
+        if not name.startswith(_DEVICE_PLANE):
+            continue
+        want = {k for k, n in stat_names.items() if n == _OP_NAME_STAT}
+        names: Dict[str, str] = {}
+        for entry in ev_meta:
+            for f3, w3, v3 in _pb_fields(entry):
+                if f3 != 2 or w3 != 2:
+                    continue
+                ev_name, op_name = "", ""
+                for f4, w4, v4 in _pb_fields(v3):
+                    if f4 == 2 and w4 == 2:
+                        ev_name = v4.decode("utf-8", "replace")
+                    elif f4 == 5 and w4 == 2:
+                        sid, sval = None, ""
+                        for f5, w5, v5 in _pb_fields(v4):
+                            if f5 == 1 and w5 == 0:
+                                sid = v5
+                            elif f5 == 5 and w5 == 2:
+                                sval = v5.decode("utf-8", "replace")
+                            elif f5 == 7 and w5 == 0:
+                                sval = stat_names.get(v5, "")
+                        if sid in want:
+                            op_name = sval
+                if ev_name and op_name:
+                    names[ev_name] = op_name
+        out[name] = names
+    return out
+
+
+def load_profile(path: str) -> Dict:
+    """One xplane file as plain lists: ``spans`` (the engine's host
+    annotations in the shape every analyzer here takes: name, t0, t1 in
+    microseconds, tid, args), ``ops`` and ``modules`` per device plane
+    (``[name, t0_us, t1_us, scope]`` — what the chip ran, and whole
+    programs ``jit_srt_...(<fingerprint>)``)."""
+    import warnings
+
+    from jax.profiler import ProfileData
+    from spark_rapids_tpu.trace import INSTANT_CATALOG, SPAN_CATALOG
+    # jaxlib's event_stats iterator trips a DeprecationWarning per call
+    warnings.filterwarnings("ignore", category=DeprecationWarning,
+                            message=".*event_stats.*")
+    data = ProfileData.from_file(path)
+    op_names = xplane_op_names(path)
+    raw_spans: List[Tuple] = []
+    ops: Dict[str, List] = {}
+    modules: Dict[str, List] = {}
+    tid = 0
+    for plane in data.planes:
+        if plane.name.startswith(_DEVICE_PLANE):
+            for line in plane.lines:
+                into = {_OPS_LINE: ops, _MODULES_LINE: modules}.get(
+                    line.name)
+                if into is None:
+                    continue
+                rows = into.setdefault(plane.name, [])
+                scopes = op_names.get(plane.name, {})
+                for e in line.events:
+                    scope = (scope_of_op(scopes.get(e.name, ""))
+                             if into is ops else "")
+                    rows.append([e.name, float(e.start_ns),
+                                 float(e.start_ns + e.duration_ns),
+                                 scope])
+        elif plane.name.startswith("/host:"):
+            for line in plane.lines:
+                tid += 1
+                for e in line.events:
+                    # the engine's: a catalog kind, or a metric mirror
+                    # `<Exec>.<metric>` carrying a query id
+                    known = e.name in SPAN_CATALOG \
+                        or e.name in INSTANT_CATALOG
+                    if not known and "." not in e.name:
+                        continue
+                    stats = dict(e.stats)
+                    if not known and "q" not in stats:
+                        continue  # someone else's dotted name
+                    raw_spans.append((e.name, float(e.start_ns),
+                                      float(e.start_ns + e.duration_ns),
+                                      tid, stats))
+    starts = [s[1] for s in raw_spans] + [
+        r[1] for rows in list(ops.values()) + list(modules.values())
+        for r in rows]
+    base = min(starts) if starts else 0.0
+    for rows in list(ops.values()) + list(modules.values()):
+        for r in rows:
+            r[1] = (r[1] - base) / 1e3
+            r[2] = (r[2] - base) / 1e3
+    spans = [{"name": n, "t0": (a - base) / 1e3, "t1": (b - base) / 1e3,
+              "tid": t, "args": st} for n, a, b, t, st in raw_spans]
+    return {"file": path, "spans": spans, "ops": ops, "modules": modules}
+
+
+def _union(ivs: List[Tuple[float, float]]) -> List[List[float]]:
+    merged: List[List[float]] = []
+    for a, b in sorted(i for i in ivs if i[1] > i[0]):
+        if merged and a <= merged[-1][1]:
+            merged[-1][1] = max(merged[-1][1], b)
+        else:
+            merged.append([a, b])
+    return merged
+
+
+def device_occupancy(ops: Dict[str, List],
+                     window: Optional[Tuple[float, float]] = None
+                     ) -> Dict[str, Dict]:
+    """Per device plane: true busy time (the union of the intervals in
+    which an operation ran), idle, occupancy over ``window`` (default:
+    first operation to last, over all planes) and the idle gaps as
+    ``(t0, t1)``. This is what "occupancy" means; ``enqueue_occupancy``
+    is the host's view of when it asked."""
+    rows = [r for rs in ops.values() for r in rs]
+    if not rows:
+        return {}
+    if window is None:
+        window = (min(r[1] for r in rows), max(r[2] for r in rows))
+    w0, w1 = window
+    out: Dict[str, Dict] = {}
+    for plane, rs in sorted(ops.items()):
+        busy = [[max(a, w0), min(b, w1)] for a, b in
+                _union([(r[1], r[2]) for r in rs])
+                if min(b, w1) > max(a, w0)]
+        gaps, at = [], w0
+        for a, b in busy:
+            if a > at:
+                gaps.append((at, a))
+            at = max(at, b)
+        if at < w1:
+            gaps.append((at, w1))
+        busy_us = sum(b - a for a, b in busy)
+        out[plane] = {"busy_us": busy_us,
+                      "idle_us": (w1 - w0) - busy_us,
+                      "occupancy": busy_us / max(w1 - w0, 1e-9),
+                      "ops": len(rs), "gaps": gaps}
+    return out
+
+
+def attribute_gaps(gaps: List[Tuple[float, float]], spans: List[dict],
+                   min_gap_us: float = GAP_MIN_US) -> Dict:
+    """Put every idle gap of at least ``min_gap_us`` down to what the
+    host was doing in it. Each instant of a gap belongs to the DEEPEST
+    engine span covering it on any host thread — the one that started
+    last (ties: the shorter) — or to ``no_span``; a gap two spans share
+    is split between them at the boundary. Returns the totals by span
+    kind and by query id (exact, from the pieces), the gaps themselves
+    with the kind that covers most of each, and the idle time in gaps
+    too short to attribute."""
+    by_kind: Dict[str, float] = {}
+    by_q: Dict = {}
+    labelled: List[Dict] = []
+    short_us = 0.0
+    cands = sorted(spans, key=lambda s: s["t0"])
+    for g0, g1 in gaps:
+        if g1 - g0 < min_gap_us:
+            short_us += g1 - g0
+            continue
+        over = [s for s in cands if s["t0"] < g1 and s["t1"] > g0]
+        cuts = sorted({g0, g1} | {min(max(t, g0), g1) for s in over
+                                  for t in (s["t0"], s["t1"])})
+        shares: Dict[Tuple, float] = {}
+        for a, b in zip(cuts, cuts[1:]):
+            mid = (a + b) / 2
+            cover = [s for s in over if s["t0"] <= mid < s["t1"]]
+            if cover:
+                s = max(cover, key=lambda s: (s["t0"], -s["t1"]))
+                key = (s["name"], s.get("args", {}).get("q"))
+            else:
+                key = (NO_SPAN, None)
+            shares[key] = shares.get(key, 0.0) + b - a
+        for (kind, q), us in shares.items():
+            by_kind[kind] = by_kind.get(kind, 0.0) + us
+            by_q[q] = by_q.get(q, 0.0) + us
+        (kind, q), _us = max(shares.items(), key=lambda kv: kv[1])
+        labelled.append({"t0": g0, "t1": g1, "kind": kind, "q": q,
+                         "shares": {k[0]: v for k, v in shares.items()}})
+    return {"byKind": by_kind, "byQuery": by_q, "gaps": labelled,
+            "shortGaps_us": short_us}
+
+
+def device_time(ops: List, modules: List) -> Dict:
+    """Device microseconds by program (the ``XLA Modules`` events,
+    fingerprints stripped: ``jit_srt_agg_partial``) and, within each
+    program, by named scope of the operations that ran inside one of
+    its runs (``groupby_sort``, ``Filter``, ...). Control-flow ops
+    (``while``) hold their bodies' time, so scopes of one program can
+    sum past its total."""
+    import bisect
+    import re
+
+    runs = sorted((m[1], m[2], re.sub(r"[(_]\d+[)_]?$", "", m[0]))
+                  for m in modules)
+    starts = [r[0] for r in runs]
+    by_program: Dict[str, float] = {}
+    for a, b, p in runs:
+        by_program[p] = by_program.get(p, 0.0) + b - a
+    by_scope: Dict[str, Dict[str, float]] = {}
+    for _name, a, b, scope in ops:
+        i = bisect.bisect_right(starts, a) - 1
+        p = runs[i][2] if i >= 0 and a < runs[i][1] else "?"
+        d = by_scope.setdefault(p, {})
+        scope = scope or "(no scope)"
+        d[scope] = d.get(scope, 0.0) + b - a
+    return {"byProgram": by_program, "byScope": by_scope}
+
+
+def analyze_profile(path: str, query=None,
+                    loaded: Optional[Dict] = None) -> Dict:
+    """Machine-readable form of ``tools trace <profile dir>`` for one
+    xplane file: per chip busy/idle, idle attributed to host spans,
+    device time by program and scope. ``query`` narrows the host spans
+    (and with them the window) to one query id; ``loaded`` is that
+    file's ``load_profile`` result when the caller has it already."""
+    pr = loaded if loaded is not None else load_profile(path)
+    spans = _of_query(pr["spans"], query)
+    window = None
+    roots = [s for s in spans if s["name"] == "srt.query"]
+    if roots:
+        # the queries' own extent: idle before the first and after the
+        # last query is the caller's, not the engine's
+        window = (min(s["t0"] for s in roots), max(s["t1"] for s in roots))
+    occ = device_occupancy(pr["ops"], window)
+    out: Dict = {"file": path, "spanCount": len(spans),
+                 "queries": sorted({s["args"]["q"] for s in spans
+                                    if "q" in s["args"]}),
+                 "chips": {}}
+    for plane, d in occ.items():
+        att = attribute_gaps(d["gaps"], spans)
+        out["chips"][plane] = {
+            "busy_s": d["busy_us"] / 1e6, "idle_s": d["idle_us"] / 1e6,
+            "occupancy": d["occupancy"], "ops": d["ops"],
+            "idleByKind_s": {k: v / 1e6 for k, v in sorted(
+                att["byKind"].items(), key=lambda kv: -kv[1])},
+            "idleByQuery_s": {str(k): v / 1e6
+                              for k, v in att["byQuery"].items()},
+            "idleShortGaps_s": att["shortGaps_us"] / 1e6,
+            "longestGaps": [
+                {"at_s": g["t0"] / 1e6, "s": (g["t1"] - g["t0"]) / 1e6,
+                 "kind": g["kind"], "q": g["q"]}
+                for g in sorted(att["gaps"],
+                                key=lambda g: g["t0"] - g["t1"])[:8]],
+            "device": {k: {n: us / 1e6 for n, us in v.items()}
+                       if k == "byProgram" else
+                       {p: {n: us / 1e6 for n, us in sc.items()}
+                        for p, sc in v.items()}
+                       for k, v in device_time(
+                           pr["ops"].get(plane, []),
+                           pr["modules"].get(plane, [])).items()},
+        }
+    return out
+
+
+def format_profile_report(path: str, top: int = 10, query=None) -> str:
+    """Human-readable ``tools trace <profile dir>`` report: the host
+    analyzers over the engine's annotations, then per chip what the
+    device planes say."""
+    pr = load_profile(path)
+    a = analyze_profile(path, query, loaded=pr)
+    pr_spans = work_spans(_of_query(pr["spans"], query))
+    lines = ["=== TPU Profile Report ===", f"profile: {path}"
+             + (f" (query {query} only)" if query is not None else ""),
+             f"{a['spanCount']} engine annotations, queries "
+             f"{a['queries'] or '-'}", ""]
+    if pr_spans:
+        cp, idle = critical_path(pr_spans)
+        t_begin, t_end = _trace_bounds(pr_spans)
+        window = t_end - t_begin
+        lines.append("host critical path over the engine's annotations:")
+        for name, us in sorted(cp.items(), key=lambda kv: -kv[1])[:top]:
+            lines.append(f"  {us / 1e6:8.3f}s  {us / window:5.1%}  {name}")
+        lines.append(f"  {idle / 1e6:8.3f}s  {idle / window:5.1%}  (idle)")
+        lines.append("")
+    if not a["chips"]:
+        lines.append("no device plane in this trace (a CPU run has "
+                     "none): occupancy, idle attribution and device "
+                     "time need a run on the chip")
+        return "\n".join(lines)
+    for plane, c in a["chips"].items():
+        total = c["busy_s"] + c["idle_s"]
+        lines.append(f"{plane}: {c['occupancy']:6.1%} busy "
+                     f"({c['busy_s']:.3f}s of {total:.3f}s, "
+                     f"{c['ops']} operations), idle {c['idle_s']:.3f}s")
+        lines.append("  idle by the host span it falls under (gaps of "
+                     f"{GAP_MIN_US / 1e3:.0f} ms and more):")
+        for kind, s in list(c["idleByKind_s"].items())[:top]:
+            lines.append(f"    {s:8.3f}s  "
+                         f"{s / max(c['idle_s'], 1e-12):5.1%}  {kind}")
+        lines.append(f"    {c['idleShortGaps_s']:8.3f}s  "
+                     f"{c['idleShortGaps_s'] / max(c['idle_s'], 1e-12):5.1%}"
+                     "  (gaps under 1 ms, not attributed)")
+        lines.append("  idle by query: " + ", ".join(
+            f"q={q} {s:.3f}s" for q, s in c["idleByQuery_s"].items()))
+        lines.append("  longest gaps:")
+        for g in c["longestGaps"][:top]:
+            lines.append(f"    {g['s']:8.3f}s at {g['at_s']:.3f}s  "
+                         f"{g['kind']} (q={g['q']})")
+        lines.append("  device time by program, then by named scope:")
+        progs = sorted(c["device"]["byProgram"].items(),
+                       key=lambda kv: -kv[1])
+        for p, s in progs[:top]:
+            lines.append(f"    {s:8.3f}s  {p}")
+            scopes = sorted(c["device"]["byScope"].get(p, {}).items(),
+                            key=lambda kv: -kv[1])
+            for sc, ss in scopes[:5]:
+                lines.append(f"        {ss:8.3f}s  {sc}")
+    return "\n".join(lines)
+
+
+
 def hotspots_report(paths: List[str], top: int = 20) -> str:
     """Rank EXCLUSIVE self-time per span name across a whole trace
     directory (the `tools hotspots` CLI): the picker for the NEXT
@@ -434,7 +905,7 @@ def hotspots_report(paths: List[str], top: int = 20) -> str:
     window = 0.0
     for fp in paths:
         tr = load_trace(fp)
-        spans = tr["spans"]
+        spans = work_spans(tr["spans"])
         if not spans:
             continue
         t0, t1 = _trace_bounds(spans)
@@ -510,6 +981,9 @@ def _main(argv: List[str]) -> int:
                     help="docs: output directory for generated markdown")
     ap.add_argument("--top", type=int, default=10,
                     help="trace: rows per report section")
+    ap.add_argument("--query", type=int, default=None, metavar="ID",
+                    help="trace: restrict the report to one query id "
+                    "(every span carries its query's `q`)")
     ap.add_argument("--conf", action="append", default=[],
                     help="serve: key=value spark.rapids confs")
     ap.add_argument("--host", default=None, help="serve/serve-client: "
@@ -698,6 +1172,16 @@ def _main(argv: List[str]) -> int:
         if not os.path.exists(path):
             print(f"no such trace file or directory: {path}")
             return 1
+        xplanes = profile_files(path)
+        if xplanes and args.command == "trace":
+            # a jax.profiler directory (or one .xplane.pb): the
+            # engine's annotations beside the device planes
+            for i, fp in enumerate(xplanes):
+                if i:
+                    print()
+                print(format_profile_report(fp, top=args.top,
+                                            query=args.query))
+            return 0
         if os.path.isdir(path):
             files = sorted(
                 os.path.join(path, f) for f in os.listdir(path)
@@ -715,7 +1199,8 @@ def _main(argv: List[str]) -> int:
             for i, fp in enumerate(files):
                 if i:
                     print()
-                print(format_trace_report(fp, top=args.top))
+                print(format_trace_report(fp, top=args.top,
+                                          query=args.query))
         except (ValueError, KeyError) as e:  # incl. JSONDecodeError
             print(f"not a readable Chrome-trace file: {e}")
             return 1
@@ -1172,6 +1657,98 @@ def generate_observability_docs() -> str:
         "to overflow lanes (`<thread>!k`) so every lane's B/E stream is",
         "strictly nested — the schema tests assert this invariant.",
         "",
+        "### One id per query, on every record",
+        "",
+        "`session.execute_plan` opens one `trace.QueryScope` per query",
+        "(`q` = process-wide id, tenant, `t_begin`, `parent` = the",
+        "enclosing query's id for a scalar subquery) and stamps it on",
+        "every metric registry of the executing plan",
+        "(`trace.stamp_plan`, the way `memory.stamp_plan_tenant` stamps",
+        "the tenant), so pool threads reach it from the registry they",
+        "already hold; the task, scan-producer and shuffle pool threads",
+        "also re-enter it as their thread's scope (`trace.attach`).",
+        "Every span and instant carries `q` in its args (`parent` too",
+        "for a subquery). The sinks stay process-wide — a query that",
+        "arrives while another's file trace is open still lands in that",
+        "file — but every record says whose it is: `tools trace` lists",
+        "the queries of a file and `--query <id>` restricts the report",
+        "to one. `session.sql` opens the scope ahead of execution (the",
+        "parse is the first part of the query's `plan` time) and the",
+        "query server opens it before admission, so `serveQueueWait`",
+        "and `resultCacheHit` carry the id of the query they belong to.",
+        "",
+        "### The same spans on the profiler's clock",
+        "",
+        "Every span is ALSO a `jax.profiler.TraceAnnotation(kind, q=,",
+        "batch=, program=, ...)` over the same interval, and every query",
+        "a root `srt.query` annotation. This is not gated by",
+        "`spark.rapids.sql.trace.enabled`: outside a profiler session an",
+        "annotation is a flag test (about a microsecond), and inside",
+        "one — the benchmark's `--trace 1`, `chip_smoke.py`, an",
+        "operator's `jax.profiler.trace(dir)` — the engine's spans sit",
+        "on the host planes beside the device's `XLA Ops`, on one",
+        "clock, with no offset to estimate. An annotation must end on",
+        "the thread that began it, so three recordings stay in the host",
+        "stream only: `compile` through a cache's `get`/`put` pair",
+        "(the interval is known only once it is over; `get_or_build`",
+        "annotates), `serveQueueWait` billed to a batch-fusion member",
+        "after the fact, and the host stream's own `srt.query` root",
+        "(the profiler has the live one).",
+        "",
+        "### Program names",
+        "",
+        "Every device program is built through",
+        "`jit_cache.named_jit(name, fn, **jit_kwargs)`: it sets",
+        "`fn.__name__` and returns `jax.jit(fn)` itself (no wrapper, so a",
+        "dispatch costs what it did), and XLA names the module",
+        "`jit_<name>`. Names are `srt_<family>[_<tag>]`, at most 48",
+        "characters of `[A-Za-z0-9_]`: `srt_stage_Filter_Project`,",
+        "`srt_agg_partial` / `_merge` / `_merge_partial` / `_final` /",
+        "`_complete` (`_kernel` on the Pallas path), `srt_sort`,",
+        "`srt_topn`, `srt_join_build` / `_probe` / `_gather_<type>` /",
+        "`_gather_fast_<type>` / `_mask` / `_extras`, `srt_decode`,",
+        "`srt_decode_fused`, `srt_upload_decode`, `srt_fetch_pack`,",
+        "`srt_concat`, `srt_shrink`, `srt_compact`, `srt_project`,",
+        "`srt_filter`, `srt_window`, `srt_generate`,",
+        "`srt_exchange_pid` / `_range_keys` / `_range_rank` /",
+        "`_split_sort` / `_extract` / `_round_robin`, `srt_ici_exchange`,",
+        "`srt_ici_sizes`, `srt_mesh_agg_step`. **The rule:** a name is a",
+        "function of the program's structural key and nothing else — no",
+        "literal value, no capacity, no `hash()`, no counter, no",
+        "address. JAX's persistent compilation cache keys on the",
+        "module's name, so a name that varies between processes misses",
+        "there every time (a cold set-up compiles for minutes). Inside a",
+        "program, `jax.named_scope` marks each constituent operator of a",
+        "fused stage (`Filter`, `Project`) and the aggregate's steps",
+        "(`agg_inputs`, `groupby_sort`, `groupby_reduce`, `compact`,",
+        "`agg_result`); scopes are op_name metadata and change no",
+        "compiled code. The tpu-lint `jit-direct` rule treats",
+        "`named_jit` as `jax.jit`. Dispatch spans, `compile` spans and",
+        "the `firstDispatch` instant carry `program=<name>`.",
+        "",
+        "### Three intervals no operator timer covers",
+        "",
+        "Always-on timers of one process-wide registry (owner `Query`;",
+        "two clock reads each), each mirrored to a span kind:",
+        "",
+        "| timer | span | where | what |",
+        "|---|---|---|---|",
+        "| `planTime` | `plan` (`phase=parse` / `rewrite`, `cacheHit=`)"
+        " | `session.sql`; `execute_plan` up to `execute_collect` |"
+        " parse, analysis, overrides, plan cache, fingerprints, on the"
+        " calling thread |",
+        "| `firstDispatchTime` | `firstDispatch` (instant, `program=`)"
+        " | the query's first enqueue of any device program: page"
+        " decode (`columnar/transfer.py`), fused stage, aggregate, join"
+        " probe, sort | ns from the query's begin to that enqueue,"
+        " once per query |",
+        "| `deviceSyncTime` | `deviceSync` (`site=`) | every host read"
+        " of a device value: `rowCount` (`DeviceBatch.row_count`),"
+        " `fetch` (`finish_fetch`, so `to_host`/collect), `aggCounts`"
+        " and `aggMerge` (the aggregate's counts and overflow flags),"
+        " `joinSize`, `joinBuild`, `exchangeSplit`, `iciSizes`,"
+        " `ansiError` | thread-ns blocked, summed over task threads |",
+        "",
         "## Configuration",
         "",
         "| Key | Default | Description |",
@@ -1215,11 +1792,37 @@ def generate_observability_docs() -> str:
         "  documented double counts at the reporting layer: e.g.",
         "  `retryBlock` (spill+backoff) nests inside operator timers,",
         "  so operators' self-time no longer absorbs retry stalls.",
-        "- **per-chip occupancy** — busy fraction + top idle gaps per",
-        "  chip from chip-attributed spans; mesh skew and a degraded",
-        "  chip show up as occupancy imbalance.",
+        "- **queries** — the query ids in the file with their span",
+        "  counts and longest kinds; `--query <id>` restricts every",
+        "  section to one.",
+        "- **per-chip enqueue occupancy** — the union of the HOST",
+        "  intervals of chip-attributed spans (uploads, dispatches):",
+        "  when programs were enqueued for a chip, not when it ran",
+        "  them (dispatch is asynchronous). Mesh skew shows up here;",
+        "  the device's own busy time is in a profiler trace.",
         "- **top slowest spans** and **instant marker counts** (retry",
         "  storms surface here).",
+        "",
+        "`python -m spark_rapids_tpu.tools trace <profile dir>` takes a",
+        "`jax.profiler` directory (`plugins/profile/*/*.xplane.pb`, or",
+        "one such file) instead: the host analyzers run over the",
+        "engine's annotations, and per chip the device planes give",
+        "**occupancy** (busy = union of the `XLA Ops` intervals, within",
+        "the queries' extent), **idle by host span** (each gap of 1 ms",
+        "or more goes, instant by instant, to the deepest engine span",
+        "covering it on any host thread — the one that started last —",
+        "or to `no_span`; totals by kind and by `q`), and **device",
+        "time by program and named scope**.",
+        "",
+        "How to find why the device was idle: (1) record a profile"
+        " around the queries (`jax.profiler.trace(dir)`, or the"
+        " benchmark's `--trace 1`); (2) `tools trace <dir>`; (3) read"
+        " `idle by the host span`: `plan`/`scanPrefetch` before"
+        " `firstDispatch` is start-up, `deviceSync site=...` is the"
+        " host waiting for a result it needs before it can enqueue"
+        " more, `no_span` is host code nobody has instrumented; (4)"
+        " `longest gaps` gives each gap's time, owner and `q`; (5)"
+        " `device time by program` says what the busy part ran.",
         "",
         "`bench.py` runs a traced q1 leg (`detail.trace`): occupancy,",
         "critical-path breakdown, and measured tracing overhead vs the",
@@ -1527,15 +2130,16 @@ def generate_observability_docs() -> str:
         "these tables; metric-mirror spans are the dynamic",
         "`<Exec>.<metric>` family covered by `metric-key`):",
         "",
-        "| Span kind | Meaning |",
-        "|---|---|",
+        "| Span kind | Meaning | Read by |",
+        "|---|---|---|",
     ]
-    from spark_rapids_tpu.trace import INSTANT_CATALOG, SPAN_CATALOG
+    from spark_rapids_tpu.trace import (INSTANT_CATALOG, KIND_READERS,
+                                        SPAN_CATALOG)
     for kind, desc in sorted(SPAN_CATALOG.items()):
-        lines.append(f"| `{kind}` | {desc} |")
-    lines += ["", "| Instant kind | Meaning |", "|---|---|"]
+        lines.append(f"| `{kind}` | {desc} | {KIND_READERS[kind]} |")
+    lines += ["", "| Instant kind | Meaning | Read by |", "|---|---|---|"]
     for kind, desc in sorted(INSTANT_CATALOG.items()):
-        lines.append(f"| `{kind}` | {desc} |")
+        lines.append(f"| `{kind}` | {desc} | {KIND_READERS[kind]} |")
     lines += [
         "",
         "## Metric-name reference",

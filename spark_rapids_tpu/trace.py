@@ -14,7 +14,13 @@ recorded at the engine's existing choke points and exported as
 Chrome-trace-event JSON — one file per query under
 ``spark.rapids.sql.trace.dir`` — that loads directly in Perfetto /
 chrome://tracing.  ``tools.py trace <file>`` analyzes the same stream
-offline (critical path, exclusive self-time, per-chip occupancy).
+offline (critical path, exclusive self-time, per-chip enqueue
+occupancy).  Every span is also a ``jax.profiler.TraceAnnotation``
+over the same interval, so any profiler session shows the engine's
+spans on the host planes beside the device's operations, on one clock
+(``tools.py trace <profile dir>`` reads those: true device busy/idle,
+idle gaps put down to host spans, device time by program and scope).
+Every record carries its query's id (``QueryScope``).
 
 Integration contract (docs/observability.md):
 
@@ -28,21 +34,25 @@ Integration contract (docs/observability.md):
   span so the offline analyzer's *exclusive* self-time report undoes
   the documented retryBlockTime-inside-opTime double count.
 
-Overhead discipline: when no trace is active (``trace.enabled`` off,
-or the query was not sampled per ``trace.sampleRate``) every hook is a
-single module-global ``None`` check; span recording itself is a tuple
-append under the GIL (no lock on the hot path).
+Overhead discipline: when no trace sink is open (``trace.enabled``
+off, or the query was not sampled per ``trace.sampleRate``) a span
+costs two clock reads, its ``TraceAnnotation`` (a flag test outside a
+profiler session, about a microsecond in all) and one module-global
+``None`` check; span recording itself is a tuple append under the GIL
+(no lock on the hot path).
 """
 
 from __future__ import annotations
 
-import contextlib
+import itertools
 import json
 import os
 import random
 import threading
 import time
-from typing import Any, Dict, Iterator, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from spark_rapids_tpu.conf import conf
 
@@ -122,7 +132,14 @@ SPAN_CATALOG: Dict[str, str] = {
     "meshExchange": "HBM-resident all-to-all data exchange over the "
                     "mesh",
     "compile": "JIT build+compile on a cache miss (cache= names the "
-               "LRU)",
+               "LRU, program= the srt_ program built)",
+    "srt.query": "root span of one query, session.execute_plan start "
+                 "to end (q=, tenant=, parent= for a scalar subquery)",
+    "plan": "host planning on the calling thread (mirrors planTime): "
+            "phase=parse in session.sql, phase=rewrite in execute_plan "
+            "up to execute_collect (cacheHit= plan-cache outcome)",
+    "deviceSync": "the calling thread blocked reading a device value "
+                  "back (mirrors deviceSyncTime; site= names the read)",
     "semaphoreWait": "wall blocked on the device semaphore",
     "serveQueueWait": "admission-queue wait of a served query "
                       "(docs/serving.md)",
@@ -144,6 +161,9 @@ SPAN_CATALOG: Dict[str, str] = {
 }
 
 INSTANT_CATALOG: Dict[str, str] = {
+    "firstDispatch": "the query's first enqueue of any device program "
+                     "(program= names it; firstDispatchTime holds the "
+                     "nanoseconds since the query began)",
     "retryOOM": "an OOM retry re-attempted the operation",
     "splitRetry": "an input batch split in half after OOM exhaustion",
     "ioRetry": "a transient reader IO error was retried",
@@ -164,6 +184,219 @@ INSTANT_CATALOG: Dict[str, str] = {
                   "grouping-key hash (modulus=/depth=; "
                   "docs/out_of_core.md)",
 }
+
+
+# Who reads each kind (docs/observability.md renders this beside the
+# catalogs): tracing is code on the hot path, so a kind nobody reads —
+# no `tools` report, no trigger, no benchmark metric — is deleted with
+# its catalog line, not kept. Every kind is also in `tools trace`'s
+# generic sections (critical path, self-time, slowest spans, marker
+# counts) and, when annotated, a candidate owner of a device idle gap in
+# `tools trace <profile dir>`; what is listed here is the SPECIFIC use.
+_GENERIC = "`tools trace` critical path / self-time / idle-gap owner"
+KIND_READERS: Dict[str, str] = {
+    "scanPrefetch": "`tools doctor` scan stage; mirrors scanPrefetchTime "
+                    "-> benchmark `scan_host_s`; idle-gap owner while "
+                    "the scan starts",
+    "uploadAhead": "`tools doctor` scan stage; " + _GENERIC,
+    "finishUpload": _GENERIC + " (per staging mode and chip)",
+    "TpuFusedStageExec.dispatch": "`tools trace` enqueue occupancy per "
+                                  "chip; program= ties a host enqueue "
+                                  "to the device's program",
+    "TpuHashAggregateExec.dispatch": "`tools hotspots` per-kernel/bucket "
+                                     "split; enqueue occupancy",
+    "kernelDispatch": "`tools hotspots` per-kernel/bucket split "
+                      "(autotuner targets)",
+    "exchangeMaterialize": _GENERIC + " (the exchange's drain wall)",
+    "meshStack": _GENERIC + " (four-chip runs)",
+    "meshSizeExchange": _GENERIC + " (four-chip runs)",
+    "meshExchange": _GENERIC + " (four-chip runs)",
+    "compile": "`tools doctor` compileStorm verdict (compile stage)",
+    "srt.query": "`tools trace` per-query table and `--query`; the "
+                 "window of `tools trace <profile dir>`",
+    "plan": "mirrors planTime -> benchmark `plan_host_s`; idle-gap owner "
+            "before the first dispatch",
+    "deviceSync": "mirrors deviceSyncTime -> benchmark "
+                  "`device_wait_host_s`; idle-gap owner (site= says "
+                  "which read)",
+    "semaphoreWait": _GENERIC + " (mirrors semaphoreWaitTime)",
+    "serveQueueWait": _GENERIC + " of served queries (admission wait)",
+    "spillToHost": _GENERIC + " (store tier movement)",
+    "spillToDisk": _GENERIC + " (store tier movement)",
+    "promoteFromDisk": _GENERIC + " (store tier movement)",
+    "promoteToDevice": _GENERIC + " (store tier movement)",
+    "retryBlock": "`tools doctor` retrySpill verdict (divergent stage); "
+                  "subtracted from operator self-time",
+    "aqeReplan": _GENERIC + " (adaptive replans)",
+    "resultCacheHit": _GENERIC + " of served queries (a hit's whole "
+                      "wall)",
+    "cacheEntryDrop": _GENERIC + " (cache-tier pressure)",
+    "firstDispatch": "mirrors firstDispatchTime -> benchmark "
+                     "`first_dispatch_s`; marks the end of the first "
+                     "device gap",
+    "retryOOM": "`tools trace` marker counts (retry storms)",
+    "splitRetry": "`tools trace` marker counts (retry storms)",
+    "ioRetry": "`tools trace` marker counts",
+    "chipFailure": "`tools trace` marker counts (degraded mesh)",
+    "compileCacheContention": "`tools trace` marker counts "
+                              "(single-flight waits)",
+    "queryEnd": "query boundaries in a flight-recorder dump (slow-query "
+                "bundles, `tools trace` on ring dumps)",
+    "telemetryTrigger": "marks in a ring dump where a trigger fired "
+                        "(bundle forensics)",
+    "queryCancelled": "`tools trace` marker counts (lifecycle forensics)",
+    "oocJoinPlan": "`tools trace` marker counts (out-of-core plans)",
+    "oocAggPlan": "`tools trace` marker counts (out-of-core plans)",
+}
+
+
+# ---------------------------------------------------------------------------
+# The per-query record: one id on every span, whichever sink is open
+# ---------------------------------------------------------------------------
+
+_QSEQ = itertools.count(1)      # next() is atomic under the GIL
+_FIRST_LOCK = threading.Lock()  # QueryScope.first_dispatch
+_QTLS = threading.local()
+
+
+class QueryScope:
+    """One query's identity for the tracing layer: ``q`` (process-wide
+    id), ``tenant``, ``t_begin`` (``perf_counter_ns`` at
+    ``execute_plan``'s start), ``parent`` (the enclosing query's id for
+    a scalar subquery). Its host intervals (planTime,
+    firstDispatchTime, deviceSyncTime) go to the process's
+    ``metrics.query_registry``.
+
+    It reaches the work two ways. ``execute_plan`` stamps it on every
+    registry of the executing plan (``stamp_plan``), so code that holds
+    a registry — on whatever pool thread — finds it as
+    ``scope_of(metrics)``; and it is the calling thread's
+    ``current_scope()``, which ``attach`` re-enters on the task, scan
+    and shuffle pool threads for the sites that hold no registry.
+
+    ``pending`` marks a scope opened ahead of execution (``session.sql``
+    for the parse, the server before admission): the next
+    ``execute_plan`` on that plan or thread adopts it instead of
+    nesting under it."""
+
+    __slots__ = ("q", "tenant", "t_begin", "parent", "pending",
+                 "running", "_dispatched")
+
+    def __init__(self, tenant: Optional[str] = None,
+                 pending: bool = False):
+        outer = getattr(_QTLS, "scope", None)
+        self.q = next(_QSEQ)
+        self.tenant = tenant
+        self.t_begin = time.perf_counter_ns()
+        # a parent is a query whose execute_plan is on this thread's
+        # stack right now (scalar subqueries run inside its planning)
+        self.parent = (outer.q if outer is not None and outer.running
+                       else None)
+        self.pending = pending
+        self.running = False
+        self._dispatched = False
+
+    def first_dispatch(self, program: Optional[str]) -> None:
+        """Called at every enqueue of a device program; the first one
+        of the query books firstDispatchTime (ns since ``t_begin``)
+        and the ``firstDispatch`` instant — exactly once: the task
+        threads race for it under one process-wide lock that only
+        first dispatches ever take."""
+        with _FIRST_LOCK:
+            if self._dispatched:
+                return
+            self._dispatched = True
+        from spark_rapids_tpu import metrics as M
+        M.query_registry().create(
+            M.FIRST_DISPATCH_TIME, M.ESSENTIAL).add(
+            time.perf_counter_ns() - self.t_begin)
+        instant("firstDispatch", scope=self, program=program)
+
+
+def current_scope() -> Optional[QueryScope]:
+    """The calling thread's query (None outside any)."""
+    return getattr(_QTLS, "scope", None)
+
+
+def scope_of(metrics) -> Optional[QueryScope]:
+    """The query a metric registry was stamped with, else the calling
+    thread's."""
+    sc = getattr(metrics, "_query", None)
+    return sc if sc is not None else getattr(_QTLS, "scope", None)
+
+
+class attach:
+    """``with attach(scope):`` — make ``scope`` the calling thread's
+    query for the block (pool threads re-enter the creating thread's
+    scope, exactly like ``lifecycle.token_scope``); None is a no-op."""
+
+    __slots__ = ("scope", "prev")
+
+    def __init__(self, scope: Optional[QueryScope]):
+        self.scope = scope
+
+    def __enter__(self):
+        self.prev = getattr(_QTLS, "scope", None)
+        if self.scope is not None:
+            _QTLS.scope = self.scope
+        return self.scope
+
+    def __exit__(self, *exc):
+        _QTLS.scope = self.prev
+        return False
+
+
+def stamp_plan(physical, scope: Optional[QueryScope]) -> None:
+    """Tag every metric registry of ``physical`` (fused constituents
+    included) with the executing query, the way
+    ``memory.stamp_plan_tenant`` tags the tenant: the registry travels
+    with the exec's closures into whatever pool thread does the work."""
+    if scope is None or physical is None:
+        return
+    from spark_rapids_tpu.metrics import plan_registries
+    for reg in plan_registries(physical):
+        reg._query = scope
+
+
+def first_dispatch(metrics, fn) -> None:
+    """Dispatch-site hook: ``fn`` (a ``named_jit`` program) is about to
+    be enqueued on behalf of ``metrics``' query."""
+    sc = scope_of(metrics)
+    if sc is not None and not sc._dispatched:
+        from spark_rapids_tpu.jit_cache import program_of
+        sc.first_dispatch(program_of(fn))
+
+
+def device_sync(site: str, metrics=None) -> "span":
+    """``with device_sync("rowCount", metrics):`` around a host read of
+    a device value (``np.asarray``, ``int()``, ``bool()`` of a device
+    array): the calling thread blocks there until the device has
+    produced it. Books thread-nanoseconds into the query's
+    deviceSyncTime and a ``deviceSync`` span (``site=``)."""
+    from spark_rapids_tpu import metrics as M
+    return span("deviceSync", scope=scope_of(metrics), site=site,
+                timer=M.query_registry().create(M.DEVICE_SYNC_TIME,
+                                                M.ESSENTIAL))
+
+
+def annotation(kind: str, scope: Optional[QueryScope] = None,
+               batch=None, attrs: Optional[dict] = None
+               ) -> TraceAnnotation:
+    """The span ``kind`` as a ``jax.profiler.TraceAnnotation`` (enter
+    and exit it on ONE thread): any profiler session then shows the
+    engine's spans on the host planes beside the device's ``XLA Ops``,
+    on one clock. Not gated by ``trace.enabled`` — outside a profiler
+    session entering it is a flag test."""
+    kw = {}
+    if scope is not None:
+        kw["q"] = scope.q
+    if batch is not None:
+        kw["batch"] = batch
+    if attrs:
+        for k, v in attrs.items():
+            if v is not None:
+                kw[k] = v
+    return TraceAnnotation(kind, **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -207,14 +440,27 @@ class QueryTrace:
     def add(self, kind: str, t0: int, t1: int, batch=None, chip=None,
             **attrs) -> None:
         self.spans.append((kind, t0, t1, self._thread(), batch, chip,
-                           _clean(attrs)))
+                           _clean(_with_q(attrs))))
 
     def mark(self, kind: str, **attrs) -> None:
         self.instants.append((kind, time.perf_counter_ns(),
-                              self._thread(), _clean(attrs)))
+                              self._thread(), _clean(_with_q(attrs))))
 
     def count(self, series: str, value) -> None:
         self.counters.append((series, time.perf_counter_ns(), value))
+
+
+def _with_q(attrs: dict, scope=None) -> dict:
+    """Every record says whose it is: ``q`` (and ``parent`` for a
+    scalar subquery) from the ``scope`` the caller holds, else from
+    the recording thread's (``attach`` carries it onto pool threads)."""
+    if "q" not in attrs:
+        sc = scope if scope is not None else getattr(_QTLS, "scope", None)
+        if sc is not None:
+            attrs["q"] = sc.q
+            if sc.parent is not None:
+                attrs["parent"] = sc.parent
+    return attrs
 
 
 def _clean(attrs: dict) -> Optional[dict]:
@@ -262,6 +508,7 @@ def reset_tracing() -> None:
         _SEQ = 0
         _RNG = None
         _RNG_SEED = None
+    _QTLS.scope = None
 
 
 def begin_query(conf_obj) -> Optional[str]:
@@ -339,7 +586,7 @@ def end_query(conf_obj, token: Optional[str], wall_s: float = 0.0,
         qt = ring_active()
         if qt is not None:
             qt.mark("queryEnd", wallSeconds=round(wall_s, 6), rows=rows,
-                    error=bool(error) or None)
+                    error=bool(error) or None)  # q= from the thread's scope
         return None
     with _LOCK:
         _DEPTH = max(0, _DEPTH - 1)
@@ -365,28 +612,67 @@ def end_query(conf_obj, token: Optional[str], wall_s: float = 0.0,
 # Recording helpers (the instrumentation surface)
 # ---------------------------------------------------------------------------
 
-@contextlib.contextmanager
-def span(kind: str, batch=None, chip=None, **attrs) -> Iterator[None]:
-    """Trace-only span (sites whose duration already reaches a metric
-    through another channel, e.g. store stats). One None check when
-    tracing is off."""
-    qt = _ACTIVE
-    if qt is None:
-        yield
-        return
-    t0 = time.perf_counter_ns()
-    try:
-        yield
-    finally:
-        qt.add(kind, t0, time.perf_counter_ns(), batch=batch, chip=chip,
-               **attrs)
+class span:
+    """``with span(kind, ...):`` — one interval into both channels: the
+    profiler's trace (a ``TraceAnnotation``, always) and the host span
+    stream (when a trace sink is open). ``scope`` (or ``metrics``, a
+    stamped registry) names the query; the calling thread's scope is
+    the fallback. ``timer`` additionally books the interval into that
+    always-on ``MetricRegistry`` timer — measured once, so the metric,
+    the span and the annotation agree. Must be entered and left on one
+    thread."""
+
+    __slots__ = ("kind", "scope", "batch", "chip", "attrs", "timer",
+                 "t0", "t1", "_ann")
+
+    def __init__(self, kind: str, batch=None, chip=None, scope=None,
+                 metrics=None, timer=None, **attrs):
+        self.kind = kind
+        self.scope = scope if scope is not None else scope_of(metrics)
+        self.batch = batch
+        self.chip = chip
+        self.attrs = attrs
+        self.timer = timer
+
+    def __enter__(self):
+        self._ann = annotation(self.kind, self.scope, self.batch,
+                               self.attrs)
+        self._ann.__enter__()
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = self.t1 = time.perf_counter_ns()
+        self._ann.__exit__(*exc)
+        if self.timer is not None:
+            self.timer.add(t1 - self.t0)
+        qt = _ACTIVE
+        if qt is not None:
+            record(qt, self.kind, self.t0, t1, self.scope, self.batch,
+                   self.chip, self.attrs)
+        return False
 
 
-def instant(kind: str, **attrs) -> None:
-    """Point-in-time marker (retry/backoff/split/chip-failure events)."""
+def record(qt, kind: str, t0: int, t1: int, scope=None, batch=None,
+           chip=None, attrs: Optional[dict] = None) -> None:
+    """Append one finished interval to the open sink ``qt`` under
+    ``scope``'s query id (host stream only: the interval is over, so
+    the profiler cannot be told — sites that can, use ``span``)."""
+    qt.add(kind, t0, t1, batch=batch, chip=chip,
+           **_with_q(dict(attrs) if attrs else {}, scope))
+
+
+def instant(kind: str, scope=None, **attrs) -> None:
+    """Point-in-time marker (retry/backoff/split/chip-failure events):
+    a zero-length annotation in the profiler's trace, a marker in the
+    host stream."""
+    if scope is None:
+        scope = getattr(_QTLS, "scope", None)
+    with annotation(kind, scope, None, attrs):
+        pass
     qt = _ACTIVE
     if qt is not None:
-        qt.mark(kind, **attrs)
+        qt.mark(kind, **_with_q(attrs, scope))
 
 
 def counter(series: str, value) -> None:

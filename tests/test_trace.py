@@ -306,7 +306,7 @@ def test_tools_trace_cli_smoke(tmp_path, capsys):
     assert _main(["trace", path]) == 0
     out = capsys.readouterr().out
     assert "critical path" in out
-    assert "per-chip occupancy" in out
+    assert "per-chip enqueue occupancy" in out
     assert "exclusive self-time" in out
     # directory mode reports every trace in it
     assert _main(["trace", str(tdir)]) == 0
@@ -414,3 +414,238 @@ def test_semaphore_wait_timed_on_exchange_and_broadcast_paths():
     assert found["exchange"] or found["broadcast"], (
         "semaphoreWaitTime recorded on neither the exchange drain nor "
         "the broadcast build")
+
+
+# ---------------------------------------------------------------------------
+# One id per query on every record; the same spans on the profiler's clock;
+# the three per-query timers (docs/observability.md)
+# ---------------------------------------------------------------------------
+
+def _all_records(doc) -> list:
+    return [(s["name"], s["args"]) for s in doc["spans"]] + \
+        [(i["name"], i["args"]) for i in doc["instants"]]
+
+
+def test_every_record_carries_its_query_id(tmp_path):
+    """One traced query: every span and instant says whose it is, and
+    the file holds its root, its plan and its first dispatch."""
+    _run(_q1_silhouette, _conf(tmp_path / "tr", **{
+        "spark.rapids.sql.taskParallelism": "2"}))
+    doc = TR.load_trace(_trace_files(tmp_path / "tr")[0])
+    recs = _all_records(doc)
+    assert recs and all("q" in a for _n, a in recs), [
+        n for n, a in recs if "q" not in a]
+    assert len({a["q"] for _n, a in recs}) == 1
+    names = {n for n, _a in recs}
+    assert {"srt.query", "plan", "firstDispatch", "deviceSync"} <= names
+    first = [a for n, a in recs if n == "firstDispatch"]
+    assert len(first) == 1 and first[0]["program"].startswith("srt_")
+    dispatch = [a for n, a in recs if n.endswith(".dispatch")]
+    assert dispatch and all(a["program"].startswith("srt_")
+                            for a in dispatch)
+
+
+def test_concurrent_queries_record_disjoint_query_ids(tmp_path):
+    """Two queries at once fold into ONE open file trace (the sinks are
+    process-wide) — but no record is without a `q`, the two ids are
+    distinct, and each query's spans stay its own: `--query` filters
+    one out."""
+    import threading
+    from spark_rapids_tpu.tools import analyze_trace
+    s1 = TpuSparkSession(_conf(tmp_path / "tr", **{
+        "spark.rapids.sql.taskParallelism": "2"}))
+    s2 = TpuSparkSession(_conf(tmp_path / "tr", **{
+        "spark.rapids.sql.taskParallelism": "2"}))
+    gate = threading.Barrier(2, timeout=60)
+    scopes, errors = {}, []
+
+    def run(name, spark, df_fn):
+        try:
+            df = df_fn(spark)
+            gate.wait()
+            for _ in range(3):
+                df._execute()
+            scopes[name] = True
+        except BaseException as e:  # surfaced below
+            errors.append(e)
+
+    try:
+        ts = [threading.Thread(target=run, args=("a", s1, _q1_silhouette)),
+              threading.Thread(target=run, args=("b", s2, _q3_silhouette))]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=300)
+        assert not any(t.is_alive() for t in ts) and not errors, errors
+    finally:
+        s1.stop()
+        s2.stop()
+    files = _trace_files(tmp_path / "tr")
+    assert files
+    seen = set()
+    for f in files:
+        doc = TR.load_trace(f)
+        recs = _all_records(doc)
+        assert all("q" in a for _n, a in recs), [
+            (n, a) for n, a in recs if "q" not in a][:5]
+        qs = {a["q"] for _n, a in recs}
+        seen |= qs
+        # every query id owns exactly one root, and its records never
+        # claim another's
+        roots = [a["q"] for n, a in recs if n == "srt.query"]
+        assert len(roots) == len(set(roots))
+        for q in qs:
+            only = analyze_trace(f, query=q)
+            assert only["queries"] == [q]
+            assert 0 < only["spanCount"] <= len(doc["spans"])
+    # a query that begins while another's file is open folds into that
+    # file and is cut off when it closes, so not all six executions
+    # (2 threads x 3) leave records — but the two that overlap do
+    assert 2 <= len(seen) <= 6, seen
+
+
+def test_scalar_subquery_spans_carry_their_parents_id(tmp_path):
+    spark = TpuSparkSession(_conf(tmp_path / "tr"))
+    try:
+        spark.createDataFrame(
+            {"a": list(range(100))}, "a int").createOrReplaceTempView("t")
+        rows = spark.sql("SELECT count(*) c FROM t WHERE a > "
+                         "(SELECT avg(a) FROM t)").collect()
+        assert rows[0][0] == 50
+    finally:
+        spark.stop()
+    doc = TR.load_trace(_trace_files(tmp_path / "tr")[0])
+    roots = {a["q"]: a.get("parent") for n, a in _all_records(doc)
+             if n == "srt.query"}
+    assert len(roots) == 2
+    (outer,) = [q for q, p in roots.items() if p is None]
+    (inner,) = [q for q, p in roots.items() if p is not None]
+    assert roots[inner] == outer
+    assert all(a.get("parent") == outer for _n, a in _all_records(doc)
+               if a.get("q") == inner)
+
+
+def test_engine_spans_reach_the_profilers_trace(tmp_path):
+    """With NO trace conf set, any jax.profiler session gets the
+    engine's spans as TraceAnnotations on the host planes, `q` set:
+    the root, the plan, a dispatch and a device sync."""
+    import jax
+    from spark_rapids_tpu.tools import load_profile, profile_files
+    spark = TpuSparkSession(_conf())       # tracing conf OFF
+    try:
+        df = _q1_silhouette(spark)
+        df._execute()                      # compile outside the trace
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 1
+        jax.profiler.start_trace(str(tmp_path / "prof"),
+                                 profiler_options=options)
+        try:
+            df._execute()
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        spark.stop()
+    (xplane,) = profile_files(str(tmp_path / "prof"))
+    spans = load_profile(xplane)["spans"]
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s["name"], []).append(s)
+    assert {"srt.query", "plan", "deviceSync"} <= set(by_name), \
+        sorted(by_name)
+    dispatch = [s for n, ss in by_name.items() if n.endswith(".dispatch")
+                for s in ss]
+    assert dispatch
+    (root,) = by_name["srt.query"]
+    q = root["args"]["q"]
+    for s in by_name["plan"] + by_name["deviceSync"] + dispatch:
+        assert s["args"]["q"] == q
+        assert root["t0"] <= s["t0"] and s["t1"] <= root["t1"] + 1.0
+    assert all(str(s["args"]["program"]).startswith("srt_")
+               for s in dispatch)
+    assert {s["args"]["site"] for s in by_name["deviceSync"]}
+    # the CLI reads the directory (no device plane on the CPU)
+    from spark_rapids_tpu.tools import _main
+    assert _main(["trace", str(tmp_path / "prof")]) == 0
+
+
+def test_query_timers_in_the_process_totals(tmp_path):
+    """planTime, firstDispatchTime and deviceSyncTime are always-on
+    registry timers: they show in the process totals the benchmark
+    reads (no trace conf needed) and grow with every query;
+    firstDispatchTime is booked once per query."""
+    from spark_rapids_tpu.telemetry.prometheus import aggregator
+    timers = (M.PLAN_TIME, M.FIRST_DISPATCH_TIME, M.DEVICE_SYNC_TIME)
+
+    def totals():
+        return dict(aggregator().scrape()[0])
+
+    spark = TpuSparkSession(_conf(**{
+        "spark.rapids.sql.taskParallelism": "4"}))   # tracing conf OFF
+    try:
+        df = _q1_silhouette(spark)
+        df._execute()
+        seen = [totals()]
+        for _ in range(3):
+            df._execute()
+            seen.append(totals())
+    finally:
+        spark.stop()
+    for k in timers:
+        assert M.describe_metric(k)
+        vals = [t.get(k, 0) for t in seen]
+        assert vals[0] > 0 and vals == sorted(set(vals)), (k, vals)
+    # once per query: each traced query's file holds ONE firstDispatch,
+    # though four task threads race to the first enqueue
+    traced = TpuSparkSession(_conf(tmp_path / "tr", **{
+        "spark.rapids.sql.taskParallelism": "4"}))
+    try:
+        df = _q1_silhouette(traced)
+        for _ in range(3):
+            df._execute()
+    finally:
+        traced.stop()
+    files = _trace_files(tmp_path / "tr")
+    assert len(files) == 3
+    for f in files:
+        firsts = [i for i in TR.load_trace(f)["instants"]
+                  if i["name"] == "firstDispatch"]
+        assert len(firsts) == 1, firsts
+
+
+def test_annotation_path_overhead_bound_with_no_profiler_on():
+    """The annotation every span now enters costs, with no profiler
+    session, on the order of a microsecond: 20k spans through
+    MetricRegistry.timed (metric + annotation, no sink) stay under
+    10 us each on average — at q1's few hundred spans a query that is
+    far below 0.1% of a 21 s wall."""
+    import time
+    TR.reset_tracing()
+    reg = M.MetricRegistry(owner="Overhead")
+    n = 20000
+    t0 = time.perf_counter()
+    for i in range(n):
+        with reg.timed(M.OP_TIME):
+            pass
+    per_span = (time.perf_counter() - t0) / n
+    assert reg.value(M.OP_TIME) > 0
+    assert per_span < 10e-6, per_span
+    t0 = time.perf_counter()
+    for i in range(n):
+        with TR.device_sync("rowCount", reg):
+            pass
+    assert (time.perf_counter() - t0) / n < 15e-6
+
+
+def test_every_span_kind_names_its_reader():
+    """A kind nobody reads is deleted with its catalog line: the
+    readers' table covers exactly the two catalogs, and the generated
+    doc prints it."""
+    kinds = set(TR.SPAN_CATALOG) | set(TR.INSTANT_CATALOG)
+    assert set(TR.KIND_READERS) == kinds
+    assert all(TR.KIND_READERS[k].strip() for k in kinds)
+    from spark_rapids_tpu.tools import generate_observability_docs
+    doc = generate_observability_docs()
+    assert "| Span kind | Meaning | Read by |" in doc
+    for k in ("plan", "deviceSync", "firstDispatch", "srt.query"):
+        assert f"| `{k}` |" in doc
