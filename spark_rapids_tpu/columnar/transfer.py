@@ -636,7 +636,23 @@ def _encoded_decode_body(layout: Tuple, cap: int, words, n_arr, extras,
     ``pallas_call``) — bit-identity between the two paths is
     structural, not tested-into (the murmur3 kernel's model).
     ``char_chunk`` bounds the string char-gather's live index matrix
-    (autotunable; 0 = unchunked) without changing a byte."""
+    (autotunable; 0 = unchunked) without changing a byte.
+
+    Every column is decoded in DENSE coordinates: lane i of
+    ``arange(cap)`` is the i-th stored (non-null) value of the chunk.
+    Its page and its run come from ``rle.run_index`` (one scatter of
+    the table's starts and one prefix sum; no per-lane search, no
+    loop), and each stored value is decoded exactly once. Rows reach
+    their values by ONE gather through ``j`` (row -> dense rank) at
+    the end, and a column without definition levels (``ndl == 0``, a
+    static fact of the layout) skips it: there every active row IS its
+    own dense lane, and rows past ``n`` are zeroed by ``validity``
+    either way. Each lane runs under a ``jax.named_scope``
+    (``decode_run_lookup``, ``decode_page_lookup``, ``decode_bits``
+    with ``/bytes``, ``/run_fields`` and ``/window`` inside it,
+    ``decode_dict``, ``decode_plain``, ``decode_chars``,
+    ``decode_delta``, ``decode_rows``) so a device profile ranks them
+    (``tools trace <profile dir>``, docs/observability.md)."""
     from spark_rapids_tpu.io.device_decode import (PGE_BSS, PGE_DELTA,
                                                    PGE_DICT, PGE_DL_STR,
                                                    PGE_PLAIN_STR)
@@ -646,8 +662,15 @@ def _encoded_decode_body(layout: Tuple, cap: int, words, n_arr, extras,
     def get_bytes():
         nonlocal bytes_all
         if bytes_all is None:
-            bytes_all = R.bytes_of_words(words)
+            with jax.named_scope("decode_bits/bytes"):
+                bytes_all = R.bytes_of_words(words)
         return bytes_all
+
+    def rows(dense, j):
+        if j is None:
+            return dense
+        with jax.named_scope("decode_rows"):
+            return dense[j]
 
     active = jnp.arange(cap) < n_arr
     pos = jnp.arange(cap, dtype=jnp.int64)
@@ -671,12 +694,17 @@ def _encoded_decode_body(layout: Tuple, cap: int, words, n_arr, extras,
             pg_first = extras[cur]
             cur += 1
         if ndl:
+            # definition levels are per ROW: row i's level is lane i of
+            # their stream; every other lookup below is per stored value
             dl = extras[cur:cur + 5]
             cur += 5
             dl_v = R.hybrid_lookup(get_bytes(), pos, *dl)
             validity = (dl_v == 1) & active
+            with jax.named_scope("decode_rows"):
+                j = jnp.clip(R.dense_ranks(validity), 0, cap - 1)
         else:
             validity = active
+            j = None
         vr = None
         if nvr:
             vr = extras[cur:cur + 5]
@@ -692,120 +720,111 @@ def _encoded_decode_body(layout: Tuple, cap: int, words, n_arr, extras,
         dicts = [extras[cur + i] for i in range(len(dict_shapes))]
         cur += len(dict_shapes)
 
-        j = jnp.clip(R.dense_ranks(validity), 0, cap - 1) \
-            .astype(jnp.int64)
         if kind == "bool":
-            v = R.hybrid_lookup(get_bytes(), j, *vr)
+            v = rows(R.hybrid_lookup(get_bytes(), pos, *vr), j)
             data = jnp.where(validity, v != 0, False)
             outs.extend([data, validity])
             continue
-        pg = jnp.clip(
-            jnp.searchsorted(dense_start, j, side="right") - 1,
-            0, npg - 1)
-        local = j - dense_start[pg]
-        enc_pg = pg_enc[pg]
+        with jax.named_scope("decode_page_lookup"):
+            pg = jnp.minimum(R.run_index(dense_start, cap), npg - 1)
+            pg_start = dense_start[pg]
+            local = pos - pg_start
+            enc_pg = pg_enc[pg]
         didx = None
         if vr is not None and dict_shapes:
-            didx = jnp.clip(R.hybrid_lookup(get_bytes(), j, *vr),
+            didx = jnp.clip(R.hybrid_lookup(get_bytes(), pos, *vr),
                             0, dict_shapes[0][0][0] - 1)
         if kind == "str":
             if has_slen:
-                # offset+bytes model (SURVEY.md §7 c), computed in
-                # DENSE coordinates (pos) — each stored value's
-                # footprint counts exactly once even when null rows
-                # repeat a dense index through j: offsets are a
+                # offset+bytes model (SURVEY.md §7 c): each stored
+                # value's footprint counts exactly once — offsets are a
                 # per-page segmented prefix-sum over the byte
                 # footprints (PLAIN values add their 4-byte length
                 # prefix), then one gather builds the char matrix
-                pgd = jnp.clip(
-                    jnp.searchsorted(dense_start, pos,
-                                     side="right") - 1, 0, npg - 1)
-                encd = pg_enc[pgd]
-                sl_d = slen.astype(jnp.int64)
-                lp_d = jnp.where(encd == PGE_PLAIN_STR, 4, 0) \
-                    .astype(jnp.int64)
-                is_str_d = (encd == PGE_PLAIN_STR) \
-                    | (encd == PGE_DL_STR)
-                contrib = jnp.where(is_str_d, sl_d + lp_d, 0)
-                based = jnp.clip(dense_start[pgd], 0, cap - 1)
-                rel_d = R.seg_excl_cumsum(contrib, based)
-                start_d = plain_byte[pgd] + rel_d + lp_d
-                jj = jnp.clip(j, 0, cap - 1)
-                pchars = R.gather_chars_chunked(get_bytes(), start_d[jj],
-                                                sl_d[jj].astype(jnp.int32),
-                                                char_cap, char_chunk)
-                plens = sl_d[jj].astype(jnp.int32)
+                with jax.named_scope("decode_chars"):
+                    lp = jnp.where(enc_pg == PGE_PLAIN_STR, 4, 0) \
+                        .astype(jnp.int64)
+                    is_str = (enc_pg == PGE_PLAIN_STR) \
+                        | (enc_pg == PGE_DL_STR)
+                    contrib = jnp.where(
+                        is_str, slen.astype(jnp.int64) + lp, 0)
+                    rel = R.seg_excl_cumsum(
+                        contrib, jnp.clip(pg_start, 0, cap - 1))
+                    plens = slen.astype(jnp.int32)
+                    pchars = R.gather_chars_chunked(
+                        get_bytes(), plain_byte[pg] + rel + lp, plens,
+                        char_cap, char_chunk)
             else:
                 pchars = jnp.zeros((cap, char_cap), dtype=jnp.uint8)
                 plens = jnp.zeros(cap, dtype=jnp.int32)
             if didx is not None:
-                is_dict_pg = enc_pg == PGE_DICT
-                chars = jnp.where(is_dict_pg[:, None],
-                                  dicts[0][didx], pchars)
-                lengths = jnp.where(is_dict_pg,
-                                    dicts[1][didx].astype(jnp.int32),
-                                    plens)
+                with jax.named_scope("decode_dict"):
+                    is_dict_pg = enc_pg == PGE_DICT
+                    chars = jnp.where(is_dict_pg[:, None],
+                                      dicts[0][didx], pchars)
+                    lengths = jnp.where(
+                        is_dict_pg, dicts[1][didx].astype(jnp.int32),
+                        plens)
             else:
                 chars, lengths = pchars, plens
-            chars = jnp.where(validity[:, None], chars, 0)
-            lengths = jnp.where(validity, lengths, 0)
+            chars = jnp.where(validity[:, None], rows(chars, j), 0)
+            lengths = jnp.where(validity, rows(lengths, j), 0)
             outs.extend([chars, lengths, validity])
             continue
         if kind == "dec128":
             if has_plain:
-                off = plain_byte[pg] + local * elem_bytes
-                p_hi, p_lo = R.read_be_limbs(get_bytes(), off,
-                                             elem_bytes)
+                with jax.named_scope("decode_plain"):
+                    off = plain_byte[pg] + local * elem_bytes
+                    p_hi, p_lo = R.read_be_limbs(get_bytes(), off,
+                                                 elem_bytes)
             else:
                 p_hi = p_lo = jnp.zeros(cap, dtype=jnp.int64)
             if didx is not None:
-                is_dict_pg = enc_pg == PGE_DICT
-                hi = jnp.where(is_dict_pg, dicts[0][didx], p_hi)
-                lo = jnp.where(is_dict_pg, dicts[1][didx], p_lo)
+                with jax.named_scope("decode_dict"):
+                    is_dict_pg = enc_pg == PGE_DICT
+                    hi = jnp.where(is_dict_pg, dicts[0][didx], p_hi)
+                    lo = jnp.where(is_dict_pg, dicts[1][didx], p_lo)
             else:
                 hi, lo = p_hi, p_lo
-            hi = jnp.where(validity, hi, 0)
-            lo = jnp.where(validity, lo, 0)
+            hi = jnp.where(validity, rows(hi, j), 0)
+            lo = jnp.where(validity, rows(lo, j), 0)
             outs.extend([hi, lo, validity])
             continue
         # fixed-width scalar kinds: select in the int64 bit domain
         if has_plain:
-            off = plain_byte[pg] + local * elem_bytes
-            if kind == "dec64":
-                v = R.read_be_signed(get_bytes(), off, elem_bytes)
-            else:
-                v = R.read_le(get_bytes(), off, elem_bytes)
+            with jax.named_scope("decode_plain"):
+                off = plain_byte[pg] + local * elem_bytes
+                if kind == "dec64":
+                    v = R.read_be_signed(get_bytes(), off, elem_bytes)
+                else:
+                    v = R.read_le(get_bytes(), off, elem_bytes)
         else:
             v = jnp.zeros(cap, dtype=jnp.int64)
         if has_bss:
-            # BYTE_STREAM_SPLIT: byte j of value i lives at
-            # page_base + j*values_in_page + i
-            stride = jnp.clip(dense_start[pg + 1] - dense_start[pg],
-                              0, cap)
-            b_v = R.read_bss(get_bytes(), plain_byte[pg], stride,
-                             local, elem_bytes)
-            v = jnp.where(enc_pg == PGE_BSS, b_v, v)
+            # BYTE_STREAM_SPLIT: byte k of value i lives at
+            # page_base + k*values_in_page + i
+            with jax.named_scope("decode_plain"):
+                stride = jnp.clip(dense_start[pg + 1] - pg_start,
+                                  0, cap)
+                b_v = R.read_bss(get_bytes(), plain_byte[pg], stride,
+                                 local, elem_bytes)
+                v = jnp.where(enc_pg == PGE_BSS, b_v, v)
         if has_delta:
-            # DELTA_BINARY_PACKED, in DENSE coordinates (each delta
-            # counts once even when null rows repeat a dense index):
-            # per-value deltas from the miniblock run table,
-            # reconstructed by a per-page segmented prefix-sum off
-            # the page's first_value, then gathered per row
-            pgd = jnp.clip(
-                jnp.searchsorted(dense_start, pos,
-                                 side="right") - 1, 0, npg - 1)
-            encd = pg_enc[pgd]
+            # DELTA_BINARY_PACKED: per-value deltas from the miniblock
+            # run table, reconstructed by a per-page segmented
+            # prefix-sum off the page's first_value
             d_raw = R.delta_lookup(get_bytes(), pos, *dr)
-            d_contrib = jnp.where(
-                (encd == PGE_DELTA) & (pos > dense_start[pgd]),
-                d_raw, 0)
-            c = jnp.cumsum(d_contrib)
-            based = jnp.clip(dense_start[pgd], 0, cap - 1)
-            val_d = pg_first[pgd] + (c - c[based])
-            d_v = val_d[jnp.clip(j, 0, cap - 1)]
-            v = jnp.where(enc_pg == PGE_DELTA, d_v, v)
+            with jax.named_scope("decode_delta"):
+                d_contrib = jnp.where(
+                    (enc_pg == PGE_DELTA) & (pos > pg_start), d_raw, 0)
+                c = jnp.cumsum(d_contrib)
+                d_v = pg_first[pg] \
+                    + (c - c[jnp.clip(pg_start, 0, cap - 1)])
+                v = jnp.where(enc_pg == PGE_DELTA, d_v, v)
         if didx is not None:
-            v = jnp.where(enc_pg == PGE_DICT, dicts[0][didx], v)
+            with jax.named_scope("decode_dict"):
+                v = jnp.where(enc_pg == PGE_DICT, dicts[0][didx], v)
+        v = rows(v, j)
         if kind == "f32":
             data = jax.lax.bitcast_convert_type(
                 v.astype(jnp.int32), jnp.float32)

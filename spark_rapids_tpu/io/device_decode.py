@@ -64,7 +64,7 @@ PGE_BSS = 3      # BYTE_STREAM_SPLIT at pg_plain_byte
 PGE_PLAIN_STR = 4  # PLAIN byte array (4-byte length prefixes)
 PGE_DL_STR = 5   # DELTA_LENGTH byte array (concatenated bytes)
 
-# searchsorted sentinel for padded run/page tables
+# padding of run/page tables: a start no lane reaches (rle.run_index)
 _SENTINEL = 1 << 62
 
 

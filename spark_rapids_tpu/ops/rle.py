@@ -9,7 +9,10 @@ fuse them into ONE decode program per scan batch:
   stream (dictionary indices, definition levels). The *run headers* are
   parsed on the host (they are a few bytes per run); the *payload* —
   every packed value — is extracted here, on device, from the raw page
-  bytes. Each output position binary-searches its run, then either
+  bytes. Every lookup is positional — lane i of ``arange(cap)`` wants
+  the run (or page) that covers dense position i — so a lane's run is
+  found by ``run_index``: mark each run's first lane once, take one
+  prefix sum. No per-lane search, no loop. The lane then either
   broadcasts the run's RLE value or bit-gathers from the packed words.
 - ``read_le`` / ``read_be_signed`` / ``read_be_limbs``: PLAIN
   fixed-width and FIXED_LEN_BYTE_ARRAY (decimal) reinterpretation at
@@ -54,29 +57,61 @@ def read_packed(bytes_all: jax.Array, bit_off: jax.Array,
     (dictionary index width differs across pages); width <= 32."""
     byte0 = bit_off >> 3
     shift = bit_off & 7
-    win = _gather_window(bytes_all, byte0, _PACKED_WINDOW)
-    k = jnp.arange(_PACKED_WINDOW, dtype=jnp.int64) * 8
-    word = jnp.sum(win << k, axis=1)
+    with jax.named_scope("window"):
+        win = _gather_window(bytes_all, byte0, _PACKED_WINDOW)
+        k = jnp.arange(_PACKED_WINDOW, dtype=jnp.int64) * 8
+        word = jnp.sum(win << k, axis=1)
     mask = (jnp.int64(1) << width.astype(jnp.int64)) - 1
     return (word >> shift) & mask
+
+
+def run_index(out_start: jax.Array, cap: int) -> jax.Array:
+    """Lane i of ``arange(cap)`` -> index of the last entry of the
+    ascending table ``out_start`` whose start is <= i (int32, clipped
+    to the table): ``searchsorted(out_start, i, side="right") - 1``
+    for every lane at once. Because the query is the lane number
+    itself, the count of entries <= i is a prefix sum: scatter a one at
+    each entry's start lane (starts >= cap, the padding sentinel among
+    them, land in a spare lane that is cut off), cumsum, subtract 1.
+    Duplicate starts (empty runs or pages) add up on one lane, so the
+    LAST of them wins, as it does in the search."""
+    lanes = jnp.clip(out_start, 0, cap).astype(jnp.int32)
+    marks = jnp.zeros(cap + 1, dtype=jnp.int32).at[lanes].add(1)
+    return jnp.clip(jnp.cumsum(marks[:cap]) - 1, 0,
+                    out_start.shape[0] - 1)
+
+
+def _run_fields(pos: jax.Array, out_start: jax.Array,
+                bit_start: jax.Array, width: jax.Array) -> tuple:
+    """What a lane needs of its run: ``(rid, bit_off, w)`` — the run's
+    index, the absolute bit offset of the lane's packed value (the
+    run's payload start plus the lane's place in the run times the
+    run's width) and that width. Shared by the hybrid and the delta
+    lookup, whose run tables have one shape."""
+    with jax.named_scope("decode_run_lookup"):
+        rid = run_index(out_start, pos.shape[0])
+    with jax.named_scope("decode_bits/run_fields"):
+        w = width[rid]
+        return rid, bit_start[rid] + (pos - out_start[rid]) * w, w
 
 
 def hybrid_lookup(bytes_all: jax.Array, pos: jax.Array,
                   out_start: jax.Array, packed: jax.Array,
                   value: jax.Array, bit_start: jax.Array,
                   width: jax.Array) -> jax.Array:
-    """Decode the RLE/bit-packed hybrid stream at positions ``pos``.
+    """Decode the RLE/bit-packed hybrid stream at every dense position:
+    ``pos`` is ``arange(cap)`` (int64), the lanes of the stream.
 
     The run table (out_start ascending, padded with a huge sentinel;
     packed flag; RLE value; absolute payload bit offset; per-run bit
     width) comes from the host-side header parse. Positions beyond the
     last real run decode garbage — callers mask by validity/active."""
-    rid = jnp.searchsorted(out_start, pos, side="right") - 1
-    rid = jnp.clip(rid, 0, out_start.shape[0] - 1)
-    local = pos - out_start[rid]
-    w = width[rid]
-    v_packed = read_packed(bytes_all, bit_start[rid] + local * w, w)
-    return jnp.where(packed[rid], v_packed, value[rid])
+    rid, bit_off, w = _run_fields(pos, out_start, bit_start, width)
+    with jax.named_scope("decode_bits"):
+        v_packed = read_packed(bytes_all, bit_off, w)
+        with jax.named_scope("run_fields"):
+            is_packed, rle_value = packed[rid], value[rid]
+        return jnp.where(is_packed, v_packed, rle_value)
 
 
 def read_packed64(bytes_all: jax.Array, bit_off: jax.Array,
@@ -99,15 +134,16 @@ def delta_lookup(bytes_all: jax.Array, pos: jax.Array,
     per miniblock (out_start = dense lane of the miniblock's first
     delta, value = the block's min_delta, bit_start = absolute payload
     bit offset, width = miniblock bit width). Lane ``pos`` returns
-    min_delta + unpacked[pos - out_start]; positions outside any run
-    (a page's first value, other-encoding pages) decode garbage —
-    callers mask before the segmented cumsum."""
-    rid = jnp.searchsorted(out_start, pos, side="right") - 1
-    rid = jnp.clip(rid, 0, out_start.shape[0] - 1)
-    local = pos - out_start[rid]
-    w = width[rid]
-    raw = read_packed64(bytes_all, bit_start[rid] + local * w, w)
-    return value[rid] + raw
+    min_delta + unpacked[pos - out_start], for ``pos = arange(cap)``;
+    positions outside any run (a page's first value, other-encoding
+    pages) decode garbage — callers mask before the segmented
+    cumsum."""
+    rid, bit_off, w = _run_fields(pos, out_start, bit_start, width)
+    with jax.named_scope("decode_bits"):
+        raw = read_packed64(bytes_all, bit_off, w)
+        with jax.named_scope("run_fields"):
+            min_delta = value[rid]
+        return min_delta + raw
 
 
 def read_bss(bytes_all: jax.Array, base: jax.Array, stride: jax.Array,

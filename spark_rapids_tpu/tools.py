@@ -884,7 +884,7 @@ def format_profile_report(path: str, top: int = 10, query=None) -> str:
             lines.append(f"    {s:8.3f}s  {p}")
             scopes = sorted(c["device"]["byScope"].get(p, {}).items(),
                             key=lambda kv: -kv[1])
-            for sc, ss in scopes[:5]:
+            for sc, ss in scopes[:top]:
                 lines.append(f"        {ss:8.3f}s  {sc}")
     return "\n".join(lines)
 
@@ -1721,8 +1721,15 @@ def generate_observability_docs() -> str:
         "program, `jax.named_scope` marks each constituent operator of a",
         "fused stage (`Filter`, `Project`) and the aggregate's steps",
         "(`agg_inputs`, `groupby_sort`, `groupby_reduce`, `compact`,",
-        "`agg_result`); scopes are op_name metadata and change no",
-        "compiled code. The tpu-lint `jit-direct` rule treats",
+        "`agg_result`) and the lanes of the Parquet page decode",
+        "(`srt_decode`: `decode_run_lookup`, `decode_page_lookup`,",
+        "`decode_bits` with `/bytes` (staging words to bytes),",
+        "`/run_fields` (each lane's gathers from its run's table row)",
+        "and `/window` (the 5-byte gather a packed value spans) inside",
+        "it, `decode_dict`, `decode_plain`, `decode_chars`,",
+        "`decode_delta`, `decode_rows` — docs/scan.md §1); scopes are",
+        "op_name metadata and change no compiled code. The tpu-lint",
+        "`jit-direct` rule treats",
         "`named_jit` as `jax.jit`. Dispatch spans, `compile` spans and",
         "the `firstDispatch` instant carry `program=<name>`.",
         "",

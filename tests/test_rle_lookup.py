@@ -1,0 +1,199 @@
+"""Positional run/page lookup of the device decode (ops/rle.run_index):
+one scatter of the table's starts and one prefix sum must give what a
+per-lane binary search gives, and the decode program must hold no
+loop."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from spark_rapids_tpu.io.device_decode import _SENTINEL
+from spark_rapids_tpu.ops import rle as R
+
+
+def _table(starts, pad_to):
+    t = np.full(pad_to, _SENTINEL, dtype=np.int64)
+    t[:len(starts)] = starts
+    return t
+
+
+def _searched(table, query):
+    """The per-lane binary search the lookup replaced, clipped as the
+    decode body clips it."""
+    return np.clip(np.searchsorted(table, query, side="right") - 1,
+                   0, len(table) - 1)
+
+
+# (case, real starts, padded table length, cap)
+_TABLES = [
+    ("plain_runs", [0, 8, 16, 40, 41, 100], 8, 128),
+    ("duplicate_starts", [0, 8, 8, 8, 24, 24, 60], 8, 64),
+    ("starts_at_and_beyond_cap", [0, 10, 64, 70, 4000], 8, 64),
+    ("first_start_above_zero", [5, 9, 30], 8, 48),
+    ("one_real_entry", [0], 8, 32),
+    ("one_real_entry_above_zero", [7], 8, 32),
+    ("cap_not_a_power_of_two", [0, 3, 3, 50, 99, 100], 8, 100),
+    ("no_padding", [0, 1, 2, 3, 4, 5, 6, 7], 8, 24),
+    ("page_table_odd_length", [0, 20, 20, 45, 45, 45, 80, 90, 96], 9, 96),
+    ("every_lane_a_run", list(range(64)), 64, 64),
+    ("wide_table", sorted(np.random.default_rng(3)
+                          .integers(0, 5000, 300).tolist()), 512, 4099),
+]
+
+
+@pytest.mark.parametrize("case,starts,pad_to,cap", _TABLES,
+                         ids=[t[0] for t in _TABLES])
+def test_run_index_matches_binary_search(case, starts, pad_to, cap):
+    table = _table(starts, pad_to)
+    got = np.asarray(R.run_index(jnp.asarray(table), cap))
+    assert got.dtype == np.int32 and got.shape == (cap,)
+    assert np.array_equal(got, _searched(table, np.arange(cap))), case
+
+
+def _validity(case, cap, rng):
+    v = rng.random(cap) < 0.7
+    if case == "leading_nulls":
+        v[:11] = False
+    elif case == "trailing_nulls":
+        v[-17:] = False
+    elif case == "all_null_stretches":
+        v[20:45] = False
+        v[60:61] = False
+        v[90:] = False
+    elif case == "all_null":
+        v[:] = False
+    elif case == "no_nulls":
+        v[:] = True
+    return v
+
+
+@pytest.mark.parametrize("case", ["leading_nulls", "trailing_nulls",
+                                  "all_null_stretches", "all_null",
+                                  "no_nulls", "scattered"])
+def test_run_index_through_dense_ranks(case):
+    """Rows reach their run through ``j`` (row -> dense rank): the
+    dense-lane answer gathered by ``j`` equals searching ``j`` itself,
+    null rows (which repeat a rank, or clip to 0) included."""
+    cap = 120
+    rng = np.random.default_rng(len(case))
+    validity = _validity(case, cap, rng)
+    for starts, pad_to in (([0, 8, 8, 30, 31, 77], 8),
+                           ([4, 50, 200], 4),
+                           ([0, 16, 16, 16, 48, 64, 64, 80, 119], 9)):
+        table = _table(starts, pad_to)
+        j = jnp.clip(R.dense_ranks(jnp.asarray(validity)), 0, cap - 1)
+        got = np.asarray(R.run_index(jnp.asarray(table), cap)[j])
+        assert np.array_equal(got, _searched(table, np.asarray(j))), \
+            (case, starts)
+
+
+# -- structural guard: no loop in the decode program -------------------------
+
+_RUN_DTYPES = ("int64", "bool", "int64", "int64", "int64")
+
+# the seven ("dev", ...) entries q1 gives at SF1 (cap 786,432; one decode
+# a partition): all RLE_DICTIONARY, extendedprice overflowing to PLAIN
+_Q1_CAP = 786_432
+_Q1_LAYOUT = (
+    ("dev", "dec64", "int64", 7, 0, 64, 0, 2048, 0,
+     (((64,), "int64"),), False, False, False, False),
+    ("dev", "dec64", "int64", 7, 0, 64, 0, 512, 0,
+     (((262144,), "int64"),), True, False, False, False),
+    ("dev", "dec64", "int64", 7, 0, 64, 0, 2048, 0,
+     (((16,), "int64"),), False, False, False, False),
+    ("dev", "dec64", "int64", 7, 0, 64, 0, 2048, 0,
+     (((16,), "int64"),), False, False, False, False),
+    ("dev", "str", "uint8", 0, 8, 64, 0, 2048, 0,
+     (((4, 8), "uint8"), ((4,), "int32")), False, False, False, False),
+    ("dev", "str", "uint8", 0, 8, 64, 0, 4096, 0,
+     (((2, 8), "uint8"), ((2,), "int32")), False, False, False, False),
+    ("dev", "int", "int32", 4, 0, 64, 0, 2048, 0,
+     (((4096,), "int64"),), False, False, False, False),
+)
+
+_LAYOUTS = {
+    "q1_sf1": (_Q1_LAYOUT, _Q1_CAP),
+    "nullable_dict_and_plain": ((
+        ("dev", "int", "int64", 8, 0, 8, 16, 8, 0,
+         (((32,), "int64"),), True, False, False, False),), 1024),
+    "string_with_lengths": ((
+        ("dev", "str", "uint8", 0, 16, 8, 8, 8, 0,
+         (((8, 16), "uint8"), ((8,), "int32")),
+         False, False, False, True),), 1024),
+    "delta_binary_packed": ((
+        ("dev", "int", "int64", 8, 0, 8, 8, 0, 16, (),
+         False, True, False, False),), 1024),
+    "nullable_bool": ((
+        ("dev", "bool", "bool", 1, 0, 8, 8, 8, 0, (),
+         False, False, False, False),), 1024),
+    "dec128_plain_and_dict": ((
+        ("dev", "dec128", "int64", 16, 0, 8, 8, 8, 0,
+         (((8,), "int64"), ((8,), "int64")),
+         True, False, False, False),), 1024),
+    "byte_stream_split_f64": ((
+        ("dev", "f64", "float64", 8, 0, 8, 0, 0, 0, (),
+         True, False, True, False),), 1024),
+    "host_column_beside_a_device_one": ((
+        ("host", 2),
+        ("dev", "f32", "float32", 4, 0, 8, 8, 8, 0,
+         (((16,), "int64"),), True, False, False, False)), 1024),
+}
+
+
+def _abstract_extras(layout, cap):
+    """Shapes of the plan tables a layout's decode program takes, in the
+    order ``prepare_encoded_upload`` stages them."""
+    def arr(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
+
+    out = []
+    for ent in layout:
+        if ent[0] == "host":
+            out.extend(arr((cap,), "int64") for _ in range(ent[1]))
+            continue
+        (_tag, _kind, _np_dt, _eb, _cc, npg, ndl, nvr, ndr, dict_shapes,
+         _has_plain, has_delta, _has_bss, has_slen) = ent
+        out.extend([arr((npg + 1,), "int64"), arr((npg,), "int64"),
+                    arr((npg,), "int32")])
+        if has_delta:
+            out.append(arr((npg,), "int64"))
+        for n_runs in (ndl, nvr, ndr):
+            if n_runs:
+                out.extend(arr((n_runs,), d) for d in _RUN_DTYPES)
+        if has_slen:
+            out.append(arr((cap,), "int32"))
+        out.extend(arr(shape, dtype) for shape, dtype in dict_shapes)
+    return out
+
+
+def _primitives(jaxpr, seen=None):
+    """Every primitive name of a jaxpr, sub-jaxprs (pjit, cond
+    branches, loop bodies, custom calls) included."""
+    seen = set() if seen is None else seen
+    for eqn in jaxpr.eqns:
+        seen.add(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _primitives(sub, seen)
+    return seen
+
+
+def test_guard_sees_the_search_it_guards_against():
+    prims = _primitives(jax.make_jaxpr(
+        lambda t, q: jnp.searchsorted(t, q, side="right"))(
+            jnp.zeros(8, jnp.int64), jnp.zeros(16, jnp.int64)).jaxpr)
+    assert prims & {"while", "scan"}
+
+
+@pytest.mark.parametrize("name", sorted(_LAYOUTS))
+def test_decode_program_has_no_loop(name):
+    from spark_rapids_tpu.columnar.transfer import _build_encoded_decode
+    layout, cap = _LAYOUTS[name]
+    fn = _build_encoded_decode(layout, cap)
+    words = jax.ShapeDtypeStruct((4096,), jnp.int32)
+    n_arr = jax.ShapeDtypeStruct((), jnp.int64)
+    closed = jax.make_jaxpr(fn)(words, n_arr,
+                                *_abstract_extras(layout, cap))
+    prims = _primitives(closed.jaxpr)
+    assert "cumsum" in prims, sorted(prims)
+    assert not prims & {"while", "scan"}, sorted(prims)
