@@ -1,9 +1,11 @@
 """Seeded star-schema slice for TPC-DS query 3, as numpy arrays.
 
 Copied from ``bench.write_tpcds`` (PR 21) with the seed as a parameter and
-the same order of draws: ``item`` and ``date_dim`` at their SF1 row counts,
-``store_sales`` with uniform keys, money as the unscaled int64 of
-``decimal(7,2)``. Imports nothing of the engine.
+the same order of draws: ``store_sales`` with uniform keys, money as the
+unscaled int64 of ``decimal(7,2)``, drawn over that type's whole domain
+(1.00 .. 99,999.99) so that a group's sum of two or three prices passes
+2**24 unscaled and needs the exact ``decimal(17,2)`` the configuration
+guarantees (``reference.control_answer``). Imports nothing of the engine.
 """
 
 import numpy as np
@@ -28,6 +30,6 @@ def generate(seed: int, rows: dict) -> dict:
     store_sales = {
         "ss_sold_date_sk": rng.integers(1, n_date + 1, n),
         "ss_item_sk": rng.integers(1, n_item + 1, n),
-        "ss_ext_sales_price": rng.integers(100, 1_000_000, n),
+        "ss_ext_sales_price": rng.integers(100, 10_000_000, n),
     }
     return {"item": item, "date_dim": date_dim, "store_sales": store_sales}

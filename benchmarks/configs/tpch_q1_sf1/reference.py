@@ -4,6 +4,12 @@ Works on the arrays the generator made (money as unscaled integers of scale
 2). A decimal in the answer is the pair ``(unscaled, scale)``; the result
 types are those Spark defines for ``sum`` and ``avg`` over decimals, which the
 benchmark's tests hold against the CPU engine. Imports nothing of the engine.
+
+``control_answer`` is the same statement with one stated guarantee broken,
+exact decimal arithmetic: sums carried in float64, the step the conf's
+``variableFloatAgg`` would tempt a later PR to take. The comparison has to
+refuse it (``benchmarks/tests/test_control.py``); no run of the benchmark
+calls it.
 """
 
 import numpy as np
@@ -25,7 +31,15 @@ def _avg_half_up(total: int, count: int, shift: int) -> int:
     return (2 * num + count) // (2 * count)
 
 
-def answer(tables: dict, binding: dict) -> list:
+def _float64_sum(values: np.ndarray) -> int:
+    return int(values.astype(np.float64).sum())
+
+
+def control_answer(tables: dict, binding: dict) -> list:
+    return answer(tables, binding, total=_float64_sum)
+
+
+def answer(tables: dict, binding: dict, total=_exact_sum) -> list:
     t = tables["lineitem"]
     keep = t["l_shipdate"] <= CUTOFF
     qty = t["l_quantity"].astype(np.int64)
@@ -42,14 +56,14 @@ def answer(tables: dict, binding: dict) -> list:
             count = int(m.sum())
             if count == 0:
                 continue
-            s_qty, s_price = _exact_sum(qty[m]), _exact_sum(price[m])
+            s_qty, s_price = total(qty[m]), total(price[m])
             out.append((
                 str(flag), str(status),
                 (s_qty, 2), (s_price, 2),
-                (_exact_sum(disc_price[m]), 4),
-                (_exact_sum(charge[m]), 6),
+                (total(disc_price[m]), 4),
+                (total(charge[m]), 6),
                 (_avg_half_up(s_qty, count, 4), 6),
                 (_avg_half_up(s_price, count, 4), 6),
-                (_avg_half_up(_exact_sum(disc[m]), count, 4), 6),
+                (_avg_half_up(total(disc[m]), count, 4), 6),
                 count))
     return out

@@ -43,8 +43,10 @@ class QueryRecord:
     binding: int
     t_start: float
     t_end: float
-    ok: bool
+    ok: bool                        # it answered; after ``judge``: and rightly
     error: str = ""
+    differs: bool = False           # it answered, and not what the reference says
+    rows: Optional[List[tuple]] = None   # the answer, kept for ``judge``
     queue_wait_ms: Optional[float] = None
     exec_ms: Optional[float] = None
     traced: bool = False

@@ -1,5 +1,7 @@
 """The closed loop both drivers share: a client sends its next query only
-when the last has returned, and starts none that would overrun the window."""
+when the last has returned, and starts none that would overrun the window.
+Every answer is kept and held to the plain reference by ``judge`` once the
+window has closed, so the reference's time is no part of the set-up."""
 
 import contextlib
 import threading
@@ -18,8 +20,8 @@ JOIN_SLACK_S = 240.0
 
 def one_query(cell: Cell, send: Send, client: int, binding: int,
               tracer: Optional[WindowTracer]) -> QueryRecord:
-    """Send one statement and hold its rows to the reference."""
-    header, error, ok = None, "", False
+    """Send one statement and keep its rows for ``judge``."""
+    header, error, ok, got = None, "", False, None
     traced = bool(tracer and tracer.active)
     t_start = time.perf_counter()
     try:
@@ -29,18 +31,25 @@ def one_query(cell: Cell, send: Send, client: int, binding: int,
             rows, header = send(cell.sql(binding))
         t_end = time.perf_counter()
         got = normal_rows(rows)
-        ok = got == cell.answers[binding]
-        if not ok:
-            error = ("rows differ from the reference: "
-                     + first_difference(cell.answers[binding], got))
+        ok = True
     except Exception:  # noqa: BLE001 - a failed query is counted, not fatal
         t_end = time.perf_counter()
         error = traceback.format_exc()
     header = header or {}
     return QueryRecord(client=client, binding=binding, t_start=t_start,
-                       t_end=t_end, ok=ok, error=error,
+                       t_end=t_end, ok=ok, error=error, rows=got,
                        queue_wait_ms=header.get("queueWaitMs"),
                        exec_ms=header.get("execMs"), traced=traced)
+
+
+def judge(cell: Cell, records: List[QueryRecord]) -> None:
+    """Hold every answer to the reference's (``cell.compute_answers()`` has
+    run): one that differs is no longer ``ok`` and says where it differs."""
+    for r in records:
+        if r.ok and r.rows != cell.answers[r.binding]:
+            r.ok, r.differs = False, True
+            r.error = ("rows differ from the reference: "
+                       + first_difference(cell.answers[r.binding], r.rows))
 
 
 def run_clients(cell: Cell, sends: List[Send], steps: Optional[int],

@@ -1,8 +1,10 @@
 """The comparison that decides ``correct``: rows of the system under test
-against the plain reference, exactly."""
+against the plain reference, exactly, so every number compared has the
+limit 0."""
 
 import decimal
-from typing import List, Sequence
+import sys
+from typing import Dict, List, Sequence
 
 
 def normal_value(v):
@@ -33,3 +35,30 @@ def first_difference(want: List[tuple], got: List[tuple]) -> str:
         if w != g:
             return f"row {i}: reference {w!r} against {g!r}"
     return "equal"
+
+
+def compared_numbers(records: Sequence, fallbacks: Sequence[str]) -> Dict:
+    """Every number ``correct`` rests on, beside its limit: the answers of the
+    window held to the reference, those that differ from it, those that
+    raised or never came, and the statements' fallback reports."""
+    return {
+        "answers_compared": {"value": len(records), "at_least": 1},
+        "answers_wrong": {"value": sum(r.differs for r in records), "limit": 0},
+        "answers_missing": {
+            "value": sum(not r.ok and not r.differs for r in records),
+            "limit": 0},
+        "fallback_reports": {"value": len(fallbacks), "limit": 0}}
+
+
+def is_correct(compared: Dict) -> bool:
+    return all(n["value"] <= n["limit"] if "limit" in n
+               else n["value"] >= n["at_least"] for n in compared.values())
+
+
+def print_compared(compared: Dict, correct: bool) -> None:
+    """The run's last lines on standard error."""
+    for name, n in compared.items():
+        limit = (f"limit {n['limit']}" if "limit" in n
+                 else f"at least {n['at_least']}")
+        print(f"[correct] {name} {n['value']} ({limit})", file=sys.stderr)
+    print(f"[correct] {correct}", file=sys.stderr, flush=True)

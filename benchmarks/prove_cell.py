@@ -9,13 +9,16 @@ of its own, as the driver makes it, and all share one compile cache.
 
 This parent never imports JAX, so it never holds the chip. It writes every
 run's information lines and result to ``chiprun_out/prove_<cell>.jsonl`` and
-prints, per end-to-end metric and set, the median and the spread the bounds
+prints, per end-to-end metric and set, the median, the spread the bounds
 are set from (first to third quartile of ``statistics.quantiles(n=4)`` as a
-share of the median).
+share of the median) and whether it is at or under half the manifest's bound
+(``admissible: yes|no``); then per metric the bound that ``rule_bound`` gives
+from the widest spread, so that the rule is computed by code and not by hand.
 """
 
 import argparse
 import json
+import math
 import os
 import subprocess
 import sys
@@ -28,8 +31,11 @@ sys.path.insert(0, ROOT)
 from benchmarks.harness.stats import iqr_spread, median  # noqa: E402
 
 # large, as the driver's are: a seed must not be assumed to fit 31 bits
+# the sets take the first six, traced runs the next ones, first runs the last
 SEEDS = (2147483659, 2147483693, 2147483713, 2147483743, 2147483777,
-         2147483783, 2147483813, 2147483857)
+         2147483783, 2147483813, 2147483869, 2147483887, 2147483929,
+         2147483951, 2147483857)
+SET_SEEDS = 6
 
 
 def run_once(command, workload, seed, seconds, trace, log, label, timeout):
@@ -72,6 +78,58 @@ def run_once(command, workload, seed, seconds, trace, log, label, timeout):
     return result
 
 
+def rule_bound(metric: str, widest_spread: float) -> float:
+    """The bound PR 28's rule gives a metric from the widest spread any of
+    its sets showed: twice it (the driver admits a cell whose spread is at
+    most half the bound) plus half a percent for the driver's own seeds,
+    rounded up to a multiple of half a percent, never under 1%. The set-up
+    time keeps 0.25 and is judged on its median alone."""
+    if metric == "setup_s":
+        return 0.25
+    steps = math.ceil(round((2 * widest_spread + 0.005) / 0.005, 9))
+    return max(0.01, steps * 0.005)
+
+
+def report_sets(workload, sets, entries) -> None:
+    """Per metric and set the median, the spread and whether the spread is at
+    or under half the manifest's bound (what the driver admits a cell on);
+    then the widest spread, the bound the rule gives, and how the manifest's
+    bound stands to it: too tight under twice the widest spread, too loose
+    over eight times it (a bound of 1% is never too loose)."""
+    names = sorted({n for got in sets for r in got for n in r["metrics"]})
+    for n in names:
+        bound = entries.get(n, {}).get("bound")
+        spreads, medians = [], []
+        for s, got in enumerate(sets):
+            vals = [r["metrics"][n]["value"] for r in got if n in r["metrics"]]
+            if len(vals) < 2:
+                continue
+            spread = iqr_spread(vals)
+            spreads.append(spread)
+            medians.append(median(vals))
+            admissible = bound is not None and spread <= bound / 2
+            print(f"[prove] {workload} {n} set{s}: n={len(vals)} "
+                  f"median={median(vals):.6g} spread={spread:.5f} "
+                  f"half_bound={bound and bound / 2} "
+                  f"admissible: {'yes' if admissible else 'no'} "
+                  f"values={vals}", flush=True)
+        if not spreads:
+            continue
+        widest = max(spreads)
+        verdict = "stands"
+        if bound is not None and n != "setup_s":
+            if widest > bound / 2:
+                verdict = "too tight"
+            elif bound > 0.01 and bound > 8 * widest:
+                verdict = "too loose"
+        drift = (abs(medians[-1] / medians[0] - 1) if len(medians) > 1
+                 and medians[0] else 0.0)
+        print(f"[prove] {workload} {n}: widest_spread={widest:.5f} "
+              f"rule_bound={rule_bound(n, widest):.3f} manifest_bound={bound} "
+              f"{verdict}; medians set to set differ by {drift:.5f}",
+              flush=True)
+
+
 def main() -> int:
     p = argparse.ArgumentParser()
     p.add_argument("--workload", required=True)
@@ -81,7 +139,7 @@ def main() -> int:
     p.add_argument("--traced", type=int, default=1, help="traced runs before the sets")
     p.add_argument("--seconds", type=float, default=None)
     p.add_argument("--seed-offset", type=int, default=0,
-                   help="the sets start at this place in the list of seeds, "
+                   help="the sets start at this place among their six seeds, "
                         "so that a later call can add runs to an earlier one's")
     p.add_argument("--timeout", type=float, default=1200.0)
     p.add_argument("--scale-rows", default=None,
@@ -104,7 +162,8 @@ def main() -> int:
                          f"{workload} first{i}", args.timeout)
             ok &= bool(r and r["correct"])
         for i in range(args.traced):
-            r = run_once(command, workload, SEEDS[i % len(SEEDS)], seconds, 1,
+            r = run_once(command, workload,
+                         SEEDS[(SET_SEEDS + i) % len(SEEDS)], seconds, 1,
                          log, f"{workload} traced{i}", args.timeout)
             ok &= bool(r and r["correct"])
             if r:
@@ -114,20 +173,14 @@ def main() -> int:
         for s in range(args.sets if ok else 0):
             got = []
             for i in range(args.runs):
-                seed = SEEDS[(args.seed_offset + i) % len(SEEDS)]
+                seed = SEEDS[(args.seed_offset + i) % SET_SEEDS]
                 r = run_once(command, workload, seed, seconds, 0, log,
                              f"{workload} set{s} run{i}", args.timeout)
                 ok &= bool(r and r["correct"])
                 if r:
                     got.append(r)
             sets.append(got)
-    for n in sorted({n for got in sets for r in got for n in r["metrics"]}):
-        for s, got in enumerate(sets):
-            vals = [r["metrics"][n]["value"] for r in got if n in r["metrics"]]
-            if vals:
-                print(f"[prove] {workload} {n} set{s}: n={len(vals)} "
-                      f"median={median(vals):.6g} "
-                      f"spread={iqr_spread(vals)} values={vals}", flush=True)
+    report_sets(workload, sets, {m["name"]: m for m in manifest["end_to_end"]})
     print(f"[prove] all correct: {ok}", flush=True)
     return 0 if ok else 1
 

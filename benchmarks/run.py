@@ -8,11 +8,15 @@ finds the cell in ``BENCHMARK.json``, and by the names there its
 configuration (``configs/<config>/``), its traffic mix
 (``traffic/<traffic>.json``), the mix's loop driver
 (``harness/drivers/<driver>.py``) and one reader per metric
-(``metrics/<metric>.py``). Set-up: data from ``--seed``, Parquet through the
-engine's writer, the plain reference's answers, one warm-up pass over every
-statement the window will send. Then ``--seconds`` of measurement. The last
+(``metrics/<metric>.py``). Set-up: data from ``--seed``, written as Parquet
+(``harness/tables.py``) and read back as a ``[bench] layout`` line, one
+warm-up pass over every statement the window will send. Then ``--seconds`` of
+measurement; then, the window closed and the sessions stopped, the plain
+reference's answers, to which every answer of the window is held. The last
 line of standard output is the result; lines before it, marked ``[bench]``,
-are information.
+are information. The numbers ``correct`` rests on stand beside their limits
+under the result's last key, ``compared``, and as the last lines of standard
+error.
 
 It refuses to start without a TPU holding the chips the cell asks for. Only
 when the caller itself sets ``JAX_PLATFORMS=cpu`` does it run as a rehearsal
@@ -101,6 +105,9 @@ def main(argv=None) -> int:
     # the package must be there before any data is made: a directory with the
     # benchmark alone fails here, with no result printed
     import spark_rapids_tpu  # noqa: F401
+    from benchmarks.harness import closed_loop as CL
+    from benchmarks.harness import compare as CMP
+    from benchmarks.harness import layout as LY
     from benchmarks.harness import profiler as PR
     from benchmarks.harness.peaks import peaks_for
     from benchmarks.harness.tracing import QUERY_ANNOTATION, WindowTracer
@@ -131,8 +138,8 @@ def main(argv=None) -> int:
         cell.write(data_root)
         t_write = time.perf_counter() - t
         t = time.perf_counter()
-        cell.compute_answers()
-        t_ref = time.perf_counter() - t
+        say("layout", LY.tables_layout(cell.paths))
+        t_layout = time.perf_counter() - t
         t = time.perf_counter()
         driver.start()
         warm = driver.warm_up()
@@ -144,7 +151,7 @@ def main(argv=None) -> int:
         setup_compiles = watch.snapshot()
         import jax
         say("setup", {
-            "generate_s": t_gen, "write_s": t_write, "reference_s": t_ref,
+            "generate_s": t_gen, "write_s": t_write, "layout_s": t_layout,
             "start_and_warmup_s": t_warm,
             "warmup_latency_s": [r.latency_s for r in warm],
             "programs_compiled_or_loaded": setup_compiles.compiles,
@@ -170,6 +177,9 @@ def main(argv=None) -> int:
         compiles = watch.snapshot().since(compiles0)
         counters = delta(process_totals(), totals0)
         driver_stats = driver.stats()
+        device["memory_peak_bytes"] = max(
+            int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for d in devices)
     except Exception as e:  # noqa: BLE001 - reported; no result is printed
         import traceback
         traceback.print_exc()
@@ -183,6 +193,19 @@ def main(argv=None) -> int:
         shutil.rmtree(trace_dir, ignore_errors=True)
         print(f"benchmarks/run.py: no result: {failure!r}", file=sys.stderr)
         return 1
+
+    # the window has closed, the peak is read and the sessions are stopped:
+    # now the plain reference answers, and every answer kept is held to it
+    t = time.perf_counter()
+    try:
+        cell.compute_answers()
+    except ValueError as e:
+        shutil.rmtree(trace_dir, ignore_errors=True)
+        print(f"benchmarks/run.py: no result: {e}", file=sys.stderr)
+        return 1
+    CL.judge(cell, records)
+    say("reference", {"reference_s": time.perf_counter() - t,
+                      "answers_held_to_it": len(records)})
 
     window = Window(seconds=args.seconds, t_start=t0, setup_s=setup_s,
                     scan_rows=cell.scan_rows, records=records,
@@ -237,21 +260,19 @@ def main(argv=None) -> int:
         value = readers[m["name"]].read(window)
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
-    peak = 0
-    for d in devices:
-        stats = d.memory_stats() or {}
-        peak = max(peak, int(stats.get("peak_bytes_in_use", 0)))
-    device["memory_peak_bytes"] = peak
     if window.trace:
         device["busy_s"] = window.trace["busy_s"]
         device["window_s"] = window.trace["window_s"]
+    compared = CMP.compared_numbers(records, fallbacks)
     result = {
-        "correct": bool(records) and not failed and not fallbacks,
+        "correct": CMP.is_correct(compared),
         "attempted": len(records), "failed": len(failed),
         "metrics": metrics, "device": device}
     if breakdown:
         result["breakdown"] = breakdown
+    result["compared"] = compared       # last in the line, as on stderr
     print(json.dumps(result), flush=True)
+    CMP.print_compared(compared, result["correct"])
     return 0
 
 

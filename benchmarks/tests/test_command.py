@@ -1,5 +1,7 @@
 """The command itself, rehearsed on the CPU at a tiny size: one last line
-with exactly the contract's keys, naming the CPU as its device."""
+with exactly the contract's keys and, last among them, the numbers compared
+beside their limits, naming the CPU as its device; the same numbers as the
+last lines of standard error; a ``[bench] layout`` line before it."""
 
 import json
 import os
@@ -10,7 +12,8 @@ import pytest
 
 from benchmarks.harness import cell as C
 
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = ["correct", "attempted", "failed", "metrics", "device",
+               "compared"]
 
 
 def run_command(*extra, env=None):
@@ -38,7 +41,18 @@ def test_last_line_is_the_contracts_result(workload, trace):
                     "--scale-rows", scale)
     assert p.returncode == 0, p.stderr[-3000:]
     result = json.loads(p.stdout.strip().splitlines()[-1])
-    assert set(result) == RESULT_KEYS
+    assert list(result) == RESULT_KEYS          # ``compared`` comes last
+    for number in result["compared"].values():
+        assert set(number) in ({"value", "limit"}, {"value", "at_least"})
+    assert result["compared"]["answers_compared"]["value"] == result["attempted"]
+    errors = p.stderr.strip().splitlines()
+    assert errors[-1] == "[correct] True"
+    assert [ln.split()[1] for ln in errors[-5:-1]] == list(result["compared"])
+    layouts = [ln for ln in p.stdout.splitlines()
+               if ln.startswith("[bench] layout: ")]
+    assert len(layouts) == 1
+    assert set(json.loads(layouts[0].split(": ", 1)[1])) == set(
+        C.load_cell(workload, 1).config["tables"])
     assert result["device"]["platform"] == "cpu"     # a rehearsal says so
     assert set(result["device"]) >= {"platform", "kind", "count",
                                      "memory_peak_bytes"}

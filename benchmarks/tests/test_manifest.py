@@ -20,6 +20,41 @@ def test_committed_manifest_stands(manifest):
     assert CM.check(manifest) == []
 
 
+def test_the_two_cell_manifest(manifest):
+    """q1 and the star join as one-chip batch cells under one traffic mix,
+    three end-to-end metrics whose bounds follow PR 28's rule (at most 0.04;
+    the set-up time 0.25), nine per-layer metrics that every cell reports."""
+    assert [c["name"] for c in manifest["configs"]] == ["tpch_q1_sf1",
+                                                        "tpcds_star_2m"]
+    assert [(w["name"], w["config"], w["traffic"], w["chips"])
+            for w in manifest["workloads"]] == [
+        ("q1_sf1_batch", "tpch_q1_sf1", "closed_direct_c1", 1),
+        ("star_2m_batch", "tpcds_star_2m", "closed_direct_c1", 1)]
+    assert manifest["run_seconds"] == 48
+    bounds = {m["name"]: m["bound"] for m in manifest["end_to_end"]}
+    assert set(bounds) == {"setup_s", "query_s", "rows_per_s"}
+    assert bounds["setup_s"] == 0.25
+    assert 0.01 <= bounds["query_s"] <= 0.04
+    assert 0.01 <= bounds["rows_per_s"] <= 0.04
+    assert len(manifest["per_layer"]) == 9
+    assert all("workloads" not in m and m["moves"] == "query_s"
+               for m in manifest["per_layer"])
+
+
+def test_every_fact_table_pins_its_split_of_rows(manifest):
+    """The split of a partitioned table's rows over its files is written
+    down, the same for every seed, and sums to the table's rows."""
+    for c in manifest["configs"]:
+        with open(os.path.join(CM.ROOT, c["file"])) as f:
+            config = json.load(f)
+        assert any("partition_rows" in note for note in config["assumed"])
+        for table, spec in config["tables"].items():
+            if spec["partitions"] > 1:
+                pinned = spec["layout"]["partition_rows"]
+                assert len(pinned) == spec["partitions"], table
+                assert sum(pinned) == spec["rows"], table
+
+
 def _with(manifest, group, index, **changes):
     m = copy.deepcopy(manifest)
     m[group][index].update(changes)

@@ -107,6 +107,9 @@ def test_served_driver_runs_the_star_join_for_four_clients(tmp_path):
         records = driver.run_window(2.0, None, time.perf_counter())
     finally:
         driver.stop()
+    from benchmarks.harness.closed_loop import judge
+    judge(cell, warm + records)
+    assert all(r.rows for r in warm + records)
     assert records and all(r.ok for r in warm + records), \
         [r.error for r in warm + records if not r.ok][:1]
     assert {r.client for r in records} == {0, 1, 2, 3}

@@ -3,12 +3,9 @@ stands; q1's cell and the star join's, rehearsed on the CPU under the
 profiler, print the three metrics; and a program without the timers (the
 parent commit) makes their readers report nothing instead of a zero.
 
-``star_2m_batch`` is not in ``BENCHMARK.json``: the driver's two sets of six
-runs spread its ``rows_per_s`` by 0.79% and 0.58% against half of a 1% bound
-(the seed makes the data, and the page decode's time follows the data), so it
-waits in ``entries_not_proved.json`` for a ``benchmark`` issue. It is
-rehearsed here from those entries, laid over the manifest in a temporary
-file."""
+``star_2m_batch`` is a cell of ``BENCHMARK.json`` since PR 28, which found
+what the seed changed (the scan's packing of files into partitions) and
+pinned the split of rows; ``entries_not_proved.json`` keeps the served cell."""
 
 import json
 import os
@@ -26,12 +23,6 @@ NEW_METRICS = {"plan_host_s": ("planTime", "L6_plan"),
                "first_dispatch_s": ("firstDispatchTime", "scan"),
                "device_wait_host_s": ("deviceSyncTime", "L4_transfer")}
 
-# run.py finds its manifest through harness.cell.MANIFEST and nowhere else
-RUN_WITH_MANIFEST = (
-    "import sys; sys.path.insert(0, {root!r}); "
-    "from benchmarks.harness import cell as C; C.MANIFEST = {manifest!r}; "
-    "from benchmarks import run; sys.exit(run.main(sys.argv[1:]))")
-
 
 def manifest():
     with open(os.path.join(CM.ROOT, "BENCHMARK.json")) as f:
@@ -42,18 +33,6 @@ def not_proved():
     with open(os.path.join(CM.ROOT, "benchmarks",
                            "entries_not_proved.json")) as f:
         return json.load(f)
-
-
-def with_the_star_join(m):
-    """The manifest with ``star_2m_batch`` and its configuration appended,
-    exactly as the not-proved file holds them."""
-    kept = not_proved()
-    m = dict(m)
-    have = {w["name"] for w in m["workloads"]}
-    if "star_2m_batch" not in have:
-        m["configs"] = m["configs"] + kept["configs"][:1]
-        m["workloads"] = m["workloads"] + kept["workloads"][:1]
-    return m
 
 
 def test_manifest_stands_with_the_new_metrics():
@@ -68,26 +47,27 @@ def test_manifest_stands_with_the_new_metrics():
         assert "workloads" not in p      # every cell reports them
 
 
-def test_manifest_stands_with_the_star_join_added():
-    """What a ``benchmark`` issue will add is whole: configuration
-    ``tpcds_star_2m``, traffic ``closed_direct_c1``, one chip."""
-    m = with_the_star_join(manifest())
-    assert CM.check(m) == []
+def test_the_star_join_is_a_cell_of_the_manifest():
+    """Configuration ``tpcds_star_2m``, traffic ``closed_direct_c1``, one
+    chip; what waits beside the manifest no longer names it."""
+    m = manifest()
     (cell,) = [w for w in m["workloads"] if w["name"] == "star_2m_batch"]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "tpcds_star_2m", "closed_direct_c1", 1)
+    kept = not_proved()
+    assert "configs" not in kept
+    assert [w["name"] for w in kept["workloads"]] == ["star_2m_served_c4"]
+    assert [e["name"] for e in kept["end_to_end"]] == ["query_p95_s"]
+    assert [p["name"] for p in kept["per_layer"]] == ["queue_wait_s",
+                                                      "exec_share"]
 
 
 @pytest.mark.parametrize("workload", ["q1_sf1_batch", "star_2m_batch"])
-def test_traced_rehearsal_prints_the_three_metrics(workload, tmp_path):
-    path = str(tmp_path / "BENCHMARK.json")
-    with open(path, "w") as f:
-        json.dump(with_the_star_join(manifest()), f)
+def test_traced_rehearsal_prints_the_three_metrics(workload):
     p = subprocess.run(
-        [sys.executable, "-c",
-         RUN_WITH_MANIFEST.format(root=C.ROOT, manifest=path),
-         "--workload", workload, "--seed", "2147483743", "--seconds", "3",
-         "--trace", "1", "--scale-rows", "0.02"],
+        manifest()["command"] + [
+            "--workload", workload, "--seed", "2147483743", "--seconds", "3",
+            "--trace", "1", "--scale-rows", "0.02"],
         cwd=C.ROOT, env=dict(os.environ, JAX_PLATFORMS="cpu"),
         capture_output=True, text=True, timeout=900)
     assert p.returncode == 0, p.stderr[-3000:]
