@@ -665,6 +665,7 @@ class TpuHashAggregateExec(TpuExec):
                     merged.append(chunk[0])
                     continue
                 whole = concat_device([h.get() for h in chunk])
+                self.metrics.create(M.AGG_MERGE_COUNT, M.ESSENTIAL).add(1)
                 from spark_rapids_tpu import retry as R
                 out, cnt, _ovf = R.with_retry(
                     lambda w=whole: self._aggregate_batch(w, mode="merge"),
@@ -832,7 +833,16 @@ class TpuHashAggregateExec(TpuExec):
                             return
                 if self.mode == "final":
                     whole = self._merge_bounded(handles, store)
+                    if whole._num_rows is not None:
+                        # one row a group: merged above, or the one
+                        # compacted batch of a single partial
+                        self.metrics.create(
+                            M.AGG_GROUP_COUNT, M.ESSENTIAL).add(
+                                whole._num_rows)
                 else:  # complete consumes raw rows; concat directly
+                    if len(handles) > 1:
+                        self.metrics.create(M.AGG_MERGE_COUNT,
+                                            M.ESSENTIAL).add(1)
                     whole = concat_device([h.get() for h in handles])
                     for h in handles:
                         h.close()

@@ -158,11 +158,7 @@ class TpuShuffledHashJoinExec(TpuExec):
         from spark_rapids_tpu import trace as TR
         with self.metrics.timed(M.JOIN_TIME, chip=TR.chip_of(lwhole)):
             out = R.with_retry(attempt, self.conf, self.metrics)
-        if out._num_rows is not None:
-            # known counts only: fetching one here would be a blocking
-            # roundtrip per joined batch purely for the metric
-            self.metrics.create(M.NUM_OUTPUT_ROWS, M.ESSENTIAL).add(
-                out._num_rows)
+        self._book_output(out)
         # the exec's declared output may prune/reorder pair columns
         if self.join_type not in MASK_JOINS:
             out = self._project_output(out)
@@ -261,6 +257,7 @@ class TpuShuffledHashJoinExec(TpuExec):
         with TR.span("aqeReplan", action="broadcastDemotion",
                      buildBytes=total, thresholdBytes=threshold):
             self.metrics.create("aqeBroadcastFlip", M.ESSENTIAL).add(1)
+            self.metrics.create(M.JOIN_DEMOTED_COUNT, M.ESSENTIAL).add(1)
             self.metrics.create("aqeReplans", M.ESSENTIAL).add(1)
             probe = self._subplan_cache_key()
             rwhole = probe[0].lookup(probe[1]) if probe is not None \
@@ -417,6 +414,7 @@ class TpuShuffledHashJoinExec(TpuExec):
         TpuBroadcastHashJoinExec and the AQE runtime flip."""
         goal = self.conf.batch_size_rows
         chunkable = self.join_type in self._LEFT_STREAM_TYPES
+        self._book_build(rwhole)
         # one sizing probe for the WHOLE broadcast: unique build keys
         # (the dimension-table norm) certify every stream chunk for the
         # no-sync FK fast path (ops/join.py build_key_max_multiplicity).
@@ -642,17 +640,20 @@ class TpuShuffledHashJoinExec(TpuExec):
         total_l = sum(h.rows for h in lhandles)
         chunkable = (self.join_type in self._LEFT_STREAM_TYPES
                      or self.join_type in self._CHUNKED_OUTER)
+        # build side concatenated once, whether the stream side joins
+        # whole or goal-rows at a time
+        rwhole = (concat_device(rb) if len(rb) > 1 else
+                  rb[0] if rb else
+                  DeviceBatch.empty(self.right.schema))
+        self._book_build(rwhole)
         if not chunkable or total_l <= goal:
             lb = [h.get() for h in lhandles]
             for h in lhandles:
                 h.close()
-            yield from self._join_one(lb, rb)
+            yield from self._join_one(lb, [rwhole])
             return
-        # chunked stream: build side concatenated once, left
-        # handles re-promoted and joined goal-rows at a time
-        rwhole = (concat_device(rb) if len(rb) > 1 else
-                  rb[0] if rb else
-                  DeviceBatch.empty(self.right.schema))
+        # chunked stream: left handles re-promoted and joined
+        # goal-rows at a time
         chunk_type = self._CHUNKED_OUTER.get(self.join_type)
         matched_any = None
         if chunk_type is not None:
@@ -691,6 +692,20 @@ class TpuShuffledHashJoinExec(TpuExec):
                 rwhole, matched_any, left_fields, pair_schema)
             yield self._project_output(extras)
 
+    def _book_build(self, rwhole: DeviceBatch) -> None:
+        """joinBuildRows, once per build, from a count someone already
+        read (a lazy one stays unread: no sync for a counter)."""
+        if rwhole._num_rows is not None:
+            self.metrics.create(M.JOIN_BUILD_ROWS, M.ESSENTIAL).add(
+                rwhole._num_rows)
+
+    def _book_output(self, out: DeviceBatch) -> None:
+        """Known counts only: fetching one here would be a blocking
+        roundtrip per joined batch purely for the metric."""
+        if out._num_rows is not None:
+            for key in (M.NUM_OUTPUT_ROWS, M.JOIN_OUTPUT_ROWS):
+                self.metrics.create(key, M.ESSENTIAL).add(out._num_rows)
+
     def _pair_schema(self) -> T.StructType:
         return T.StructType(
             [T.StructField(a.name, a.data_type, a.nullable)
@@ -716,9 +731,7 @@ class TpuShuffledHashJoinExec(TpuExec):
                                     conf=self.conf,
                                     metrics=self.metrics),
                 self.conf, self.metrics)
-        if out._num_rows is not None:
-            self.metrics.create(M.NUM_OUTPUT_ROWS, M.ESSENTIAL).add(
-                out._num_rows)
+        self._book_output(out)
         return self._project_output(out), matched
 
     def simple_string(self):

@@ -74,6 +74,13 @@ PLANNED_OOC_ESCALATIONS = "plannedOutOfCoreEscalations"  # re-plans
 PLAN_TIME = "planTime"                    # parse + rewrite, calling thread
 FIRST_DISPATCH_TIME = "firstDispatchTime"  # query begin -> first enqueue
 DEVICE_SYNC_TIME = "deviceSyncTime"       # blocked reading device values
+# what the aggregates and joins of a plan did (exec/agg.py, exec/join.py):
+# each is added where the number already exists, never by a device sync
+AGG_MERGE_COUNT = "aggMergeCount"    # partial results concatenated + merged
+AGG_GROUP_COUNT = "aggGroupCount"    # groups into the final aggregates
+JOIN_BUILD_ROWS = "joinBuildRows"    # build-side rows, once per build
+JOIN_OUTPUT_ROWS = "joinOutputRows"  # joined rows, where the count is known
+JOIN_DEMOTED_COUNT = "joinDemotedCount"  # shuffled joins run as broadcast
 
 
 # ---------------------------------------------------------------------------
@@ -130,6 +137,23 @@ METRIC_DESCRIPTIONS: Dict[str, str] = {
                              "escalated (re-partitioned at a doubled "
                              "modulus) after a partition still "
                              "overflowed its budget share",
+    AGG_MERGE_COUNT: "times a final or complete aggregate held more than "
+                     "one batch of partial results, concatenated them and "
+                     "aggregated again",
+    AGG_GROUP_COUNT: "groups the final aggregates received merged and "
+                     "emitted, before any HAVING filter (counted where "
+                     "the merge already read the number; a complete-mode "
+                     "aggregate over raw rows adds nothing)",
+    JOIN_BUILD_ROWS: "build-side rows of the joins, once per build (per "
+                     "co-partition of a shuffled join, once for a "
+                     "broadcast), where the count is known without a "
+                     "device sync",
+    JOIN_OUTPUT_ROWS: "rows the joins emitted, where the count is known "
+                      "without a device sync (a semi or anti join only "
+                      "flips the active mask and adds nothing)",
+    JOIN_DEMOTED_COUNT: "shuffled hash joins that adaptive execution ran "
+                        "as broadcast joins because the materialized "
+                        "build side was under the threshold",
     PLAN_TIME: "host planning wall on the calling thread (ns): SQL "
                "parse, analysis, overrides, plan cache, fingerprints, up "
                "to execute_collect — once per query",

@@ -3946,6 +3946,31 @@ class ScalarSubquery(Expression):
         return "scalar-subquery"
 
 
+class InSubquery(Expression):
+    """``value [NOT] IN (SELECT ...)`` as the parser leaves it (Catalyst
+    InSubquery over a ListQuery). Only the value is a child: the
+    subquery's plan is uncorrelated and resolved on its own. The
+    analysis rule ``logical.rewrite_joins_and_subqueries`` turns a
+    top-level conjunct of a WHERE/HAVING into a left semi join
+    (RewritePredicateSubquery) and refuses every other position by
+    name — this node never reaches planning, so it has no ``eval``."""
+
+    def __init__(self, value: Expression, plan):
+        self.children = [value]
+        self.plan = plan
+
+    @property
+    def value(self) -> Expression:
+        return self.children[0]
+
+    @property
+    def data_type(self) -> T.DataType:
+        return T.BooleanT
+
+    def __repr__(self) -> str:
+        return f"{self.value!r} IN (subquery)"
+
+
 def materialize_scalar_subqueries(plan, session):
     """Replace every ScalarSubquery with the Literal it evaluates to
     (executing each subquery ONCE per query, like Spark's subquery
@@ -3972,10 +3997,8 @@ def materialize_scalar_subqueries(plan, session):
             cache[key] = Literal(val, e.data_type)
         return cache[key]
 
-    _EXPR_ATTRS = ("project_list", "condition", "aggregates",
-                   "grouping", "order", "window_exprs",
-                   "partition_spec", "order_spec", "generator",
-                   "expressions")
+    from spark_rapids_tpu.sql.logical import (EXPR_ATTRS as _EXPR_ATTRS,
+                                              node_expressions)
 
     def walk(p):
         """Copy-on-write: the input plan keeps its ScalarSubquery nodes
@@ -4001,13 +4024,9 @@ def materialize_scalar_subqueries(plan, session):
         return q
 
     def has_subquery(p) -> bool:
-        for attr in _EXPR_ATTRS:
-            v = getattr(p, attr, None)
-            vs = v if isinstance(v, list) else [v] if v is not None else []
-            for x in vs:
-                if isinstance(x, Expression) and x.collect(
-                        lambda n: isinstance(n, ScalarSubquery)):
-                    return True
+        if any(x.collect(lambda n: isinstance(n, ScalarSubquery))
+               for x in node_expressions(p)):
+            return True
         return any(has_subquery(c) for c in p.children)
 
     if has_subquery(plan):

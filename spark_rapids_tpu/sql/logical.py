@@ -41,6 +41,15 @@ class LogicalPlan:
         return type(self).__name__
 
 
+class UnresolvedColumnError(KeyError):
+    """No input attribute has this name; ``column`` is the name."""
+
+    def __init__(self, column: str, inputs: Sequence[AttributeReference]):
+        super().__init__(f"cannot resolve '{column}' among "
+                         f"{[a.name for a in inputs]}")
+        self.column = column
+
+
 def resolve(expr: Expression, inputs: Sequence[AttributeReference],
             case_sensitive: bool = False) -> Expression:
     """Replace UnresolvedAttribute with matching AttributeReference."""
@@ -86,9 +95,7 @@ def resolve(expr: Expression, inputs: Sequence[AttributeReference],
                     out = GetStructField(out, name=fld)
                 if ok:
                     return out
-            raise KeyError(
-                f"cannot resolve '{e.name}' among "
-                f"{[a.name for a in inputs]}")
+            raise UnresolvedColumnError(e.name, inputs)
         return None
 
     return expr.transform(rule)
@@ -410,3 +417,201 @@ class Window(LogicalPlan):
     def output(self) -> List[AttributeReference]:
         return self.child.output + [named_output(e)
                                     for e in self.window_exprs]
+
+
+# ---------------------------------------------------------------------------
+# Analysis rules over a resolved plan (session.plan_physical runs them on
+# every statement, before scalar subqueries are materialized; the CPU
+# engine and the device rewrite both plan what they return)
+# ---------------------------------------------------------------------------
+
+# attributes of plan nodes that hold expressions (or lists of them)
+EXPR_ATTRS = ("project_list", "condition", "aggregates", "grouping",
+              "order", "window_exprs", "partition_spec", "order_spec",
+              "generator", "expressions")
+
+
+def node_expressions(p: LogicalPlan) -> List[Expression]:
+    out: List[Expression] = []
+    for attr in EXPR_ATTRS:
+        v = getattr(p, attr, None)
+        for x in (v if isinstance(v, list) else [v]):
+            if isinstance(x, Expression):
+                out.append(x)
+    return out
+
+
+def split_conjuncts(e: Expression) -> List[Expression]:
+    from spark_rapids_tpu.sql.expressions import And
+    if isinstance(e, And):
+        return split_conjuncts(e.left) + split_conjuncts(e.right)
+    return [e]
+
+
+def _conjunction(parts: List[Expression]) -> Optional[Expression]:
+    from spark_rapids_tpu.sql.expressions import And
+    out = None
+    for c in parts:
+        out = c if out is None else And(out, c)
+    return out
+
+
+def _ids(attrs) -> set:
+    return {a.expr_id for a in attrs}
+
+
+def _is_comma_join(p: LogicalPlan) -> bool:
+    return isinstance(p, Join) and p.join_type == "cross" \
+        and p.condition is None
+
+
+def _has_in_subquery(e: Expression) -> bool:
+    from spark_rapids_tpu.sql.expressions import InSubquery
+    return bool(e.collect(lambda x: isinstance(x, InSubquery)))
+
+
+def needs_rewrite(p: LogicalPlan) -> bool:
+    """Whether ``rewrite_joins_and_subqueries`` has anything to do: the
+    one walk a statement with neither form pays."""
+    if isinstance(p, Filter) and _is_comma_join(p.child):
+        return True
+    if any(_has_in_subquery(e) for e in node_expressions(p)):
+        return True
+    return any(needs_rewrite(c) for c in p.children)
+
+
+def rewrite_joins_and_subqueries(plan: LogicalPlan) -> LogicalPlan:
+    """Two rules, copy-on-write (a DataFrame's plan is planned again by
+    every action):
+
+    - a ``WHERE`` over a comma list of relations (cross joins without a
+      condition) becomes inner joins: conjuncts that read one relation
+      are pushed to it, and the relations join in the text's order, each
+      next one the first that a remaining conjunct connects to those
+      joined so far, with those conjuncts as its condition; a relation
+      nothing connects stays a cross join (Catalyst's ReorderJoin and
+      PushPredicateThroughJoin). No cost decides anything.
+    - an uncorrelated ``IN (subquery)`` that is a conjunct of a filter
+      becomes a left semi join (RewritePredicateSubquery) on the side of
+      the inner joins below that its value reads, so the joins above see
+      the rows it keeps. ``NOT IN (subquery)`` needs Spark's null-aware
+      anti join and an ``IN (subquery)`` anywhere else an existence
+      join: both raise NotImplementedError by name.
+
+    Returns ``plan`` itself when neither has anything to do."""
+    import copy
+    from spark_rapids_tpu.sql.expressions import InSubquery, Not
+    p = plan
+    new_children = [rewrite_joins_and_subqueries(c) for c in p.children]
+    if new_children != p.children:
+        p = copy.copy(p)
+        p.children = new_children
+    if isinstance(p, Filter):
+        if _is_comma_join(p.child):
+            p = _join_comma_list(p)
+        if isinstance(p, Filter):
+            p = _in_subqueries_to_semi_joins(p)
+    for e in node_expressions(p):
+        for x in e.collect(lambda x: isinstance(x, InSubquery)):
+            neg = e.collect(lambda n: isinstance(n, Not)
+                            and n.children[0] is x)
+            raise NotImplementedError(
+                "NOT IN (subquery) is not supported: it needs Spark's "
+                "null-aware anti join" if neg else
+                "IN (subquery) is supported only as a conjunct of WHERE "
+                f"or HAVING, not inside {e!r}")
+    return p
+
+
+def _join_comma_list(f: Filter) -> LogicalPlan:
+    rels: List[LogicalPlan] = []
+    node = f.child
+    while _is_comma_join(node):
+        rels.append(node.right)
+        node = node.left
+    rels.append(node)
+    rels.reverse()
+    rel_ids = [_ids(r.output) for r in rels]
+    pushed: List[List[Expression]] = [[] for _ in rels]
+    rest: List[tuple] = []          # (conjunct, ids it reads)
+    for c in split_conjuncts(f.condition):
+        refs = _ids(c.references())
+        home = [i for i, ids in enumerate(rel_ids) if refs & ids]
+        if len(home) == 1 and refs <= rel_ids[home[0]]:
+            pushed[home[0]].append(c)
+        else:
+            rest.append((c, refs))
+    for i, conds in enumerate(pushed):
+        if conds:
+            rels[i] = rewrite_joins_and_subqueries(
+                Filter(_conjunction(conds), rels[i]))
+    joined, joined_ids = rels[0], set(rel_ids[0])
+    left = list(range(1, len(rels)))
+    while left:
+        pick, conds = left[0], []
+        for i in left:
+            conds = [c for c, refs in rest
+                     if refs <= joined_ids | rel_ids[i]
+                     and refs & rel_ids[i] and refs & joined_ids]
+            if conds:
+                pick = i
+                break
+        left.remove(pick)
+        rest = [(c, refs) for c, refs in rest
+                if not any(c is d for d in conds)]
+        joined = Join(joined, rels[pick], "inner" if conds else "cross",
+                      _conjunction(conds))
+        joined_ids |= rel_ids[pick]
+    if [a.expr_id for a in joined.output] != \
+            [a.expr_id for a in f.child.output]:
+        joined = Project(list(f.child.output), joined)
+    above = _conjunction([c for c, _refs in rest])
+    return Filter(above, joined) if above is not None else joined
+
+
+def _in_subqueries_to_semi_joins(f: Filter) -> LogicalPlan:
+    from spark_rapids_tpu.sql.dataframe import _coerce_resolved
+    from spark_rapids_tpu.sql.expressions import EqualTo, InSubquery
+    child, rest = f.child, []
+    for c in split_conjuncts(f.condition):
+        if not isinstance(c, InSubquery) or _has_in_subquery(c.value):
+            rest.append(c)
+            continue
+        sub = rewrite_joins_and_subqueries(c.plan)
+        # the subquery may read a table the outer query reads too (Q18
+        # reads lineitem twice): fresh ids keep the two sides apart
+        if _ids(sub.output) & _plan_ids(child):
+            sub = Project([Alias(a, a.name) for a in sub.output], sub)
+        cond = _coerce_resolved(EqualTo(c.value, sub.output[0]))
+        child = _semi_join_below(child, _ids(c.value.references()),
+                                 sub, cond)
+    if child is f.child:
+        return f
+    return Filter(_conjunction(rest), child) if rest else child
+
+
+def _plan_ids(p: LogicalPlan) -> set:
+    out = _ids(p.output)
+    for c in p.children:
+        out |= _plan_ids(c)
+    return out
+
+
+def _semi_join_below(p: LogicalPlan, refs: set, sub: LogicalPlan,
+                     cond: Expression) -> LogicalPlan:
+    """``p LEFT SEMI JOIN sub ON cond``, pushed through the filters and
+    inner joins of ``p`` to the side that holds all of ``refs``."""
+    import copy
+    if refs:
+        if isinstance(p, Join) and p.join_type in ("inner", "cross"):
+            for i, side in enumerate(p.children):
+                if refs <= _ids(side.output):
+                    q = copy.copy(p)
+                    q.children = list(p.children)
+                    q.children[i] = _semi_join_below(side, refs, sub, cond)
+                    return q
+        elif isinstance(p, Filter) and not _has_in_subquery(p.condition):
+            q = copy.copy(p)
+            q.children = [_semi_join_below(p.child, refs, sub, cond)]
+            return q
+    return Join(p, sub, "leftsemi", cond)

@@ -9,6 +9,19 @@ engine's function library. Expressions are built through the Column API
 (spark_rapids_tpu.sql.functions) so SQL gets exactly the same coercion
 rules as the DataFrame surface.
 
+``FROM a, b JOIN c ON ..., d`` is a comma-separated list of relations,
+each with its own JOIN chain (JOIN binds tighter than the comma): a
+cross join until the WHERE's predicates say otherwise — the analysis
+rule ``logical.rewrite_joins_and_subqueries`` turns the conjuncts that
+connect two relations into inner joins and pushes the others to their
+relation. ``x [NOT] IN (SELECT ...)`` parses to ``InSubquery``: as a
+conjunct of WHERE or HAVING an uncorrelated ``IN`` becomes a left semi
+join by the same rule; ``NOT IN (SELECT ...)`` (Spark's null-aware anti
+join), an ``IN (SELECT ...)`` under OR/NOT or outside a filter, and a
+correlated subquery (one that reads the outer query's columns) are
+refused by name, never answered with other semantics. A token the
+grammar has no place for raises a ``ValueError`` that names it.
+
 Aggregation follows Spark's analyzer shape: aggregate subtrees in the
 select/having lists are extracted into an Aggregate node and the select
 list becomes a Project over its output.
@@ -75,11 +88,19 @@ _RESERVED_AFTER_RELATION = {
 }
 
 
+# what may follow a FROM clause inside one SELECT
+_AFTER_FROM = {"where", "group", "having", "order", "limit", "union"}
+
+
 class _Parser:
     def __init__(self, text: str, session=None):
         self.toks = _tokenize(text)
         self.i = 0
         self.session = session
+        # FROM relations of the enclosing SELECTs, innermost last: what
+        # a subquery's unresolved name is looked up in to call the
+        # subquery correlated rather than misspelt
+        self._outer: List = []
 
     # -- token helpers -----------------------------------------------------
 
@@ -149,15 +170,24 @@ class _Parser:
             break
         self.expect("from")
         df = self.from_clause()
-        if self.kw("where"):
-            df = df.filter(self.expr())
-        group: Optional[List[Column]] = None
-        if self.kw("group", "by"):
-            group = [self.expr()]
-            while self.peek()[1] == ",":
-                self.next()
-                group.append(self.expr())
-        having = self.expr() if self.kw("having") else None
+        kind, val = self.peek()
+        if kind != "eof" and val != ")" \
+                and val.lower() not in _AFTER_FROM:
+            raise ValueError(
+                f"unexpected token {val!r} after the FROM clause")
+        self._outer.append(df)
+        try:
+            if self.kw("where"):
+                df = df.filter(self.expr())
+            group: Optional[List[Column]] = None
+            if self.kw("group", "by"):
+                group = [self.expr()]
+                while self.peek()[1] == ",":
+                    self.next()
+                    group.append(self.expr())
+            having = self.expr() if self.kw("having") else None
+        finally:
+            self._outer.pop()
         df = self._project(df, items, group, having)
         # DISTINCT applies to the projected rows (ORDER BY/LIMIT are
         # parsed by query(), after any UNION branches)
@@ -204,6 +234,14 @@ class _Parser:
     # -- FROM / joins ------------------------------------------------------
 
     def from_clause(self):
+        """Comma-separated relations, each with its own JOIN chain."""
+        df = self.joined_relation()
+        while self.peek()[1] == ",":
+            self.next()
+            df = df.crossJoin(self.joined_relation())
+        return df
+
+    def joined_relation(self):
         df = self.relation()
         while True:
             jt = None
@@ -400,8 +438,37 @@ class _Parser:
             else:
                 return left
 
+    def _subquery(self):
+        """``SELECT ...`` up to its closing parenthesis, resolved against
+        its own FROM alone. A name it cannot resolve that an enclosing
+        SELECT's FROM can is a correlated subquery: refused by name."""
+        try:
+            sub = self.query()
+        except L.UnresolvedColumnError as e:
+            for outer in reversed(self._outer):
+                try:
+                    L.resolve(E.UnresolvedAttribute(e.column),
+                              outer.plan.output)
+                except KeyError:
+                    continue
+                raise NotImplementedError(
+                    f"correlated subquery: {e.column!r} is a column "
+                    "of the outer query; only uncorrelated subqueries "
+                    "are supported") from None
+            raise
+        self.expect(")")
+        return sub
+
     def _in_list(self, left: Column) -> Column:
         self.expect("(")
+        if self.at_kw("select"):
+            sub = self._subquery()
+            out = sub.plan.output
+            if len(out) != 1:
+                raise ValueError(
+                    "IN (subquery) must return one column, got "
+                    f"{len(out)}")
+            return Column(E.InSubquery(left.expr, sub.plan))
         vals = [self._literal_value()]
         while self.peek()[1] == ",":
             self.next()
@@ -479,8 +546,7 @@ class _Parser:
                 # uncorrelated scalar subquery (Catalyst ScalarSubquery;
                 # materialized to a Literal before physical planning)
                 self.next()
-                sub = self.query()
-                self.expect(")")
+                sub = self._subquery()
                 out = sub.plan.output
                 if len(out) != 1:
                     raise ValueError(

@@ -480,10 +480,7 @@ def split_equi_join(condition: Optional[E.Expression],
     return lk, rk, ns, res
 
 
-def split_conjuncts(e: E.Expression) -> List[E.Expression]:
-    if isinstance(e, E.And):
-        return split_conjuncts(e.left) + split_conjuncts(e.right)
-    return [e]
+split_conjuncts = L.split_conjuncts
 
 
 _PUSH_OPS = {E.EqualTo: "eq", E.LessThan: "lt", E.LessThanOrEqual: "le",

@@ -190,6 +190,13 @@ class TpuSparkSession:
         from spark_rapids_tpu import udf_compiler
         from spark_rapids_tpu.sql.expressions import \
             materialize_scalar_subqueries
+        # comma lists -> inner joins, IN (subquery) -> left semi join
+        # (sql/logical.py); a statement with neither gets its own plan
+        # back from one walk, and no span
+        if L.needs_rewrite(plan):
+            from spark_rapids_tpu import trace as TR
+            with TR.span("plan", phase="subquery"):
+                plan = L.rewrite_joins_and_subqueries(plan)
         plan = materialize_scalar_subqueries(
             plan, self if execute_subqueries else None)
         plan = udf_compiler.rewrite_plan(plan, self.conf_obj)

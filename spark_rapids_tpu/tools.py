@@ -1541,6 +1541,43 @@ def generate_supported_ops() -> str:
             f"| {sig_str(rule.checks.inputs)} | {note} |")
     lines += [
         "",
+        "## SQL dialect: relation lists and subqueries",
+        "",
+        "`FROM a, b JOIN c ON ..., d` is a comma-separated list of "
+        "relations, each with its own JOIN chain (JOIN binds tighter "
+        "than the comma): a cross join until the WHERE's predicates "
+        "say otherwise. An analysis rule "
+        "(`sql/logical.py: rewrite_joins_and_subqueries`, Catalyst's "
+        "ReorderJoin and PushPredicateThroughJoin without the costs) "
+        "pushes the conjuncts that read one relation down to it and "
+        "joins the relations in the text's order, each next one the "
+        "first that a remaining conjunct connects to those joined so "
+        "far, with those conjuncts as its inner-join condition; a "
+        "relation nothing connects stays a cross join. Explicit "
+        "`JOIN ... ON` chains are planned as written. Both engines "
+        "plan the rewritten plan.",
+        "",
+        "| Form | What it does |",
+        "|---|---|",
+        "| `x IN (SELECT ...)`, uncorrelated, a conjunct of WHERE or "
+        "HAVING | left semi join (RewritePredicateSubquery) on the "
+        "side of the inner joins below that holds `x`'s columns, so "
+        "the joins above see only the rows it keeps; a row is kept "
+        "once however often it matches; a NULL `x` or no match drops "
+        "the row |",
+        "| `x NOT IN (SELECT ...)` | refused: `NotImplementedError` "
+        "naming Spark's null-aware anti join, which it would need |",
+        "| `IN (SELECT ...)` under OR/NOT/CASE or outside a filter | "
+        "refused: `NotImplementedError` (it would need an existence "
+        "join) |",
+        "| `(SELECT ...)` as a scalar, uncorrelated | executed once "
+        "and substituted as a literal before planning |",
+        "| a subquery that reads a column of the outer query "
+        "(correlated) | refused: `NotImplementedError` naming the "
+        "column; a name that resolves nowhere stays a `KeyError` |",
+        "| a token the grammar has no place for | `ValueError` naming "
+        "the token |",
+        "",
         "## Parquet device decode (encoding matrix)",
         "",
         "Device decode is the DEFAULT scan path "
@@ -1740,7 +1777,9 @@ def generate_observability_docs() -> str:
         "",
         "| timer | span | where | what |",
         "|---|---|---|---|",
-        "| `planTime` | `plan` (`phase=parse` / `rewrite`, `cacheHit=`)"
+        "| `planTime` | `plan` (`phase=parse` / `rewrite`, `cacheHit=`;"
+        " inside `rewrite`, untimed, `phase=subquery` when a comma list"
+        " or an `IN (subquery)` is rewritten into joins)"
         " | `session.sql`; `execute_plan` up to `execute_collect` |"
         " parse, analysis, overrides, plan cache, fingerprints, on the"
         " calling thread |",

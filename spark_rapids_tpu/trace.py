@@ -137,7 +137,9 @@ SPAN_CATALOG: Dict[str, str] = {
                  "to end (q=, tenant=, parent= for a scalar subquery)",
     "plan": "host planning on the calling thread (mirrors planTime): "
             "phase=parse in session.sql, phase=rewrite in execute_plan "
-            "up to execute_collect (cacheHit= plan-cache outcome)",
+            "up to execute_collect (cacheHit= plan-cache outcome); "
+            "nested in it and untimed, phase=subquery: a comma list or "
+            "an IN (subquery) rewritten into joins (sql/logical.py)",
     "deviceSync": "the calling thread blocked reading a device value "
                   "back (mirrors deviceSyncTime; site= names the read)",
     "semaphoreWait": "wall blocked on the device semaphore",
