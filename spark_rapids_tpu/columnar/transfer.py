@@ -640,16 +640,19 @@ def _encoded_decode_body(layout: Tuple, cap: int, words, n_arr, extras,
 
     Every column is decoded in DENSE coordinates: lane i of
     ``arange(cap)`` is the i-th stored (non-null) value of the chunk.
-    Its page and its run come from ``rle.run_index`` (one scatter of
-    the table's starts and one prefix sum; no per-lane search, no
-    loop), and each stored value is decoded exactly once. Rows reach
+    Its page comes from ``rle.run_index`` (one scatter of the table's
+    starts and one prefix sum; no per-lane search, no loop) and the
+    fields of its run — bit offset, width, value — from
+    ``rle.step_fields`` (one scatter of each field's steps and one
+    prefix sum: fields by prefix sum, no gather through a run's
+    index), and each stored value is decoded exactly once. Rows reach
     their values by ONE gather through ``j`` (row -> dense rank) at
     the end, and a column without definition levels (``ndl == 0``, a
     static fact of the layout) skips it: there every active row IS its
     own dense lane, and rows past ``n`` are zeroed by ``validity``
     either way. Each lane runs under a ``jax.named_scope``
-    (``decode_run_lookup``, ``decode_page_lookup``, ``decode_bits``
-    with ``/bytes``, ``/run_fields`` and ``/window`` inside it,
+    (``decode_page_lookup``, ``decode_bits`` with ``/bytes``,
+    ``/run_fields`` and ``/window`` inside it,
     ``decode_dict``, ``decode_plain``, ``decode_chars``,
     ``decode_delta``, ``decode_rows``) so a device profile ranks them
     (``tools trace <profile dir>``, docs/observability.md)."""

@@ -10,10 +10,13 @@ fuse them into ONE decode program per scan batch:
   parsed on the host (they are a few bytes per run); the *payload* —
   every packed value — is extracted here, on device, from the raw page
   bytes. Every lookup is positional — lane i of ``arange(cap)`` wants
-  the run (or page) that covers dense position i — so a lane's run is
-  found by ``run_index``: mark each run's first lane once, take one
-  prefix sum. No per-lane search, no loop. The lane then either
-  broadcasts the run's RLE value or bit-gathers from the packed words.
+  the run (or page) that covers dense position i — so a lane's page is
+  found by ``run_index`` (mark each entry's first lane once, take one
+  prefix sum) and the fields of its run reach it by ``step_fields``
+  (mark each field's step at the run's first lane, take one prefix
+  sum): fields by prefix sum. No per-lane search, no loop, no gather
+  through a run's index. The lane then either keeps the run's RLE
+  value or bit-gathers from the packed words.
 - ``read_le`` / ``read_be_signed`` / ``read_be_limbs``: PLAIN
   fixed-width and FIXED_LEN_BYTE_ARRAY (decimal) reinterpretation at
   arbitrary byte offsets.
@@ -81,18 +84,70 @@ def run_index(out_start: jax.Array, cap: int) -> jax.Array:
                     out_start.shape[0] - 1)
 
 
+def step_fields(out_start: jax.Array, cap: int, *fields: jax.Array
+                ) -> tuple:
+    """Each field (int32 or int64) of an ascending table, broadcast to
+    the lanes its entries cover: lane i of ``arange(cap)`` gets
+    ``field[r]`` for ``r = run_index(out_start, cap)[i]`` — without a
+    gather. A field read through ``r`` is a step function of the lane,
+    so scatter each entry's step (``field[r] - field[r - 1]``) at the
+    entry's start lane and take one prefix sum: the steps up to a lane
+    telescope to the field of the last entry that starts at or before
+    it. Entry 0 carries ``field[0]`` and lands on lane 0, so lanes
+    before the first start read entry 0, as the clipped index makes
+    them; duplicate starts add up on one lane and the last of them
+    wins; starts >= cap, the padding sentinel among them, fall into a
+    spare lane that is cut off. Differences may wrap: addition is
+    modular and the prefix sum undoes it exactly.
+
+    All fields ride ONE scatter and ONE ``cumsum`` of int32 rows
+    (lanes minor). An int64 field rides as its two 32-bit halves, each
+    a step function of its own, rejoined per lane: exact for any
+    int64, and the chip (which has no 64-bit lanes) sums two int32
+    rows in under half the time of one int64 row (PERF.md §6, PR
+    31)."""
+    rows = []
+    for f in fields:
+        rows.append(f.astype(jnp.int32))
+        if f.dtype == jnp.int64:
+            rows.append((f >> 32).astype(jnp.int32))
+    tab = jnp.stack(rows)
+    steps = jnp.concatenate([tab[:, :1], tab[:, 1:] - tab[:, :-1]], axis=1)
+    lanes = jnp.concatenate([
+        jnp.zeros(1, dtype=jnp.int32),
+        jnp.clip(out_start[1:], 0, cap).astype(jnp.int32)])
+    buf = jnp.zeros((len(rows), cap + 1), jnp.int32).at[:, lanes].add(steps)
+    got = iter(jnp.cumsum(buf[:, :cap], axis=1))
+    outs = []
+    for f in fields:
+        lo = next(got)
+        if f.dtype == jnp.int64:
+            lo = (lo.astype(jnp.int64) & 0xFFFFFFFF) \
+                | (next(got).astype(jnp.int64) << 32)
+        outs.append(lo)
+    return tuple(outs)
+
+
 def _run_fields(pos: jax.Array, out_start: jax.Array,
-                bit_start: jax.Array, width: jax.Array) -> tuple:
-    """What a lane needs of its run: ``(rid, bit_off, w)`` — the run's
-    index, the absolute bit offset of the lane's packed value (the
-    run's payload start plus the lane's place in the run times the
-    run's width) and that width. Shared by the hybrid and the delta
-    lookup, whose run tables have one shape."""
-    with jax.named_scope("decode_run_lookup"):
-        rid = run_index(out_start, pos.shape[0])
+                bit_start: jax.Array, width: jax.Array,
+                value: jax.Array) -> tuple:
+    """What a lane needs of its run: ``(bit_off, w, v)`` — the absolute
+    bit offset of the lane's packed value (the run's payload start
+    plus the lane's place in the run times the run's width), that
+    width and the run's value. All reach the lanes by prefix sum
+    (``step_fields``), none by a gather.
+    ``bit_start[r] + (pos - out_start[r]) * w[r]`` is
+    ``base[r] + pos * w[r]`` with ``base = bit_start - out_start * w``
+    folded once on the table: two fields broadcast for three. The
+    width rides as one int32 row (a Parquet bit width is at most 64),
+    base and value as an int64's two each. Shared by the hybrid and
+    the delta lookup, whose run tables have one shape."""
     with jax.named_scope("decode_bits/run_fields"):
-        w = width[rid]
-        return rid, bit_start[rid] + (pos - out_start[rid]) * w, w
+        base, w, v = step_fields(
+            out_start, pos.shape[0], bit_start - out_start * width,
+            width.astype(jnp.int32), value)
+        w = w.astype(jnp.int64)
+        return base + pos * w, w, v
 
 
 def hybrid_lookup(bytes_all: jax.Array, pos: jax.Array,
@@ -104,14 +159,18 @@ def hybrid_lookup(bytes_all: jax.Array, pos: jax.Array,
 
     The run table (out_start ascending, padded with a huge sentinel;
     packed flag; RLE value; absolute payload bit offset; per-run bit
-    width) comes from the host-side header parse. Positions beyond the
-    last real run decode garbage — callers mask by validity/active."""
-    rid, bit_off, w = _run_fields(pos, out_start, bit_start, width)
+    width) comes from the host-side header parse; its fields reach the
+    lanes by prefix sum (``step_fields``). The packed flag is folded
+    into the other fields on the table: an RLE run reads zero packed
+    bits (width 0 unpacks to 0) and keeps its value, a packed run
+    keeps its width and no value, so a lane ORs the two with nothing
+    to select. Positions beyond the last real run decode garbage —
+    callers mask by validity/active."""
+    bit_off, w, rle_value = _run_fields(
+        pos, out_start, bit_start, jnp.where(packed, width, 0),
+        jnp.where(packed, 0, value))
     with jax.named_scope("decode_bits"):
-        v_packed = read_packed(bytes_all, bit_off, w)
-        with jax.named_scope("run_fields"):
-            is_packed, rle_value = packed[rid], value[rid]
-        return jnp.where(is_packed, v_packed, rle_value)
+        return read_packed(bytes_all, bit_off, w) | rle_value
 
 
 def read_packed64(bytes_all: jax.Array, bit_off: jax.Array,
@@ -138,11 +197,10 @@ def delta_lookup(bytes_all: jax.Array, pos: jax.Array,
     positions outside any run (a page's first value, other-encoding
     pages) decode garbage — callers mask before the segmented
     cumsum."""
-    rid, bit_off, w = _run_fields(pos, out_start, bit_start, width)
+    bit_off, w, min_delta = _run_fields(pos, out_start, bit_start, width,
+                                        value)
     with jax.named_scope("decode_bits"):
         raw = read_packed64(bytes_all, bit_off, w)
-        with jax.named_scope("run_fields"):
-            min_delta = value[rid]
         return min_delta + raw
 
 

@@ -1759,9 +1759,9 @@ def generate_observability_docs() -> str:
         "fused stage (`Filter`, `Project`) and the aggregate's steps",
         "(`agg_inputs`, `groupby_sort`, `groupby_reduce`, `compact`,",
         "`agg_result`) and the lanes of the Parquet page decode",
-        "(`srt_decode`: `decode_run_lookup`, `decode_page_lookup`,",
+        "(`srt_decode`: `decode_page_lookup`,",
         "`decode_bits` with `/bytes` (staging words to bytes),",
-        "`/run_fields` (each lane's gathers from its run's table row)",
+        "`/run_fields` (a run's fields to its lanes by prefix sum)",
         "and `/window` (the 5-byte gather a packed value spans) inside",
         "it, `decode_dict`, `decode_plain`, `decode_chars`,",
         "`decode_delta`, `decode_rows` — docs/scan.md §1); scopes are",

@@ -223,6 +223,31 @@ def test_delta_binary_packed_nulls_and_page_boundaries(tmp_path):
     _assert_parity(path)
 
 
+@pytest.mark.parametrize("n", [5000, 11_000])
+def test_nullable_bool_and_delta_at_odd_capacity(tmp_path, n):
+    # row counts whose capacity bucket is no power of two (5,120 and
+    # 12,288 lanes): definition levels, bool bits and DELTA miniblocks
+    # all read their run's fields lane by lane over such a cap
+    rng = np.random.default_rng(n)
+    tbl = pa.table({
+        "i": pa.array([None if (i // 29) % 4 == 1 else int(v) for i, v
+                       in enumerate(rng.integers(0, 300, n))],
+                      type=pa.int64()),
+        "b": pa.array([None if i % 13 == 5 else bool(v) for i, v
+                       in enumerate(rng.integers(0, 2, n))],
+                      type=pa.bool_()),
+        "d": pa.array([None if (i // 53) % 5 == 0 else int(v) for i, v
+                       in enumerate(rng.integers(-(1 << 45), 1 << 45, n))],
+                      type=pa.int64()),
+    })
+    path = _write(tmp_path, tbl, use_dictionary=["i"],
+                  column_encoding={"d": "DELTA_BINARY_PACKED"},
+                  data_page_size=2048)
+    m = _assert_parity(path)
+    assert m.get("deviceDecodedValues.DELTA_BINARY_PACKED", 0) > 0, m
+    assert m.get("deviceDecodedValues.RLE_DICTIONARY", 0) > 0, m
+
+
 def test_delta_decimal_int_physical(tmp_path):
     # decimal with INT32/INT64 physical storage rides the delta path
     n = 4000

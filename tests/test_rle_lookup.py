@@ -1,7 +1,10 @@
-"""Positional run/page lookup of the device decode (ops/rle.run_index):
-one scatter of the table's starts and one prefix sum must give what a
-per-lane binary search gives, and the decode program must hold no
-loop."""
+"""Positional run/page lookup of the device decode (ops/rle.run_index,
+ops/rle.step_fields): one scatter of the table's starts and one prefix
+sum must give what a per-lane binary search gives, one scatter of a
+field's steps and one prefix sum what a gather through that search
+gives, and the decode program must hold no loop and no gather by run."""
+
+import collections
 
 import jax
 import jax.numpy as jnp
@@ -49,6 +52,76 @@ def test_run_index_matches_binary_search(case, starts, pad_to, cap):
     got = np.asarray(R.run_index(jnp.asarray(table), cap))
     assert got.dtype == np.int32 and got.shape == (cap,)
     assert np.array_equal(got, _searched(table, np.arange(cap))), case
+
+
+def _field(kind, n, rng):
+    """A table column of ``n`` entries, padding entries included (no
+    lane may read them, so they hold values like any other)."""
+    if kind == "int64_wrapping":
+        # magnitudes near 2^62 with sign changes: first differences
+        # pass 2^63 and wrap, and the prefix sum has to undo it
+        mag = (1 << 62) - rng.integers(0, 1 << 20, n)
+        return np.where(rng.random(n) < 0.5, mag, -mag).astype(np.int64)
+    if kind == "packed_flag":
+        return (rng.random(n) < 0.5).astype(np.int32)
+    if kind == "width":
+        return rng.integers(0, 65, n).astype(np.int32)
+    raise AssertionError(kind)
+
+
+_FIELD_KINDS = {
+    "int64_wrapping": ("int64_wrapping",),
+    "packed_flag": ("packed_flag",),
+    "width": ("width",),
+    "five_at_once": ("int64_wrapping", "width", "packed_flag",
+                     "int64_wrapping", "width"),
+}
+
+
+@pytest.mark.parametrize("kinds", sorted(_FIELD_KINDS))
+@pytest.mark.parametrize("case,starts,pad_to,cap", _TABLES,
+                         ids=[t[0] for t in _TABLES])
+def test_step_fields_match_gather_through_search(case, starts, pad_to,
+                                                 cap, kinds):
+    table = _table(starts, pad_to)
+    rng = np.random.default_rng(len(case) + len(kinds))
+    fields = [_field(k, pad_to, rng) for k in _FIELD_KINDS[kinds]]
+    got = R.step_fields(jnp.asarray(table), cap,
+                        *[jnp.asarray(f) for f in fields])
+    rid = _searched(table, np.arange(cap))
+    assert len(got) == len(fields)
+    for f, g in zip(fields, got):
+        g = np.asarray(g)
+        assert g.dtype == f.dtype and g.shape == (cap,)
+        assert np.array_equal(g, f[rid]), (case, kinds)
+
+
+@pytest.mark.parametrize("case,starts,pad_to,cap", _TABLES,
+                         ids=[t[0] for t in _TABLES])
+def test_run_fields_match_the_gathers_they_replaced(case, starts, pad_to,
+                                                    cap):
+    """Bit offset, width and value of every lane, garbage lanes
+    (before the first start, past the last run) included: what
+    ``bit_start[rid] + (pos - out_start[rid]) * width[rid]`` and the
+    plain reads through ``rid`` gave, in wrapping int64."""
+    table = _table(starts, pad_to)
+    rng = np.random.default_rng(len(case))
+    width = rng.integers(0, 65, pad_to).astype(np.int64)
+    width[len(starts):] = 1
+    bit_start = np.cumsum(rng.integers(0, 504 * 64, pad_to))
+    value = _field("int64_wrapping", pad_to, rng)
+    pos = np.arange(cap, dtype=np.int64)
+    bit_off, w, v = R._run_fields(
+        jnp.asarray(pos), jnp.asarray(table), jnp.asarray(bit_start),
+        jnp.asarray(width), jnp.asarray(value))
+    rid = _searched(table, pos)
+    with np.errstate(over="ignore"):
+        want = bit_start[rid] + (pos - table[rid]) * width[rid]
+    assert np.asarray(bit_off).dtype == np.int64
+    assert np.array_equal(np.asarray(bit_off), want), case
+    assert np.asarray(w).dtype == np.int64
+    assert np.array_equal(np.asarray(w), width[rid]), case
+    assert np.array_equal(np.asarray(v), value[rid]), case
 
 
 def _validity(case, cap, rng):
@@ -168,11 +241,11 @@ def _abstract_extras(layout, cap):
 
 
 def _primitives(jaxpr, seen=None):
-    """Every primitive name of a jaxpr, sub-jaxprs (pjit, cond
-    branches, loop bodies, custom calls) included."""
-    seen = set() if seen is None else seen
+    """How often each primitive occurs in a jaxpr, sub-jaxprs (pjit,
+    cond branches, loop bodies, custom calls) included."""
+    seen = collections.Counter() if seen is None else seen
     for eqn in jaxpr.eqns:
-        seen.add(eqn.primitive.name)
+        seen[eqn.primitive.name] += 1
         for sub in jax.core.jaxprs_in_params(eqn.params):
             _primitives(sub, seen)
     return seen
@@ -182,18 +255,49 @@ def test_guard_sees_the_search_it_guards_against():
     prims = _primitives(jax.make_jaxpr(
         lambda t, q: jnp.searchsorted(t, q, side="right"))(
             jnp.zeros(8, jnp.int64), jnp.zeros(16, jnp.int64)).jaxpr)
-    assert prims & {"while", "scan"}
+    assert prims.keys() & {"while", "scan"}
 
 
-@pytest.mark.parametrize("name", sorted(_LAYOUTS))
-def test_decode_program_has_no_loop(name):
+# ``gather`` primitives each layout's decode program holds now that no
+# field of a run is read through the run's index (five fewer a hybrid
+# stream, four fewer a DELTA stream, than with the gathers by ``rid``:
+# q1's seven streams held 67). What is left: the 5-byte window of each
+# stream, the dictionary reads, the PLAIN/BSS byte windows, the page
+# tables' ``dense_start[pg]`` / ``pg_enc[pg]`` / ``plain_byte[pg]`` /
+# ``pg_first[pg]`` and the row gather through ``j``.
+_GATHERS = {
+    "q1_sf1": 32,
+    "nullable_dict_and_plain": 8,
+    "string_with_lengths": 11,
+    "delta_binary_packed": 8,
+    "nullable_bool": 3,
+    "dec128_plain_and_dict": 11,
+    "byte_stream_split_f64": 7,
+    "host_column_beside_a_device_one": 8,
+}
+
+
+def _decode_jaxpr(name):
     from spark_rapids_tpu.columnar.transfer import _build_encoded_decode
     layout, cap = _LAYOUTS[name]
     fn = _build_encoded_decode(layout, cap)
     words = jax.ShapeDtypeStruct((4096,), jnp.int32)
     n_arr = jax.ShapeDtypeStruct((), jnp.int64)
-    closed = jax.make_jaxpr(fn)(words, n_arr,
-                                *_abstract_extras(layout, cap))
-    prims = _primitives(closed.jaxpr)
+    return jax.make_jaxpr(fn)(words, n_arr,
+                              *_abstract_extras(layout, cap)).jaxpr
+
+
+@pytest.mark.parametrize("name", sorted(_LAYOUTS))
+def test_decode_program_has_no_loop(name):
+    prims = _primitives(_decode_jaxpr(name))
     assert "cumsum" in prims, sorted(prims)
-    assert not prims & {"while", "scan"}, sorted(prims)
+    assert not prims.keys() & {"while", "scan"}, sorted(prims)
+
+
+@pytest.mark.parametrize("name", sorted(_LAYOUTS))
+def test_decode_program_gathers_no_field_of_a_run(name):
+    """The static proof that run fields reach their lanes by prefix
+    sum in every layout: the count of ``gather`` is pinned (a gather
+    by ``rid`` that came back would raise it)."""
+    count = _primitives(_decode_jaxpr(name))["gather"]
+    assert count == _GATHERS[name], (name, count)
