@@ -808,185 +808,6 @@ def run_profile(clean_wall: float, cpu_rows) -> dict:
     }
 
 
-_KERNEL_NAMES = ("groupbyHash", "joinProbe", "murmur3", "decodeFused")
-
-# the q1 agg-drain span families whose EXCLUSIVE self-time the kernel
-# tier targets (ISSUE 11 acceptance: >= 2x on the drain, kernel vs
-# oracle): the per-batch aggregation dispatches plus the drain wall
-_DRAIN_SPANS = ("TpuHashAggregateExec.dispatch",
-                "TpuHashAggregateExec.pipelineDrainTime",
-                "pipelineDrainTime")
-
-
-def run_kernels(clean_wall: float, cpu_rows) -> dict:
-    """detail.kernels (docs/kernels.md): per-kernel A/B walls — q1
-    with the Pallas kernel tier on (stock conf) vs the XLA-op oracle
-    composition (kernel.enabled=false), plus one leg per kernel with
-    only that kernel disabled — with the q1 agg-drain EXCLUSIVE
-    self-time extracted from each leg's trace (tools.exclusive_times)
-    and the kernelDispatchCount/kernelFallbacks counters. Every leg
-    asserts bit-identical rows. On backends without native Pallas
-    lowering the kernels run in interpreter-mode emulation: the legs
-    still measure (the parity/counter story holds) but walls are not
-    representative of TPU kernels — `pallasMode` says which."""
-    import glob
-
-    from spark_rapids_tpu import device_caps as DC
-    from spark_rapids_tpu import trace as TR
-    from spark_rapids_tpu.sql.session import TpuSparkSession
-    from spark_rapids_tpu.tools import exclusive_times
-    from spark_rapids_tpu.trace import load_trace
-    mode = DC.pallas_mode()
-    if mode is None:
-        return {"skipped": True,
-                "reason": "pallas unavailable on this backend"}
-    tdir = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        ".bench-data", "kernel-traces")
-
-    def leg(extra, traced=True, runs=2) -> dict:
-        shutil.rmtree(tdir, ignore_errors=True)
-        TR.reset_tracing()
-        fresh_leg()
-        conf = dict(TPU_CONF)
-        if traced:
-            conf["spark.rapids.sql.trace.enabled"] = "true"
-            conf["spark.rapids.sql.trace.dir"] = tdir
-        conf.update(extra)
-        tpu = TpuSparkSession(conf)
-        try:
-            q = build_query(tpu)
-            run_once(q)  # jit compile warm-up
-            times, rows = [], None
-            for i in range(runs):
-                if i == runs - 1:
-                    tpu.start_capture()
-                dt, rows = run_once(q)
-                times.append(dt)
-            assert_rows_match(cpu_rows, rows)
-            counters = collect_counters(
-                tpu.get_captured_plans(),
-                tuple(f"kernelDispatchCount.{n}" for n in _KERNEL_NAMES)
-                + tuple(f"kernelFallbacks.{n}" for n in _KERNEL_NAMES)
-                + ("deviceDecodePrograms", "deviceDecodedBatches"))
-            out = {"wall_s": round(min(times), 4),
-                   "kernelDispatchCount": {
-                       n: counters[f"kernelDispatchCount.{n}"]
-                       for n in _KERNEL_NAMES
-                       if counters[f"kernelDispatchCount.{n}"]},
-                   "kernelFallbacks": {
-                       n: counters[f"kernelFallbacks.{n}"]
-                       for n in _KERNEL_NAMES
-                       if counters[f"kernelFallbacks.{n}"]}}
-            if counters["deviceDecodedBatches"]:
-                # decode-stage programs billed per device-decoded
-                # batch: 1.0 when every batch ran the fused kernel, the
-                # XLA chain's stage count otherwise (docs/kernels.md)
-                out["decodeProgramsPerBatch"] = round(
-                    counters["deviceDecodePrograms"]
-                    / counters["deviceDecodedBatches"], 4)
-            if traced:
-                files = sorted(glob.glob(
-                    os.path.join(tdir, "trace-*.json")))
-                if files:
-                    excl = exclusive_times(
-                        load_trace(files[-1])["spans"])
-                    out["aggDrainSelf_s"] = round(sum(
-                        d["exclusive"] for name, d in excl.items()
-                        if name in _DRAIN_SPANS) / 1e6, 4)
-            return out
-        finally:
-            tpu.stop()
-            TR.reset_tracing()
-
-    on = leg({})
-    off = leg({"spark.rapids.sql.kernel.enabled": "false"})
-    per_kernel = {}
-    for name in _KERNEL_NAMES:
-        per_kernel[name] = leg(
-            {f"spark.rapids.sql.kernel.{name}.enabled": "false"},
-            traced=False, runs=1)
-
-    def decode_fused_ab() -> dict:
-        """Fused single-program decode vs the stock XLA chain at equal
-        run counts: the stock ``on`` leg IS the fused leg (decodeFused
-        defaults on), so only the chain side runs fresh."""
-        chain = leg(
-            {"spark.rapids.sql.kernel.decodeFused.enabled": "false"},
-            traced=False, runs=2)
-        ab = {
-            "fused": {
-                "wall_s": on["wall_s"],
-                "programsPerBatch": on.get("decodeProgramsPerBatch")},
-            "chain": {
-                "wall_s": chain["wall_s"],
-                "programsPerBatch": chain.get(
-                    "decodeProgramsPerBatch")},
-        }
-        if on["wall_s"]:
-            ab["wallSpeedup"] = round(
-                chain["wall_s"] / on["wall_s"], 4)
-        return ab
-
-    def autotune_leg() -> dict:
-        """Cold sweep cost vs warm-start zero-cost: a first leg against
-        a fresh tuning dir sweeps each (kernel, bucket) once during
-        warm-up; after a simulated restart (tables dropped, file kept)
-        the second leg must load every winner off disk and perform ZERO
-        sweeps. Totals include session build + warm-up, so the sweep
-        cost shows up in coldTotal_s vs warmTotal_s."""
-        import tempfile
-
-        from spark_rapids_tpu.kernels import autotune as AT
-        d = tempfile.mkdtemp(prefix="bench-kernel-autotune-")
-        extra = {"spark.rapids.sql.kernel.autotune.enabled": "true",
-                 "spark.rapids.sql.kernel.autotune.dir": d}
-        try:
-            AT.reset_for_tests()
-            t0 = time.perf_counter()
-            cold = leg(extra, traced=False, runs=1)
-            cold_total = time.perf_counter() - t0
-            cold_stats = AT.stats()
-            AT.reset_for_tests()  # "restart": memory gone, file kept
-            t0 = time.perf_counter()
-            warm = leg(extra, traced=False, runs=1)
-            warm_total = time.perf_counter() - t0
-            warm_stats = AT.stats()
-            return {
-                "coldWall_s": cold["wall_s"],
-                "coldTotal_s": round(cold_total, 4),
-                "coldSweeps": cold_stats["sweeps"],
-                "rejected": cold_stats["rejected"],
-                "warmWall_s": warm["wall_s"],
-                "warmTotal_s": round(warm_total, 4),
-                "warmSweeps": warm_stats["sweeps"],
-                "warmLoaded": warm_stats["loaded"],
-                "warmHits": warm_stats["hits"],
-            }
-        finally:
-            AT.reset_for_tests()
-            shutil.rmtree(d, ignore_errors=True)
-
-    out = {
-        "skipped": False,
-        "pallasMode": mode,
-        "clean_wall_s": round(clean_wall, 4),
-        "kernelsOn": on,
-        "kernelsOff": off,
-        "oneKernelOff": per_kernel,
-        "wallSpeedup": round(off["wall_s"] / on["wall_s"], 4),
-        "decodeFused": decode_fused_ab(),
-        "autotune": autotune_leg(),
-    }
-    if on.get("aggDrainSelf_s") and off.get("aggDrainSelf_s"):
-        out["aggDrainSpeedup"] = round(
-            off["aggDrainSelf_s"] / on["aggDrainSelf_s"], 4)
-    if mode != "native":
-        out["note"] = ("interpret-mode emulation: parity/counters are "
-                       "real, walls are not representative of TPU "
-                       "kernel performance")
-    return out
-
-
 def run_serving(clean_wall: float, cpu_rows, q3_cpu_rows) -> dict:
     """Mixed q1/q3 workload through the query server
     (docs/serving.md): sustained QPS and p50/p99 latency at
@@ -1681,9 +1502,7 @@ def run_tuning(clean_wall: float, cpu_rows) -> dict:
     ledger and a server RESTART serves the first request from the
     pre-warmed plan cache; a site:tuning injected harmful action
     auto-reverts within the guard window (visible in the stats, the
-    history store, srt_tuning_* and the `tools tuning` table); a
-    forced kernelFallback verdict flips the culprit kernel conf
-    server-wide with results still bit-identical to the CPU oracle.
+    history store, srt_tuning_* and the `tools tuning` table).
     The controller tick interval is parked at 3600s so the LEG drives
     every tick — each phase is deterministic, not timing-dependent."""
     from spark_rapids_tpu import lifecycle as LC
@@ -1700,7 +1519,6 @@ def run_tuning(clean_wall: float, cpu_rows) -> dict:
     H.reset_history()
     R.reset_fault_injection()
     fresh_leg()
-    kernel_key = "spark.rapids.sql.kernel.groupbyHash.enabled"
     conf = {
         **TPU_CONF,
         "spark.rapids.sql.planCache.enabled": "true",
@@ -1779,24 +1597,6 @@ def run_tuning(clean_wall: float, cpu_rows) -> dict:
             },
         }
 
-        # -- forced kernelFallback: a synthetic signature whose newest
-        # record names the culprit kernel -> server-wide conf flip ---------
-        sig2 = "b" * 40
-        t0 = time.time()
-        for i in range(4):
-            store.append({"version": 1, "ts": t0 - 40 + i,
-                          "signature": sig2, "status": "finished",
-                          "wallSeconds": 0.05,
-                          "queueWaitSeconds": 0.0, "outputRows": 4})
-        store.append({"version": 1, "ts": t0, "signature": sig2,
-                      "status": "finished", "wallSeconds": 0.5,
-                      "queueWaitSeconds": 0.0, "outputRows": 4,
-                      "kernelFallbacks": 6,
-                      "kernelFallbacksByName": {"groupbyHash": 6}})
-        tun.tick()  # tick 5: flips kernel_key to false
-        flipped = str(tun._get_conf(kernel_key)).lower() == "false"
-        with ServeClient(srv.port, tenant="bench") as c:
-            flipped_wall = run_q1(c)  # bit-identity holds post-flip
         stats_before_restart = srv.stats().get("tuning") or {}
     finally:
         srv.shutdown()
@@ -1834,12 +1634,6 @@ def run_tuning(clean_wall: float, cpu_rows) -> dict:
         "skipped": False,
         "clean_wall_s": round(clean_wall, 4),
         "prewarm": prewarm_leg,
-        "kernelFallback": {
-            "flipped": 1.0 if flipped else 0.0,
-            "conf": kernel_key,
-            "postFlipWall_s": round(flipped_wall, 4),
-            "bitIdentical": True,  # run_q1 asserted it
-        },
         "guard": guard,
         "controller": stats_before_restart,
     }
@@ -2177,10 +1971,6 @@ def main():
     profile_leg = run_leg(failed_legs, "profile leg", run_profile,
                           fused["wall_s"], cpu_rows)
 
-    # Pallas kernel tier A/B (docs/kernels.md), equally fault-isolated
-    kernels_leg = run_leg(failed_legs, "kernels leg", run_kernels,
-                          fused["wall_s"], cpu_rows)
-
     # serving leg (docs/serving.md): QPS/latency through the query
     # server at concurrency 1/4/16, equally fault-isolated
     serving = run_leg(failed_legs, "serving leg", run_serving, fused["wall_s"],
@@ -2204,8 +1994,8 @@ def main():
                           fused["wall_s"], cpu_rows)
 
     # self-tuning leg (docs/tuning.md): forced compileStorm pre-warm
-    # hit on restart, forced kernelFallback conf flip, injected
-    # harmful action auto-reverted by the guardrail
+    # hit on restart, injected harmful action auto-reverted by the
+    # guardrail
     tuning_leg = run_leg(failed_legs, "tuning leg", run_tuning,
                          fused["wall_s"], cpu_rows)
 
@@ -2260,7 +2050,6 @@ def main():
             "outOfCore": out_of_core_leg,
             "trace": trace_leg,
             "profile": profile_leg,
-            "kernels": kernels_leg,
             "serving": serving,
             "telemetry": telemetry_leg,
             "lifecycle": lifecycle_leg,

@@ -21,13 +21,11 @@ seed) and the TPC-DS q3-shaped star join over 2M fact rows —
 - one ``mapInPandas`` call: the python worker is the only child the
   package starts, and it must answer without touching the chip;
 - with more than one chip visible: q1 and the join with both sides
-  shuffled under ``spark.rapids.shuffle.mode=ici`` over every chip;
-- per Pallas kernel: a native compile + run with the platform gate
-  open, reported ``lowers`` or ``refused: <compiler's message>``.
+  shuffled under ``spark.rapids.shuffle.mode=ici`` over every chip.
 
 It refuses to start without a TPU, never reports a failure as a skip,
-and exits non-zero if any leg failed, a kernel key was poisoned, a retry
-counter moved, a fallback report was not empty, or a warm run compiled.
+and exits non-zero if any leg failed, a retry counter moved, a
+fallback report was not empty, or a warm run compiled.
 The walls and compile seconds it prints are information for the next
 PR, not a benchmark. The last line of stdout is one JSON object:
 ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
@@ -54,11 +52,9 @@ DATA_ROOT = os.path.join(ROOT, ".bench-data", "chip_smoke")
 
 SF1_LINEITEM_ROWS = 6_001_215
 STAR_FACT_ROWS = 2_000_000
-KERNEL_NAMES = ("groupbyHash", "joinProbe", "murmur3", "decodeFused")
 
 # printed per query; the second group must stay zero
-_REPORTED = ("kernelDispatchCount.", "kernelFallbacks.",
-             "deviceFallbackUnits", "deviceFallbackColumns")
+_REPORTED = ("deviceFallbackUnits", "deviceFallbackColumns")
 _MUST_BE_ZERO = ("deviceDecodeOomFallbacks", "retryCount",
                  "splitRetryCount")
 
@@ -175,15 +171,6 @@ def check_counters(label: str, report: Dict) -> None:
     require(not moved, f"{label}: counters that must stay 0 moved: {moved}")
 
 
-def check_no_poison(label: str) -> None:
-    from spark_rapids_tpu import kernels as KR
-    poisoned = KR.poisoned()
-    require(not poisoned,
-            f"{label}: kernel keys poisoned (a lowering, compile or "
-            f"dispatch failure hid behind the oracle fallback): "
-            f"{sorted({(n, r) for (n, _k), r in poisoned.items()})}")
-
-
 def first_difference(want: List[tuple], got: List[tuple]) -> str:
     if len(want) != len(got):
         return f"{len(want)} reference rows vs {len(got)}"
@@ -219,16 +206,6 @@ def device_leg() -> Dict:
                    "compile_cache_env": os.environ.get(
                        "JAX_COMPILATION_CACHE_DIR", "")})
     return info
-
-
-def pallas_leg() -> str:
-    """On a TPU the kernel tier lowers natively or is off; it is never
-    interpreted."""
-    from spark_rapids_tpu import device_caps as DC
-    mode = DC.pallas_mode()
-    say("pallas_mode", str(mode))
-    require(mode == "native", f"pallas_mode() is {mode!r}, not 'native'")
-    return mode
 
 
 def data_leg(root: str, lineitem_rows: int, fact_rows: int) -> Dict[str, str]:
@@ -370,7 +347,6 @@ def direct_leg(paths: Dict[str, str], ref: Dict[str, List[tuple]],
     finally:
         for spark in sessions.values():
             spark.stop()
-    check_no_poison("direct")
     return out
 
 
@@ -443,7 +419,6 @@ def served_leg(paths: Dict[str, str], ref: Dict[str, List[tuple]],
             and report["storeHostBytes"] == 0,
             "served: the store is not empty after the drain")
     check_counters("served", p.report)
-    check_no_poison("served")
     return report
 
 
@@ -483,7 +458,7 @@ def python_worker_leg() -> Dict:
 
 def small_join_tables():
     """A 3000-row fact and a 300-row dimension (the shapes
-    tests/test_kernels.py joins): small enough that the multi-chip
+    tests/test_device_join.py joins): small enough that the multi-chip
     leg's shuffled join costs little to compile on every chip."""
     import numpy as np
 
@@ -570,156 +545,7 @@ def multichip_leg(paths: Dict[str, str], ref: Dict[str, List[tuple]],
                 f"chip {d.id} dispatched nothing: {dispatch}")
         require(scanned.get(f"meshScanUnits.chip{d.id}", 0) > 0,
                 f"chip {d.id} scanned nothing: {scanned}")
-    check_no_poison("multichip")
     return {"chips": n, "runs": out}
-
-
-def join_probe_natively() -> None:
-    """joinProbe is reached by neither q1 nor q3 (their joins are
-    broadcast joins outside the probe's shapes): compile and run it at
-    the largest shape its own tests give it (a 3000-row probe side
-    against a 300-row build side, tests/test_kernels.py) and compare
-    with an XLA composition of the same answer."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from spark_rapids_tpu.kernels import groupby_hash as KG
-    from spark_rapids_tpu.kernels import join_probe as KJ
-    from spark_rapids_tpu.ops.groupby import hash_subkey_words
-    cap_r, cap_l = 512, 4096
-    rng = np.random.default_rng(5)
-    rk = jnp.asarray(rng.integers(0, 300, cap_r))
-    lk = jnp.asarray(rng.integers(0, 420, cap_l))
-    vr = jnp.asarray(rng.random(cap_r) > 0.4)
-    vl = jnp.asarray(rng.random(cap_l) > 0.1)
-
-    def kernel(rk, vr, lk, vl):
-        wr = [rk.astype(jnp.int64).view(jnp.uint64)]
-        wl = [lk.astype(jnp.int64).view(jnp.uint64)]
-        return KJ.build_probe(
-            KG.pack_words_i64(wr), hash_subkey_words(wr).view(jnp.int64),
-            vr, KG.pack_words_i64(wl),
-            hash_subkey_words(wl).view(jnp.int64), vl)
-
-    def composition(rk, vr, lk, vl):
-        eq = (lk[:, None] == rk[None, :]) & vr[None, :] & vl[:, None]
-        matched = jnp.any(eq, axis=1)
-        first = jnp.argmax(eq, axis=1).astype(jnp.int32)
-        return matched, jnp.where(matched, first, 0)
-
-    got = jax.jit(kernel).lower(rk, vr, lk, vl).compile()(rk, vr, lk, vl)
-    want = jax.jit(composition)(rk, vr, lk, vl)
-    m = np.asarray(want[0])
-    require(np.array_equal(np.asarray(got[0]), m)
-            and np.array_equal(np.asarray(got[1])[m],
-                               np.asarray(want[1])[m]),
-            "joinProbe lowered but disagrees with its XLA composition")
-
-
-def murmur3_natively() -> None:
-    """murmur3 is reached by neither q1 nor q3 (no hash exchange over
-    more than one device partition): compile and run it at the largest
-    shape its own tests give it (a 4000-row batch,
-    tests/test_kernels.py) over int, long, decimal and string keys, and
-    compare with its XLA composition, ``ops.hashing.murmur3_columns``."""
-    import jax
-    import numpy as np
-
-    from spark_rapids_tpu.columnar.device import DeviceBatch
-    from spark_rapids_tpu.columnar.host import HostBatch, HostColumn
-    from spark_rapids_tpu.kernels import murmur3 as KM
-    from spark_rapids_tpu.ops import hashing as H
-    from spark_rapids_tpu.sql import types as T
-    n = 4000
-    rng = np.random.default_rng(9)
-    dec = T.DecimalType(15, 2)
-    cols = [
-        (T.IntegerT, rng.integers(-2**31, 2**31, n).astype(np.int32)),
-        (T.LongT, rng.integers(-2**62, 2**62, n)),
-        (dec, rng.integers(-10**10, 10**10, n)),
-        (T.StringT, np.array(["", "a", "abcd", "abcde", "x\x00y",
-                              "0123456789abcdef"],
-                             dtype=object)[rng.integers(0, 6, n)]),
-    ]
-    hb = HostBatch(
-        T.StructType([T.StructField(f"c{i}", dt)
-                      for i, (dt, _v) in enumerate(cols)]),
-        [HostColumn(dt, v, rng.random(n) > 0.15).normalized()
-         for dt, v in cols], n)
-    require(KM.hash_kernel_eligible([dt for dt, _v in cols]),
-            "murmur3 test columns are not kernel-eligible")
-    db = DeviceBatch.from_host(hb)
-    got = jax.jit(lambda: KM.murmur3_columns_kernel(
-        db.columns, db.capacity, 42)).lower().compile()()
-    want = jax.jit(lambda: H.murmur3_columns(db.columns, db.capacity, 42))()
-    require(np.array_equal(np.asarray(got), np.asarray(want)),
-            "murmur3 lowered but disagrees with its XLA composition")
-
-
-def kernel_leg(paths: Dict[str, str], ref: Dict[str, List[tuple]]) -> Dict:
-    """Per kernel: compile and run it natively, compare with its XLA
-    composition, and print ``lowers`` or ``refused: <first line of the
-    compiler's message>``.
-
-    groupbyHash and decodeFused run at the shapes q1 and q3 give them:
-    both queries once more with the platform gate open. A kernel either
-    lowers and the rows still equal the reference, or its error is
-    caught by the engine's own fallback, whose recorded reason is the
-    verdict. joinProbe and murmur3 are called directly.
-
-    The verdicts must agree with the gate's table: a refused kernel is
-    listed in ``kernels.NATIVE_REFUSED`` (so the stock path never
-    tries it), a kernel that lowers is not."""
-    from spark_rapids_tpu import kernels as KR
-    from spark_rapids_tpu.sql.session import TpuSparkSession
-    gated = dict(KR.NATIVE_REFUSED)
-    refusals: Dict[str, List[str]] = {}
-    before = process_totals()
-    KR.NATIVE_REFUSED.clear()
-    KR.clear_poison()
-    try:
-        spark = TpuSparkSession(device_conf())
-        try:
-            register_views(spark, paths)
-            for name, sql in queries().items():
-                checked_collect(f"kernel leg {name} (gate open)", spark,
-                                spark.sql(sql), ref[name])
-        finally:
-            spark.stop()
-        for (name, _key), reason in KR.poisoned().items():
-            if reason not in refusals.setdefault(name, []):
-                refusals[name].append(reason)
-    finally:
-        KR.NATIVE_REFUSED.update(gated)
-        KR.clear_poison()
-    after = process_totals()
-    for name in ("groupbyHash", "decodeFused"):
-        key = f"kernelDispatchCount.{name}"
-        require(after.get(key, 0) > before.get(key, 0),
-                f"q1 and q3 never dispatched {name} with the gate open")
-    for name, run in (("joinProbe", join_probe_natively),
-                      ("murmur3", murmur3_natively)):
-        try:
-            run()
-        except SmokeFailure:
-            raise
-        except Exception as e:  # noqa: BLE001 - the refusal IS the verdict
-            traceback.print_exc()
-            refusals[name] = [KR.first_line(e)]
-    verdicts = {}
-    for name in KERNEL_NAMES:
-        why = refusals.get(name)
-        verdicts[name] = f"refused: {why[0]}" if why else "lowers"
-        say(f"kernel.{name}", {"verdict": verdicts[name],
-                               "distinctRefusals": why or [],
-                               "gatedOff": name in gated})
-    for name in KERNEL_NAMES:
-        require((name in refusals) == (name in gated),
-                f"{name}: the compiler says {verdicts[name]!r} but "
-                f"kernels.NATIVE_REFUSED "
-                f"{'lists' if name in gated else 'does not list'} it")
-    return verdicts
 
 
 # ---------------------------------------------------------------------------
@@ -750,7 +576,6 @@ def run_legs(device: Dict, lineitem_rows: int, fact_rows: int,
     import jax
     watch = CompileWatch()
     kind = device["kind"]
-    leg("pallas", pallas_leg)
     paths = leg("data", data_leg, data_root, lineitem_rows, fact_rows)
     ref = leg("reference", reference_leg, paths) if paths else None
     if ref is None:
@@ -762,8 +587,6 @@ def run_legs(device: Dict, lineitem_rows: int, fact_rows: int,
         leg("multichip", multichip_leg, paths, ref, watch, kind)
     else:
         say("leg.multichip", "not run: one chip visible")
-    # last: a compiler that dies on a refused kernel takes only this leg
-    leg("kernels", kernel_leg, paths, ref)
     c, s, h, m = watch.snapshot()
     say(f"compile totals on {kind}",
         {"xlaCompiles": c, "xlaCompile_s": round(s, 3),

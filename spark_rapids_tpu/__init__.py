@@ -4,7 +4,7 @@ Built from scratch with the capabilities of NVIDIA's RAPIDS Accelerator for
 Apache Spark (reference: /root/reference, spark-rapids 21.10): a physical-plan
 rewrite engine that replaces supported operators/expressions with Tpu*Exec
 nodes whose columnar batches are HBM-resident JAX arrays, with the kernel
-library (the cuDF equivalent) implemented as XLA/Pallas programs, a tiered
+library (the cuDF equivalent) implemented as XLA programs, a tiered
 HBM->host->disk spill framework in place of RMM, and an ICI/DCN all-to-all
 shuffle in place of the UCX RapidsShuffleManager.
 
@@ -22,7 +22,7 @@ Layering mirrors SURVEY.md section 1:
   L3 memory/spill          spark_rapids_tpu.memory
   L2 shuffle/communication spark_rapids_tpu.shuffle
   L1 kernel library        spark_rapids_tpu.columnar  (cuDF equivalent)
-  L0 device runtime        JAX / XLA / Pallas
+  L0 device runtime        JAX / XLA
 """
 
 __version__ = "0.1.0"

@@ -5,7 +5,7 @@
   RapidsHostColumnVector (sql-plugin GpuColumnVector.java neighborhood).
 - device.py: HBM-resident columns as JAX arrays with bucketed static
   capacities — the analogue of GpuColumnVector over cudf device memory.
-- kernels/: XLA/Pallas programs for the cuDF Table operations the reference
+- ../ops/: XLA programs for the cuDF Table operations the reference
   calls through JNI (Table.concatenate, groupBy, join gather maps, sort,
   filter, contiguousSplit...; SURVEY.md L1).
 """

@@ -4,7 +4,7 @@ The reference relies on cuDF's spark-murmur3 mode so that GPU hash
 partitioning places rows in the same shuffle partitions CPU Spark would
 (GpuHashPartitioning.scala; SURVEY.md 2.5 'murmur3-compatible GPU hash').
 This module is the host/reference implementation; the device twin (jnp) is
-columnar/kernels/hashing.py and must match bit-for-bit.
+ops/hashing.py and must match bit-for-bit.
 
 Algorithm: Spark's Murmur3_x86_32 (hashInt/hashLong/hashUnsafeBytes with
 trailing bytes processed one-at-a-time as signed ints), seed 42, columns

@@ -442,17 +442,15 @@ def _stage_direct(batch, cap: int):
     return ("direct", batch.schema, n, spec, np_arrays)
 
 
-def prepare_upload(batch, cap: int, conf=None, metrics=None):
+def prepare_upload(batch, cap: int, metrics=None):
     """Host-side half of an upload (pack/stage, NO device touch): the
     returned opaque token feeds finish_upload. Splitting the phases lets
     a producer thread pack batch k+1 while batch k's bytes move.
-    ``conf``/``metrics`` (scan path) gate the fused-decode kernel and
-    receive its dispatch/fallback counters; without them the encoded
-    path runs the stock XLA chain uncounted."""
+    ``metrics`` (scan path) names the query whose first dispatch the
+    decode program may be."""
     from spark_rapids_tpu.io.device_decode import EncodedBatch
     if isinstance(batch, EncodedBatch):
-        return prepare_encoded_upload(batch, cap, conf=conf,
-                                      metrics=metrics)
+        return prepare_encoded_upload(batch, cap, metrics=metrics)
     n = batch.num_rows
     if n < PACKED_MIN_ROWS or any(
             isinstance(f.data_type, (T.ArrayType, T.StructType))
@@ -487,10 +485,10 @@ def start_upload(staged, device: Optional[jax.Device] = None):
         return ("direct", schema, n, spec, put(np_arrays))
     if staged[0] == "encoded":
         (_tag, schema, n, cap, words, extras, layout, spec,
-         fuse) = staged
+         metrics) = staged
         dev = put([words, np.asarray(n, dtype=np.int64)] + list(extras))
         return ("encoded", schema, n, cap, words.nbytes, layout, spec,
-                dev, fuse)
+                dev, metrics)
     _tag, schema, n, cap, words, extras, layout = staged
     return ("packed", schema, n, cap, words.nbytes, layout,
             put([words] + extras))
@@ -544,7 +542,7 @@ def _pad_pow2(n: int, floor: int = 8) -> int:
     return 1 << (n - 1).bit_length()
 
 
-def prepare_encoded_upload(enc, cap: int, conf=None, metrics=None):
+def prepare_encoded_upload(enc, cap: int, metrics=None):
     """EncodedBatch -> staged token: pads plan tables to pow2 buckets so
     the decode-program cache keys repeat across row groups (the row
     count itself rides as a device scalar, so row groups of any size
@@ -612,31 +610,13 @@ def prepare_encoded_upload(enc, cap: int, conf=None, metrics=None):
     if nw > len(words):
         words = np.concatenate([words,
                                 np.zeros(nw - len(words), np.int32)])
-    # fuse context: resolved HERE (the host-side half, where the conf
-    # lives) so the device-side finish never touches conf objects; the
-    # params come from the autotuner's warm table (defaults untuned)
-    fuse = None
-    if conf is not None or metrics is not None:
-        fuse = {"enabled": False, "metrics": metrics, "params": {},
-                "tuned": False}
-        from spark_rapids_tpu import kernels as KR
-        if conf is not None and KR.kernel_enabled(conf, "decodeFused"):
-            from spark_rapids_tpu.kernels import autotune as AT
-            params, tuned = AT.params_for(conf, "decodeFused", cap)
-            fuse.update(enabled=True, params=params, tuned=tuned)
     return ("encoded", enc.schema, n, cap, words, extras,
-            tuple(layout), tuple(spec), fuse)
+            tuple(layout), tuple(spec), metrics)
 
 
-def _encoded_decode_body(layout: Tuple, cap: int, words, n_arr, extras,
-                         char_chunk: int = 0):
-    """The encoded-decode arithmetic, shared verbatim by the XLA chain
-    (``_build_encoded_decode`` jits it directly) and the fused Pallas
-    kernel (``kernels/decode_fused.py`` executes it inside one
-    ``pallas_call``) — bit-identity between the two paths is
-    structural, not tested-into (the murmur3 kernel's model).
-    ``char_chunk`` bounds the string char-gather's live index matrix
-    (autotunable; 0 = unchunked) without changing a byte.
+def _encoded_decode_body(layout: Tuple, cap: int, words, n_arr, extras):
+    """The encoded-decode arithmetic, jitted by
+    ``_build_encoded_decode``.
 
     Every column is decoded in DENSE coordinates: lane i of
     ``arange(cap)`` is the i-th stored (non-null) value of the chunk.
@@ -754,9 +734,9 @@ def _encoded_decode_body(layout: Tuple, cap: int, words, n_arr, extras,
                     rel = R.seg_excl_cumsum(
                         contrib, jnp.clip(pg_start, 0, cap - 1))
                     plens = slen.astype(jnp.int32)
-                    pchars = R.gather_chars_chunked(
+                    pchars = R.gather_chars(
                         get_bytes(), plain_byte[pg] + rel + lp, plens,
-                        char_cap, char_chunk)
+                        char_cap)
             else:
                 pchars = jnp.zeros((cap, char_cap), dtype=jnp.uint8)
                 plens = jnp.zeros(cap, dtype=jnp.int32)
@@ -872,48 +852,9 @@ def _chain_fn(layout, cap: int, nbytes: int):
 
 def _finish_encoded_upload(token):
     from spark_rapids_tpu.columnar import device as D
-    _tag, schema, n, cap, nbytes, layout, spec, dev, fuse = token
-    from spark_rapids_tpu import kernels as KR
-    from spark_rapids_tpu.kernels import decode_fused as DF
-    metrics = fuse.get("metrics") if fuse else None
-    fused = bool(fuse and fuse["enabled"]) \
-        and not KR.is_poisoned("decodeFused", (layout, cap))
-    active = outs = None
-    if fused:
-        params = fuse.get("params") or {}
-        char_chunk = int(params.get("charChunk", 0))
-        key = ("encF", layout, cap, nbytes, char_chunk)
-        try:
-            KR.check_injected_failure("decodeFused")
-            fn = _DECODE_CACHE.get(key)
-            if fn is None:
-                fn = _DECODE_CACHE.put(key, DF.build_fused_decode(
-                    layout, cap, interpret=KR.interpret(),
-                    char_chunk=char_chunk))
-            KR.count_dispatch(metrics, "decodeFused")
-            _trace.first_dispatch(metrics, fn)
-            with KR.dispatch_span("decodeFused", bucket=cap,
-                                  tuned=bool(fuse.get("tuned"))):
-                active, outs = fn(dev[0], dev[1], *dev[2:])
-        except Exception as e:
-            if not KR.is_oracle_fallback_error(e):
-                raise
-            # lowering/compile/dispatch failure: poison this (layout,
-            # cap) and decode THIS batch (and every later one of the
-            # shape) on the stock XLA chain — bit-identical either way
-            KR.poison("decodeFused", (layout, cap), e)
-            KR.count_fallback(metrics, "decodeFused")
-            fused = False
-            active = outs = None
-    if outs is None:
-        fn = _chain_fn(layout, cap, nbytes)
-        _trace.first_dispatch(metrics, fn)
-        active, outs = fn(dev[0], dev[1], *dev[2:])
-    if metrics is not None:
-        # programs-per-batch attribution for the fused A/B: the chain
-        # bills its static per-layout logical stage count, the fused
-        # kernel bills 1 (bench divides by deviceDecodedBatches)
-        metrics.create("deviceDecodePrograms").add(
-            1 if fused else DF.chain_programs(layout))
+    _tag, schema, n, cap, nbytes, layout, spec, dev, metrics = token
+    fn = _chain_fn(layout, cap, nbytes)
+    _trace.first_dispatch(metrics, fn)
+    active, outs = fn(dev[0], dev[1], *dev[2:])
     return D.DeviceBatch(schema, D.rebuild_columns(list(spec), outs),
                          active, n)

@@ -646,13 +646,6 @@ TELEMETRY_RETRY_COUNT_THRESHOLD = conf(
     "this many retryCount (OOM retries) emits a slow-query bundle. "
     "0 disables the trigger.").integer(0)
 
-TELEMETRY_KERNEL_FALLBACK_THRESHOLD = conf(
-    "spark.rapids.sql.telemetry.kernelFallbackThreshold").doc(
-    "Per-query kernel-fallback trigger: a query whose plan accumulates "
-    "MORE than this many kernelFallbacks.* (Pallas kernel calls that "
-    "fell back to the XLA-op oracle) emits a slow-query bundle. "
-    "0 disables the trigger.").integer(0)
-
 TELEMETRY_RETRY_STORM_THRESHOLD = conf(
     "spark.rapids.sql.telemetry.retryStormThreshold").doc(
     "Process-wide retry-storm trigger: MORE than this many OOM retries "
@@ -705,7 +698,7 @@ TELEMETRY_HISTORY_DIR = conf(
     "spark.rapids.sql.telemetry.history.dir").doc(
     "Directory of the persistent query-history store: one compact "
     "JSONL record per finished query (signature, tenant, terminal "
-    "status/reason, wall/queue-wait, retry/spill/kernel/jit counters, "
+    "status/reason, wall/queue-wait, retry/spill/jit counters, "
     "fallback coverage, peak HBM, artifact paths), appended at query "
     "close by session.execute_plan and the query server, rotated into "
     "bounded segments and compacted by telemetry.history.maxBytes / "
@@ -762,7 +755,7 @@ SERVE_TUNING_ENABLED = conf("spark.rapids.sql.serve.tuning.enabled").doc(
     "on a periodic tick, and applies bounded, logged, reversible "
     "per-signature actions from the declared ACTION_CATALOG — cache "
     "pre-warm for compile storms, admission narrowing / out-of-core "
-    "seeding for retry-spill shapes, culprit-kernel fallback flips, "
+    "seeding for retry-spill shapes, "
     "and per-tenant admission weight shifts for SLO burn. Every "
     "action lands in the history store as a tuning record, exports "
     "as srt_tuning_* Prometheus families, and auto-reverts when the "
@@ -847,101 +840,6 @@ PARQUET_DEVICE_DECODE_BSS = conf(
     "Device-decode BYTE_STREAM_SPLIT pages (float/double/int32/int64): "
     "the byte-plane reinterleave is a strided device gather. Off = "
     "those columns fall back to the pyarrow host decode.").boolean(True)
-
-KERNEL_ENABLED = conf("spark.rapids.sql.kernel.enabled").doc(
-    "Master switch for the hand-written Pallas kernel tier "
-    "(spark_rapids_tpu/kernels/): ops whose shape a kernel supports "
-    "swap their stock XLA-op composition for the kernel behind the "
-    "same JitCache keys, with automatic per-call fallback to the "
-    "composition (the bit-identity oracle) on lowering/compile "
-    "failure or hash-table overflow — counted as kernelFallbacks.* "
-    "metrics. On backends without native Pallas lowering (CPU) the "
-    "kernels run in interpreter mode so every kernel path stays "
-    "exercised (docs/kernels.md).").boolean(True)
-
-KERNEL_GROUPBY_HASH = conf(
-    "spark.rapids.sql.kernel.groupbyHash.enabled").doc(
-    "Single-pass open-addressed hash-table group-by kernel for the "
-    "PARTIAL aggregation update (SUM/COUNT/MIN/MAX over fixed-width "
-    "keys and values): replaces the lexsort + segmented-scan pipeline "
-    "with one insert/combine pass over the batch. Batches with more "
-    "distinct groups than kernel.groupbyHash.tableSlots overflow and "
-    "re-run on the oracle composition (docs/kernels.md).").boolean(True)
-
-KERNEL_GROUPBY_TABLE_SLOTS = conf(
-    "spark.rapids.sql.kernel.groupbyHash.tableSlots").doc(
-    "Hash-table capacity (slots, rounded up to a power of two) of the "
-    "group-by kernel. Bounds the distinct groups one batch may "
-    "produce through the kernel; beyond it the batch overflows to the "
-    "oracle composition (kernelFallbacks.groupbyHash). Sized for "
-    "low-cardinality aggregations (the q1 shape); raise it for "
-    "wider group counts at the cost of on-chip table state."
-    ).integer(1024)
-
-KERNEL_JOIN_PROBE = conf(
-    "spark.rapids.sql.kernel.joinProbe.enabled").doc(
-    "Hash-table build/probe kernel for the join gather map: the build "
-    "side inserts into an open-addressed table (first-occurrence row "
-    "per key), the stream side probes it — replacing the sort-based "
-    "key plan for semi/anti joins and the certified-unique-build-key "
-    "(FK) fast path. Applies when the build side fits "
-    "kernel.joinProbe.maxBuildRows (docs/kernels.md).").boolean(True)
-
-KERNEL_JOIN_MAX_BUILD_ROWS = conf(
-    "spark.rapids.sql.kernel.joinProbe.maxBuildRows").doc(
-    "Largest build-side row capacity the join probe kernel accepts; "
-    "the table is sized at twice the capacity (load factor <= 0.5, so "
-    "probe chains always terminate and overflow is impossible). "
-    "Bigger build sides keep the sort-based oracle plan.").integer(8192)
-
-KERNEL_MURMUR3 = conf("spark.rapids.sql.kernel.murmur3.enabled").doc(
-    "Fused Murmur3 partition-hashing kernel: the per-column "
-    "rotl/fmix chains of Spark's Murmur3_x86_32 fold in one pass over "
-    "the row block instead of a chain of stock XLA ops. Bit-identical "
-    "to ops/hashing.py (the same arithmetic runs inside the kernel); "
-    "used by the in-process hash exchange (docs/kernels.md)."
-    ).boolean(True)
-
-KERNEL_DECODE_FUSED = conf(
-    "spark.rapids.sql.kernel.decodeFused.enabled").doc(
-    "Fused Parquet decode kernel: collapse the per-batch encoded-scan "
-    "decode chain (RLE/bit-unpack, dictionary gather, definition-level "
-    "validity expansion, byte-array offsets-from-lengths + char "
-    "gather) into ONE Pallas kernel per (layout, capacity bucket), "
-    "behind the same uploadDecode cache keys. The stock XLA "
-    "composition stays the bit-identity oracle and the per-call "
-    "fallback on any lowering/compile/dispatch failure "
-    "(kernelFallbacks.decodeFused); host-decoded columns pass through "
-    "outside the kernel untouched (docs/kernels.md).").boolean(True)
-
-KERNEL_AUTOTUNE_ENABLED = conf(
-    "spark.rapids.sql.kernel.autotune.enabled").doc(
-    "Per-kernel parameter autotuner (docs/kernels.md): the first "
-    "dispatch of a kernel at a new (kernel, shape bucket, device kind) "
-    "sweeps a small bounded parameter grid (block shapes, tableSlots "
-    "multiplier, char-gather chunking), validates every candidate "
-    "against the kernel's oracle, and persists the winner in the "
-    "crash-safe table under kernel.autotune.dir. Off (the default) = "
-    "read-only: previously recorded winners still apply, but no sweep "
-    "ever runs — production servers against a warmed table never "
-    "re-tune.").boolean(False)
-
-KERNEL_AUTOTUNE_DIR = conf("spark.rapids.sql.kernel.autotune.dir").doc(
-    "Directory of the autotuner's persistent winner table "
-    "(kernel-autotune.jsonl, append-only JSON lines next to the "
-    "JitCache artifacts): loaded once per process at first use, so a "
-    "second session against the same directory performs zero sweeps. "
-    "Torn or garbage lines are skipped on load; an unreadable table "
-    "falls back to default parameters. Empty = autotuning fully off "
-    "(defaults everywhere).").string("")
-
-KERNEL_AUTOTUNE_BUDGET_MS = conf(
-    "spark.rapids.sql.kernel.autotune.budgetMs").doc(
-    "Wall budget in milliseconds for ONE autotune sweep (one kernel at "
-    "one shape bucket): candidate timing stops once the budget is "
-    "spent and the best validated candidate so far wins. Bounds the "
-    "cold-start cost a sweep can add to the first query at a new "
-    "shape.").integer(2000)
 
 PARQUET_DEVICE_DECODE_MAX_IN_FLIGHT = conf(
     "spark.rapids.sql.format.parquet.deviceDecode.maxInFlight").doc(

@@ -20,7 +20,6 @@ the reference uses for its not-bit-exact ops (GpuOverrides .incompat()).
 from __future__ import annotations
 
 import functools
-import logging
 
 import numpy as np
 
@@ -91,49 +90,6 @@ def f64_bitcast_exact() -> bool:
                               bits.view(np.float64), equal_nan=True)
     except Exception:
         return False
-
-
-@functools.lru_cache(maxsize=None)
-def pallas_mode():
-    """How the Pallas kernel tier (spark_rapids_tpu/kernels/) runs on
-    the default backend: ``"interpret"`` on the CPU platform only
-    (interpreter-mode emulation — tier-1 exercises every kernel path
-    through it), ``"native"`` on an accelerator where ``pl.pallas_call``
-    lowers and executes for real, ``None`` when the probe fails
-    (kernels stay disabled and every op keeps its XLA-op oracle
-    composition). An accelerator never answers ``"interpret"``: a
-    kernel emulated op by op on the chip is slower than its oracle."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    def kern(x_ref, o_ref):
-        o_ref[...] = x_ref[...] * 2
-
-    mode = "interpret" if jax.default_backend() == "cpu" else "native"
-    # one native int32 tile: the smallest shape Mosaic always accepts
-    x = np.arange(8 * 128, dtype=np.int32).reshape(8, 128)
-    try:
-        # .lower().compile() forces REAL lowering even when the first
-        # probe call happens inside an outer trace (a plain call would
-        # inline the pallas_call into the outer jaxpr and "succeed"
-        # without ever testing the backend)
-        # tpu-lint: disable=jit-direct(one-shot lru_cached capability probe, never re-compiled)
-        fn = jax.jit(lambda v: pl.pallas_call(
-            kern, out_shape=jax.ShapeDtypeStruct(x.shape, jnp.int32),
-            interpret=mode == "interpret")(v))
-        out = fn.lower(x).compile()(x)
-    except Exception as e:  # noqa: BLE001 - reported, kernel tier off
-        logging.getLogger("spark_rapids_tpu.device_caps").warning(
-            "Pallas %s probe failed on %s; kernel tier disabled: %s",
-            mode, jax.default_backend(), e)
-        return None
-    return mode if np.array_equal(np.asarray(out), x * 2) else None
-
-
-def pallas_interpret() -> bool:
-    """True when kernels must pass ``interpret=True`` to pallas_call."""
-    return pallas_mode() == "interpret"
 
 
 def float_arith_reason(kind: str = "arithmetic") -> str:

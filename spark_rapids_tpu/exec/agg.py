@@ -275,9 +275,7 @@ class TpuHashAggregateExec(TpuExec):
                   slot_srcs: List[E.Expression],
                   prims: List[Tuple[str, T.DataType]],
                   has_nans: bool, prelude_steps=None,
-                  donate: bool = False,
-                  kernel_slots: Optional[int] = None,
-                  kernel_params: Optional[dict] = None) -> Callable:
+                  donate: bool = False) -> Callable:
         aliases = self._agg_aliases()
         slot_counts = [len(self.slots[a.expr_id]) for a in aliases]
         grouping = self.grouping
@@ -319,26 +317,6 @@ class TpuHashAggregateExec(TpuExec):
                 src_map.append(uniq_of[k])
             with jax.named_scope("agg_inputs"):
                 slot_vals = [X.dev_eval(e, ctx) for e in uniq_srcs]
-            if kernel_slots is not None:
-                # Pallas hash-table kernel (docs/kernels.md): one
-                # open-addressed insert/combine pass replaces the
-                # lexsort + segmented scans below. Same compacted
-                # partial-output contract, plus the overflow flag the
-                # exec resolves at drain time (overflowed batches
-                # re-run on this very oracle path, kernels off).
-                from spark_rapids_tpu.columnar.device import _compact_body
-                from spark_rapids_tpu.kernels import groupby_hash as KG
-                entries = [(slot_vals[j], p, dt)
-                           for j, (p, dt) in zip(src_map, prims)]
-                key_out, buffers, used, cnt, ovf = KG.hash_groupby(
-                    key_cols, entries, active, kernel_slots,
-                    has_nans=has_nans, params=kernel_params)
-                out_cols = list(key_out if grouping else []) \
-                    + list(buffers)
-                flat2, spec2 = flatten_columns(out_cols)
-                new_active, outs2 = _compact_body(used, flat2)
-                return rebuild_columns(spec2, outs2), new_active, cnt, \
-                    ovf
             # keys AND slot values ride the segment sort as payload (one
             # fused lane-matrix gather; sorting each array separately is
             # a flat ~25-40ms per op on this backend)
@@ -414,10 +392,8 @@ class TpuHashAggregateExec(TpuExec):
                 else:
                     raise X.DeviceUnsupported(f"agg result expr {e!r}")
             return out_cols, out_active
-        return named_jit(
-            program_name("agg", mode,
-                         "kernel" if kernel_slots is not None else None),
-            fn, donate_argnums=(0, 1) if donate else ())
+        return named_jit(program_name("agg", mode), fn,
+                         donate_argnums=(0, 1) if donate else ())
 
     def _out_desc(self) -> Tuple:
         """Structural descriptor of the result-column layout (what the
@@ -441,15 +417,10 @@ class TpuHashAggregateExec(TpuExec):
         return tuple(desc)
 
     def _aggregate_batch(self, batch: DeviceBatch,
-                         mode: Optional[str] = None,
-                         force_oracle: bool = False):
-        """Run one aggregation program. Returns ``(DeviceBatch, cnt,
-        overflow)``: ``cnt`` is the device-scalar group count for
-        partial/merge modes (compacted output) and None for
-        final/complete; ``overflow`` is the kernel path's device
-        hash-table-overflow flag (None on the oracle path) — the
-        partial drain re-runs overflowed batches with
-        ``force_oracle=True`` (docs/kernels.md)."""
+                         mode: Optional[str] = None):
+        """Run one aggregation program. Returns ``(DeviceBatch, cnt)``:
+        ``cnt`` is the device-scalar group count for partial/merge
+        modes (compacted output) and None for final/complete."""
         mode = mode or self.mode
         prelude = (self._prelude_ops
                    if self._prelude_ops and mode == "partial" else None)
@@ -484,40 +455,11 @@ class TpuHashAggregateExec(TpuExec):
             prelude_steps = bind_chain_steps(prelude)
             struct = struct[:-1] + (
                 X.stage_structural_key(prelude_steps),)
-        # Pallas kernel tier (docs/kernels.md): the hash-table kernel
-        # takes the partial update when the whole program's shape is
-        # eligible; a structure whose kernel build/dispatch ever
-        # failed is poisoned back to the oracle for the process life
-        from spark_rapids_tpu import kernels as KR
-        from spark_rapids_tpu.kernels import groupby_hash as KG
-        kern_slots = None
-        kern_params: dict = {}
-        kern_tuned = False
-        if (not force_oracle
-                and KR.kernel_enabled(self.conf, "groupbyHash")
-                and KG.agg_kernel_eligible(mode, self.grouping,
-                                           slot_srcs, prims)
-                and not KR.is_poisoned("groupbyHash", struct)):
-            # per-bucket tuning from the autotuner's warm table (the
-            # defaults when untuned); slotsMult scales the table bound
-            # BEFORE the batch clamp so tuning can trade VMEM for
-            # fewer overflow re-runs
-            from spark_rapids_tpu.kernels import autotune as AT
-            kern_params, kern_tuned = AT.params_for(
-                self.conf, "groupbyHash", batch.capacity)
-            kern_slots = KR.table_slots(
-                self.conf, batch.capacity,
-                slots_mult=int(kern_params.get("slotsMult", 1)))
         if prelude:
             from spark_rapids_tpu.exec.fused import batch_donatable
             # per-batch: aliased buffers (one array on two pytree
-            # leaves) must not be donated twice; the kernel path also
-            # never donates — an overflowed batch re-runs on the
-            # oracle, so its input buffers must survive the dispatch —
-            # and neither does a force_oracle re-run, whose input is a
-            # STORE-RETAINED batch a concurrent spill may still read
-            donate = (self._donate_input and batch_donatable(batch)
-                      and kern_slots is None and not force_oracle)
+            # leaves) must not be donated twice
+            donate = self._donate_input and batch_donatable(batch)
         lit_vals = X.literal_values(list(key_bound) + list(slot_srcs))
         if prelude_steps:
             lit_vals = (X.stage_literal_values(prelude_steps), lit_vals)
@@ -528,76 +470,34 @@ class TpuHashAggregateExec(TpuExec):
         chip = TR.chip_of(batch)  # None (no device query) when untraced
         import time as _time
 
-        kp_key = tuple(sorted(kern_params.items()))
-
-        def _get_fn(kslots):
-            # tuning parameters are part of the program structure (a
-            # different block shape is a different trace), so they key
-            # the cache alongside the slot count
-            return _AGG_FN_CACHE.get_or_build(
-                struct + (donate, kslots)
-                + (kp_key if kslots is not None else ()),
-                lambda: self._build_fn(mode, key_bound, slot_srcs,
-                                       prims, has_nans=salt[0],
-                                       prelude_steps=prelude_steps,
-                                       donate=donate,
-                                       kernel_slots=kslots,
-                                       kernel_params=(kern_params
-                                                      if kslots is not None
-                                                      else None)))
-
-        fn, was_miss = _get_fn(kern_slots)
+        fn, was_miss = _AGG_FN_CACHE.get_or_build(
+            struct + (donate,),
+            lambda: self._build_fn(mode, key_bound, slot_srcs, prims,
+                                   has_nans=salt[0],
+                                   prelude_steps=prelude_steps,
+                                   donate=donate))
         mirror_to_metrics(_AGG_FN_CACHE, self.metrics, was_miss)
-        ovf = None
         TR.first_dispatch(self.metrics, fn)
-        # the ENQUEUE interval (jax dispatch is asynchronous). The
-        # annotation is opened by hand: it covers a failed kernel
-        # attempt too, while the host interval restarts at the fallback
-        ann = TR.annotation(
-            "TpuHashAggregateExec.dispatch", TR.scope_of(self.metrics),
-            attrs={"mode": mode, "program": program_of(fn)})
-        ann.__enter__()
-        t0 = _time.perf_counter_ns()
-        try:
-            if kern_slots is not None:
-                KR.check_injected_failure("groupbyHash")
-                KR.count_dispatch(self.metrics, "groupbyHash")
-                out_cols, out_active, cnt, ovf = fn(
-                    batch.columns, batch.active, lit_vals)
-            elif mode in ("partial", "merge", "merge_partial"):
-                out_cols, out_active, cnt = fn(batch.columns,
-                                               batch.active, lit_vals)
-            else:
-                out_cols, out_active = fn(batch.columns, batch.active,
-                                          lit_vals)
-        except Exception as e:
-            if kern_slots is None or not KR.is_oracle_fallback_error(e):
-                raise
-            # kernel failed to lower/compile/execute: poison the
-            # structure and re-run this call on the oracle composition
-            KR.poison("groupbyHash", struct, e)
-            KR.count_fallback(self.metrics, "groupbyHash")
-            kern_slots = None
-            fn, was_miss = _get_fn(None)
-            mirror_to_metrics(_AGG_FN_CACHE, self.metrics, was_miss)
+        with TR.annotation(
+                "TpuHashAggregateExec.dispatch", TR.scope_of(self.metrics),
+                attrs={"mode": mode, "program": program_of(fn)}):
+            # the ENQUEUE interval (jax dispatch is asynchronous)
             t0 = _time.perf_counter_ns()
-            out_cols, out_active, cnt = fn(batch.columns, batch.active,
-                                           lit_vals)
-        finally:
-            ann.__exit__(None, None, None)
+            outs = fn(batch.columns, batch.active, lit_vals)
         elapsed = _time.perf_counter_ns() - t0
+        if mode in ("partial", "merge", "merge_partial"):
+            out_cols, out_active, cnt = outs
+        else:
+            out_cols, out_active = outs
         qt = TR._ACTIVE
         if qt is not None:
             # the same measurement feeds computeAggTime/stageCompileTime
             # below — trace and metrics agree (docs/observability.md)
-            attrs = {"mode": mode, "compile": bool(was_miss),
-                     "program": program_of(fn)}
-            if kern_slots is not None:
-                attrs.update(kernel="groupbyHash", bucket=batch.capacity,
-                             tuned=kern_tuned)
             TR.record(qt, "TpuHashAggregateExec.dispatch", t0,
                       t0 + elapsed, TR.scope_of(self.metrics),
-                      chip=chip, attrs=attrs)
+                      chip=chip,
+                      attrs={"mode": mode, "compile": bool(was_miss),
+                             "program": program_of(fn)})
         if was_miss:
             # first call after a compile miss carries trace+XLA compile
             self.metrics.create(M.STAGE_COMPILE_TIME,
@@ -626,7 +526,7 @@ class TpuHashAggregateExec(TpuExec):
         else:
             schema = self.schema
         return DeviceBatch(schema, list(out_cols), out_active,
-                           None), cnt, ovf
+                           None), cnt
 
     def _empty_global_result(self) -> DeviceBatch:
         cols: List[HostColumn] = []
@@ -667,7 +567,7 @@ class TpuHashAggregateExec(TpuExec):
                 whole = concat_device([h.get() for h in chunk])
                 self.metrics.create(M.AGG_MERGE_COUNT, M.ESSENTIAL).add(1)
                 from spark_rapids_tpu import retry as R
-                out, cnt, _ovf = R.with_retry(
+                out, cnt = R.with_retry(
                     lambda w=whole: self._aggregate_batch(w, mode="merge"),
                     self.conf, self.metrics)
                 with TR.device_sync("aggMerge", self.metrics):
@@ -714,8 +614,7 @@ class TpuHashAggregateExec(TpuExec):
             with self.metrics.timed(M.PARTITION_TIME):
                 parts = R.with_retry(
                     lambda b=b: split_by_pid(
-                        b, hash_partition_ids(bound_keys, b, modulus,
-                                              self.conf, self.metrics),
+                        b, hash_partition_ids(bound_keys, b, modulus),
                         modulus),
                     self.conf, self.metrics)
             h.close()
@@ -790,7 +689,7 @@ class TpuHashAggregateExec(TpuExec):
                         continue
                 for h in bh:
                     h.close()
-            out, _cnt, _ovf = R.with_retry(
+            out, _cnt = R.with_retry(
                 lambda w=whole: self._aggregate_batch(w),
                 self.conf, self.metrics)
             yield out
@@ -849,7 +748,7 @@ class TpuHashAggregateExec(TpuExec):
                 # no shrink: results stay mask-scattered (caps here are
                 # already small post-exchange; skipping saves a sync)
                 from spark_rapids_tpu import retry as R
-                out, _cnt, _ovf = R.with_retry(
+                out, _cnt = R.with_retry(
                     lambda: self._aggregate_batch(whole),
                     self.conf, self.metrics)
                 if not grouped and self.mode in ("final", "complete") \
@@ -879,32 +778,19 @@ class TpuHashAggregateExec(TpuExec):
         pending = []
         prefetched = True
 
-        def run_piece(piece):
-            out, cnt, ovf = self._aggregate_batch(piece)
-            return piece, out, cnt, ovf
-
         for b in thunk():
             # OOM protocol on the per-batch update program: spill+retry
             # first, then split the input in half by rows — partial
             # outputs from the halves merge downstream exactly like two
             # ordinary input batches, so results stay bit-identical
-            for piece, out, cnt, ovf in R.with_split_retry(
-                    b, run_piece, self.conf, self.metrics,
+            for out, cnt in R.with_split_retry(
+                    b, self._aggregate_batch, self.conf, self.metrics,
                     translate_real=not self._donate_input):
                 # async host copy starts NOW: by drain time the scalar
                 # is already local, so the drain costs pipeline-
                 # completion, not + a flat ~0.2s roundtrip per fetch
-                prefetched = _prefetch_host(
-                    [cnt] + ([ovf] if ovf is not None else [])) \
-                    and prefetched
-                # kernel path: RETAIN the input (spillable) until the
-                # drain resolves its overflow flag — an overflowed
-                # table means the output is missing groups and the
-                # batch re-runs on the oracle (docs/kernels.md)
-                h_in = (self.register_spillable(store, piece)
-                        if ovf is not None else None)
-                pending.append((self.register_spillable(store, out),
-                                cnt, ovf, h_in))
+                prefetched = _prefetch_host([cnt]) and prefetched
+                pending.append((self.register_spillable(store, out), cnt))
         if not pending:
             return
         # This read is where the whole async upstream pipeline (upload
@@ -920,53 +806,15 @@ class TpuHashAggregateExec(TpuExec):
         with self.metrics.timed_wall("pipelineDrainTime"), \
                 TR.device_sync("aggCounts", self.metrics):
             if prefetched:
-                counts = [int(np.asarray(c)) for _h, c, _o, _i in pending]
-                overflows = [o is not None and bool(np.asarray(o))
-                             for _h, _c, o, _i in pending]
+                counts = [int(np.asarray(c)) for _h, c in pending]
             else:
                 counts = np.asarray(
-                    _stack_counts([c for _h, c, _o, _i in pending]))
-                # one stacked fetch for ALL overflow flags too — each
-                # separate D2H read is a sync, exactly like the counts
-                # above
-                ovf_list = [o for _h, _c, o, _i in pending
-                            if o is not None]
-                flags = (np.asarray(_stack_counts(ovf_list))
-                         if ovf_list else [])
-                it = iter(flags)
-                overflows = [o is not None and bool(next(it))
-                             for _h, _c, o, _i in pending]
+                    _stack_counts([c for _h, c in pending]))
         shrunk = []
-        from spark_rapids_tpu import kernels as KR
-        for (h, _c, _o, h_in), cnt, ovf in zip(pending, counts,
-                                               overflows):
-            if ovf:
-                # hash-table overflow: more distinct groups than the
-                # kernel's table holds. Re-run the RETAINED input on
-                # the oracle composition — bit-identity is preserved
-                # because the kernel output is simply discarded. The
-                # re-run keeps the full split-retry protocol (and
-                # force_oracle never donates: the input is
-                # store-retained)
-                KR.count_fallback(self.metrics, "groupbyHash")
-                h.close()
-                whole = h_in.get()
-                h_in.close()
-                for b2, cnt2, _ovf2 in R.with_split_retry(
-                        whole,
-                        lambda piece: self._aggregate_batch(
-                            piece, force_oracle=True),
-                        self.conf, self.metrics):
-                    with TR.device_sync("aggCounts", self.metrics):
-                        b2._num_rows = int(np.asarray(cnt2))
-                    b2 = slice_compacted_to_bucket(b2)
-                    shrunk.append(self.register_spillable(store, b2))
-                continue
+        for (h, _c), cnt in zip(pending, counts):
             b = h.get()
             b._num_rows = int(cnt)
             h.close()
-            if h_in is not None:
-                h_in.close()
             b = slice_compacted_to_bucket(b)
             shrunk.append(self.register_spillable(store, b))
         total = sum(h.rows for h in shrunk)
@@ -974,7 +822,7 @@ class TpuHashAggregateExec(TpuExec):
             whole = concat_device([h.get() for h in shrunk])
             for h in shrunk:
                 h.close()
-            out, _cnt, _ovf = R.with_retry(
+            out, _cnt = R.with_retry(
                 lambda: self._aggregate_batch(whole,
                                               mode="merge_partial"),
                 self.conf, self.metrics)

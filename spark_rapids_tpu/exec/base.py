@@ -381,7 +381,7 @@ class TpuRowToColumnarExec(TpuExec):
             # (the 1-deep prefetch bounds this at 2 per stream), freed
             # as soon as _finish returns
             return whole.num_rows, prepare_upload(
-                whole, cap, conf=self.conf, metrics=metrics), whole
+                whole, cap, metrics=metrics), whole
 
     def _finish(self, prepared, sem, metrics,
                 device=None) -> List[DeviceBatch]:

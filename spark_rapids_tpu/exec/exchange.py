@@ -45,51 +45,18 @@ _RANGE_RANK_CACHE = JitCache("rangeRank")
 
 
 def hash_partition_ids(exprs: List[E.Expression], batch: DeviceBatch,
-                       num_partitions: int, conf=None,
-                       metrics=None) -> jax.Array:
-    """pmod(murmur3(keys, 42), n) per row — Spark HashPartitioning.
-    With the murmur3 kernel enabled (and every key type hashable by
-    it), the cached program folds the columns through the fused Pallas
-    kernel instead of the stock-XLA chain — bit-identical, same
-    placement (docs/kernels.md). Kernel failures fall back to the
-    oracle composition per structure (``kernelFallbacks.murmur3``)."""
-    struct = tuple(X.expr_key(e) for e in exprs)
-    from spark_rapids_tpu import kernels as KR
-    use_k = (KR.kernel_enabled(conf, "murmur3")
-             and not KR.is_poisoned("murmur3", struct))
-    if use_k:
-        from spark_rapids_tpu.kernels.murmur3 import hash_kernel_eligible
-        use_k = hash_kernel_eligible([e.data_type for e in exprs])
+                       num_partitions: int) -> jax.Array:
+    """pmod(murmur3(keys, 42), n) per row — Spark HashPartitioning."""
+    key = (tuple(X.expr_key(e) for e in exprs), num_partitions)
+    fn = _PID_CACHE.get(key)
+    if fn is None:
+        from spark_rapids_tpu.ops import hashing
 
-    def _get(kernel_on: bool):
-        key = (struct, num_partitions, kernel_on)
-        fn = _PID_CACHE.get(key)
-        if fn is None:
-            from spark_rapids_tpu.ops import hashing
-
-            def _fn(cols, active, lit_vals):
-                return hashing.traced_partition_ids(
-                    exprs, cols, active, lit_vals, num_partitions,
-                    use_kernel=kernel_on)
-            fn = _PID_CACHE.put(key, named_jit(
-                "srt_exchange_pid_kernel" if kernel_on
-                else "srt_exchange_pid", _fn))
-        return fn
-
-    lits = X.literal_values(exprs)
-    if use_k:
-        try:
-            KR.check_injected_failure("murmur3")
-            KR.count_dispatch(metrics, "murmur3")
-            from spark_rapids_tpu import trace as TR
-            with KR.dispatch_span("murmur3", chip=TR.chip_of(batch)):
-                return _get(True)(batch.columns, batch.active, lits)
-        except Exception as e:
-            if not KR.is_oracle_fallback_error(e):
-                raise
-            KR.poison("murmur3", struct, e)
-            KR.count_fallback(metrics, "murmur3")
-    return _get(False)(batch.columns, batch.active, lits)
+        def _fn(cols, active, lit_vals):
+            return hashing.traced_partition_ids(
+                exprs, cols, active, lit_vals, num_partitions)
+        fn = _PID_CACHE.put(key, named_jit("srt_exchange_pid", _fn))
+    return fn(batch.columns, batch.active, X.literal_values(exprs))
 
 
 def _round_robin_body(active: jax.Array, start: jax.Array,
@@ -551,9 +518,7 @@ class TpuShuffleExchangeExec(TpuExec):
                     # pid+sort-split program (pure over b — idempotent)
                     parts = R.with_retry(
                         lambda: split_by_pid(
-                            b, hash_partition_ids(bound, b, n,
-                                                  self.conf,
-                                                  self.metrics), n),
+                            b, hash_partition_ids(bound, b, n), n),
                         self.conf, self.metrics)
                 # register IMMEDIATELY (store is thread-safe) so the
                 # spill budget applies during the drain, not after

@@ -147,8 +147,7 @@ class TpuShuffledHashJoinExec(TpuExec):
         def attempt():
             out = device_join(lwhole, rwhole, lk, rk, self.join_type,
                               out_schema, null_safe=self.null_safe,
-                              fk_hint=fk_hint, conf=self.conf,
-                              metrics=self.metrics)
+                              fk_hint=fk_hint, metrics=self.metrics)
             if self.condition is not None:
                 cond = E.bind_references(self.condition,
                                          self._pair_attrs())
@@ -553,8 +552,7 @@ class TpuShuffledHashJoinExec(TpuExec):
             with self.metrics.timed(M.PARTITION_TIME):
                 parts = R.with_retry(
                     lambda b=b: split_by_pid(
-                        b, hash_partition_ids(bound_keys, b, modulus,
-                                              self.conf, self.metrics),
+                        b, hash_partition_ids(bound_keys, b, modulus),
                         modulus),
                     self.conf, self.metrics)
             h.close()
@@ -728,7 +726,6 @@ class TpuShuffledHashJoinExec(TpuExec):
                 lambda: device_join(lwhole, rwhole, lk, rk, chunk_type,
                                     out_schema, collect_matched_r=True,
                                     null_safe=self.null_safe,
-                                    conf=self.conf,
                                     metrics=self.metrics),
                 self.conf, self.metrics)
         self._book_output(out)

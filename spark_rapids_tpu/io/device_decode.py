@@ -68,20 +68,6 @@ PGE_DL_STR = 5   # DELTA_LENGTH byte array (concatenated bytes)
 _SENTINEL = 1 << 62
 
 
-def dev_entry_stages(ndl: int, n_dicts: int, has_slen: bool,
-                     has_delta: bool, has_bss: bool) -> int:
-    """Logical decode-stage count one device-decoded column runs
-    through on the stock XLA chain: the base page-select/value read,
-    plus definition-level validity expansion, dictionary gather, the
-    string offsets-from-lengths segmented cumsum and its char gather,
-    DELTA reconstruction and the BSS reinterleave when the plan uses
-    them. The fused Pallas kernel (kernels/decode_fused.py) replaces
-    ALL of them with one program — the ``deviceDecodePrograms`` metric
-    bills this count on the chain and 1 on the fused path."""
-    return (1 + (1 if ndl else 0) + (1 if n_dicts else 0)
-            + (2 if has_slen else 0) + (1 if has_delta else 0)
-            + (1 if has_bss else 0))
-
 _HOST_CODECS = {"UNCOMPRESSED": None, "SNAPPY": "snappy", "ZSTD": "zstd",
                 "GZIP": "gzip", "BROTLI": "brotli"}
 

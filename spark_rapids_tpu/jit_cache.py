@@ -86,19 +86,7 @@ def program_in(value) -> Optional[str]:
 
 
 _CACHES: Dict[str, "JitCache"] = {}
-# non-JitCache stat sources (the kernel autotuner's warm-table) that
-# want the same surfacing: providers must return a JitCache-shaped
-# dict (size/capacity/hits/misses/evictions/contention at minimum —
-# the Prometheus renderer reads those keys unconditionally)
-_EXTRA_STATS: Dict[str, Callable[[], Dict[str, int]]] = {}
 _REG_LOCK = threading.Lock()
-
-
-def register_stats_provider(name: str,
-                            fn: Callable[[], Dict[str, int]]) -> None:
-    """Expose an auxiliary stats source under ``cache_stats()[name]``."""
-    with _REG_LOCK:
-        _EXTRA_STATS[name] = fn
 
 
 class JitCache:
@@ -261,14 +249,7 @@ def cache_stats() -> Dict[str, Dict[str, int]]:
     """Snapshot of every registered compile cache (bench detail JSON)."""
     with _REG_LOCK:
         caches = list(_CACHES.values())
-        extras = list(_EXTRA_STATS.items())
-    out = {c.name: c.stats() for c in caches}
-    for name, fn in extras:
-        try:
-            out[name] = fn()
-        except Exception:
-            pass  # a broken provider must not take stats down
-    return out
+    return {c.name: c.stats() for c in caches}
 
 
 def mirror_to_metrics(cache: JitCache, metrics, was_miss: bool) -> None:

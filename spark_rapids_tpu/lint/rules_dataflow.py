@@ -15,7 +15,7 @@ HBM storage for the outputs, so a later read sees freed/aliased memory
 donating dispatch" invariant).
 
 ``hidden-sync`` — inside the hot-path scopes (``exec/``, ``ops/``,
-``kernels/``, ``parallel/``, ``columnar/``), a device->host forcing
+``parallel/``, ``columnar/``), a device->host forcing
 operation (``np.asarray``/``np.array``, ``float``/``int``/``bool``,
 ``.item()``, ``jax.device_get``, ``.block_until_ready()``) applied to
 a value that reaches from a device-producing call stalls the async

@@ -405,9 +405,9 @@ def check_history_fields(pctx):
 
 def _action_catalog(fctx: A.FileCtx):
     """Parse ``ACTION_CATALOG`` from the tuning module's AST: the set
-    of action names, and the knob strings each declares (the ``knob``
-    value plus every ``knobs`` list member). Returns (names, knobs,
-    lineno) or None when the module has no parseable catalog."""
+    of action names, and the ``knob`` string each declares. Returns
+    (names, knobs, lineno) or None when the module has no parseable
+    catalog."""
     for stmt in fctx.tree.body:
         if isinstance(stmt, ast.AnnAssign):
             targets = [stmt.target]
@@ -429,15 +429,10 @@ def _action_catalog(fctx: A.FileCtx):
             if not isinstance(v, ast.Dict):
                 continue
             for fk, fv in zip(v.keys, v.values):
-                if not (isinstance(fk, ast.Constant)
-                        and fk.value in ("knob", "knobs")):
-                    continue
-                elts = fv.elts if isinstance(fv, (ast.List,
-                                                  ast.Tuple)) else [fv]
-                for e in elts:
-                    if isinstance(e, ast.Constant) and isinstance(
-                            e.value, str):
-                        knobs.append((e.value, e.lineno))
+                if isinstance(fk, ast.Constant) and fk.value == "knob" \
+                        and isinstance(fv, ast.Constant) \
+                        and isinstance(fv.value, str):
+                    knobs.append((fv.value, fv.lineno))
         return names, knobs, stmt.lineno
     return None
 

@@ -195,10 +195,6 @@ METRIC_DESCRIPTIONS: Dict[str, str] = {
     "deviceDecodeTime": "host-side half of the device decode path "
                         "(IO, page headers, decode plans)",
     "deviceDecodedBatches": "scan batches decoded on device",
-    "deviceDecodePrograms": "logical decode-stage programs billed per "
-                            "device-decoded batch (1 when the fused "
-                            "kernel ran; the XLA chain's stage count "
-                            "otherwise — docs/kernels.md)",
     "deviceFallbackUnits": "scan units that fell back to host decode",
     "deviceFallbackColumns": "columns that fell back to host decode",
     # scan pipeline (docs/scan.md): producer-thread prefetch + bounded
@@ -217,11 +213,6 @@ METRIC_PREFIX_DESCRIPTIONS: Dict[str, str] = {
     "dispatchCount.chip": "device programs dispatched on chip <N>",
     "meshScanUnits.chip": "scan units assigned to chip <N>'s stream",
     "deviceDecodedValues.": "values decoded on device per encoding",
-    "kernelDispatchCount.": "device programs dispatched through the "
-                            "named Pallas kernel (docs/kernels.md)",
-    "kernelFallbacks.": "kernel-path calls that fell back to the "
-                        "XLA-op oracle composition (lowering/compile "
-                        "failure or hash-table overflow)",
     "hostDecodedValues.": "values host-decoded (fallback columns) per "
                           "encoding",
 }

@@ -4,6 +4,5 @@ Everything in this package operates on JAX arrays with static shapes
 (capacity-bucketed batches, validity/active masks) so XLA compiles each
 kernel once per bucket. The reference reaches cuDF through JNI for these
 ops (SURVEY.md section 2.4 'implication for the TPU build'); here they are
-jit-compiled XLA programs, with Pallas reserved for the few ops XLA cannot
-fuse well.
+jit-compiled XLA programs; no hand-written kernel exists.
 """

@@ -300,24 +300,15 @@ def xxhash64_columns(cols, capacity: int, seed: int = 42) -> jax.Array:
 
 
 def traced_partition_ids(exprs, cols, active, lit_vals,
-                         n_parts: int,
-                         use_kernel: bool = False) -> jax.Array:
+                         n_parts: int) -> jax.Array:
     """Inside a traced program: pmod(murmur3(keys, 42), n) per row — the
     single definition of Spark HashPartitioning placement, shared by the
     in-process exchange and the ICI shard_map exchange so the two paths
     can never diverge. ``lit_vals`` must be passed as traced inputs (the
-    compile caches key on expression *structure*, not literal values).
-    ``use_kernel`` swaps the stock-XLA murmur3 chain for the fused
-    Pallas kernel (bit-identical — the kernel body runs this module's
-    own hash functions; docs/kernels.md). Callers must fold the flag
-    into their compile-cache keys."""
+    compile caches key on expression *structure*, not literal values)."""
     from spark_rapids_tpu.ops import exprs as X
     cap = active.shape[0]
     ctx = X.Ctx(cols, cap, tuple(exprs), lit_vals)
     key_cols = [X.dev_eval(e, ctx) for e in exprs]
-    if use_kernel:
-        from spark_rapids_tpu.kernels import murmur3 as KM
-        hv = KM.murmur3_columns_kernel(key_cols, cap, 42)
-    else:
-        hv = murmur3_columns(key_cols, cap, 42)
+    hv = murmur3_columns(key_cols, cap, 42)
     return jnp.mod(hv.astype(jnp.int64), n_parts).astype(jnp.int32)

@@ -351,96 +351,6 @@ def _align_string_caps(kl: Sequence[AnyDeviceColumn],
     return out_l, out_r
 
 
-def _probe_kernel_eligible(conf, lkeys, rkeys, cap_r: int,
-                           struct) -> bool:
-    """Static gate for the Pallas build/probe kernel (docs/kernels.md):
-    conf + backend on, structure not poisoned by an earlier failure,
-    build side within the table bound, every key a fixed-width-word
-    type (floats keep the oracle — their NaN word encodings are
-    float-typed)."""
-    from spark_rapids_tpu import kernels as KR
-    if not lkeys or len(lkeys) != len(rkeys):
-        return False  # keyless (cross) shapes have no words to probe
-    if not KR.kernel_enabled(conf, "joinProbe"):
-        return False
-    if KR.is_poisoned("joinProbe", struct):
-        return False
-    from spark_rapids_tpu.conf import KERNEL_JOIN_MAX_BUILD_ROWS
-    if cap_r > int(conf.get(KERNEL_JOIN_MAX_BUILD_ROWS)):
-        return False
-    from spark_rapids_tpu.kernels.groupby_hash import _key_type_ok
-    return all(_key_type_ok(e.data_type)
-               for e in list(lkeys) + list(rkeys))
-
-
-def _kernel_probe(lkeys, rkeys, null_safe, ctx_l, ctx_r, active_l,
-                  active_r):
-    """Shared kernel front half: evaluate keys, derive the oracle's
-    exact valid sets, and run the build/probe kernel. Returns
-    ``(matched, first_row)`` per left row."""
-    from spark_rapids_tpu.kernels.groupby_hash import pack_words_i64
-    from spark_rapids_tpu.kernels.join_probe import build_probe
-    ns = list(null_safe) or [False] * len(lkeys)
-    kl = [X.dev_eval(e, ctx_l) for e in lkeys]
-    kr = [X.dev_eval(e, ctx_r) for e in rkeys]
-    valid_l = active_l
-    for c, nsf in zip(kl, ns):
-        if not nsf:
-            valid_l = valid_l & c.validity
-    valid_r = active_r
-    for c, nsf in zip(kr, ns):
-        if not nsf:
-            valid_r = valid_r & c.validity
-    kl, kr = _align_string_caps(kl, kr)
-    wl = _key_words(kl, ns)
-    wr = _key_words(kr, ns)
-    hl = G.hash_subkey_words(wl).view(jnp.int64)
-    hr = G.hash_subkey_words(wr).view(jnp.int64)
-    matched, ri = build_probe(pack_words_i64(wr), hr, valid_r,
-                              pack_words_i64(wl), hl, valid_l)
-    return matched, ri
-
-
-def _build_mask_kernel_fn(lkeys: Tuple[E.Expression, ...],
-                          rkeys: Tuple[E.Expression, ...],
-                          join_type: str,
-                          null_safe: Tuple[bool, ...] = ()) -> Callable:
-    """Kernel twin of _build_mask_fn: semi/anti need only per-left-row
-    existence of a matching valid build row — the probe's answer."""
-    is_semi = join_type == "leftsemi"
-
-    def fn(cols_l, active_l, lits_l, cols_r, active_r, lits_r):
-        ctx_l = X.Ctx(cols_l, active_l.shape[0], lkeys, lits_l)
-        ctx_r = X.Ctx(cols_r, active_r.shape[0], rkeys, lits_r)
-        matched, _ri = _kernel_probe(lkeys, rkeys, null_safe, ctx_l,
-                                     ctx_r, active_l, active_r)
-        if is_semi:
-            return active_l & matched
-        return active_l & ~matched
-    return named_jit("srt_join_mask_kernel", fn)
-
-
-def _build_fast_probe_fn(lkeys: Tuple[E.Expression, ...],
-                         rkeys: Tuple[E.Expression, ...],
-                         join_type: str,
-                         null_safe: Tuple[bool, ...] = ()) -> Callable:
-    """Kernel twin of the FK fast path (_build_fast_gather_fn): build
-    keys are certified UNIQUE, so the probe's first-occurrence row IS
-    the single match — no count program, no sizing sync."""
-    inner = join_type in ("inner", "cross")
-
-    def fn(cols_l, active_l, lits_l, cols_r, active_r, lits_r):
-        ctx_l = X.Ctx(cols_l, active_l.shape[0], lkeys, lits_l)
-        ctx_r = X.Ctx(cols_r, active_r.shape[0], rkeys, lits_r)
-        matched, ri = _kernel_probe(lkeys, rkeys, null_safe, ctx_l,
-                                    ctx_r, active_l, active_r)
-        out_r = take_columns(cols_r, jnp.where(matched, ri, 0),
-                             valid_at=matched)
-        active = (active_l & matched) if inner else active_l
-        return out_r, active, jnp.sum(active.astype(jnp.int64))
-    return named_jit("srt_join_probe_fast", fn)
-
-
 _MULT_CACHE = JitCache("joinMult")
 
 
@@ -563,7 +473,7 @@ def device_join(left: DeviceBatch, right: DeviceBatch,
                 collect_matched_r: bool = False,
                 null_safe: Sequence[bool] = (),
                 fk_hint: bool = False,
-                conf=None, metrics=None):
+                metrics=None):
     """Run the equi-join of two device batches; keys are pre-bound device
     expressions. Returns the joined batch (pair layout: left columns then
     right columns) or, for semi/anti, the masked left batch. With
@@ -580,33 +490,7 @@ def device_join(left: DeviceBatch, right: DeviceBatch,
     lits_l = X.literal_values(list(lk))
     lits_r = X.literal_values(list(rk))
 
-    from spark_rapids_tpu import kernels as KR
-    kern_ok = _probe_kernel_eligible(conf, lk, rk, right.capacity,
-                                     struct)
-
     if join_type in MASK_JOINS:
-        if kern_ok:
-            kfn, _ = _MASK_CACHE.get_or_build(
-                (struct, join_type, "kernel"),
-                lambda: _build_mask_kernel_fn(lk, rk, join_type, nst))
-            try:
-                KR.check_injected_failure("joinProbe")
-                KR.count_dispatch(metrics, "joinProbe")
-                from spark_rapids_tpu import trace as TR
-                with KR.dispatch_span("joinProbe",
-                                      chip=TR.chip_of(left)):
-                    with G.nan_scope(salt[0]):
-                        new_active = kfn(left.columns, left.active,
-                                         lits_l, right.columns,
-                                         right.active, lits_r)
-                out = DeviceBatch(left.schema, left.columns,
-                                  new_active, None)
-                return (out, None) if collect_matched_r else out
-            except Exception as e:
-                if not KR.is_oracle_fallback_error(e):
-                    raise
-                KR.poison("joinProbe", struct, e)
-                KR.count_fallback(metrics, "joinProbe")
         key = (struct, join_type)
         fn, _ = _MASK_CACHE.get_or_build(
             key, lambda: _build_mask_fn(lk, rk, join_type, nst))
@@ -618,33 +502,6 @@ def device_join(left: DeviceBatch, right: DeviceBatch,
 
     if join_type not in PAIR_JOINS:
         raise X.DeviceUnsupported(f"join type {join_type}")
-
-    if fk_hint and kern_ok and not collect_matched_r \
-            and join_type in ("inner", "left", "leftouter"):
-        # certified-unique build keys + kernel: the probe IS the
-        # gather map — no count program, no sizing sync at all
-        pfn, _ = _GATHER_CACHE.get_or_build(
-            (struct, join_type, "kernelFast"),
-            lambda: _build_fast_probe_fn(lk, rk, join_type, nst))
-        try:
-            KR.check_injected_failure("joinProbe")
-            KR.count_dispatch(metrics, "joinProbe")
-            from spark_rapids_tpu import trace as TR
-            with KR.dispatch_span("joinProbe", chip=TR.chip_of(left)):
-                with G.nan_scope(salt[0]):
-                    out_r, active, cnt = pfn(
-                        left.columns, left.active, lits_l,
-                        right.columns, right.active, lits_r)
-            from spark_rapids_tpu.columnar.device import _prefetch_host
-            _prefetch_host([cnt])
-            return DeviceBatch(out_schema,
-                               list(left.columns) + list(out_r),
-                               active, None, cnt)
-        except Exception as e:
-            if not KR.is_oracle_fallback_error(e):
-                raise
-            KR.poison("joinProbe", struct, e)
-            KR.count_fallback(metrics, "joinProbe")
 
     ckey = (struct, join_type)
     count_fn, _ = _COUNT_CACHE.get_or_build(

@@ -230,27 +230,6 @@ def gather_chars(bytes_all: jax.Array, starts: jax.Array,
     return jnp.where(mask, g, 0).astype(jnp.uint8)
 
 
-def gather_chars_chunked(bytes_all: jax.Array, starts: jax.Array,
-                         lengths: jax.Array, char_cap: int,
-                         row_chunk: int = 0) -> jax.Array:
-    """``gather_chars`` evaluated over row chunks of ``row_chunk``
-    rows (autotunable: bounds the (rows, char_cap) gather index
-    matrix's live size). Each row's gather is independent of every
-    other row's, so chunking cannot change a byte — the concatenated
-    chunks ARE the unchunked result. ``row_chunk <= 0`` or a chunk
-    that does not divide the row count runs the plain gather."""
-    n = starts.shape[0]
-    if row_chunk <= 0 or row_chunk >= n or n % row_chunk:
-        return gather_chars(bytes_all, starts, lengths, char_cap)
-    parts = [gather_chars(bytes_all,
-                          jax.lax.slice(starts, (lo,), (lo + row_chunk,)),
-                          jax.lax.slice(lengths, (lo,),
-                                        (lo + row_chunk,)),
-                          char_cap)
-             for lo in range(0, n, row_chunk)]
-    return jnp.concatenate(parts, axis=0)
-
-
 def seg_excl_cumsum(contrib: jax.Array, seg_first_lane: jax.Array
                     ) -> jax.Array:
     """Exclusive prefix sum of ``contrib`` restarting at each segment:
@@ -286,8 +265,7 @@ def read_be_signed(bytes_all: jax.Array, byte_off: jax.Array,
     """FIXED_LEN_BYTE_ARRAY decimal: big-endian two's-complement of
     nbytes (<= 8) -> signed int64 (the engine's DECIMAL64 storage)."""
     win = _gather_window(bytes_all, byte_off, nbytes)
-    # iota-based descending shifts: a negative-step arange materializes
-    # a concrete constant, which the fused Pallas kernel cannot capture
+    # descending shifts, byte 0 the most significant
     k = (nbytes - 1 - jnp.arange(nbytes, dtype=jnp.int64)) * 8
     return _sign_extend(jnp.sum(win << k, axis=1), nbytes)
 

@@ -151,12 +151,6 @@ def plan_signature(plan, conf) -> str:
     # whether a byte-identical result is served from memory: excluding
     # them keeps cache-on and cache-off runs of one shape on one
     # signature, so they share doctor baselines and quarantine streaks.
-    # kernel.autotune.* and the per-kernel tuning-parameter confs
-    # (tableSlots, maxBuildRows) steer HOW a kernel runs — block
-    # shapes, table capacity, sweep policy — never WHAT the plan
-    # computes (bit-identity is the kernel tier's contract): excluding
-    # them keeps tuned and untuned runs of one shape on one signature,
-    # same rationale as test.inject* above.
     parts.append(";".join(
         f"{k}={v}" for k, v in sorted(
             (str(k), str(v)) for k, v in conf.settings.items())
@@ -166,11 +160,7 @@ def plan_signature(plan, conf) -> str:
             "spark.rapids.sql.resultCache.",
             "spark.rapids.sql.subplanCache.",
             # tpu-lint: disable=conf-key(prefix over the test.inject* key family, not a key literal)
-            "spark.rapids.sql.test.inject",
-            # tpu-lint: disable=conf-key(prefix over the kernel.autotune.* key family, not a key literal)
-            "spark.rapids.sql.kernel.autotune.",
-            "spark.rapids.sql.kernel.groupbyHash.tableSlots",
-            "spark.rapids.sql.kernel.joinProbe.maxBuildRows"))))
+            "spark.rapids.sql.test.inject"))))
     return "".join(parts)
 
 

@@ -89,40 +89,6 @@ def _node_entry(p) -> Dict[str, Any]:
     return entry
 
 
-def _kernel_summary(physical) -> Dict[str, Dict[str, int]]:
-    """Top-level kernel-tier attribution (docs/kernels.md): per-kernel
-    dispatch and fallback counts summed across the executed plan, so a
-    query that silently rode the XLA-op oracle path (fallbacks > 0, or
-    zero dispatches with the tier enabled) is visible in the artifact
-    header without grepping per-node metrics."""
-    out: Dict[str, Dict[str, int]] = {"dispatches": {}, "fallbacks": {}}
-
-    def add(p) -> None:
-        m = getattr(p, "metrics", None)
-        if m is None:
-            return
-        for k, metric in m.metrics.items():
-            if not metric.value:
-                continue
-            for prefix, bucket in (("kernelDispatchCount.",
-                                    "dispatches"),
-                                   ("kernelFallbacks.", "fallbacks")):
-                if k.startswith(prefix):
-                    name = k[len(prefix):]
-                    out[bucket][name] = \
-                        out[bucket].get(name, 0) + metric.value
-
-    def walk(p) -> None:
-        add(p)
-        for op in getattr(p, "fused_ops", []):
-            add(op)
-        for c in getattr(p, "children", []):
-            walk(c)
-
-    walk(physical)
-    return out
-
-
 def build_profile(physical, report, conf_obj, wall_s: float, rows: int,
                   query_id: int) -> Dict[str, Any]:
     """Assemble the artifact dict from an EXECUTED plan (its registries
@@ -145,7 +111,6 @@ def build_profile(physical, report, conf_obj, wall_s: float, rows: int,
             "tenants": (store.tenant_stats()
                         if store is not None else {}),
         },
-        "kernels": _kernel_summary(physical),
         "jitCaches": cache_stats(),
     }
     if conf_obj is not None:
@@ -245,16 +210,8 @@ def _render_node(entry: Dict[str, Any], lines: List[str],
     ms = entry.get("metrics") or {}
     shown = [_fmt_metric(k, ms[k]) for k in _TREE_METRICS
              if ms.get(k)]
-    # kernel-tier attribution rides in the headline list: a node whose
-    # work went through (or fell back from) a Pallas kernel says so at
-    # a glance (docs/kernels.md)
-    shown += [_fmt_metric(k, v) for k, v in sorted(ms.items())
-              if v and k.startswith(("kernelDispatchCount.",
-                                     "kernelFallbacks."))]
     extra = [_fmt_metric(k, v) for k, v in sorted(ms.items())
-             if v and k not in _TREE_METRICS
-             and not k.startswith(("kernelDispatchCount.",
-                                   "kernelFallbacks."))]
+             if v and k not in _TREE_METRICS]
     for chunk in (shown, extra):
         if chunk:
             lines.append(pad + "    [" + ", ".join(chunk) + "]")
@@ -263,9 +220,6 @@ def _render_node(entry: Dict[str, Any], lines: List[str],
         fms = fe.get("metrics") or {}
         fshown = [_fmt_metric(k, fms[k]) for k in _TREE_METRICS
                   if fms.get(k)]
-        fshown += [_fmt_metric(k, v) for k, v in sorted(fms.items())
-                   if v and k.startswith(("kernelDispatchCount.",
-                                          "kernelFallbacks."))]
         if fshown:
             lines.append(pad + "        [" + ", ".join(fshown) + "]")
     for c in entry.get("children", []):
@@ -301,24 +255,6 @@ def format_profile(prof: Dict[str, Any], top: int = 10) -> str:
                          f"{_fmt_bytes(st.get('liveBytes', 0)):>10s}")
     else:
         lines.append("  (no operator registered spillable batches)")
-
-    kern = prof.get("kernels") or {}
-    disp = kern.get("dispatches") or {}
-    fb = kern.get("fallbacks") or {}
-    if disp or fb:
-        parts = []
-        if disp:
-            parts.append("dispatches " + ", ".join(
-                f"{k}={v}" for k, v in sorted(disp.items())))
-        if fb:
-            parts.append("FALLBACKS " + ", ".join(
-                f"{k}={v}" for k, v in sorted(fb.items())))
-        lines += ["", "kernel tier (docs/kernels.md): "
-                  + "; ".join(parts)]
-        if fb:
-            lines.append("  (fallback calls rode the XLA-op oracle "
-                         "composition — check kernel confs / "
-                         "tableSlots)")
 
     ex = prof.get("explain")
     if ex:

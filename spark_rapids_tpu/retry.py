@@ -74,11 +74,10 @@ _OOM_MARKERS = ("RESOURCE_EXHAUSTED", "Resource exhausted",
 
 def is_vmem_refusal(e: BaseException) -> bool:
     """A compile-time ``RESOURCE_EXHAUSTED`` that names VMEM: the
-    program (a Pallas kernel, in practice) does not fit the chip's
-    on-core vector memory. Spilling HBM or splitting the batch cannot
-    change a static kernel footprint, so this is a kernel refusal for
-    the oracle fallback (kernels.is_oracle_fallback_error), never
-    traffic for the retry ladder."""
+    program does not fit the chip's on-core vector memory. Spilling
+    HBM or splitting the batch cannot change a static program
+    footprint, so this is a compiler refusal, never traffic for the
+    retry ladder."""
     s = str(e)
     return "vmem" in s.lower() and any(m in s for m in _OOM_MARKERS)
 

@@ -369,9 +369,7 @@ class QueryServer:
     def _set_conf_key(self, key: str, value) -> None:
         """Server-wide conf write for TuningController actions: the
         base conf covers future sessions, live sessions update in
-        place (execution-time reads follow immediately; a changed
-        signature-relevant key — kernel.*.enabled — starts a NEW
-        signature history, the kernelFallback action's re-baseline)."""
+        place (execution-time reads follow immediately)."""
         with self._sessions_lock:
             if value is None:
                 self._base_conf.pop(key, None)

@@ -2541,7 +2541,7 @@ class GetTimestamp(BinaryExpression):
 
 class Murmur3Hash(Expression):
     """Spark Murmur3Hash(seed=42) over columns left-to-right; the rewrite
-    maps this to the device twin in kernels/hashing.py
+    maps this to the device twin in ops/hashing.py
     (reference: GpuMurmur3Hash, HashFunctions.scala)."""
 
     def __init__(self, children: List[Expression], seed: int = 42):

@@ -47,18 +47,6 @@ CHECKS: List[Tuple[str, str, bool, str]] = [
      "file-tracing overhead"),
     ("detail.profile.profilingOverhead", "lower", True,
      "profiling overhead"),
-    ("detail.kernels.wallSpeedup", "higher", True,
-     "kernel-tier wall speedup"),
-    ("detail.kernels.aggDrainSpeedup", "higher", True,
-     "q1 agg-drain speedup"),
-    ("detail.kernels.decodeFused.wallSpeedup", "higher", True,
-     "fused-decode wall speedup (fused vs chain)"),
-    ("detail.kernels.decodeFused.fused.programsPerBatch", "lower", True,
-     "fused-decode programs per batch"),
-    ("detail.kernels.autotune.warmSweeps", "lower", True,
-     "autotune warm-start sweeps (zero when the table holds)"),
-    ("detail.kernels.autotune.coldTotal_s", "lower", False,
-     "autotune cold-sweep leg wall"),
     ("detail.serving.concurrency.c1.qps", "higher", True,
      "serving QPS @ c=1"),
     ("detail.serving.concurrency.c4.qps", "higher", True,
@@ -114,8 +102,6 @@ CHECKS: List[Tuple[str, str, bool, str]] = [
      "tuning pre-warm plan-cache hit on restart"),
     ("detail.tuning.prewarm.restartSpeedup", "higher", False,
      "tuning pre-warm first-request restart speedup"),
-    ("detail.tuning.kernelFallback.flipped", "higher", False,
-     "tuning kernel-fallback conf flip applied"),
     ("detail.tuning.guard.autoReverted", "higher", False,
      "tuning guardrail auto-revert of the injected harmful action"),
 ]

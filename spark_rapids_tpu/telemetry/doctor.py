@@ -45,9 +45,6 @@ VERDICT_CLASSES: Dict[str, str] = {
     "retrySpill": "OOM retry / split-retry / spill activity above "
                   "baseline — the retryBlock recovery wall (spill + "
                   "backoff) stretched the query",
-    "kernelFallback": "Pallas kernel calls fell back to the XLA-op "
-                      "oracle composition above baseline — check "
-                      "kernel confs / tableSlots",
     "scanBound": "scan-side stages (decode, prefetch, upload) diverge "
                  "from baseline — input IO/decode got slower, not the "
                  "compute",
@@ -237,8 +234,6 @@ def _baseline(records: List[Dict[str, Any]],
                    + r.get("splitRetryCount", 0)) for r in base]),
         "spillBytesMean": _mean(
             [float(r.get("spillBytes", 0)) for r in base]),
-        "fallbacksMean": _mean(
-            [float(r.get("kernelFallbacks", 0)) for r in base]),
         "jitMissesMean": _mean(
             [float(r.get("jitMisses", 0)) for r in base]),
         "rowsMean": _mean(
@@ -288,7 +283,6 @@ def diagnose_record(records: List[Dict[str, Any]],
     retries = float(target.get("retryCount", 0)
                     + target.get("splitRetryCount", 0))
     spill = float(target.get("spillBytes", 0))
-    fallbacks = float(target.get("kernelFallbacks", 0))
     jit_misses = float(target.get("jitMisses", 0))
     rows = float(target.get("outputRows", 0))
 
@@ -377,21 +371,6 @@ def diagnose_record(records: List[Dict[str, Any]],
                 "partition up front instead of riding the "
                 "spill-and-retry loop (docs/out_of_core.md)")
         verdict("retrySpill", score, ev)
-
-    # kernel-fallback: the oracle ride, with the culprit kernel(s)
-    # named from the record's per-kernel counters so the operator
-    # checks ONE conf instead of the whole kernel tier
-    if fallbacks > base["fallbacksMean"] + 0.5:
-        ev = [f"kernel fallbacks {fallbacks:.0f} vs baseline mean "
-              f"{base['fallbacksMean']:.1f} — check kernel confs / "
-              f"tableSlots"]
-        by_name = target.get("kernelFallbacksByName") or {}
-        for name, n in sorted(by_name.items(),
-                              key=lambda kv: (-kv[1], kv[0])):
-            ev.append(f"{name}: {n:.0f} fallback(s) — check "
-                      f"spark.rapids.sql.kernel.{name}.enabled "
-                      f"and its tuning confs")
-        verdict("kernelFallback", 0.4, ev)
 
     # scan-bound: scan-side stages own the regression
     scan_share = stage_share(_SCAN_FRAGMENTS)

@@ -107,15 +107,6 @@ HISTORY_FIELD_CATALOG: Dict[str, str] = {
     "splitRetryCount": "split-and-retry events accumulated by the "
                        "query's plan",
     "spillBytes": "device bytes spilled by the query's plan",
-    "kernelDispatches": "Pallas kernel dispatches "
-                        "(sum of kernelDispatchCount.*)",
-    "kernelFallbacks": "Pallas kernel oracle fallbacks "
-                       "(sum of kernelFallbacks.*)",
-    "kernelFallbacksByName": "per-kernel oracle fallback counts "
-                             "(nonzero kernelFallbacks.<name> entries; "
-                             "present only when any fired) — the "
-                             "doctor's kernelFallback verdict names "
-                             "the culprit kernel(s) from these",
     "jitMisses": "compile-cache misses billed to the query's plan "
                  "(compileCacheMisses)",
     "fallbackCoverage": "rewrite device-operator coverage (0..1) from "
@@ -348,17 +339,7 @@ def _plan_counters(physical) -> Dict[str, Any]:
         "splitRetryCount": int(vals.get("splitRetryCount", 0)),
         "spillBytes": int(vals.get("spillBytes", 0)),
         "jitMisses": int(vals.get("compileCacheMisses", 0)),
-        "kernelDispatches": sum(
-            v for k, v in vals.items()
-            if k.startswith("kernelDispatchCount.")),
-        "kernelFallbacks": sum(
-            v for k, v in vals.items()
-            if k.startswith("kernelFallbacks.")),
     }
-    by_name = {k.split(".", 1)[1]: int(v) for k, v in vals.items()
-               if k.startswith("kernelFallbacks.") and v}
-    if by_name:
-        out["kernelFallbacksByName"] = by_name
     poc = {k: int(vals[k]) for k in ("plannedPartitions",
                                      "plannedOutOfCoreEscalations",
                                      "budgetPressurePeak")
@@ -600,8 +581,6 @@ def signature_aggregates(records: List[Dict[str, Any]]
         retries = sum(1 for r in fin
                       if (r.get("retryCount", 0)
                           + r.get("splitRetryCount", 0)) > 0)
-        fallbacks = sum(1 for r in fin
-                        if r.get("kernelFallbacks", 0) > 0)
         out[sig] = {
             "count": len(recs),
             "finished": len(fin),
@@ -609,8 +588,6 @@ def signature_aggregates(records: List[Dict[str, Any]]
             "wallP99": round(_percentile(walls, 0.99), 6),
             "trendSlopePerHour": round(trend_slope(fin), 6),
             "retryRate": round(retries / len(fin), 4) if fin else 0.0,
-            "fallbackRate": round(fallbacks / len(fin), 4) if fin
-            else 0.0,
             "statuses": statuses,
             "tenants": sorted(tenants),
         }
@@ -628,8 +605,8 @@ def format_history(records: List[Dict[str, Any]], top: int = 30) -> str:
     aggs = signature_aggregates(records)
     lines.append(
         f"  {'signature':14s} {'tenants':14s} {'n':>5s} {'ok':>5s} "
-        f"{'p50_s':>8s} {'p99_s':>8s} {'trend/h':>9s} {'retry%':>7s} "
-        f"{'fb%':>5s}  statuses")
+        f"{'p50_s':>8s} {'p99_s':>8s} {'trend/h':>9s} {'retry%':>7s}  "
+        f"statuses")
     ranked = sorted(aggs.items(), key=lambda kv: -kv[1]["count"])
     for sig, a in ranked[:top]:
         sts = ",".join(f"{k}:{v}" for k, v in sorted(a["statuses"].items()))
@@ -638,7 +615,7 @@ def format_history(records: List[Dict[str, Any]], top: int = 30) -> str:
             f"  {sig_digest(sig):14s} {tns:14s} {a['count']:5d} "
             f"{a['finished']:5d} {a['wallP50']:8.3f} "
             f"{a['wallP99']:8.3f} {a['trendSlopePerHour']:+9.4f} "
-            f"{a['retryRate']:7.1%} {a['fallbackRate']:5.0%}  {sts}")
+            f"{a['retryRate']:7.1%}  {sts}")
     # per-tenant rollup over finished records
     by_tenant: Dict[str, List[float]] = {}
     for r in records:

@@ -5,7 +5,7 @@ Two kinds of families:
 
 - **engine metrics** — every metric key the registries carry, exported
   as ``srt_<snake_case>`` (prefix families like
-  ``kernelFallbacks.groupbyHash`` become one family with a ``key``
+  ``deviceDecodedValues.PLAIN`` become one family with a ``key``
   label; ``*Time`` metrics convert ns -> seconds with a
   ``_seconds_total`` suffix). HELP text comes from
   ``metrics.describe_metric`` — a key that does not resolve is NOT

@@ -7,9 +7,10 @@ the places they become true —
 
 - **slowQuery**    query wall over ``telemetry.slowQueryMs``
                    (evaluated at query end, session.execute_plan);
-- **retryCount** / **kernelFallbacks**  per-query metric deltas over
-                   their thresholds (same evaluation point — the
-                   executed plan's registries ARE the delta);
+- **retryCount**   per-query retry deltas over
+                   ``telemetry.retryCountThreshold`` (same evaluation
+                   point — the executed plan's registries ARE the
+                   delta);
 - **retryStorm**   more than ``telemetry.retryStormThreshold`` OOM
                    retries in a 60 s window (evaluated at retry time,
                    retry.py);
@@ -51,7 +52,6 @@ from typing import Any, Callable, Dict, Optional
 
 from spark_rapids_tpu.conf import (TELEMETRY_DIR,
                                    TELEMETRY_HBM_WATERMARK,
-                                   TELEMETRY_KERNEL_FALLBACK_THRESHOLD,
                                    TELEMETRY_MAX_BUNDLE_BYTES,
                                    TELEMETRY_MAX_BUNDLES,
                                    TELEMETRY_MIN_INTERVAL_S,
@@ -329,8 +329,7 @@ class TriggerEngine:
             return
         slow_ms = int(conf_obj.get(TELEMETRY_SLOW_QUERY_MS))
         retry_thr = int(conf_obj.get(TELEMETRY_RETRY_COUNT_THRESHOLD))
-        fb_thr = int(conf_obj.get(TELEMETRY_KERNEL_FALLBACK_THRESHOLD))
-        if slow_ms <= 0 and retry_thr <= 0 and fb_thr <= 0:
+        if slow_ms <= 0 and retry_thr <= 0:
             return
         self._ensure_worker()
         out_dir = str(conf_obj.get(TELEMETRY_DIR))
@@ -342,25 +341,16 @@ class TriggerEngine:
                 "slowQuery", {**base, "slowQueryMs": slow_ms},
                 out_dir=out_dir, min_interval=interval,
                 profile_path=profile_path)
-        if plan is not None and (retry_thr > 0 or fb_thr > 0):
+        if plan is not None and retry_thr > 0:
             from spark_rapids_tpu.metrics import registry_snapshot
             vals = registry_snapshot(plans=[plan])["metrics"]
             retries = vals.get("retryCount", 0) \
                 + vals.get("splitRetryCount", 0)
-            if retry_thr > 0 and retries > retry_thr:
+            if retries > retry_thr:
                 self._maybe_fire(
                     "retryCount",
                     {**base, "retryCount": retries,
                      "threshold": retry_thr},
-                    out_dir=out_dir, min_interval=interval,
-                    profile_path=profile_path)
-            fallbacks = sum(v for k, v in vals.items()
-                            if k.startswith("kernelFallbacks."))
-            if fb_thr > 0 and fallbacks > fb_thr:
-                self._maybe_fire(
-                    "kernelFallbacks",
-                    {**base, "kernelFallbacks": fallbacks,
-                     "threshold": fb_thr},
                     out_dir=out_dir, min_interval=interval,
                     profile_path=profile_path)
 

@@ -21,10 +21,6 @@ declared :data:`ACTION_CATALOG`:
   flight means each gets more HBM headroom) and/or **seedOutOfCore**
   (turn the budget oracle on so joins/aggs partition up front,
   docs/out_of_core.md);
-- ``kernelFallback`` -> **kernelFallback**: flip the culprit kernel
-  conf named by the record's ``kernelFallbacksByName`` and
-  re-baseline (``kernel.*.enabled`` is signature-relevant, so the
-  flip starts a NEW signature history);
 - SLO burn -> **tenantWeight**: shift the burning tenant's admission
   weight up so it gets a larger fair share.
 
@@ -49,9 +45,9 @@ admitted narrowly tomorrow — and ``tools tuning --pin/--revert``
 writes control flags the controller honors at its next tick, so the
 CLI never races the live server's knob writes.
 
-Tuning never changes what a query COMPUTES — only admission shaping,
-cache residency, and kernel-tier routing, all of which are
-bit-identity-preserving by their own contracts.
+Tuning never changes what a query COMPUTES — only admission shaping
+and cache residency, both of which are bit-identity-preserving by
+their own contracts.
 """
 
 from __future__ import annotations
@@ -126,20 +122,6 @@ ACTION_CATALOG: Dict[str, Dict[str, Any]] = {
         "doc": "turn the budget oracle on server-wide so joins/aggs "
                "over-budget partition UP FRONT (docs/out_of_core.md) "
                "instead of discovering the overflow via retry storms",
-    },
-    "kernelFallback": {
-        "verdict": "kernelFallback",
-        "knob": "spark.rapids.sql.kernel.groupbyHash.enabled",
-        "knobs": ["spark.rapids.sql.kernel.groupbyHash.enabled",
-                  "spark.rapids.sql.kernel.joinProbe.enabled",
-                  "spark.rapids.sql.kernel.decodeFused.enabled"],
-        "min": 0, "max": 1,
-        "doc": "flip the culprit kernel conf (named by the record's "
-               "kernelFallbacksByName) to false: a shape whose oracle "
-               "keeps falling back pays the probe cost for nothing. "
-               "kernel.*.enabled is signature-relevant, so the flip "
-               "RE-BASELINES — the new signature accumulates its own "
-               "history (accepted immediately; manual revert only)",
     },
     "tenantWeight": {
         "verdict": "sloBurn",
@@ -247,8 +229,8 @@ class TuningController:
       server pre-warm replay (None disables replay — the protection
       set still installs);
     - ``set_conf(key, value)`` / ``get_conf(key)``: server-wide conf
-      write/read for conf-knob actions (kernel flips, out-of-core
-      seeding); ``value=None`` removes the override.
+      write/read for conf-knob actions (out-of-core seeding);
+      ``value=None`` removes the override.
     """
 
     def __init__(self, conf_obj, admission=None, slo=None,
@@ -399,8 +381,7 @@ class TuningController:
         assigns the epoch, and validates the knob against the catalog
         declaration."""
         cat = ACTION_CATALOG[action]
-        allowed = cat.get("knobs", [cat["knob"]])
-        if knob not in allowed and knob not in INTERNAL_KNOBS:
+        if knob != cat["knob"] and knob not in INTERNAL_KNOBS:
             raise ValueError(f"knob {knob!r} not declared for "
                              f"action {action!r}")
         if isinstance(new_value, (int, float)) \
@@ -581,12 +562,6 @@ class TuningController:
         for a in self._state["actions"]:
             if a.get("state") != "applied" or a.get("pinned"):
                 continue
-            if a.get("action") == "kernelFallback":
-                # the flip re-baselines (new signature): the old
-                # scope's window can never fill — accepted at birth,
-                # manual revert only (documented in the catalog)
-                a["state"] = "accepted"
-                continue
             base = (a.get("evidence") or {}).get("baseline") or {}
             bp50 = float(base.get("p50", 0.0))
             bp99 = float(base.get("p99", 0.0))
@@ -643,15 +618,6 @@ class TuningController:
             return 0
 
     # -- history scoring ----------------------------------------------------
-
-    def _newest_record(self, records: List[Dict[str, Any]],
-                       digest: str) -> Dict[str, Any]:
-        for r in reversed(records):
-            if r.get("signature") == digest and \
-                    r.get("status") == STATUS_FINISHED and \
-                    not r.get("resultCacheHit"):
-                return r
-        return {}
 
     def _scan_and_apply(self, records: List[Dict[str, Any]],
                         budget: int) -> None:
@@ -713,28 +679,6 @@ class TuningController:
                         cur, "true",
                         {"verdict": verdict, "baseline": baseline,
                          "slowdown": d.get("slowdown")})
-                    self._apply(act)
-                    budget -= 1
-            elif verdict == "kernelFallback" and \
-                    self._set_conf is not None:
-                rec = self._newest_record(records, digest)
-                by_name = rec.get("kernelFallbacksByName") or {}
-                allowed = ACTION_CATALOG["kernelFallback"]["knobs"]
-                for name, n in sorted(by_name.items(),
-                                      key=lambda kv: (-kv[1], kv[0])):
-                    key = f"spark.rapids.sql.kernel.{name}.enabled"
-                    if key not in allowed or budget <= 0 or \
-                            self._active("kernelFallback", digest):
-                        continue
-                    cur = self._get_conf(key) \
-                        if self._get_conf is not None else None
-                    if str(cur).lower() == "false":
-                        continue  # already off
-                    act = self._new_action(
-                        "kernelFallback", digest, key, cur, "false",
-                        {"verdict": verdict, "baseline": baseline,
-                         "kernel": name, "fallbacks": int(n),
-                         "rebaseline": True})
                     self._apply(act)
                     budget -= 1
         # SLO burn -> tenant weight shift

@@ -892,14 +892,9 @@ def format_profile_report(path: str, top: int = 10, query=None) -> str:
 
 def hotspots_report(paths: List[str], top: int = 20) -> str:
     """Rank EXCLUSIVE self-time per span name across a whole trace
-    directory (the `tools hotspots` CLI): the picker for the NEXT
-    Pallas kernel target (docs/kernels.md) — a span family's summed
-    self-time across queries is the ceiling on what hand-writing that
-    loop can save. Kernel dispatches are split out per (kernel, shape
-    bucket) (`kernelDispatch[<name>@<bucket>]`) so kernel vs oracle
-    time is attributable per capacity class, and dispatches that ran
-    on default parameters are flagged `(untuned)` — the autotuner's
-    remaining targets."""
+    directory (the `tools hotspots` CLI): a span family's summed
+    self-time across queries is the ceiling on what rewriting that
+    loop can save."""
     from spark_rapids_tpu.trace import load_trace
     agg: Dict[str, Dict[str, float]] = {}
     window = 0.0
@@ -910,21 +905,7 @@ def hotspots_report(paths: List[str], top: int = 20) -> str:
             continue
         t0, t1 = _trace_bounds(spans)
         window += t1 - t0
-
-        def _name(s) -> str:
-            a = s.get("args", {})
-            k = a.get("kernel")
-            if k and s["name"] in ("kernelDispatch",
-                                   "TpuHashAggregateExec.dispatch"):
-                b = a.get("bucket")
-                bucket = f"@{b}" if b is not None else ""
-                flag = (" (untuned)"
-                        if "tuned" in a and not a["tuned"] else "")
-                return f"{s['name']}[{k}{bucket}]{flag}"
-            return s["name"]
-
-        for name, d in exclusive_times(
-                [dict(s, name=_name(s)) for s in spans]).items():
+        for name, d in exclusive_times(spans).items():
             e = agg.setdefault(name, {"count": 0, "total": 0.0,
                                       "exclusive": 0.0})
             e["count"] += d["count"]
@@ -933,8 +914,7 @@ def hotspots_report(paths: List[str], top: int = 20) -> str:
     lines = ["=== TPU Hotspot Report ===",
              f"{len(paths)} trace file(s), "
              f"{window / 1e6:.3f}s summed traced window", "",
-             "exclusive self-time per span family (the next kernel "
-             "targets — docs/kernels.md):", ""]
+             "exclusive self-time per span family:", ""]
     if not agg:
         lines.append("no spans recorded")
         return "\n".join(lines)
@@ -1741,10 +1721,10 @@ def generate_observability_docs() -> str:
         "`jit_<name>`. Names are `srt_<family>[_<tag>]`, at most 48",
         "characters of `[A-Za-z0-9_]`: `srt_stage_Filter_Project`,",
         "`srt_agg_partial` / `_merge` / `_merge_partial` / `_final` /",
-        "`_complete` (`_kernel` on the Pallas path), `srt_sort`,",
+        "`_complete`, `srt_sort`,",
         "`srt_topn`, `srt_join_build` / `_probe` / `_gather_<type>` /",
         "`_gather_fast_<type>` / `_mask` / `_extras`, `srt_decode`,",
-        "`srt_decode_fused`, `srt_upload_decode`, `srt_fetch_pack`,",
+        "`srt_upload_decode`, `srt_fetch_pack`,",
         "`srt_concat`, `srt_shrink`, `srt_compact`, `srt_project`,",
         "`srt_filter`, `srt_window`, `srt_generate`,",
         "`srt_exchange_pid` / `_range_keys` / `_range_rank` /",
@@ -1983,8 +1963,6 @@ def generate_observability_docs() -> str:
         "close |",
         "| retryCount | per-query retry+split deltas > telemetry."
         "retryCountThreshold | query close |",
-        "| kernelFallbacks | per-query kernelFallbacks.* delta > "
-        "telemetry.kernelFallbackThreshold | query close |",
         "| retryStorm | > telemetry.retryStormThreshold OOM retries "
         "in a 60 s window | retry time |",
         "| hbmWatermark | store live bytes > telemetry.hbmWatermark x "
@@ -2021,7 +1999,7 @@ def generate_observability_docs() -> str:
         "`tools serve --metrics-port N` HTTP twin (`GET /metrics`),",
         "export one text exposition per scrape: every registry metric",
         "as `srt_<snake_case>[_seconds]_total` (prefix families like",
-        "`kernelFallbacks.groupbyHash` become one family with a",
+        "`deviceDecodedValues.PLAIN` become one family with a",
         "`key` label; `*Time` metrics convert ns to seconds; `peak*`",
         "metrics are gauges folded by MAX across registries, not",
         "summed — a high-watermark, never a sum of dead plans' peaks),",
@@ -2141,7 +2119,7 @@ def generate_observability_docs() -> str:
         "",
         "`tools bench-diff <baseline.json> <candidate.json|dir>` diffs",
         "two bench outputs — headline rows/s, device walls, decode",
-        "overlap, kernel A/B, serving QPS, tracing/profiling/ring",
+        "overlap, serving QPS, tracing/profiling/ring",
         "overheads — against a relative `--threshold` (default 10%),",
         "prints a verdict table (`--json` for machines), and exits 1",
         "when a gating check regressed; bench.py runs it against the",
@@ -2236,8 +2214,8 @@ def generate_tuning_docs() -> str:
         "doctor-verdict pipeline (the same walk `tools doctor --all`",
         "runs) and applies per-signature actions from the declared",
         "catalog below. Tuning never changes what a query COMPUTES —",
-        "only admission shaping, cache residency, and kernel-tier",
-        "routing, all bit-identity-preserving by their own contracts",
+        "only admission shaping and cache residency,",
+        "both bit-identity-preserving by their own contracts",
         "(tier-1 asserts results are identical with tuning on vs",
         "off).",
         "",
@@ -2272,10 +2250,8 @@ def generate_tuning_docs() -> str:
         "|---|---|---|---|---|",
     ]
     for name, cat in sorted(ACTION_CATALOG.items()):
-        knobs = cat.get("knobs", [cat["knob"]])
-        knob_s = " / ".join(f"`{k}`" for k in knobs)
         lines.append(
-            f"| `{name}` | {cat['verdict']} | {knob_s} | "
+            f"| `{name}` | {cat['verdict']} | `{cat['knob']}` | "
             f"[{cat['min']}, {cat['max']}] | {cat['doc']} |")
     lines += [
         "",
@@ -2309,11 +2285,6 @@ def generate_tuning_docs() -> str:
         "- otherwise the action graduates to **accepted** (still",
         "  manually revertible);",
         "- **pinned** actions are exempt from auto-revert;",
-        "- `kernelFallback` is accepted at birth: the conf flip",
-        "  changes the plan signature (kernel.*.enabled is",
-        "  signature-relevant), so the new shape RE-BASELINES under",
-        "  its own history and the old scope's window can never fill",
-        "  — manual revert only.",
         "",
         "Applied/accepted actions persist in",
         "`<history.dir>/tuning-state.json` and re-actuate at the next",

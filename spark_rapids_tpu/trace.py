@@ -120,9 +120,7 @@ SPAN_CATALOG: Dict[str, str] = {
                                   "dispatch (chip, batch seq, compile "
                                   "flag)",
     "TpuHashAggregateExec.dispatch": "one aggregation device program "
-                                     "dispatch (mode, kernel= attr)",
-    "kernelDispatch": "one Pallas kernel dispatch (kernel= names it; "
-                      "docs/kernels.md)",
+                                     "dispatch (mode, compile flag)",
     "exchangeMaterialize": "exchange input drain + partition "
                            "materialization",
     "meshStack": "per-device shard assembly into the globally-sharded "
@@ -205,10 +203,9 @@ KIND_READERS: Dict[str, str] = {
     "TpuFusedStageExec.dispatch": "`tools trace` enqueue occupancy per "
                                   "chip; program= ties a host enqueue "
                                   "to the device's program",
-    "TpuHashAggregateExec.dispatch": "`tools hotspots` per-kernel/bucket "
-                                     "split; enqueue occupancy",
-    "kernelDispatch": "`tools hotspots` per-kernel/bucket split "
-                      "(autotuner targets)",
+    "TpuHashAggregateExec.dispatch": "`tools trace` enqueue occupancy "
+                                     "per chip; program= ties a host "
+                                     "enqueue to the device's program",
     "exchangeMaterialize": _GENERIC + " (the exchange's drain wall)",
     "meshStack": _GENERIC + " (four-chip runs)",
     "meshSizeExchange": _GENERIC + " (four-chip runs)",

@@ -1,9 +1,8 @@
 """Bring-up guards that need no chip (ISSUE 21): the places that could
-hide a missing or refusing chip fail or report instead — native kernel
-construction under the installed JAX, the Pallas mode on an accelerator,
-VMEM refusals vs HBM OOMs, the platform gate, where the compile cache
-goes, a budget that is never guessed on an accelerator, and the two
-entry scripts' exit codes."""
+hide a missing or refusing chip fail or report instead — VMEM refusals
+vs HBM OOMs, the platform gate, where the compile cache goes, a budget
+that is never guessed on an accelerator, and the two entry scripts'
+exit codes."""
 
 import os
 import subprocess
@@ -13,39 +12,11 @@ import pytest
 
 import jax
 
-from spark_rapids_tpu import device_caps as DC
 from spark_rapids_tpu import device_manager
-from spark_rapids_tpu import kernels as KR
 from spark_rapids_tpu import memory as MEM
 from spark_rapids_tpu import retry as R
-from spark_rapids_tpu.conf import TpuConf
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def test_tiled_groupby_kernel_constructs_natively():
-    """interpret=False takes the Mosaic compiler-params path, which
-    interpret-mode tests never reach (it named an API the installed
-    JAX no longer has)."""
-    from spark_rapids_tpu.kernels import groupby_hash as KG
-    assert callable(KG._build_kernel_tiled(512, 1, 3, 1, 1, 128, False))
-
-
-@pytest.fixture
-def fresh_pallas_mode():
-    DC.pallas_mode.cache_clear()
-    yield
-    DC.pallas_mode.cache_clear()
-
-
-def test_pallas_mode_never_interprets_on_an_accelerator(
-        monkeypatch, fresh_pallas_mode):
-    """Backend faked to tpu: the native probe fails here (XLA:CPU only
-    interprets), and the answer is None — never "interpret"."""
-    assert DC.pallas_mode() == "interpret"
-    DC.pallas_mode.cache_clear()
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert DC.pallas_mode() is None
 
 
 def test_vmem_exhaustion_is_a_kernel_refusal_not_an_oom():
@@ -56,39 +27,8 @@ def test_vmem_exhaustion_is_a_kernel_refusal_not_an_oom():
         "RESOURCE_EXHAUSTED: Out of memory while trying to allocate "
         "17179869184 bytes in memory space hbm.")
     assert R.is_vmem_refusal(vmem) and not R.is_oom_error(vmem)
-    assert KR.is_oracle_fallback_error(vmem)
     assert R.is_oom_error(hbm) and not R.is_vmem_refusal(hbm)
-    assert not KR.is_oracle_fallback_error(hbm)
-    assert not KR.is_oracle_fallback_error(R.TpuRetryOOM("injected"))
-
-
-def test_native_gate_follows_the_refusal_table(monkeypatch):
-    conf = TpuConf({})
-    monkeypatch.setattr(KR, "NATIVE_REFUSED", {"murmur3": "refused"})
-    # interpret mode (this backend) runs every kernel, listed or not
-    assert KR.kernel_enabled(conf, "murmur3")
-    monkeypatch.setattr(DC, "pallas_mode", lambda: "native")
-    assert not KR.kernel_enabled(conf, "murmur3")
-    assert KR.kernel_enabled(conf, "joinProbe")
-    monkeypatch.setattr(DC, "pallas_mode", lambda: None)
-    assert not KR.kernel_enabled(conf, "joinProbe")
-
-
-def test_every_refused_kernel_is_a_registered_kernel():
-    assert set(KR.NATIVE_REFUSED) <= set(KR.KERNELS)
-    assert all(KR.NATIVE_REFUSED.values())
-
-
-def test_poison_keeps_the_reason():
-    KR.clear_poison()
-    try:
-        KR.poison("murmur3", ("k",), ValueError("first line\nsecond"))
-        assert KR.is_poisoned("murmur3", ("k",))
-        assert KR.poisoned() == {
-            ("murmur3", ("k",)): "ValueError: first line"}
-    finally:
-        KR.clear_poison()
-    assert not KR.poisoned()
+    assert R.is_oom_error(R.TpuRetryOOM("injected"))
 
 
 def test_no_guessed_budget_on_an_accelerator(monkeypatch):

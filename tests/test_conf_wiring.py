@@ -18,6 +18,18 @@ def _df(s, cols, n=512, seed=77, parts=2):
     return s.createDataFrame(gen_batch(cols, n, seed), num_partitions=parts)
 
 
+def test_no_kernel_tier_conf_key_is_registered():
+    # the Pallas kernel tier went with its eleven keys (PR 32); a
+    # session given one behaves as for any unknown key
+    from spark_rapids_tpu.conf import _REGISTRY, TpuConf
+    assert not [k for k in _REGISTRY
+                if k.startswith("spark.rapids.sql.kernel.")
+                or k == "spark.rapids.sql.telemetry."
+                        "kernelFallbackThreshold"]
+    conf = TpuConf({"spark.rapids.sql.kernel.enabled": "false"})
+    assert conf.settings["spark.rapids.sql.kernel.enabled"] == "false"
+
+
 def test_has_nans_false_same_results():
     """With NaN-free data, hasNans=false (drops the is-NaN sort word —
     one fewer radix pass per float key) must give identical sort/group

@@ -108,6 +108,70 @@ def test_murmur3_string_edge_lengths():
     np.testing.assert_array_equal(got[:10], np.array(expect, np.int32))
 
 
+DEC_15_2 = T.DecimalType(15, 2)
+_HASH_BATTERY_TYPES = ["b", "i", "l", "f", "d", "dt", "ts", "dec", "s"]
+
+
+def _hash_battery(n=200, seed=9):
+    """HostBatch covering every murmur3-hashable type, 15% nulls each,
+    with the edge cases the host/device twins must agree on: -0.0,
+    empty strings, embedded NUL bytes, and high-bit (negative-as-int8)
+    trailing bytes."""
+    rng = np.random.default_rng(seed)
+    strs = np.empty(n, dtype=object)
+    pool = ["", "a", "ab", "abc", "abcd", "abcde", "\x00", "x\x00y",
+            "\x7f\x00", "éä", "ÿþ", "0123456789abcdef",
+            "tailé"]
+    for i in range(n):
+        strs[i] = pool[rng.integers(0, len(pool))]
+    cols = [
+        ("b", T.BooleanT, rng.integers(0, 2, n).astype(bool)),
+        ("i", T.IntegerT, rng.integers(-2**31, 2**31, n,
+                                       dtype=np.int64).astype(np.int32)),
+        ("l", T.LongT, rng.integers(-2**62, 2**62, n)),
+        ("f", T.FloatT, np.where(rng.random(n) < 0.1, -0.0,
+                                 rng.standard_normal(n)
+                                 ).astype(np.float32)),
+        ("d", T.DoubleT, np.where(rng.random(n) < 0.1, -0.0,
+                                  rng.standard_normal(n))),
+        ("dt", T.DateT, rng.integers(-11000, 47000, n
+                                     ).astype(np.int32)),
+        ("ts", T.TimestampT, rng.integers(-10**15, 10**15, n)),
+        ("dec", DEC_15_2, rng.integers(-10**10, 10**10, n)),
+        ("s", T.StringT, strs),
+    ]
+    assert [c[0] for c in cols] == _HASH_BATTERY_TYPES
+    fields, hcols = [], []
+    for name, dt, vals in cols:
+        valid = rng.random(n) > 0.15
+        fields.append(T.StructField(name, dt))
+        hcols.append(HostColumn(dt, vals, valid).normalized())
+    return HostBatch(T.StructType(fields), hcols, n)
+
+
+@pytest.mark.parametrize("which", _HASH_BATTERY_TYPES + ["chain"])
+def test_murmur3_host_device_twin_parity(which):
+    """Device murmur3 (ops/hashing.py) against the host implementation
+    (columnar/murmur3.py through expressions._hash_column): one case a
+    column type, so a failure names the type, and the chain of all
+    nine folded left to right as the exchange folds its keys."""
+    import jax
+
+    from spark_rapids_tpu.sql.expressions import _hash_column
+    hb = _hash_battery()
+    n = hb.num_rows
+    db = DeviceBatch.from_host(hb)  # capacity-bucketed: compare prefix
+    picked = (list(range(len(hb.columns))) if which == "chain"
+              else [hb.schema.field_index(which)])
+    host = np.full(n, 42, dtype=np.int32)
+    for ci in picked:
+        host = _hash_column(hb.columns[ci], host)
+    dcols = [db.columns[ci] for ci in picked]
+    dev = np.asarray(jax.jit(
+        lambda: hashing.murmur3_columns(dcols, db.capacity, 42))())
+    np.testing.assert_array_equal(dev[:n], host)
+
+
 APPROX_EXPRS = (E.Exp, E.Log, E.Log10, E.Sin, E.Cos, E.Tan, E.Asin,
                 E.Acos, E.Atan, E.Sinh, E.Cosh, E.Tanh, E.Pow)
 

@@ -151,6 +151,20 @@ def test_mixed_types_with_nulls_snappy(tmp_path):
     _assert_parity(path)
 
 
+@pytest.mark.parametrize("case", ["plain", "dict"])
+def test_mixed_table_one_page_parity(tmp_path, case):
+    # every column type of the corpus in ONE file and one page a
+    # chunk: all PLAIN with the strings left in (byte-array and
+    # fixed-width lanes side by side), and the writer's defaults
+    # (dictionary pages with nulls)
+    if case == "plain":
+        path = _write(tmp_path, _mixed_table(with_nulls=False),
+                      use_dictionary=False)
+    else:
+        path = _write(tmp_path, _mixed_table())
+    _assert_parity(path)
+
+
 def test_zstd_compression(tmp_path):
     path = _write(tmp_path, _mixed_table(seed=3), compression="zstd")
     _assert_parity(path)
@@ -668,23 +682,27 @@ def _uvarint(v: int) -> bytes:
             return bytes(out)
 
 
-def test_hybrid_lookup_kernel_matches_oracle():
+@pytest.mark.parametrize("width", range(1, 33))
+def test_hybrid_lookup_kernel_matches_oracle(width):
+    # every width of read_packed's contract (<= 32): value k of a
+    # packed group sits at bit k * width, so the odd widths put values
+    # at every shift of the 5-byte window, 31 bits at shift 7 among them
     import jax.numpy as jnp
 
     from spark_rapids_tpu.ops import rle as R
-    rng = np.random.default_rng(11)
-    for width in (1, 3, 7, 12, 20):
-        vals = rng.integers(0, 1 << width, 300)
-        vals[40:200] = vals[40]  # force an RLE run
-        payload, runs = _hybrid_stream(vals, width)
-        words = np.zeros((len(payload) + 3) // 4 * 4, dtype=np.uint8)
-        words[:len(payload)] = payload
-        bytes_all = R.bytes_of_words(jnp.asarray(words.view(np.int32)))
-        arrs = [jnp.asarray(a) for a in runs.arrays(
-            max(8, 1 << (len(runs) - 1).bit_length()))]
-        pos = jnp.arange(len(vals), dtype=jnp.int64)
-        got = np.asarray(R.hybrid_lookup(bytes_all, pos, *arrs))
-        assert np.array_equal(got, vals), f"width={width}"
+    rng = np.random.default_rng(11 + width)
+    vals = rng.integers(0, 1 << width, 300)
+    vals[40:200] = vals[40]  # force an RLE run
+    payload, runs = _hybrid_stream(vals, width)
+    words = np.zeros((len(payload) + 3) // 4 * 4, dtype=np.uint8)
+    words[:len(payload)] = payload
+    bytes_all = R.bytes_of_words(jnp.asarray(words.view(np.int32)))
+    arrs = [jnp.asarray(a) for a in runs.arrays(
+        max(8, 1 << (len(runs) - 1).bit_length()))]
+    pos = jnp.arange(len(vals), dtype=jnp.int64)
+    got = np.asarray(R.hybrid_lookup(bytes_all, pos, *arrs))
+    # unsigned: a 32-bit value may come back through an int32 field
+    assert np.array_equal(got.astype(np.int64) & 0xFFFFFFFF, vals)
 
 
 def test_fixed_width_kernels_match_oracle():
@@ -734,27 +752,26 @@ def _bytes_arr(payload: bytes):
     return R.bytes_of_words(jnp.asarray(words.view(np.int32)))
 
 
-def test_read_packed64_wide_widths():
+@pytest.mark.parametrize("width", [33, 40, 47, 48, 56, 63, 64])
+def test_read_packed64_wide_widths(width):
     import jax.numpy as jnp
 
     from spark_rapids_tpu.ops import rle as R
-    rng = np.random.default_rng(21)
-    for width in (33, 47, 63, 64):
-        vals = [int(v) for v in
-                rng.integers(0, 1 << 62, 40)] if width < 64 else \
-            [int(v) for v in rng.integers(-(1 << 62), 1 << 62, 40)]
-        vals = [v & ((1 << width) - 1) for v in vals]
-        bits = 0
-        for k, v in enumerate(vals):
-            bits |= v << (k * width)
-        payload = bits.to_bytes((len(vals) * width + 7) // 8 + 8,
-                                "little")
-        ba = _bytes_arr(payload)
-        off = jnp.asarray(np.arange(len(vals), dtype=np.int64) * width)
-        w = jnp.full(len(vals), width, dtype=jnp.int64)
-        got = np.asarray(R.read_packed64(ba, off, w)).astype(np.uint64)
-        want = np.array(vals, dtype=np.uint64)
-        assert np.array_equal(got, want), f"width={width}"
+    rng = np.random.default_rng(21 + width)
+    vals = [int(v) for v in
+            rng.integers(0, 1 << 62, 40)] if width < 64 else \
+        [int(v) for v in rng.integers(-(1 << 62), 1 << 62, 40)]
+    vals = [v & ((1 << width) - 1) for v in vals]
+    bits = 0
+    for k, v in enumerate(vals):
+        bits |= v << (k * width)
+    payload = bits.to_bytes((len(vals) * width + 7) // 8 + 8, "little")
+    ba = _bytes_arr(payload)
+    off = jnp.asarray(np.arange(len(vals), dtype=np.int64) * width)
+    w = jnp.full(len(vals), width, dtype=jnp.int64)
+    got = np.asarray(R.read_packed64(ba, off, w)).astype(np.uint64)
+    want = np.array(vals, dtype=np.uint64)
+    assert np.array_equal(got, want)
 
 
 def test_delta_host_decoder_matches_pyarrow(tmp_path):
@@ -837,102 +854,3 @@ def test_read_bss_kernel():
     local = jnp.asarray(np.arange(n, dtype=np.int64))
     got = np.asarray(R.read_bss(ba, base, stride, local, 8))
     assert got.tolist() == vals.tolist()
-
-
-# -- fused decode kernel (one Pallas program per batch, docs/kernels.md) ----
-
-FUSED_OFF = {"spark.rapids.sql.kernel.decodeFused.enabled": "false"}
-
-
-def _fused_vs_chain(path):
-    """Host oracle vs fused-kernel decode vs XLA-chain decode over one
-    file; all three must be bit-identical. Returns (fused metrics,
-    chain metrics)."""
-    host, _ = _collect(path, False)
-    fused, mf = _collect(path, True)
-    chain, mc = _collect(path, True, extra_conf=FUSED_OFF)
-    assert list(host) == list(fused) == list(chain)
-    for k in host:
-        assert host[k] == fused[k], f"fused decode differs on {k}"
-        assert host[k] == chain[k], f"chain decode differs on {k}"
-    return mf, mc
-
-
-def test_fused_decode_single_program_per_batch(tmp_path):
-    path = _write(tmp_path, _mixed_table())
-    mf, mc = _fused_vs_chain(path)
-    assert mf.get("kernelDispatchCount.decodeFused", 0) >= 1, mf
-    assert mf.get("kernelFallbacks.decodeFused", 0) == 0, mf
-    # the whole fused claim: ONE logical program per decoded batch
-    assert mf["deviceDecodedBatches"] >= 1
-    assert mf["deviceDecodePrograms"] == mf["deviceDecodedBatches"], mf
-    # the chain leg bills its real multi-stage program count
-    assert mc.get("kernelDispatchCount.decodeFused", 0) == 0, mc
-    assert mc["deviceDecodePrograms"] > mc["deviceDecodedBatches"], mc
-
-
-@pytest.mark.parametrize("case", ["plain", "dict", "page_nulls",
-                                  "dict_overflow"])
-def test_fused_decode_parity_matrix(tmp_path, case):
-    # the PR 8/9 encoding corpus re-run explicitly as fused-vs-chain
-    # A/B: dictionary and PLAIN lanes, nulls straddling tiny pages,
-    # and mid-chunk dict overflow all decode bit-identically in ONE
-    # program with zero fallbacks
-    if case == "plain":
-        tbl = _mixed_table(with_nulls=False)
-        path = _write(tmp_path, tbl, use_dictionary=False)
-    elif case == "dict":
-        path = _write(tmp_path, _mixed_table())
-    elif case == "page_nulls":
-        n = 6000
-        vals = [None if (i // 50) % 2 == 0 else i * 3 for i in range(n)]
-        svals = [None if (i // 37) % 3 == 1 else f"s{i % 5}"
-                 for i in range(n)]
-        tbl = pa.table({"v": pa.array(vals, type=pa.int64()),
-                        "s": pa.array(svals)})
-        path = _write(tmp_path, tbl, data_page_size=512)
-    else:
-        n = 12_000
-        rng = np.random.default_rng(13)
-        vals = [f"prefix-{int(v)}-suffix"
-                for v in rng.integers(0, 6000, n)]
-        tbl = pa.table({"s": pa.array(vals)})
-        path = _write(tmp_path, tbl, dictionary_pagesize_limit=8_000,
-                      data_page_size=4096)
-    mf, _mc = _fused_vs_chain(path)
-    assert mf.get("kernelFallbacks.decodeFused", 0) == 0, mf
-    assert mf.get("kernelDispatchCount.decodeFused", 0) >= 1, mf
-
-
-def test_fused_decode_injected_failure_falls_back_bit_identical(
-        tmp_path):
-    from spark_rapids_tpu import kernels as KR
-    path = _write(tmp_path, _mixed_table())
-    host, _ = _collect(path, False)
-    KR.inject_failure("decodeFused")
-    try:
-        dev, m = _collect(path, True)
-    finally:
-        KR.inject_failure("decodeFused", on=False)
-        KR.clear_poison()
-    for k in host:
-        assert host[k] == dev[k], f"fallback decode differs on {k}"
-    assert m.get("kernelFallbacks.decodeFused", 0) >= 1, m
-    # fallbacks billed at the chain's program count, not the fused 1
-    assert m["deviceDecodePrograms"] > m["deviceDecodedBatches"], m
-
-
-def test_fused_decode_host_only_layout_uses_chain(tmp_path):
-    # a file whose every column host-falls-back (DELTA_BYTE_ARRAY is
-    # genuinely unsupported) has no device entries: nothing to fuse,
-    # no decodeFused fallback billed, parity still holds
-    n = 500
-    tbl = pa.table({"dba": pa.array([f"prefix-common-{i}"
-                                     for i in range(n)])})
-    path = _write(tmp_path, tbl, use_dictionary=False,
-                  column_encoding={"dba": "DELTA_BYTE_ARRAY"})
-    host, _ = _collect(path, False)
-    dev, m = _collect(path, True)
-    for k in host:
-        assert host[k] == dev[k]
-    assert m.get("kernelFallbacks.decodeFused", 0) == 0, m

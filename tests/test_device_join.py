@@ -6,9 +6,12 @@ The right side is .repartition()-ed to force the shuffled path (the
 planner broadcasts small LocalRelations otherwise).
 """
 
+import numpy as np
 import pytest
 
+from spark_rapids_tpu.columnar.host import HostBatch, HostColumn
 from spark_rapids_tpu.sql import functions as F
+from spark_rapids_tpu.sql import types as T
 
 from tests.datagen import (DoubleGen, IntegerGen, KeyStringGen, LongGen,
                            SmallIntGen, StringGen, gen_batch)
@@ -135,6 +138,47 @@ def test_join_duplicate_heavy_keys():
         return l.join(r, F.col("k") == F.col("k2"), "inner")
     assert_tpu_and_cpu_equal_collect(
         fn, expect_execs=["TpuBroadcastHashJoin"])
+
+
+def _fact_and_dimension(spark, dup, m=300, n=3000):
+    """A 3,000-row fact against a 300-row dimension on 64-bit keys: a
+    tenth of the foreign keys null, some past the dimension's last
+    key, the build side's keys unique or (``dup``) a quarter of them
+    twice, a string beside each."""
+    rng = np.random.default_rng(13)
+    pk = np.arange(1, m + 1)
+    if dup:
+        pk = np.concatenate([pk, pk[: m // 4]])
+    dim = HostBatch(
+        T.StructType([T.StructField("pk", T.LongT),
+                      T.StructField("nm", T.StringT)]),
+        [HostColumn.all_valid(pk, T.LongT),
+         HostColumn.all_valid(np.array([f"n{i}" for i in range(len(pk))],
+                                       dtype=object), T.StringT)],
+        len(pk))
+    fact = HostBatch(
+        T.StructType([T.StructField("fk", T.LongT),
+                      T.StructField("v", T.LongT)]),
+        [HostColumn(T.LongT, rng.integers(1, m + 120, n),
+                    rng.random(n) > 0.1).normalized(),
+         HostColumn.all_valid(rng.integers(0, 50, n), T.LongT)], n)
+    return spark.createDataFrame(fact), spark.createDataFrame(dim)
+
+
+@pytest.mark.parametrize("jt,dup", [
+    ("leftsemi", False), ("leftanti", False), ("inner", False),
+    ("leftsemi", True), ("inner", True)],
+    ids=["semi-unique", "anti-unique", "inner-unique", "semi-dup",
+         "inner-dup"])
+def test_fact_dimension_join_long_keys(jt, dup):
+    # unique build keys take the FK fast path (no sizing sync);
+    # duplicates lose the certificate and expand
+    def fn(s):
+        f, d = _fact_and_dimension(s, dup)
+        return f.join(d, f["fk"] == d["pk"], jt)
+    assert_tpu_and_cpu_equal_collect(
+        fn, conf={"spark.rapids.sql.test.forceDevice": "true"},
+        expect_execs=["TpuBroadcastHashJoin"])
 
 
 def test_join_then_agg_pipeline_on_device():

@@ -173,13 +173,22 @@ def test_store_age_compaction(tmp_path):
     assert len(H.read_records(d)) == 1
 
 
-def test_signature_aggregates_and_trend():
+def test_signature_aggregates_and_trend(tmp_path):
     t0 = time.time()
     recs = [_rec(t0 + i * 3600, wall=0.1 + 0.05 * i, tenant="t",
                  retryCount=(1 if i == 3 else 0))
             for i in range(4)]
     recs.append(_rec(t0 + 5 * 3600, status="failed", wall=0.0))
-    recs.append(_rec(t0, sig="b" * 40, kernelFallbacks=2))
+    # a record an older process wrote: it loads, and the fields no
+    # reader knows any more (the kernel tier's, gone since PR 32) are
+    # carried and ignored
+    store = H.HistoryStore(str(tmp_path / "hist"), 1 << 20, 14)
+    store.append(_rec(t0, sig="b" * 40, kernelDispatches=9,
+                      kernelFallbacks=2,
+                      kernelFallbacksByName={"groupbyHash": 2}))
+    old = H.read_records(str(tmp_path / "hist"))
+    assert len(old) == 1 and old[0]["kernelFallbacks"] == 2
+    recs += old
     aggs = H.signature_aggregates(recs)
     a = aggs["a" * 40]
     assert a["count"] == 5 and a["finished"] == 4
@@ -189,7 +198,8 @@ def test_signature_aggregates_and_trend():
     assert a["retryRate"] == pytest.approx(0.25)
     assert a["tenants"] == ["t"]
     b = aggs["b" * 40]
-    assert b["fallbackRate"] == 1.0
+    assert b["count"] == b["finished"] == 1 and "fallbackRate" not in b
+    assert "b" * 12 in H.format_history(recs)
     # display digest: 40-hex signatures show their own prefix
     assert H.sig_digest("a" * 40) == "a" * 12
 

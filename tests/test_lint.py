@@ -194,6 +194,39 @@ def test_pallas_call_suppressible_with_reason(tmp_path):
     assert _lint(root).clean
 
 
+def test_no_module_of_the_package_imports_pallas_or_a_kernel_tier():
+    # every operator has one device path, an XLA composition (PR 32):
+    # outside lint/, whose rule keeps naming pallas_call, nothing
+    # imports Pallas or a spark_rapids_tpu.kernels package
+    import ast
+    pkg = os.path.join(default_root(), "spark_rapids_tpu")
+    assert not os.path.exists(os.path.join(pkg, "kernels"))
+    bad = []
+    for dirpath, _dirs, files in os.walk(pkg):
+        if os.path.relpath(dirpath, pkg).split(os.sep)[0] == "lint":
+            continue
+        for f in files:
+            if not f.endswith(".py"):
+                continue
+            path = os.path.join(dirpath, f)
+            with open(path) as fh:
+                tree = ast.parse(fh.read(), path)
+            for n in ast.walk(tree):
+                if isinstance(n, ast.Import):
+                    names = [a.name for a in n.names]
+                elif isinstance(n, ast.ImportFrom):
+                    names = [f"{n.module or ''}.{a.name}"
+                             for a in n.names]
+                else:
+                    continue
+                bad += [(os.path.relpath(path, pkg), n.lineno, nm)
+                        for nm in names
+                        if "pallas" in nm
+                        or nm.startswith("spark_rapids_tpu.kernels")
+                        or nm.split(".")[-1] == "kernels"]
+    assert not bad, bad
+
+
 def test_jit_module_cache_flags_raw_dicts(tmp_path):
     root = _tree(tmp_path, {"spark_rapids_tpu/exec/x.py": """
         from collections import OrderedDict

@@ -296,7 +296,7 @@ def _hist_record(qid, *, wall, rows, retries=0, spill=0, poc=None):
            "status": "finished", "tenant": "t", "wallSeconds": wall,
            "queueWaitSeconds": 0.0, "outputRows": rows,
            "retryCount": retries, "splitRetryCount": 0,
-           "spillBytes": spill, "kernelFallbacks": 0, "jitMisses": 0}
+           "spillBytes": spill, "jitMisses": 0}
     if poc:
         rec["plannedOutOfCore"] = poc
     return rec

@@ -6,9 +6,8 @@ queue / retry-storm units), the Prometheus endpoint (exposition
 parseability, describe_metric coverage, monotone counters across
 registry GC, the protocol verb + HTTP twin), `tools top`,
 `tools bench-diff` (injected regression flags + exit contract), the
-empty-trace-dir CLI contract, the profile kernel summary satellite,
-the stats-under-concurrent-mutation satellite, and lint fixtures for
-the span-kind / prom-family rules."""
+empty-trace-dir CLI contract, the stats-under-concurrent-mutation
+satellite, and lint fixtures for the span-kind / prom-family rules."""
 
 from __future__ import annotations
 
@@ -407,7 +406,10 @@ def test_prometheus_engine_families_from_described_keys():
         _agg_df(s)._execute()
     finally:
         s.stop()
+    from spark_rapids_tpu.metrics import MetricRegistry
     from spark_rapids_tpu.telemetry.prometheus import render_prometheus
+    scan = MetricRegistry(owner="PrefixProbe")
+    scan.create("deviceDecodedValues.PLAIN").add(3)
     text = render_prometheus()
     _assert_prometheus_wellformed(text)
     assert re.search(r"^srt_num_output_rows_total \d+$", text, re.M)
@@ -415,7 +417,7 @@ def test_prometheus_engine_families_from_described_keys():
     assert re.search(r"^srt_undescribed_metric_keys 0$", text, re.M)
     # prefix families carry their member as a label
     assert re.search(
-        r'^srt_kernel_dispatch_count_total\{key="groupbyHash"\} \d+$',
+        r'^srt_device_decoded_values_total\{key="PLAIN"\} \d+$',
         text, re.M)
 
 
@@ -619,6 +621,29 @@ def test_bench_diff_cli_exit_contract(tmp_path, capsys):
 # S1: trace/hotspots CLI on empty or span-free inputs
 # ---------------------------------------------------------------------------
 
+def test_hotspots_cli_exit_contract(tmp_path):
+    # PR 12 contract, through the real `python -m` entry: an EXISTING
+    # but empty trace dir is a normal answer ("no spans found", exit
+    # 0) — an idle ring recorder must not fail automation tailing it;
+    # a missing path stays an error
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run(
+        [sys.executable, "-m", "spark_rapids_tpu.tools", "hotspots",
+         str(tmp_path)],
+        capture_output=True, text=True, cwd=repo)
+    assert out.returncode == 0, out.stdout + out.stderr
+    assert "no spans found" in out.stdout
+    out = subprocess.run(
+        [sys.executable, "-m", "spark_rapids_tpu.tools", "hotspots",
+         str(tmp_path / "does-not-exist")],
+        capture_output=True, text=True, cwd=repo)
+    assert out.returncode == 1
+    assert "no such trace file or directory" in out.stdout
+
+
+
 def test_trace_cli_empty_dir_and_missing_path(tmp_path, capsys):
     from spark_rapids_tpu.tools import _main
     empty = tmp_path / "empty"
@@ -642,51 +667,6 @@ def test_trace_cli_empty_dir_and_missing_path(tmp_path, capsys):
     bad.write_text("{not json")
     assert _main(["trace", str(bad)]) == 1
     assert "not a readable Chrome-trace file" in capsys.readouterr().out
-
-
-# ---------------------------------------------------------------------------
-# S2: kernel summary in the profile artifact + rendered tree
-# ---------------------------------------------------------------------------
-
-def test_profile_kernel_summary_and_rendering(tmp_path):
-    from spark_rapids_tpu.profile import format_profile, read_profiles
-    pdir = tmp_path / "profiles"
-    s = TpuSparkSession(_base_conf(**{
-        "spark.rapids.sql.profile.enabled": "true",
-        "spark.rapids.sql.profile.dir": str(pdir)}))
-    try:
-        _agg_df(s)._execute()
-        path = s.last_profile_path
-    finally:
-        s.stop()
-    assert path
-    prof = next(read_profiles(path))
-    kern = prof["kernels"]
-    # the partial-agg update rides the groupbyHash kernel by default
-    assert kern["dispatches"].get("groupbyHash", 0) > 0, kern
-    text = format_profile(prof)
-    assert "kernel tier" in text
-    assert "groupbyHash=" in text
-    # per-node attribution is in the headline metric list too
-    assert "kernelDispatchCount.groupbyHash=" in text
-
-
-def test_profile_kernel_summary_shows_oracle_ride(tmp_path):
-    """A query forced onto the oracle path reports ZERO dispatches in
-    the summary — visible without grepping raw metrics."""
-    from spark_rapids_tpu.profile import read_profiles
-    pdir = tmp_path / "profiles"
-    s = TpuSparkSession(_base_conf(**{
-        "spark.rapids.sql.kernel.enabled": "false",
-        "spark.rapids.sql.profile.enabled": "true",
-        "spark.rapids.sql.profile.dir": str(pdir)}))
-    try:
-        _agg_df(s)._execute()
-        path = s.last_profile_path
-    finally:
-        s.stop()
-    prof = next(read_profiles(path))
-    assert prof["kernels"] == {"dispatches": {}, "fallbacks": {}}
 
 
 # ---------------------------------------------------------------------------

@@ -4,8 +4,7 @@ signature records a retrySpill action that measurably changes
 admission for that signature on the next server run), the site:tuning
 injected harmful action auto-reverting within the guard window
 (visible in `tools tuning`, the history store and the srt_tuning_*
-families), the compile-storm pre-warm ledger replay, the
-kernel-fallback conf flip (bit-identical results, accepted at birth),
+families), the compile-storm pre-warm ledger replay,
 tuning/revert record EXCLUSION from aggregates / SLO windows / doctor
 baselines, tuning-on-vs-off bit identity, the tools tuning/doctor
 --all/history --signature CLI contracts, and the `tuning-action` lint
@@ -155,15 +154,15 @@ def test_format_tuning_table(tmp_path):
         {"epoch": 1, "action": "limitConcurrency", "scope": "a" * 40,
          "knob": "signatureConcurrency", "oldValue": None,
          "newValue": 2, "state": "applied", "pinned": True},
-        {"epoch": 2, "action": "kernelFallback", "scope": "b" * 40,
-         "knob": "spark.rapids.sql.kernel.joinProbe.enabled",
-         "oldValue": "true", "newValue": "false", "state": "reverted",
+        {"epoch": 2, "action": "seedOutOfCore", "scope": "b" * 40,
+         "knob": "spark.rapids.sql.outOfCore.enabled",
+         "oldValue": "false", "newValue": "true", "state": "reverted",
          "evidence": {"injected": True}}]}
     out = T.format_tuning(st)
     assert "limitConcurrency" in out and "pinned" in out
     assert "reverted" in out and "injected" in out
     assert "-->2" in out  # old->new column, None rendered as "-"
-    assert "true->false" in out
+    assert "false->true" in out
     assert "no tuning actions" in T.format_tuning(
         {"version": 1, "epoch": 0, "actions": [], "prewarm": {}})
 
@@ -173,9 +172,9 @@ def test_action_catalog_declares_bounds_and_docs():
         assert cat["verdict"], name
         assert cat["doc"], name
         assert cat["min"] <= cat["max"], name
-        for knob in cat.get("knobs", [cat["knob"]]):
-            assert knob in T.INTERNAL_KNOBS or \
-                knob.startswith("spark.rapids."), (name, knob)
+        knob = cat["knob"]
+        assert knob in T.INTERNAL_KNOBS or \
+            knob.startswith("spark.rapids."), (name, knob)
 
 
 # ---------------------------------------------------------------------------
@@ -235,40 +234,6 @@ def test_seed_out_of_core_rides_retry_spill(tmp_path):
     assert not any(a["action"] == "seedOutOfCore"
                    for a in tun2.actions())
     assert writes2 == before
-
-
-def test_kernel_fallback_flip_accepted_at_birth(tmp_path):
-    hdir = tmp_path / "hist"
-    sig = "e" * 40
-    _storm_store(hdir, sig, kernelFallbacks=6,
-                 kernelFallbacksByName={"joinProbe": 6})
-    writes = {}
-    tun = T.TuningController(
-        TpuConf(_tuning_conf(hdir)), admission=_admission(),
-        set_conf=writes.__setitem__, get_conf=writes.get)
-    tun.tick()
-    key = "spark.rapids.sql.kernel.joinProbe.enabled"
-    assert writes.get(key) == "false"
-    acts = [a for a in tun.actions()
-            if a["action"] == "kernelFallback"]
-    assert acts and acts[0]["knob"] == key
-    assert acts[0]["evidence"]["rebaseline"] is True
-    # accepted at birth: the flip re-baselines, so the guardrail never
-    # judges it — the next tick graduates it without a window
-    tun.tick()
-    assert [a for a in tun.actions()
-            if a["action"] == "kernelFallback"][0]["state"] \
-        == "accepted"
-    # a kernel the catalog does not declare is never flipped
-    hdir2 = tmp_path / "hist2"
-    _storm_store(hdir2, sig, kernelFallbacks=6,
-                 kernelFallbacksByName={"rogueKernel": 6})
-    writes2 = {}
-    tun2 = T.TuningController(
-        TpuConf(_tuning_conf(hdir2)), admission=_admission(),
-        set_conf=writes2.__setitem__, get_conf=writes2.get)
-    tun2.tick()
-    assert writes2 == {}
 
 
 def test_slo_burn_shifts_tenant_weight(tmp_path):
@@ -744,18 +709,16 @@ def test_lint_tuning_action_bad_and_good(tmp_path):
                     "verdict": "x",
                     "knob": "spark.rapids.sql.unregistered.enabled",
                     "min": 0, "max": 1, "doc": "d"},
-                "listKnobs": {
+                "internalKnob": {
                     "verdict": "x",
                     "knob": "internalThing",
-                    "knobs": ["internalThing",
-                              "spark.rapids.sql.good.enabled"],
                     "min": 0, "max": 1, "doc": "d"},
             }
 
             class C:
                 def go(self):
                     self._new_action("goodAction", 1)
-                    self._new_action("listKnobs", 2)
+                    self._new_action("internalKnob", 2)
                     self._new_action("rogueAction", 3)
                     name = "dynamic"
                     self._new_action(name, 4)
