@@ -625,12 +625,17 @@ def _encoded_decode_body(layout: Tuple, cap: int, words, n_arr, extras):
     fields of its run — bit offset, width, value — from
     ``rle.step_fields`` (one scatter of each field's steps and one
     prefix sum: fields by prefix sum, no gather through a run's
-    index), and each stored value is decoded exactly once. Rows reach
-    their values by ONE gather through ``j`` (row -> dense rank) at
-    the end, and a column without definition levels (``ndl == 0``, a
-    static fact of the layout) skips it: there every active row IS its
-    own dense lane, and rows past ``n`` are zeroed by ``validity``
-    either way. Each lane runs under a ``jax.named_scope``
+    index), and each stored value is decoded exactly once. A
+    bit-packed value is read from the int32 staging ``words``
+    themselves — the two aligned words it lies in, ``rle.read_packed``
+    — so only the lanes that read bytes (PLAIN, BYTE_STREAM_SPLIT,
+    strings) expand the buffer to bytes, lazily, and a layout with
+    none of them never does. Rows reach their values by ONE gather
+    through ``j`` (row -> dense rank) at the end, and a column without
+    definition levels (``ndl == 0``, a static fact of the layout)
+    skips it: there every active row IS its own dense lane, and rows
+    past ``n`` are zeroed by ``validity`` either way. Each lane runs
+    under a ``jax.named_scope``
     (``decode_page_lookup``, ``decode_bits`` with ``/bytes``,
     ``/run_fields`` and ``/window`` inside it,
     ``decode_dict``, ``decode_plain``, ``decode_chars``,
@@ -681,7 +686,7 @@ def _encoded_decode_body(layout: Tuple, cap: int, words, n_arr, extras):
             # their stream; every other lookup below is per stored value
             dl = extras[cur:cur + 5]
             cur += 5
-            dl_v = R.hybrid_lookup(get_bytes(), pos, *dl)
+            dl_v = R.hybrid_lookup(words, pos, *dl)
             validity = (dl_v == 1) & active
             with jax.named_scope("decode_rows"):
                 j = jnp.clip(R.dense_ranks(validity), 0, cap - 1)
@@ -704,7 +709,7 @@ def _encoded_decode_body(layout: Tuple, cap: int, words, n_arr, extras):
         cur += len(dict_shapes)
 
         if kind == "bool":
-            v = rows(R.hybrid_lookup(get_bytes(), pos, *vr), j)
+            v = rows(R.hybrid_lookup(words, pos, *vr), j)
             data = jnp.where(validity, v != 0, False)
             outs.extend([data, validity])
             continue
@@ -715,7 +720,7 @@ def _encoded_decode_body(layout: Tuple, cap: int, words, n_arr, extras):
             enc_pg = pg_enc[pg]
         didx = None
         if vr is not None and dict_shapes:
-            didx = jnp.clip(R.hybrid_lookup(get_bytes(), pos, *vr),
+            didx = jnp.clip(R.hybrid_lookup(words, pos, *vr),
                             0, dict_shapes[0][0][0] - 1)
         if kind == "str":
             if has_slen:
@@ -796,7 +801,7 @@ def _encoded_decode_body(layout: Tuple, cap: int, words, n_arr, extras):
             # DELTA_BINARY_PACKED: per-value deltas from the miniblock
             # run table, reconstructed by a per-page segmented
             # prefix-sum off the page's first_value
-            d_raw = R.delta_lookup(get_bytes(), pos, *dr)
+            d_raw = R.delta_lookup(words, pos, *dr)
             with jax.named_scope("decode_delta"):
                 d_contrib = jnp.where(
                     (enc_pg == PGE_DELTA) & (pos > pg_start), d_raw, 0)

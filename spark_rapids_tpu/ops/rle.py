@@ -17,26 +17,30 @@ fuse them into ONE decode program per scan batch:
   sum): fields by prefix sum. No per-lane search, no loop, no gather
   through a run's index. The lane then either keeps the run's RLE
   value or bit-gathers from the packed words.
+- ``read_packed``: a bit-packed value of width <= 32 at any bit offset
+  lies inside two ALIGNED 32-bit words (phase <= 31, 31 + 32 <= 64
+  bits), so a lane reads two elements of the int32 staging words
+  themselves and shifts them together in unsigned 32-bit lanes; the
+  staging buffer is not expanded to bytes for it (PERF.md §6, PR 33:
+  on the chip a gather costs by the element gathered).
 - ``read_le`` / ``read_be_signed`` / ``read_be_limbs``: PLAIN
   fixed-width and FIXED_LEN_BYTE_ARRAY (decimal) reinterpretation at
   arbitrary byte offsets.
 
-All functions are shape-polymorphic trace-time helpers: they take the
-byte array as an int32 array (one byte per element, the form
-``bytes_of_words`` produces from the packed int32 staging words) and
-int64 offset arrays, and return int64 values. Callers mask invalid
-lanes afterwards; out-of-range offsets are clipped, never trapped.
+All functions are shape-polymorphic trace-time helpers. The bit-packed
+readers (``read_packed``, ``read_packed64``, ``hybrid_lookup``,
+``delta_lookup``) take the packed int32 staging ``words`` and int64
+BIT offsets into them; the byte readers take the byte array as an
+int32 array (one byte per element, the form ``bytes_of_words``
+produces from the staging words) and int64 byte offsets. All return
+int64 values. Callers mask invalid lanes afterwards; out-of-range
+offsets are clipped, never trapped.
 """
 
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-# A bit-packed value of width <= 32 plus a 0..7 bit phase spans at most
-# 5 bytes; gathering a fixed 5-byte window keeps the kernel one fused
-# gather + shift instead of a data-dependent loop.
-_PACKED_WINDOW = 5
 
 
 def bytes_of_words(words: jax.Array) -> jax.Array:
@@ -53,19 +57,38 @@ def _gather_window(bytes_all: jax.Array, byte_off: jax.Array,
     return bytes_all[jnp.clip(idx, 0, nb - 1)].astype(jnp.int64)
 
 
-def read_packed(bytes_all: jax.Array, bit_off: jax.Array,
+def read_packed(words: jax.Array, bit_off: jax.Array,
                 width: jax.Array) -> jax.Array:
     """Extract ``width``-bit little-endian values at arbitrary bit
-    offsets (the Parquet bit-packed layout). width may vary per lane
-    (dictionary index width differs across pages); width <= 32."""
-    byte0 = bit_off >> 3
-    shift = bit_off & 7
+    offsets into the int32 staging ``words`` (the Parquet bit-packed
+    layout). width may vary per lane (dictionary index width differs
+    across pages); width <= 32, and width == 0 reads 0 (an RLE run
+    reads zero packed bits: ``hybrid_lookup`` relies on it).
+
+    The value starts at phase ``s = bit_off & 31`` of word
+    ``bit_off >> 5`` and ends inside the next one, so two aligned
+    words a lane hold it: ``(w0 >> s) | (w1 << (32 - s))`` in unsigned
+    32-bit (the staging words are int32 and may be negative: the
+    shifts are logical). The word index goes down to int32, exact for
+    staging buffers up to 8 GiB; an index outside the buffer is
+    clipped, so a lane whose value starts before the buffer or runs
+    past it reads garbage the caller masks."""
+    nw = words.shape[0]
+    i0 = (bit_off >> 5).astype(jnp.int32)
+    s = (bit_off & 31).astype(jnp.uint32)
     with jax.named_scope("window"):
-        win = _gather_window(bytes_all, byte0, _PACKED_WINDOW)
-        k = jnp.arange(_PACKED_WINDOW, dtype=jnp.int64) * 8
-        word = jnp.sum(win << k, axis=1)
-    mask = (jnp.int64(1) << width.astype(jnp.int64)) - 1
-    return (word >> shift) & mask
+        w0 = jax.lax.bitcast_convert_type(
+            words[jnp.clip(i0, 0, nw - 1)], jnp.uint32)
+        w1 = jax.lax.bitcast_convert_type(
+            words[jnp.clip(i0 + 1, 0, nw - 1)], jnp.uint32)
+        # w1 << (32 - s) as two shifts: a shift by 32 (s == 0) is not
+        # defined, 31 then 1 is, and leaves the 0 that phase wants
+        v = (w0 >> s) | ((w1 << (31 - s)) << 1)
+    w = width.astype(jnp.uint32)
+    # width 32 is all ones: 1 << 32 does not fit the lane
+    mask = jnp.where(w >= 32, jnp.uint32(0xFFFFFFFF),
+                     (jnp.uint32(1) << w) - 1)
+    return (v & mask).astype(jnp.int64)
 
 
 def run_index(out_start: jax.Array, cap: int) -> jax.Array:
@@ -150,7 +173,7 @@ def _run_fields(pos: jax.Array, out_start: jax.Array,
         return base + pos * w, w, v
 
 
-def hybrid_lookup(bytes_all: jax.Array, pos: jax.Array,
+def hybrid_lookup(words: jax.Array, pos: jax.Array,
                   out_start: jax.Array, packed: jax.Array,
                   value: jax.Array, bit_start: jax.Array,
                   width: jax.Array) -> jax.Array:
@@ -170,22 +193,22 @@ def hybrid_lookup(bytes_all: jax.Array, pos: jax.Array,
         pos, out_start, bit_start, jnp.where(packed, width, 0),
         jnp.where(packed, 0, value))
     with jax.named_scope("decode_bits"):
-        return read_packed(bytes_all, bit_off, w) | rle_value
+        return read_packed(words, bit_off, w) | rle_value
 
 
-def read_packed64(bytes_all: jax.Array, bit_off: jax.Array,
+def read_packed64(words: jax.Array, bit_off: jax.Array,
                   width: jax.Array) -> jax.Array:
     """``read_packed`` for widths up to 64 (DELTA_BINARY_PACKED
     miniblocks store deltas at any width): the value is assembled from
     two <=32-bit reads so every intermediate fits an int64 without
     shift overflow. width may vary per lane; width == 0 reads 0."""
     w = width.astype(jnp.int64)
-    lo = read_packed(bytes_all, bit_off, jnp.minimum(w, 32))
-    hi = read_packed(bytes_all, bit_off + 32, jnp.maximum(w - 32, 0))
+    lo = read_packed(words, bit_off, jnp.minimum(w, 32))
+    hi = read_packed(words, bit_off + 32, jnp.maximum(w - 32, 0))
     return lo | (hi << 32)
 
 
-def delta_lookup(bytes_all: jax.Array, pos: jax.Array,
+def delta_lookup(words: jax.Array, pos: jax.Array,
                  out_start: jax.Array, packed: jax.Array,
                  value: jax.Array, bit_start: jax.Array,
                  width: jax.Array) -> jax.Array:
@@ -200,7 +223,7 @@ def delta_lookup(bytes_all: jax.Array, pos: jax.Array,
     bit_off, w, min_delta = _run_fields(pos, out_start, bit_start, width,
                                         value)
     with jax.named_scope("decode_bits"):
-        raw = read_packed64(bytes_all, bit_off, w)
+        raw = read_packed64(words, bit_off, w)
         return min_delta + raw
 
 

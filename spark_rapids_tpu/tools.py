@@ -1740,9 +1740,11 @@ def generate_observability_docs() -> str:
         "(`agg_inputs`, `groupby_sort`, `groupby_reduce`, `compact`,",
         "`agg_result`) and the lanes of the Parquet page decode",
         "(`srt_decode`: `decode_page_lookup`,",
-        "`decode_bits` with `/bytes` (staging words to bytes),",
+        "`decode_bits` with `/bytes` (staging words to bytes, for the",
+        "lanes that read bytes: PLAIN, BYTE_STREAM_SPLIT, strings),",
         "`/run_fields` (a run's fields to its lanes by prefix sum)",
-        "and `/window` (the 5-byte gather a packed value spans) inside",
+        "and `/window` (the two aligned staging words a packed value",
+        "lies in, gathered and shifted together) inside",
         "it, `decode_dict`, `decode_plain`, `decode_chars`,",
         "`decode_delta`, `decode_rows` — docs/scan.md §1); scopes are",
         "op_name metadata and change no compiled code. The tpu-lint",

@@ -686,7 +686,7 @@ def _uvarint(v: int) -> bytes:
 def test_hybrid_lookup_kernel_matches_oracle(width):
     # every width of read_packed's contract (<= 32): value k of a
     # packed group sits at bit k * width, so the odd widths put values
-    # at every shift of the 5-byte window, 31 bits at shift 7 among them
+    # at every phase of a staging word, across its edge among them
     import jax.numpy as jnp
 
     from spark_rapids_tpu.ops import rle as R
@@ -694,13 +694,11 @@ def test_hybrid_lookup_kernel_matches_oracle(width):
     vals = rng.integers(0, 1 << width, 300)
     vals[40:200] = vals[40]  # force an RLE run
     payload, runs = _hybrid_stream(vals, width)
-    words = np.zeros((len(payload) + 3) // 4 * 4, dtype=np.uint8)
-    words[:len(payload)] = payload
-    bytes_all = R.bytes_of_words(jnp.asarray(words.view(np.int32)))
+    words = _words_arr(payload)
     arrs = [jnp.asarray(a) for a in runs.arrays(
         max(8, 1 << (len(runs) - 1).bit_length()))]
     pos = jnp.arange(len(vals), dtype=jnp.int64)
-    got = np.asarray(R.hybrid_lookup(bytes_all, pos, *arrs))
+    got = np.asarray(R.hybrid_lookup(words, pos, *arrs))
     # unsigned: a 32-bit value may come back through an int32 field
     assert np.array_equal(got.astype(np.int64) & 0xFFFFFFFF, vals)
 
@@ -743,13 +741,17 @@ def test_dense_ranks_kernel():
     assert got.tolist() == [0, 0, 1, 2, 2, 3]
 
 
-def _bytes_arr(payload: bytes):
+def _words_arr(payload: bytes):
     import jax.numpy as jnp
 
-    from spark_rapids_tpu.ops import rle as R
     words = np.zeros((len(payload) + 3) // 4 * 4, dtype=np.uint8)
     words[:len(payload)] = np.frombuffer(payload, dtype=np.uint8)
-    return R.bytes_of_words(jnp.asarray(words.view(np.int32)))
+    return jnp.asarray(words.view(np.int32))
+
+
+def _bytes_arr(payload: bytes):
+    from spark_rapids_tpu.ops import rle as R
+    return R.bytes_of_words(_words_arr(payload))
 
 
 @pytest.mark.parametrize("width", [33, 40, 47, 48, 56, 63, 64])
@@ -766,10 +768,10 @@ def test_read_packed64_wide_widths(width):
     for k, v in enumerate(vals):
         bits |= v << (k * width)
     payload = bits.to_bytes((len(vals) * width + 7) // 8 + 8, "little")
-    ba = _bytes_arr(payload)
+    words = _words_arr(payload)
     off = jnp.asarray(np.arange(len(vals), dtype=np.int64) * width)
     w = jnp.full(len(vals), width, dtype=jnp.int64)
-    got = np.asarray(R.read_packed64(ba, off, w)).astype(np.uint64)
+    got = np.asarray(R.read_packed64(words, off, w)).astype(np.uint64)
     want = np.array(vals, dtype=np.uint64)
     assert np.array_equal(got, want)
 

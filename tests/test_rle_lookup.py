@@ -2,7 +2,11 @@
 ops/rle.step_fields): one scatter of the table's starts and one prefix
 sum must give what a per-lane binary search gives, one scatter of a
 field's steps and one prefix sum what a gather through that search
-gives, and the decode program must hold no loop and no gather by run."""
+gives, and the decode program must hold no loop and no gather by run.
+Then the bit-packed read (ops/rle.read_packed): two aligned staging
+words a lane must give what Python integers over the bytes give, at
+every width and phase, and the decode program must gather two elements
+a lane a hybrid stream, not a byte window."""
 
 import collections
 
@@ -161,6 +165,128 @@ def test_run_index_through_dense_ranks(case):
             (case, starts)
 
 
+# -- the bit-packed read: two aligned staging words a lane -------------------
+
+def _staging(kind, nw, rng):
+    """``nw`` int32 staging words; every kind but ``random`` has the
+    top bit of every word set, so an arithmetic shift would show."""
+    if kind == "random":
+        u = rng.integers(0, 1 << 32, nw, dtype=np.uint64)
+    elif kind == "top_bit_set":
+        u = rng.integers(0, 1 << 32, nw, dtype=np.uint64) | 0x80000000
+    elif kind == "all_ones":
+        u = np.full(nw, 0xFFFFFFFF, dtype=np.uint64)
+    else:
+        raise AssertionError(kind)
+    return u.astype(np.uint32).view(np.int32)
+
+
+def _plain_read(words, bit_off, width):
+    """The value Python integers give: the buffer's bytes as ONE
+    little-endian integer, shifted and masked."""
+    whole = int.from_bytes(words.tobytes(), "little")
+    return [(whole >> int(o)) & ((1 << int(w)) - 1)
+            for o, w in zip(bit_off, width)]
+
+
+def _read(fn, words, bit_off, width):
+    got = fn(jnp.asarray(words), jnp.asarray(bit_off, dtype=jnp.int64),
+             jnp.asarray(width, dtype=jnp.int64))
+    assert got.dtype == jnp.int64 and got.shape == (len(bit_off),)
+    return np.asarray(got)
+
+
+_NW = 12
+
+
+@pytest.mark.parametrize("phase", range(32))
+@pytest.mark.parametrize("width", range(33))
+def test_read_packed_every_width_at_every_phase(width, phase):
+    """Every word the value can start in, last word included where it
+    still ends inside the buffer, over words with the top bit set."""
+    rng = np.random.default_rng(width * 32 + phase)
+    words = _staging("top_bit_set" if (width + phase) % 2 else "random",
+                     _NW, rng)
+    off = np.array([32 * k + phase for k in range(_NW)
+                    if 32 * k + phase + width <= 32 * _NW], dtype=np.int64)
+    assert len(off) >= _NW - 1
+    w = np.full(len(off), width)
+    got = _read(R.read_packed, words, off, w)
+    assert got.tolist() == _plain_read(words, off, w), (width, phase)
+    if width == 0:
+        assert not got.any()
+
+
+@pytest.mark.parametrize("kind", ["random", "top_bit_set", "all_ones"])
+@pytest.mark.parametrize("width", range(1, 33))
+def test_read_packed_value_ending_on_the_buffers_last_bit(width, kind):
+    """The lane's second word does not exist: its index is clipped and
+    whatever it reads must be masked away. Beside it the same width
+    ending on the last bit of the word before."""
+    words = _staging(kind, _NW, np.random.default_rng(width))
+    off = np.array([32 * _NW - width, 32 * (_NW - 1) - width])
+    w = np.full(2, width)
+    got = _read(R.read_packed, words, off, w)
+    assert got.tolist() == _plain_read(words, off, w), (width, kind)
+
+
+@pytest.mark.parametrize("phase", [0, 31])
+@pytest.mark.parametrize("kind", ["random", "top_bit_set", "all_ones"])
+def test_read_packed_width_32_at_the_edges_of_a_word(kind, phase):
+    """Width 32 is the mask that ``1 << 32`` cannot make; phase 0 the
+    shift by 32 that is not defined; phase 31 a value with one bit in
+    its first word."""
+    words = _staging(kind, _NW, np.random.default_rng(phase))
+    off = np.array([32 * k + phase for k in range(_NW - 1)])
+    w = np.full(len(off), 32)
+    got = _read(R.read_packed, words, off, w)
+    assert got.tolist() == _plain_read(words, off, w)
+    assert (got >= 0).all() and (got < (1 << 32)).all()
+
+
+def test_read_packed_widths_mixed_across_lanes():
+    rng = np.random.default_rng(5)
+    words = _staging("top_bit_set", 256, rng)
+    w = rng.integers(0, 33, 4000)
+    off = rng.integers(0, 256 * 32 - 32, 4000)
+    got = _read(R.read_packed, words, off, w)
+    assert got.tolist() == _plain_read(words, off, w)
+
+
+@pytest.mark.parametrize("width", range(33, 65))
+def test_read_packed64_wide_widths_at_every_phase(width):
+    rng = np.random.default_rng(width)
+    words = _staging("top_bit_set", _NW, rng)
+    off = np.array([32 * k + phase for phase in range(32)
+                    for k in range(_NW)
+                    if 32 * k + phase + width <= 32 * _NW])
+    w = np.full(len(off), width)
+    got = _read(R.read_packed64, words, off, w).astype(np.uint64)
+    want = np.array(_plain_read(words, off, w), dtype=np.uint64)
+    assert np.array_equal(got, want), width
+
+
+@pytest.mark.parametrize("fn", ["read_packed", "read_packed64"])
+@pytest.mark.parametrize("case,off", [
+    ("negative", [-1, -31, -32, -33, -(1 << 40), -(1 << 62)]),
+    ("past_the_end", [32 * _NW, 32 * _NW + 7, 1 << 36, 1 << 40,
+                      (1 << 62) + 5, (1 << 63) - 1]),
+    ("running_past_the_end", [32 * _NW - 1, 32 * _NW - 8,
+                              32 * _NW - 31]),
+])
+def test_packed_reads_clip_offsets_outside_the_buffer(case, off, fn):
+    """Garbage lanes (callers mask them) must come back, not trap: an
+    index outside the buffer is clipped."""
+    words = _staging("top_bit_set", _NW, np.random.default_rng(1))
+    top = 32 if fn == "read_packed" else 64
+    for width in (0, 1, 17, top):
+        got = _read(getattr(R, fn), words, np.array(off, dtype=np.int64),
+                    np.full(len(off), width))
+        if width < 64:
+            assert (got >= 0).all() and (got < (1 << width)).all(), \
+                (case, width)
+
+
 # -- structural guard: no loop in the decode program -------------------------
 
 _RUN_DTYPES = ("int64", "bool", "int64", "int64", "int64")
@@ -240,15 +366,18 @@ def _abstract_extras(layout, cap):
     return out
 
 
-def _primitives(jaxpr, seen=None):
-    """How often each primitive occurs in a jaxpr, sub-jaxprs (pjit,
-    cond branches, loop bodies, custom calls) included."""
-    seen = collections.Counter() if seen is None else seen
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, sub-jaxprs (pjit, cond branches, loop
+    bodies, custom calls) included."""
     for eqn in jaxpr.eqns:
-        seen[eqn.primitive.name] += 1
+        yield eqn
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            _primitives(sub, seen)
-    return seen
+            yield from _eqns(sub)
+
+
+def _primitives(jaxpr):
+    """How often each primitive occurs in a jaxpr."""
+    return collections.Counter(e.primitive.name for e in _eqns(jaxpr))
 
 
 def test_guard_sees_the_search_it_guards_against():
@@ -259,21 +388,22 @@ def test_guard_sees_the_search_it_guards_against():
 
 
 # ``gather`` primitives each layout's decode program holds now that no
-# field of a run is read through the run's index (five fewer a hybrid
-# stream, four fewer a DELTA stream, than with the gathers by ``rid``:
-# q1's seven streams held 67). What is left: the 5-byte window of each
-# stream, the dictionary reads, the PLAIN/BSS byte windows, the page
+# field of a run is read through the run's index and a packed value is
+# read from two aligned staging words (two gathers of one element a
+# hybrid stream, four a DELTA stream, where the byte window was one
+# gather of five: q1's seven streams held 32). What is left beside
+# them: the dictionary reads, the PLAIN/BSS byte windows, the page
 # tables' ``dense_start[pg]`` / ``pg_enc[pg]`` / ``plain_byte[pg]`` /
 # ``pg_first[pg]`` and the row gather through ``j``.
 _GATHERS = {
-    "q1_sf1": 32,
-    "nullable_dict_and_plain": 8,
-    "string_with_lengths": 11,
-    "delta_binary_packed": 8,
-    "nullable_bool": 3,
-    "dec128_plain_and_dict": 11,
+    "q1_sf1": 39,
+    "nullable_dict_and_plain": 10,
+    "string_with_lengths": 13,
+    "delta_binary_packed": 11,
+    "nullable_bool": 5,
+    "dec128_plain_and_dict": 13,
     "byte_stream_split_f64": 7,
-    "host_column_beside_a_device_one": 8,
+    "host_column_beside_a_device_one": 10,
 }
 
 
@@ -301,3 +431,25 @@ def test_decode_program_gathers_no_field_of_a_run(name):
     by ``rid`` that came back would raise it)."""
     count = _primitives(_decode_jaxpr(name))["gather"]
     assert count == _GATHERS[name], (name, count)
+
+
+def _gathered_elements(jaxpr, scope):
+    """Elements all ``gather`` equations under a named scope put out."""
+    return sum(v.aval.size for eqn in _eqns(jaxpr)
+               if eqn.primitive.name == "gather"
+               and scope in str(eqn.source_info.name_stack)
+               for v in eqn.outvars)
+
+
+@pytest.mark.parametrize("name", sorted(_LAYOUTS))
+def test_packed_read_gathers_two_elements_a_lane(name):
+    """The static proof that a packed value is read from two aligned
+    words, however the two are spelled: under ``decode_bits/window`` a
+    hybrid stream (definition levels, dictionary indices, booleans)
+    gathers 2 elements a lane and a DELTA stream 4 (two reads of up
+    to 32 bits). A byte window that came back would gather 5 and 10."""
+    layout, cap = _LAYOUTS[name]
+    want = sum(2 * bool(ent[6]) + 2 * bool(ent[7]) + 4 * bool(ent[8])
+               for ent in layout if ent[0] == "dev")
+    got = _gathered_elements(_decode_jaxpr(name), "decode_bits/window")
+    assert got == want * cap, (name, got / cap, want)
