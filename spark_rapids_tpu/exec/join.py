@@ -2,9 +2,12 @@
 (GpuShuffledHashJoinExec.scala / GpuBroadcastHashJoinExec.scala twins over
 the count-then-gather kernel in ops/join.py).
 
-Residual (non-equi) conditions are applied as a device filter over the
-joined pairs — valid for inner/cross joins only; the rewrite tags
-conditional outer joins back to CPU (the reference compiles those to AST
+Residual (non-equi) conditions run on the device for four join types:
+inner and cross joins filter the joined pairs, left semi and left anti
+joins (a decorrelated ``[NOT] EXISTS``) evaluate the condition over each
+left row's candidate build rows inside the mask program
+(``ops/join.py: srt_join_cond_mask``). The rewrite tags outer joins with
+a residual back to the CPU by name (the reference compiles those to AST
 filters inside cudf's join, a complexity this design doesn't need yet).
 """
 
@@ -25,6 +28,10 @@ from spark_rapids_tpu.sql import physical as P
 from spark_rapids_tpu.sql import types as T
 
 
+# join types whose residual condition the device evaluates
+CONDITIONAL_JOINS = ("inner", "cross") + MASK_JOINS
+
+
 def is_device_join(join_type: str, left_keys: List[E.Expression],
                    right_keys: List[E.Expression],
                    condition: Optional[E.Expression],
@@ -32,9 +39,10 @@ def is_device_join(join_type: str, left_keys: List[E.Expression],
     """Tagging helper: None when the join can run on device."""
     if join_type not in PAIR_JOINS + MASK_JOINS:
         return f"join type {join_type} is not supported on TPU"
-    if condition is not None and join_type not in ("inner", "cross"):
+    if condition is not None and join_type not in CONDITIONAL_JOINS:
         return (f"conditional {join_type} join runs on CPU (residual "
-                "conditions are device-filtered for inner joins only)")
+                "conditions run on the device for inner, cross, left "
+                "semi and left anti joins; outer joins with one do not)")
     if condition is not None:
         r = X.is_device_expr(condition, conf)
         if r:
@@ -143,18 +151,34 @@ class TpuShuffledHashJoinExec(TpuExec):
         else:
             out_schema = self._pair_schema()
         from spark_rapids_tpu import retry as R
+        from spark_rapids_tpu import trace as TR
+        cond = None if self.condition is None else E.bind_references(
+            self.condition, self._pair_attrs())
+        if cond is not None and self.join_type in MASK_JOINS:
+            # the condition decides inside the mask program; a left row's
+            # verdict reads no other left row, so an OOM may halve the
+            # stream side
+            self.metrics.create(M.JOIN_CONDITIONAL_COUNT,
+                                M.ESSENTIAL).add(1)
+            with self.metrics.timed(M.JOIN_TIME, chip=TR.chip_of(lwhole)), \
+                    self.metrics.timed(M.JOIN_CONDITION_TIME):
+                pieces = R.with_split_retry(
+                    lwhole, lambda piece: device_join(
+                        piece, rwhole, lk, rk, self.join_type, out_schema,
+                        null_safe=self.null_safe, metrics=self.metrics,
+                        condition=cond),
+                    self.conf, self.metrics)
+            yield from pieces
+            return
 
         def attempt():
             out = device_join(lwhole, rwhole, lk, rk, self.join_type,
                               out_schema, null_safe=self.null_safe,
                               fk_hint=fk_hint, metrics=self.metrics)
-            if self.condition is not None:
-                cond = E.bind_references(self.condition,
-                                         self._pair_attrs())
+            if cond is not None:
                 out = X.run_filter(cond, out)
             return out
 
-        from spark_rapids_tpu import trace as TR
         with self.metrics.timed(M.JOIN_TIME, chip=TR.chip_of(lwhole)):
             out = R.with_retry(attempt, self.conf, self.metrics)
         self._book_output(out)

@@ -81,6 +81,12 @@ AGG_GROUP_COUNT = "aggGroupCount"    # groups into the final aggregates
 JOIN_BUILD_ROWS = "joinBuildRows"    # build-side rows, once per build
 JOIN_OUTPUT_ROWS = "joinOutputRows"  # joined rows, where the count is known
 JOIN_DEMOTED_COUNT = "joinDemotedCount"  # shuffled joins run as broadcast
+# semi/anti joins under a residual condition (a decorrelated EXISTS)
+JOIN_CONDITIONAL_COUNT = "joinConditionalCount"  # conditional mask joins run
+JOIN_CONDITION_PAIRS = "joinConditionPairs"  # candidate pairs evaluated
+JOIN_CONDITION_TIME = "joinConditionTime"    # host wall around those joins
+# what the analysis rules of a statement did (sql/session.py)
+DECORRELATED_SUBQUERY_COUNT = "decorrelatedSubqueryCount"
 
 
 # ---------------------------------------------------------------------------
@@ -154,6 +160,21 @@ METRIC_DESCRIPTIONS: Dict[str, str] = {
     JOIN_DEMOTED_COUNT: "shuffled hash joins that adaptive execution ran "
                         "as broadcast joins because the materialized "
                         "build side was under the threshold",
+    JOIN_CONDITIONAL_COUNT: "left semi and left anti joins run under a "
+                            "residual condition (a decorrelated [NOT] "
+                            "EXISTS), once per stream chunk joined",
+    JOIN_CONDITION_PAIRS: "candidate pairs (left row, build row of its "
+                          "key) a semi or anti join's residual condition "
+                          "was evaluated on; read back once per chunk "
+                          "from the count program, after the mask "
+                          "program is enqueued",
+    JOIN_CONDITION_TIME: "host wall around the conditional semi/anti "
+                         "joins: count program, mask program, the "
+                         "pairs' read (ns; inside joinTime)",
+    DECORRELATED_SUBQUERY_COUNT: "correlated [NOT] EXISTS subqueries "
+                                 "the analysis rule turned into left "
+                                 "semi or left anti joins, once per "
+                                 "statement planned",
     PLAN_TIME: "host planning wall on the calling thread (ns): SQL "
                "parse, analysis, overrides, plan cache, fingerprints, up "
                "to execute_collect — once per query",

@@ -22,7 +22,12 @@ the sort/segment machinery the groupby and sort kernels already use:
    in one fused XLA program instead of cudf calls.
 
 Semi/anti joins never expand: they are pure mask updates on the left
-batch (m > 0 / m == 0), the cheapest possible form on this design.
+batch (m > 0 / m == 0), the cheapest possible form on this design. With
+a residual condition (a decorrelated ``EXISTS``: ``srt_join_cond_mask``)
+the count phase's extents name each left row's candidates, the
+condition is evaluated rank by rank over the left row and its j-th
+candidate, gathered through the right ordering, and OR-reduced onto the
+left row: ``max_m x cap_l`` gathered elements, no output bucket.
 """
 
 from __future__ import annotations
@@ -52,6 +57,7 @@ from spark_rapids_tpu.jit_cache import JitCache, named_jit, program_name
 _COUNT_CACHE = JitCache("joinCount")
 _GATHER_CACHE = JitCache("joinGather")
 _MASK_CACHE = JitCache("joinMask")
+_COND_MASK_CACHE = JitCache("joinCondMask")
 
 # tpu-lint: disable=jit-direct(single fixed 3-scalar stack program — one executable, bounded by construction)
 _stack3 = named_jit("srt_join_stack3",
@@ -329,6 +335,46 @@ def _build_mask_fn(lkeys: Tuple[E.Expression, ...],
     return named_jit("srt_join_mask", fn)
 
 
+def _build_cond_mask_fn(cond: E.Expression, n_left: int,
+                        used_r: Tuple[int, ...], n_right: int,
+                        join_type: str) -> Callable:
+    """Semi/anti mask under a residual ``cond`` (bound to the pair
+    layout, left columns then right). Left row i's candidates are the
+    build rows ``order_r[base[i] + j]``, ``j < m[i]`` (the count
+    phase's extents): rank j of every row is gathered at once, the
+    condition evaluated over the pair, and any-reduced onto the left
+    row. A pair passes only where the condition is true, not null
+    (Spark). ``used_r`` are the right columns the condition reads; the
+    others are never gathered."""
+    is_semi = join_type == "leftsemi"
+
+    def fn(cols_l, cols_r_used, active_l, lits, m, base, order_r, max_m):
+        cap_l = active_l.shape[0]
+        cap_r = order_r.shape[0]
+
+        def rank(j, found):
+            with jax.named_scope("join_cond/gather"):
+                has = j.astype(m.dtype) < m
+                at = jnp.clip(base + j.astype(base.dtype), 0, cap_r - 1)
+                ri = jnp.take(order_r, at.astype(jnp.int32))
+                got = take_columns(cols_r_used, jnp.where(has, ri, 0),
+                                   valid_at=has)
+            with jax.named_scope("join_cond/eval"):
+                pair: List = list(cols_l) + [None] * n_right
+                for k, c in zip(used_r, got):
+                    pair[n_left + k] = c
+                p = X.dev_eval(cond, X.Ctx(pair, cap_l, (cond,), lits))
+                passes = has & p.validity & X._as_bool(p)
+            with jax.named_scope("join_cond/reduce"):
+                return found | passes
+
+        found = jax.lax.fori_loop(
+            jnp.int32(0), max_m.astype(jnp.int32), rank,
+            jnp.zeros(cap_l, dtype=jnp.bool_))
+        return active_l & (found if is_semi else ~found)
+    return named_jit("srt_join_cond_mask", fn)
+
+
 def _align_string_caps(kl: Sequence[AnyDeviceColumn],
                        kr: Sequence[AnyDeviceColumn]):
     """Pad string key columns to a common char capacity so both sides
@@ -473,10 +519,13 @@ def device_join(left: DeviceBatch, right: DeviceBatch,
                 collect_matched_r: bool = False,
                 null_safe: Sequence[bool] = (),
                 fk_hint: bool = False,
-                metrics=None):
+                metrics=None,
+                condition: Optional[E.Expression] = None):
     """Run the equi-join of two device batches; keys are pre-bound device
     expressions. Returns the joined batch (pair layout: left columns then
-    right columns) or, for semi/anti, the masked left batch. With
+    right columns) or, for semi/anti, the masked left batch — under
+    ``condition`` (semi/anti only; bound to the pair layout) a left row
+    matches where some build row of its key also passes it. With
     ``collect_matched_r`` returns ``(batch, matched_r)`` where
     ``matched_r`` is the device bool mask of right rows that matched any
     left row — the exec's chunked right/full-outer path ORs these across
@@ -490,7 +539,7 @@ def device_join(left: DeviceBatch, right: DeviceBatch,
     lits_l = X.literal_values(list(lk))
     lits_r = X.literal_values(list(rk))
 
-    if join_type in MASK_JOINS:
+    if join_type in MASK_JOINS and condition is None:
         key = (struct, join_type)
         fn, _ = _MASK_CACHE.get_or_build(
             key, lambda: _build_mask_fn(lk, rk, join_type, nst))
@@ -500,7 +549,7 @@ def device_join(left: DeviceBatch, right: DeviceBatch,
         out = DeviceBatch(left.schema, left.columns, new_active, None)
         return (out, None) if collect_matched_r else out
 
-    if join_type not in PAIR_JOINS:
+    if join_type not in PAIR_JOINS + MASK_JOINS:
         raise X.DeviceUnsupported(f"join type {join_type}")
 
     ckey = (struct, join_type)
@@ -513,6 +562,32 @@ def device_join(left: DeviceBatch, right: DeviceBatch,
          extra_order, matched_r) = count_fn(
              left.columns, left.active, lits_l,
              right.columns, right.active, lits_r)
+
+    if join_type in MASK_JOINS:
+        # the count phase's extents feed the mask program on the device
+        # (max_m too: nothing sizes it); the host reads only the
+        # candidate pairs, for the counter, once the mask is enqueued
+        n_left = len(left.columns)
+        used_r = tuple(sorted({b.ordinal - n_left for b in condition.collect(
+            lambda e: isinstance(e, E.BoundReference))
+            if b.ordinal >= n_left}))
+        mask_fn, _ = _COND_MASK_CACHE.get_or_build(
+            (X.expr_key(condition), n_left, used_r, join_type, salt),
+            lambda: _build_cond_mask_fn(condition, n_left, used_r,
+                                        len(right.columns), join_type))
+        with G.nan_scope(salt[0]):
+            new_active = mask_fn(
+                left.columns, [right.columns[k] for k in used_r],
+                left.active, X.literal_values([condition]), m, base,
+                order_r, max_m)
+        if metrics is not None:
+            from spark_rapids_tpu import metrics as M
+            with TR.device_sync("joinCondPairs", metrics):
+                pairs = int(np.asarray(total_pairs))
+            metrics.create(M.JOIN_CONDITION_PAIRS, M.ESSENTIAL).add(pairs)
+        out = DeviceBatch(left.schema, left.columns, new_active, None)
+        return (out, None) if collect_matched_r else out
+
     shapes = (tuple((a.shape, str(a.dtype))
                     for c in left.columns for a in c.arrays()),
               tuple((a.shape, str(a.dtype))

@@ -3971,6 +3971,59 @@ class InSubquery(Expression):
         return f"{self.value!r} IN (subquery)"
 
 
+class OuterReference(Expression):
+    """A column of the enclosing query read inside a subquery (Catalyst
+    OuterReference): the parser wraps the outer attribute where a name
+    in a subquery's WHERE resolves only against the enclosing FROM. The
+    wrapper keeps the two sides of a self-join apart until
+    ``logical.rewrite_joins_and_subqueries`` lifts the conjunct into the
+    join's condition and unwraps it; it never reaches planning."""
+
+    def __init__(self, attr: "AttributeReference"):
+        self.children = [attr]
+
+    @property
+    def attr(self) -> "AttributeReference":
+        return self.children[0]
+
+    @property
+    def data_type(self) -> T.DataType:
+        return self.attr.data_type
+
+    @property
+    def nullable(self) -> bool:
+        return self.attr.nullable
+
+    def __repr__(self) -> str:
+        return f"outer({self.attr!r})"
+
+
+class Exists(Expression):
+    """``EXISTS (SELECT ...)`` as the parser leaves it (Catalyst Exists);
+    ``NOT EXISTS`` is ``Not`` over it. The children are the outer
+    attributes the subquery reads (its ``OuterReference`` nodes), so
+    ``references()`` says which relation of the outer query it belongs
+    to. As a conjunct of WHERE or HAVING the analysis rule
+    ``logical.rewrite_joins_and_subqueries`` turns it into a left semi
+    (or left anti) join and refuses every other position by name — this
+    node never reaches planning, so it has no ``eval``."""
+
+    def __init__(self, plan, outer: List["AttributeReference"]):
+        self.children = list(outer)
+        self.plan = plan
+
+    @property
+    def data_type(self) -> T.DataType:
+        return T.BooleanT
+
+    @property
+    def nullable(self) -> bool:
+        return False
+
+    def __repr__(self) -> str:
+        return "EXISTS (subquery)"
+
+
 def materialize_scalar_subqueries(plan, session):
     """Replace every ScalarSubquery with the Literal it evaluates to
     (executing each subquery ONCE per query, like Spark's subquery

@@ -465,24 +465,43 @@ def _is_comma_join(p: LogicalPlan) -> bool:
         and p.condition is None
 
 
-def _has_in_subquery(e: Expression) -> bool:
-    from spark_rapids_tpu.sql.expressions import InSubquery
-    return bool(e.collect(lambda x: isinstance(x, InSubquery)))
+def _has_subquery_predicate(e: Expression) -> bool:
+    from spark_rapids_tpu.sql.expressions import Exists, InSubquery
+    return bool(e.collect(lambda x: isinstance(x, (InSubquery, Exists))))
+
+
+def outer_references(p: LogicalPlan) -> List[AttributeReference]:
+    """The enclosing query's attributes that the expressions of ``p``
+    read (one an ``expr_id``, in the order met). A nested subquery's own
+    outer references belong to the query it stands in and are not walked
+    into: its ``Exists`` node holds them as plain attributes."""
+    from spark_rapids_tpu.sql.expressions import OuterReference
+    out: List[AttributeReference] = []
+
+    def walk(q: LogicalPlan) -> None:
+        for e in node_expressions(q):
+            for o in e.collect(lambda x: isinstance(x, OuterReference)):
+                if o.attr.expr_id not in _ids(out):
+                    out.append(o.attr)
+        for c in q.children:
+            walk(c)
+    walk(p)
+    return out
 
 
 def needs_rewrite(p: LogicalPlan) -> bool:
     """Whether ``rewrite_joins_and_subqueries`` has anything to do: the
-    one walk a statement with neither form pays."""
+    one walk a statement with none of its forms pays."""
     if isinstance(p, Filter) and _is_comma_join(p.child):
         return True
-    if any(_has_in_subquery(e) for e in node_expressions(p)):
+    if any(_has_subquery_predicate(e) for e in node_expressions(p)):
         return True
     return any(needs_rewrite(c) for c in p.children)
 
 
 def rewrite_joins_and_subqueries(plan: LogicalPlan) -> LogicalPlan:
-    """Two rules, copy-on-write (a DataFrame's plan is planned again by
-    every action):
+    """Three rules, copy-on-write (a DataFrame's plan is planned again
+    by every action):
 
     - a ``WHERE`` over a comma list of relations (cross joins without a
       condition) becomes inner joins: conjuncts that read one relation
@@ -494,13 +513,21 @@ def rewrite_joins_and_subqueries(plan: LogicalPlan) -> LogicalPlan:
     - an uncorrelated ``IN (subquery)`` that is a conjunct of a filter
       becomes a left semi join (RewritePredicateSubquery) on the side of
       the inner joins below that its value reads, so the joins above see
-      the rows it keeps. ``NOT IN (subquery)`` needs Spark's null-aware
-      anti join and an ``IN (subquery)`` anywhere else an existence
-      join: both raise NotImplementedError by name.
+      the rows it keeps.
+    - a correlated ``[NOT] EXISTS (subquery)`` that is a conjunct of a
+      filter is decorrelated (``_decorrelate``): the conjuncts of the
+      subquery's WHERE that read outer columns become the condition of a
+      left semi (``NOT EXISTS``: a plain left anti) join, its equalities
+      the keys and the rest the residual, placed like the ``IN``'s; the
+      filter's other conjuncts stay beneath that join.
 
-    Returns ``plan`` itself when neither has anything to do."""
+    ``NOT IN (subquery)`` needs Spark's null-aware anti join and a
+    subquery predicate anywhere but such a conjunct an existence join:
+    both raise NotImplementedError by name.
+
+    Returns ``plan`` itself when none has anything to do."""
     import copy
-    from spark_rapids_tpu.sql.expressions import InSubquery, Not
+    from spark_rapids_tpu.sql.expressions import Exists, InSubquery, Not
     p = plan
     new_children = [rewrite_joins_and_subqueries(c) for c in p.children]
     if new_children != p.children:
@@ -510,9 +537,13 @@ def rewrite_joins_and_subqueries(plan: LogicalPlan) -> LogicalPlan:
         if _is_comma_join(p.child):
             p = _join_comma_list(p)
         if isinstance(p, Filter):
-            p = _in_subqueries_to_semi_joins(p)
+            p = _subqueries_to_semi_joins(p)
     for e in node_expressions(p):
-        for x in e.collect(lambda x: isinstance(x, InSubquery)):
+        for x in e.collect(lambda x: isinstance(x, (InSubquery, Exists))):
+            if isinstance(x, Exists):
+                raise NotImplementedError(
+                    "EXISTS (subquery) is supported only as a conjunct "
+                    f"of WHERE or HAVING, not inside {e!r}")
             neg = e.collect(lambda n: isinstance(n, Not)
                             and n.children[0] is x)
             raise NotImplementedError(
@@ -534,11 +565,16 @@ def _join_comma_list(f: Filter) -> LogicalPlan:
     rel_ids = [_ids(r.output) for r in rels]
     pushed: List[List[Expression]] = [[] for _ in rels]
     rest: List[tuple] = []          # (conjunct, ids it reads)
+    above: List[Expression] = []
     for c in split_conjuncts(f.condition):
         refs = _ids(c.references())
         home = [i for i, ids in enumerate(rel_ids) if refs & ids]
         if len(home) == 1 and refs <= rel_ids[home[0]]:
             pushed[home[0]].append(c)
+        elif _has_subquery_predicate(c):
+            # never a join's condition: it stays a filter over the joins,
+            # whence _subqueries_to_semi_joins places it
+            above.append(c)
         else:
             rest.append((c, refs))
     for i, conds in enumerate(pushed):
@@ -565,29 +601,141 @@ def _join_comma_list(f: Filter) -> LogicalPlan:
     if [a.expr_id for a in joined.output] != \
             [a.expr_id for a in f.child.output]:
         joined = Project(list(f.child.output), joined)
-    above = _conjunction([c for c, _refs in rest])
+    above = _conjunction([c for c, _refs in rest] + above)
     return Filter(above, joined) if above is not None else joined
 
 
-def _in_subqueries_to_semi_joins(f: Filter) -> LogicalPlan:
+def _subqueries_to_semi_joins(f: Filter) -> LogicalPlan:
+    """The filter's ``IN (subquery)`` and ``[NOT] EXISTS (subquery)``
+    conjuncts as left semi and left anti joins over its child, in the
+    text's order; the conjuncts without a subquery stay a filter beneath
+    them (they read the left side alone, so the joins see fewer rows),
+    one with a subquery in any other position a filter above, for
+    ``rewrite_joins_and_subqueries`` to refuse by name."""
+    from spark_rapids_tpu import metrics as M
+    from spark_rapids_tpu import trace as TR
     from spark_rapids_tpu.sql.dataframe import _coerce_resolved
-    from spark_rapids_tpu.sql.expressions import EqualTo, InSubquery
-    child, rest = f.child, []
+    from spark_rapids_tpu.sql.expressions import (EqualTo, Exists,
+                                                  InSubquery, Not)
+    plain, refused, joins = [], [], []
     for c in split_conjuncts(f.condition):
-        if not isinstance(c, InSubquery) or _has_in_subquery(c.value):
-            rest.append(c)
-            continue
-        sub = rewrite_joins_and_subqueries(c.plan)
-        # the subquery may read a table the outer query reads too (Q18
-        # reads lineitem twice): fresh ids keep the two sides apart
-        if _ids(sub.output) & _plan_ids(child):
-            sub = Project([Alias(a, a.name) for a in sub.output], sub)
-        cond = _coerce_resolved(EqualTo(c.value, sub.output[0]))
-        child = _semi_join_below(child, _ids(c.value.references()),
-                                 sub, cond)
-    if child is f.child:
+        x = c.children[0] if isinstance(c, Not) else c
+        if isinstance(x, Exists) or (x is c and isinstance(x, InSubquery)
+                                     and not _has_subquery_predicate(
+                                         x.value)):
+            joins.append(("leftanti" if x is not c else "leftsemi", x))
+        elif _has_subquery_predicate(c):
+            refused.append(c)
+        else:
+            plain.append(c)
+    if not joins:
         return f
-    return Filter(_conjunction(rest), child) if rest else child
+    child = Filter(_conjunction(plain), f.child) if plain else f.child
+    for join_type, x in joins:
+        if isinstance(x, InSubquery):
+            sub = rewrite_joins_and_subqueries(x.plan)
+            # the subquery may read a table the outer query reads too
+            # (Q18 reads lineitem twice): fresh ids keep the sides apart
+            if _ids(sub.output) & _plan_ids(child):
+                sub = Project([Alias(a, a.name) for a in sub.output], sub)
+            cond = _coerce_resolved(EqualTo(x.value, sub.output[0]))
+            refs = _ids(x.value.references())
+        else:
+            with TR.span("plan", phase="decorrelate"):
+                sub, cond = _decorrelate(x)
+            M.query_registry().create(M.DECORRELATED_SUBQUERY_COUNT,
+                                      M.ESSENTIAL).add(1)
+            refs = _ids(x.references())
+        child = _semi_join_below(child, refs, sub, cond, join_type)
+    return Filter(_conjunction(refused), child) if refused else child
+
+
+def _decorrelate(x) -> tuple:
+    """``EXISTS (subquery)`` -> ``(build side, join condition)``. The
+    conjuncts of the subquery's own WHERE that read outer columns are
+    lifted into the condition (the planner takes its ``outer = inner``
+    equalities as keys and the rest as the residual); the others stay a
+    filter beneath the build side, which is cut to the columns the
+    condition reads under fresh ids (Q21 reads lineitem three times).
+    An outer column anywhere else, under OR or NOT, or with no equality
+    to key the join on, is refused by name."""
+    from spark_rapids_tpu.sql.expressions import (BinaryComparison, EqualTo,
+                                                  Not, Or, OuterReference)
+
+    def is_outer(e: Expression) -> bool:
+        return isinstance(e, OuterReference)
+
+    def refuse(what: str):
+        raise NotImplementedError(
+            f"correlated subquery: {what}; an outer column may be read "
+            "only in an AND-conjunct of the WHERE of an [NOT] EXISTS "
+            "(subquery), with at least one outer = inner equality")
+
+    node = x.plan
+    while isinstance(node, (Project, SubqueryAlias)):
+        node = node.child     # an EXISTS reads no column of its select list
+    lifted: List[Expression] = []
+    build = node
+    if isinstance(node, Filter):
+        kept = []
+        for c in split_conjuncts(node.condition):
+            (lifted if c.collect(is_outer) else kept).append(c)
+        build = Filter(_conjunction(kept), node.child) if kept \
+            else node.child
+    stray = outer_references(build)
+    if stray:
+        refuse(f"outer column {stray[0].name!r} is read beneath the "
+               "subquery's WHERE (an aggregate, a HAVING, a join or a "
+               "derived table)")
+    if not lifted:
+        raise NotImplementedError(
+            "uncorrelated EXISTS (subquery) is not supported: its WHERE "
+            "reads no column of the outer query")
+
+    def inner_side(e: Expression) -> Expression:
+        return e.transform(lambda n: Literal(None) if is_outer(n) else None)
+
+    def keys_the_join(c: Expression) -> bool:
+        if not isinstance(c, EqualTo):
+            return False
+        # (reads outer columns, reads inner columns) of each side
+        sides = sorted((bool(k.collect(is_outer)),
+                        bool(inner_side(k).references()))
+                       for k in c.children)
+        return sides == [(False, True), (True, False)]
+
+    inner: List[AttributeReference] = []
+    for c in lifted:
+        # `<>` parses to Not(EqualTo): a NOT over one comparison is plain
+        if c.collect(lambda e: e.collect(is_outer) and (
+                isinstance(e, Or) or isinstance(e, Not) and not
+                isinstance(e.children[0], BinaryComparison))):
+            refuse(f"an outer column under OR or NOT in {c!r}")
+        if _has_subquery_predicate(c):
+            refuse(f"a nested subquery beside an outer column in {c!r}")
+        for a in inner_side(c).references():
+            if a.expr_id not in _ids(inner):
+                inner.append(a)
+    if not any(keys_the_join(c) for c in lifted):
+        refuse(f"no outer = inner equality among {lifted!r} (a "
+               "nested-loop semi join)")
+    fresh = [Alias(a, a.name) for a in inner]
+    to_fresh = {a.expr_id: f.to_attribute() for a, f in zip(inner, fresh)}
+
+    def lift(e: Expression) -> Expression:
+        # top-down, so that an OuterReference's own attribute is never
+        # taken for the inner side's (a self-join shares expr_ids)
+        if is_outer(e):
+            return e.attr
+        if isinstance(e, AttributeReference):
+            return to_fresh[e.expr_id]
+        kids = [lift(k) for k in e.children]
+        if all(k is o for k, o in zip(kids, e.children)):
+            return e
+        return e.with_children(kids)
+
+    cond = _conjunction([lift(c) for c in lifted])
+    return Project(fresh, rewrite_joins_and_subqueries(build)), cond
 
 
 def _plan_ids(p: LogicalPlan) -> set:
@@ -597,21 +745,33 @@ def _plan_ids(p: LogicalPlan) -> set:
     return out
 
 
+def _accepts_semi_join(p: LogicalPlan, refs: set) -> bool:
+    """Whether an inner join beneath ``p``'s filters has a side that
+    holds all of ``refs``."""
+    if isinstance(p, Filter) and not _has_subquery_predicate(p.condition):
+        return _accepts_semi_join(p.child, refs)
+    return isinstance(p, Join) and p.join_type in ("inner", "cross") \
+        and any(refs <= _ids(side.output) for side in p.children)
+
+
 def _semi_join_below(p: LogicalPlan, refs: set, sub: LogicalPlan,
-                     cond: Expression) -> LogicalPlan:
-    """``p LEFT SEMI JOIN sub ON cond``, pushed through the filters and
-    inner joins of ``p`` to the side that holds all of ``refs``."""
+                     cond: Expression, join_type: str) -> LogicalPlan:
+    """``p LEFT SEMI`` (or ``ANTI``) ``JOIN sub ON cond``, pushed through
+    the inner joins of ``p``, and the filters over them, to the side
+    that holds all of ``refs`` (Catalyst's
+    PushLeftSemiLeftAntiThroughJoin); over a filter with no such join
+    beneath it the join stays above."""
     import copy
-    if refs:
-        if isinstance(p, Join) and p.join_type in ("inner", "cross"):
-            for i, side in enumerate(p.children):
-                if refs <= _ids(side.output):
-                    q = copy.copy(p)
-                    q.children = list(p.children)
-                    q.children[i] = _semi_join_below(side, refs, sub, cond)
-                    return q
-        elif isinstance(p, Filter) and not _has_in_subquery(p.condition):
-            q = copy.copy(p)
-            q.children = [_semi_join_below(p.child, refs, sub, cond)]
+    if refs and _accepts_semi_join(p, refs):
+        q = copy.copy(p)
+        q.children = list(p.children)
+        if isinstance(p, Filter):
+            q.children[0] = _semi_join_below(p.child, refs, sub, cond,
+                                             join_type)
             return q
-    return Join(p, sub, "leftsemi", cond)
+        for i, side in enumerate(p.children):
+            if refs <= _ids(side.output):
+                q.children[i] = _semi_join_below(side, refs, sub, cond,
+                                                 join_type)
+                return q
+    return Join(p, sub, join_type, cond)

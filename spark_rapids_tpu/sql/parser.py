@@ -16,11 +16,16 @@ rule ``logical.rewrite_joins_and_subqueries`` turns the conjuncts that
 connect two relations into inner joins and pushes the others to their
 relation. ``x [NOT] IN (SELECT ...)`` parses to ``InSubquery``: as a
 conjunct of WHERE or HAVING an uncorrelated ``IN`` becomes a left semi
-join by the same rule; ``NOT IN (SELECT ...)`` (Spark's null-aware anti
-join), an ``IN (SELECT ...)`` under OR/NOT or outside a filter, and a
-correlated subquery (one that reads the outer query's columns) are
-refused by name, never answered with other semantics. A token the
-grammar has no place for raises a ``ValueError`` that names it.
+join by the same rule. ``[NOT] EXISTS (SELECT ...)`` parses to
+``Exists``: a name in the subquery's WHERE that only the enclosing FROM
+resolves becomes an ``OuterReference``, and the same rule lifts those
+conjuncts into the condition of a left semi (``NOT EXISTS``: left anti)
+join. ``NOT IN (SELECT ...)`` (Spark's null-aware anti join), a subquery
+predicate under OR/NOT or outside a filter, an outer column read
+anywhere but a conjunct of an EXISTS subquery's WHERE (its select list,
+an aggregate, a HAVING, two levels up), and a correlated scalar or IN
+subquery are refused by name, never answered with other semantics. A
+token the grammar has no place for raises a ``ValueError`` that names it.
 
 Aggregation follows Spark's analyzer shape: aggregate subtrees in the
 select/having lists are extracted into an Aggregate node and the select
@@ -97,9 +102,9 @@ class _Parser:
         self.toks = _tokenize(text)
         self.i = 0
         self.session = session
-        # FROM relations of the enclosing SELECTs, innermost last: what
-        # a subquery's unresolved name is looked up in to call the
-        # subquery correlated rather than misspelt
+        # FROM relations of the SELECTs being parsed, innermost last:
+        # what a subquery's unresolved name is looked up in to call it
+        # an outer reference rather than misspelt
         self._outer: List = []
 
     # -- token helpers -----------------------------------------------------
@@ -178,7 +183,7 @@ class _Parser:
         self._outer.append(df)
         try:
             if self.kw("where"):
-                df = df.filter(self.expr())
+                df = df.filter(self._mark_outer_references(self.expr(), df))
             group: Optional[List[Column]] = None
             if self.kw("group", "by"):
                 group = [self.expr()]
@@ -438,31 +443,71 @@ class _Parser:
             else:
                 return left
 
-    def _subquery(self):
-        """``SELECT ...`` up to its closing parenthesis, resolved against
-        its own FROM alone. A name it cannot resolve that an enclosing
-        SELECT's FROM can is a correlated subquery: refused by name."""
+    def _resolves_in(self, name: E.UnresolvedAttribute, df) -> bool:
+        try:
+            df._resolve(Column(name))
+        except L.UnresolvedColumnError:
+            return False
+        return True
+
+    def _mark_outer_references(self, cond: Column, df) -> Column:
+        """The WHERE of a subquery: a name its own FROM (``df``) cannot
+        resolve and the enclosing SELECT's can becomes an
+        ``OuterReference`` over that attribute. One the enclosing
+        SELECT cannot resolve either but a farther one can is refused
+        by name; anything else is left for ``filter`` to report."""
+        enclosing = self._outer[:-1]
+        if not enclosing:
+            return cond
+
+        def rule(e: E.Expression) -> Optional[E.Expression]:
+            if not isinstance(e, E.UnresolvedAttribute) \
+                    or self._resolves_in(e, df):
+                return None
+            if self._resolves_in(e, enclosing[-1]):
+                return enclosing[-1]._resolve(Column(e)).transform(
+                    lambda a: E.OuterReference(a)
+                    if isinstance(a, E.AttributeReference) else None)
+            if any(self._resolves_in(e, o) for o in enclosing[:-1]):
+                raise NotImplementedError(
+                    f"correlated subquery: {e.name!r} is a column of a "
+                    "query two levels up; a subquery may read the "
+                    "columns of the query it stands in only")
+            return None
+        return Column(cond.expr.transform(rule))
+
+    def _subquery(self, what: str):
+        """``SELECT ...`` up to its closing parenthesis -> ``(DataFrame,
+        the outer attributes it reads)``. Outer columns are read in the
+        subquery's WHERE alone (``_mark_outer_references``), and only
+        EXISTS may read any: a name that fails to resolve elsewhere and
+        is an enclosing SELECT's, and a correlated ``what`` other than
+        EXISTS, are refused by name."""
         try:
             sub = self.query()
         except L.UnresolvedColumnError as e:
-            for outer in reversed(self._outer):
-                try:
-                    L.resolve(E.UnresolvedAttribute(e.column),
-                              outer.plan.output)
-                except KeyError:
-                    continue
+            name = E.UnresolvedAttribute(e.column)
+            if any(self._resolves_in(name, o) for o in self._outer):
                 raise NotImplementedError(
-                    f"correlated subquery: {e.column!r} is a column "
-                    "of the outer query; only uncorrelated subqueries "
-                    "are supported") from None
+                    f"correlated subquery: {e.column!r} is a column of "
+                    "the outer query; an outer column may be read only "
+                    "in an AND-conjunct of the WHERE of an [NOT] EXISTS "
+                    "(subquery), not in its select list, an aggregate, "
+                    "GROUP BY or HAVING") from None
             raise
         self.expect(")")
-        return sub
+        outer = L.outer_references(sub.plan)
+        if outer and what != "EXISTS":
+            raise NotImplementedError(
+                f"correlated subquery: {outer[0].name!r} is a column of "
+                f"the outer query; a correlated {what} subquery is not "
+                "supported (only [NOT] EXISTS may read outer columns)")
+        return sub, outer
 
     def _in_list(self, left: Column) -> Column:
         self.expect("(")
         if self.at_kw("select"):
-            sub = self._subquery()
+            sub, _outer = self._subquery("IN")
             out = sub.plan.output
             if len(out) != 1:
                 raise ValueError(
@@ -546,7 +591,7 @@ class _Parser:
                 # uncorrelated scalar subquery (Catalyst ScalarSubquery;
                 # materialized to a Literal before physical planning)
                 self.next()
-                sub = self._subquery()
+                sub, _outer = self._subquery("scalar")
                 out = sub.plan.output
                 if len(out) != 1:
                     raise ValueError(
@@ -576,6 +621,12 @@ class _Parser:
             return F.lit(low == "true")
         if low == "case":
             return self._case()
+        if low == "exists" and self.peek(1)[1] == "(" \
+                and self.peek(2)[1].lower() == "select":
+            self.next()
+            self.next()
+            sub, outer = self._subquery("EXISTS")
+            return Column(E.Exists(sub.plan, outer))
         if low in ("date", "timestamp") and self.peek(1)[0] == "str":
             # ANSI typed literals: DATE '1998-09-02' (Spark AstBuilder
             # visitTypeConstructor semantics = cast of the string)

@@ -190,9 +190,10 @@ class TpuSparkSession:
         from spark_rapids_tpu import udf_compiler
         from spark_rapids_tpu.sql.expressions import \
             materialize_scalar_subqueries
-        # comma lists -> inner joins, IN (subquery) -> left semi join
-        # (sql/logical.py); a statement with neither gets its own plan
-        # back from one walk, and no span
+        # comma lists -> inner joins, IN (subquery) -> left semi join,
+        # correlated [NOT] EXISTS -> left semi / anti join with its
+        # lifted condition (sql/logical.py); a statement with none gets
+        # its own plan back from one walk, and no span
         if L.needs_rewrite(plan):
             from spark_rapids_tpu import trace as TR
             with TR.span("plan", phase="subquery"):

@@ -1552,9 +1552,40 @@ def generate_supported_ops() -> str:
         "join) |",
         "| `(SELECT ...)` as a scalar, uncorrelated | executed once "
         "and substituted as a literal before planning |",
-        "| a subquery that reads a column of the outer query "
-        "(correlated) | refused: `NotImplementedError` naming the "
-        "column; a name that resolves nowhere stays a `KeyError` |",
+        "| `[NOT] EXISTS (SELECT ... WHERE outer = inner AND ...)`, a "
+        "conjunct of WHERE or HAVING | decorrelated: a name in the "
+        "subquery's WHERE that only the enclosing FROM resolves is an "
+        "outer reference; the top-level AND conjuncts that read outer "
+        "columns are lifted into the condition of a left semi "
+        "(`NOT EXISTS`: a plain left anti) join — its `outer = inner` "
+        "equalities the keys, every other lifted conjunct (`<>`, `<`, "
+        "an expression over several columns) the residual, evaluated "
+        "on the device over each row's candidate build rows; a pair "
+        "passes only where the residual is true, not null. The "
+        "subquery's other conjuncts stay a filter beneath the build "
+        "side, whose columns get fresh ids (a self-join reads one "
+        "table under several aliases); the outer filter's other "
+        "conjuncts stay beneath the join; placement as for `IN` "
+        "(PushLeftSemiLeftAntiThroughJoin) |",
+        "| an outer column read anywhere else in a subquery: its select "
+        "list, an aggregate, GROUP BY, HAVING, beneath an aggregate or "
+        "a join, under OR or NOT (a NOT over one comparison, `<>`, is "
+        "plain), two levels up | refused: `NotImplementedError` naming "
+        "the column or the conjunct; a name that resolves nowhere "
+        "stays a `KeyError` |",
+        "| a correlated scalar subquery (`= (SELECT min(...) WHERE "
+        "...)`, TPC-H Q2/Q17/Q20), a correlated `IN (SELECT ...)` | "
+        "refused: `NotImplementedError` naming the column and the "
+        "form |",
+        "| `EXISTS` under OR/NOT NOT/CASE or outside a filter; a "
+        "correlation with no `outer = inner` equality (a nested-loop "
+        "semi join); an uncorrelated `EXISTS` | refused: "
+        "`NotImplementedError` by name |",
+        "| `LEFT`/`RIGHT`/`FULL JOIN ... ON` with a residual (non-equi) "
+        "conjunct | tagged to the CPU engine by name (`conditional "
+        "left join runs on CPU`); under `test.forceDevice` an error. "
+        "Inner, cross, left semi and left anti joins evaluate theirs "
+        "on the device |",
         "| a token the grammar has no place for | `ValueError` naming "
         "the token |",
         "",
@@ -1746,7 +1777,10 @@ def generate_observability_docs() -> str:
         "and `/window` (the two aligned staging words a packed value",
         "lies in, gathered and shifted together) inside",
         "it, `decode_dict`, `decode_plain`, `decode_chars`,",
-        "`decode_delta`, `decode_rows` — docs/scan.md §1); scopes are",
+        "`decode_delta`, `decode_rows` — docs/scan.md §1) and the three",
+        "steps of a conditional semi/anti join's rank loop",
+        "(`srt_join_cond_mask`: `join_cond/gather`, `/eval`, `/reduce`);",
+        "scopes are",
         "op_name metadata and change no compiled code. The tpu-lint",
         "`jit-direct` rule treats",
         "`named_jit` as `jax.jit`. Dispatch spans, `compile` spans and",
@@ -1760,8 +1794,10 @@ def generate_observability_docs() -> str:
         "| timer | span | where | what |",
         "|---|---|---|---|",
         "| `planTime` | `plan` (`phase=parse` / `rewrite`, `cacheHit=`;"
-        " inside `rewrite`, untimed, `phase=subquery` when a comma list"
-        " or an `IN (subquery)` is rewritten into joins)"
+        " inside `rewrite`, untimed, `phase=subquery` when a comma list,"
+        " an `IN (subquery)` or an `EXISTS` is rewritten into joins, and"
+        " inside that `phase=decorrelate`, one per correlated"
+        " `[NOT] EXISTS`)"
         " | `session.sql`; `execute_plan` up to `execute_collect` |"
         " parse, analysis, overrides, plan cache, fingerprints, on the"
         " calling thread |",
@@ -1774,7 +1810,9 @@ def generate_observability_docs() -> str:
         " of a device value: `rowCount` (`DeviceBatch.row_count`),"
         " `fetch` (`finish_fetch`, so `to_host`/collect), `aggCounts`"
         " and `aggMerge` (the aggregate's counts and overflow flags),"
-        " `joinSize`, `joinBuild`, `exchangeSplit`, `iciSizes`,"
+        " `joinSize`, `joinBuild`, `joinCondPairs` (the candidate pairs"
+        " of a conditional semi/anti join, read from the count program"
+        " once the mask program is enqueued), `exchangeSplit`, `iciSizes`,"
         " `ansiError` | thread-ns blocked, summed over task threads |",
         "",
         "## Configuration",

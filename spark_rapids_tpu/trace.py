@@ -136,8 +136,10 @@ SPAN_CATALOG: Dict[str, str] = {
     "plan": "host planning on the calling thread (mirrors planTime): "
             "phase=parse in session.sql, phase=rewrite in execute_plan "
             "up to execute_collect (cacheHit= plan-cache outcome); "
-            "nested in it and untimed, phase=subquery: a comma list or "
-            "an IN (subquery) rewritten into joins (sql/logical.py)",
+            "nested in it and untimed, phase=subquery: a comma list, "
+            "an IN (subquery) or an EXISTS rewritten into joins "
+            "(sql/logical.py), and inside that phase=decorrelate: one "
+            "correlated [NOT] EXISTS lifted into a join's condition",
     "deviceSync": "the calling thread blocked reading a device value "
                   "back (mirrors deviceSyncTime; site= names the read)",
     "semaphoreWait": "wall blocked on the device semaphore",

@@ -317,3 +317,111 @@ def test_broadcast_fk_fast_path_no_sizing_sync():
         assert got == expected[tag], tag
         fast = metric_total(plans, "fkFastPathJoins")
         assert (fast > 0) == want_fast, (tag, fast)
+
+
+# -- left semi / left anti under a residual condition (a decorrelated
+# [NOT] EXISTS: ops/join.py srt_join_cond_mask) -------------------------
+
+def _cond_sides(seed=21, n_left=700, n_right=500):
+    """Keys 0..40 with duplicates on both sides and nulls in the keys
+    and in both columns the condition reads."""
+    rng = np.random.default_rng(seed)
+
+    def column(n, hi):
+        return [None if rng.random() < 0.1 else int(v)
+                for v in rng.integers(0, hi, n)]
+    return ({"k": column(n_left, 40), "a": column(n_left, 6)},
+            {"k2": column(n_right, 40), "b": column(n_right, 6)})
+
+
+def _brute_force(left, right, jt):
+    """Row by row: a pair passes where the keys are equal and ``a <> b``
+    is true, and null is not true."""
+    kept = []
+    for k, a in zip(left["k"], left["a"]):
+        found = any(k is not None and k == k2 and a is not None
+                    and b is not None and a != b
+                    for k2, b in zip(right["k2"], right["b"]))
+        if found == (jt == "leftsemi"):
+            kept.append((k, a))
+    return kept
+
+
+_COND_PATHS = {
+    "broadcast": ({}, "TpuBroadcastHashJoin"),
+    "shuffled": ({"spark.rapids.sql.autoBroadcastJoinThreshold": "-1"},
+                 "TpuShuffledHashJoin"),
+    "shuffled_chunked": ({"spark.rapids.sql.autoBroadcastJoinThreshold": "-1",
+                          "spark.rapids.sql.batchSizeRows": "128"},
+                         "TpuShuffledHashJoin"),
+    "broadcast_chunked": ({"spark.rapids.sql.batchSizeRows": "128"},
+                          "TpuBroadcastHashJoin"),
+    "split_retry": ({"spark.rapids.sql.retry.backoffMs": "1"},
+                    "TpuBroadcastHashJoin"),
+}
+
+
+@pytest.mark.parametrize("path", sorted(_COND_PATHS))
+@pytest.mark.parametrize("jt", ["leftsemi", "leftanti"])
+def test_conditional_semi_and_anti_join_on_device(jt, path, monkeypatch):
+    from spark_rapids_tpu import retry as R
+    from spark_rapids_tpu.exec import join as J
+    from spark_rapids_tpu.sql.session import TpuSparkSession
+    from spark_rapids_tpu.telemetry.prometheus import aggregator
+    left, right = _cond_sides()
+    conf, exec_name = _COND_PATHS[path]
+    conf = dict(conf, **{"spark.rapids.sql.enabled": "true",
+                         "spark.rapids.sql.test.forceDevice": "true"})
+    if path == "split_retry":
+        # the first conditional mask of the query runs out of memory and
+        # asks for a split: both halves must then give the whole's rows
+        real, fired = J.device_join, []
+
+        def flaky(piece, *args, **kwargs):
+            if kwargs.get("condition") is not None and not fired:
+                fired.append(piece.capacity)
+                raise R.TpuSplitAndRetryOOM("injected at the mask")
+            return real(piece, *args, **kwargs)
+        monkeypatch.setattr(J, "device_join", flaky)
+    spark = TpuSparkSession(conf)
+    try:
+        spark.start_capture()
+        l = spark.createDataFrame(left, "k long, a long", num_partitions=3)
+        r = spark.createDataFrame(right, "k2 long, b long", num_partitions=2)
+        before = dict(aggregator().scrape()[0])
+        rows = l.join(r, (l["k"] == r["k2"]) & (l["a"] != r["b"]),
+                      jt).collect()
+        after = dict(aggregator().scrape()[0])
+        plan = "\n".join(p.tree_string() for p in spark.get_captured_plans())
+        assert list(spark.last_rewrite_report.fallbacks) == []
+    finally:
+        spark.stop()
+    assert exec_name in plan and f"{jt} " in plan, plan
+
+    def key(row):
+        return tuple((v is None, v) for v in row)
+    assert sorted(map(tuple, rows), key=key) == sorted(
+        _brute_force(left, right, jt), key=key)
+    moved = {k: after.get(k, 0) - before.get(k, 0) for k in (
+        "joinConditionalCount", "joinConditionPairs", "splitRetryCount")}
+    pairs = sum(1 for k in left["k"] for k2 in right["k2"]
+                if k is not None and k == k2)
+    assert moved["joinConditionPairs"] == pairs
+    assert moved["joinConditionalCount"] >= (3 if "chunked" in path else 1)
+    assert moved["splitRetryCount"] == (1 if path == "split_retry" else 0)
+
+
+def test_conditional_outer_join_says_what_runs_on_the_device():
+    from spark_rapids_tpu.conf import TpuConf
+    from spark_rapids_tpu.exec.join import is_device_join
+    from spark_rapids_tpu.sql import expressions as E
+    a = E.AttributeReference("a", T.LongT)
+    b = E.AttributeReference("b", T.LongT)
+    cond = E.Not(E.EqualTo(a, b))
+    conf = TpuConf({})
+    for jt in ("inner", "cross", "leftsemi", "leftanti"):
+        assert is_device_join(jt, [a], [b], cond, conf) is None
+    for jt in ("left", "right", "full"):
+        why = is_device_join(jt, [a], [b], cond, conf)
+        assert f"conditional {jt} join runs on CPU" in why
+        assert "left semi and left anti" in why
