@@ -66,10 +66,16 @@ class _Packer:
         self.off = 0
 
     def add(self, arr: np.ndarray) -> int:
-        b = np.ascontiguousarray(arr).view(np.uint8).reshape(-1)
+        return self.add_parts([arr])
+
+    def add_parts(self, arrs: Sequence[np.ndarray]) -> int:
+        """One region of several arrays laid end to end: aligned at
+        its start, padded only behind the last."""
         start = self.off
-        self.parts.append(b)
-        self.off += b.nbytes
+        for arr in arrs:
+            b = np.ascontiguousarray(arr).view(np.uint8).reshape(-1)
+            self.parts.append(b)
+            self.off += b.nbytes
         pad = (-self.off) % 4
         if pad:
             self.parts.append(np.zeros(pad, np.uint8))
@@ -560,15 +566,23 @@ def prepare_encoded_upload(enc, cap: int, metrics=None):
             spec.append((dt, len(parts)))
             extras.extend(parts)
             continue
+        # the page tables ride only where a lane has to look its page
+        # up: not for dictionary pages followed by PLAIN ones (npg 0)
         n_pages = len(plan.pg_enc)
-        npg = _pad_pow2(n_pages)
-        dense_start = np.full(npg + 1, 1 << 62, dtype=np.int64)
-        dense_start[:n_pages + 1] = plan.pg_dense_start
-        plain_byte = np.zeros(npg, dtype=np.int64)
-        plain_byte[:n_pages] = plan.pg_plain_byte
-        pg_enc = np.zeros(npg, dtype=np.int32)
-        pg_enc[:n_pages] = plan.pg_enc
-        extras.extend([dense_start, plain_byte, pg_enc])
+        npg = _pad_pow2(n_pages) if plan.paged else 0
+        if npg:
+            dense_start = np.full(npg + 1, 1 << 62, dtype=np.int64)
+            dense_start[:n_pages + 1] = plan.pg_dense_start
+            plain_byte = np.zeros(npg, dtype=np.int64)
+            plain_byte[:n_pages] = plan.pg_plain_byte
+            pg_enc = np.zeros(npg, dtype=np.int32)
+            pg_enc[:n_pages] = plan.pg_enc
+            extras.extend([dense_start, plain_byte, pg_enc])
+        if plan.has_plain:
+            # where the PLAIN region starts (in words) and which dense
+            # lane its first value is: device values, like the row count
+            extras.append(np.array(
+                [plan.plain_base // 4, plan.plain_dense0], dtype=np.int32))
         if plan.has_delta:
             pg_first = np.zeros(npg, dtype=np.int64)
             pg_first[:n_pages] = plan.pg_first
@@ -627,10 +641,22 @@ def _encoded_decode_body(layout: Tuple, cap: int, words, n_arr, extras):
     prefix sum: fields by prefix sum, no gather through a run's
     index), and each stored value is decoded exactly once. A
     bit-packed value is read from the int32 staging ``words``
-    themselves — the two aligned words it lies in, ``rle.read_packed``
-    — so only the lanes that read bytes (PLAIN, BYTE_STREAM_SPLIT,
-    strings) expand the buffer to bytes, lazily, and a layout with
-    none of them never does. Rows reach their values by ONE gather
+    themselves — the two aligned words it lies in, ``rle.read_packed``.
+    A PLAIN fixed-width value is read from them too, with no gather:
+    the host lays a chunk's PLAIN value sections end to end from a
+    4-aligned byte, in dense order (``device_decode._plan_column``),
+    and hands over where (``plain_at``: the region's word index and the
+    dense lane of its first value, an int32 pair on the device), so
+    ``rle.read_plain`` takes one contiguous window at the fixed stride
+    and moves it by that lane. The window may reach past the buffer
+    for lanes no row uses, so the buffer is padded once by the widest
+    window (``dynamic_slice`` would clamp the start and shift every
+    lane). Where a chunk's pages are dictionary pages followed by
+    PLAIN ones — what writers produce — no page table rides at all
+    (``npg == 0``, a static fact of the layout): a lane is PLAIN iff
+    it is not before that first lane. Only BYTE_STREAM_SPLIT and
+    string pages read bytes and expand the buffer to them, lazily; a
+    layout with neither never does. Rows reach their values by ONE gather
     through ``j`` (row -> dense rank) at the end, and a column without
     definition levels (``ndl == 0``, a static fact of the layout)
     skips it: there every active row IS its own dense lane, and rows
@@ -654,6 +680,11 @@ def _encoded_decode_body(layout: Tuple, cap: int, words, n_arr, extras):
                 bytes_all = R.bytes_of_words(words)
         return bytes_all
 
+    # ent[3], ent[10]: elem_bytes and has_plain of a "dev" entry
+    plain_pad = max((R.plain_window_words(cap, ent[3]) for ent in layout
+                     if ent[0] == "dev" and ent[10]), default=0)
+    words_plain = jnp.pad(words, (0, plain_pad)) if plain_pad else None
+
     def rows(dense, j):
         if j is None:
             return dense
@@ -673,10 +704,13 @@ def _encoded_decode_body(layout: Tuple, cap: int, words, n_arr, extras):
         (_tag, kind, np_dt, elem_bytes, char_cap, npg, ndl, nvr,
          ndr, dict_shapes, has_plain, has_delta, has_bss,
          has_slen) = ent
-        dense_start = extras[cur]
-        plain_byte = extras[cur + 1]
-        pg_enc = extras[cur + 2]
-        cur += 3
+        if npg:
+            dense_start, plain_byte, pg_enc = extras[cur:cur + 3]
+            cur += 3
+        plain_at = None
+        if has_plain:
+            plain_at = extras[cur]
+            cur += 1
         pg_first = None
         if has_delta:
             pg_first = extras[cur]
@@ -713,15 +747,30 @@ def _encoded_decode_body(layout: Tuple, cap: int, words, n_arr, extras):
             data = jnp.where(validity, v != 0, False)
             outs.extend([data, validity])
             continue
-        with jax.named_scope("decode_page_lookup"):
-            pg = jnp.minimum(R.run_index(dense_start, cap), npg - 1)
-            pg_start = dense_start[pg]
-            local = pos - pg_start
-            enc_pg = pg_enc[pg]
         didx = None
         if vr is not None and dict_shapes:
             didx = jnp.clip(R.hybrid_lookup(words, pos, *vr),
                             0, dict_shapes[0][0][0] - 1)
+        # which lanes read the dictionary: by page where a page table
+        # rides, else those before the PLAIN region's first lane, else all
+        on_dict = None
+        if npg:
+            with jax.named_scope("decode_page_lookup"):
+                pg = jnp.minimum(R.run_index(dense_start, cap), npg - 1)
+                pg_start = dense_start[pg]
+                local = pos - pg_start
+                enc_pg = pg_enc[pg]
+                on_dict = enc_pg == PGE_DICT
+        elif has_plain:
+            on_dict = pos < plain_at[1]
+
+        def from_dict(table, other):
+            got = table[didx]
+            if on_dict is None:
+                return got
+            return jnp.where(on_dict if got.ndim == 1 else on_dict[:, None],
+                             got, other)
+
         if kind == "str":
             if has_slen:
                 # offset+bytes model (SURVEY.md §7 c): each stored
@@ -747,12 +796,8 @@ def _encoded_decode_body(layout: Tuple, cap: int, words, n_arr, extras):
                 plens = jnp.zeros(cap, dtype=jnp.int32)
             if didx is not None:
                 with jax.named_scope("decode_dict"):
-                    is_dict_pg = enc_pg == PGE_DICT
-                    chars = jnp.where(is_dict_pg[:, None],
-                                      dicts[0][didx], pchars)
-                    lengths = jnp.where(
-                        is_dict_pg, dicts[1][didx].astype(jnp.int32),
-                        plens)
+                    chars = from_dict(dicts[0], pchars)
+                    lengths = from_dict(dicts[1].astype(jnp.int32), plens)
             else:
                 chars, lengths = pchars, plens
             chars = jnp.where(validity[:, None], rows(chars, j), 0)
@@ -762,16 +807,14 @@ def _encoded_decode_body(layout: Tuple, cap: int, words, n_arr, extras):
         if kind == "dec128":
             if has_plain:
                 with jax.named_scope("decode_plain"):
-                    off = plain_byte[pg] + local * elem_bytes
-                    p_hi, p_lo = R.read_be_limbs(get_bytes(), off,
-                                                 elem_bytes)
+                    p_hi, p_lo = R.read_plain(words_plain, plain_at, cap,
+                                              elem_bytes, True)
             else:
                 p_hi = p_lo = jnp.zeros(cap, dtype=jnp.int64)
             if didx is not None:
                 with jax.named_scope("decode_dict"):
-                    is_dict_pg = enc_pg == PGE_DICT
-                    hi = jnp.where(is_dict_pg, dicts[0][didx], p_hi)
-                    lo = jnp.where(is_dict_pg, dicts[1][didx], p_lo)
+                    hi = from_dict(dicts[0], p_hi)
+                    lo = from_dict(dicts[1], p_lo)
             else:
                 hi, lo = p_hi, p_lo
             hi = jnp.where(validity, rows(hi, j), 0)
@@ -781,11 +824,8 @@ def _encoded_decode_body(layout: Tuple, cap: int, words, n_arr, extras):
         # fixed-width scalar kinds: select in the int64 bit domain
         if has_plain:
             with jax.named_scope("decode_plain"):
-                off = plain_byte[pg] + local * elem_bytes
-                if kind == "dec64":
-                    v = R.read_be_signed(get_bytes(), off, elem_bytes)
-                else:
-                    v = R.read_le(get_bytes(), off, elem_bytes)
+                v = R.read_plain(words_plain, plain_at, cap, elem_bytes,
+                                 kind == "dec64")
         else:
             v = jnp.zeros(cap, dtype=jnp.int64)
         if has_bss:
@@ -811,7 +851,7 @@ def _encoded_decode_body(layout: Tuple, cap: int, words, n_arr, extras):
                 v = jnp.where(enc_pg == PGE_DELTA, d_v, v)
         if didx is not None:
             with jax.named_scope("decode_dict"):
-                v = jnp.where(enc_pg == PGE_DICT, dicts[0][didx], v)
+                v = from_dict(dicts[0], v)
         v = rows(v, j)
         if kind == "f32":
             data = jax.lax.bitcast_convert_type(

@@ -13,7 +13,13 @@ The host does only the cheap, sequential work:
    page) and RLE/bit-packed *run headers* (a varint per run),
 
 and builds a ``ColumnDevicePlan``: run tables + page tables + decoded
-dictionaries. Every per-value operation — bit-unpacking the packed
+dictionaries. It also decides where each page's bytes land in the
+staging buffer, and uses that: a chunk's PLAIN fixed-width value
+sections are laid end to end as ONE 4-aligned region in dense order
+(``plain_base``, first dense lane ``plain_dense0``), so the device
+reads PLAIN value k at the fixed stride ``plain_base + k * elem_bytes``
+with no gather and no page table (``ops/rle.read_plain``). Every
+per-value operation — bit-unpacking the packed
 runs, dictionary-index gather, PLAIN fixed-width reinterpret,
 definition-level expansion into validity masks — happens on device in
 one XLA program (ops/rle.py kernels, wired by columnar/transfer.py).
@@ -25,6 +31,7 @@ host decode, so results stay bit-for-bit identical to the host path.
 
 from __future__ import annotations
 
+import itertools
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -58,7 +65,7 @@ _ENC_NAMES = {ENC_PLAIN: "PLAIN", ENC_PLAIN_DICTIONARY: "PLAIN_DICTIONARY",
 # per-page value-section encoding classes shipped to the device
 # (columnar/transfer.py selects the decode lane per page by these)
 PGE_DICT = 0     # RLE/bit-packed hybrid stream (dict indices, bool bits)
-PGE_PLAIN = 1    # PLAIN fixed-width at pg_plain_byte
+PGE_PLAIN = 1    # PLAIN fixed-width, in the chunk's one PLAIN region
 PGE_DELTA = 2    # DELTA_BINARY_PACKED (miniblock runs + seg-cumsum)
 PGE_BSS = 3      # BYTE_STREAM_SPLIT at pg_plain_byte
 PGE_PLAIN_STR = 4  # PLAIN byte array (4-byte length prefixes)
@@ -230,8 +237,17 @@ class ColumnDevicePlan:
     elem_bytes: int       # PLAIN element width (FLBA length for decimals)
     dl: Optional[RunTable]         # definition levels (None = no nulls)
     pg_dense_start: List[int] = field(default_factory=list)
-    pg_plain_byte: List[int] = field(default_factory=list)  # -1 = dict page
+    # byte offset of a BYTE_STREAM_SPLIT / string page's value section
+    # (-1: the page's values are not read by page)
+    pg_plain_byte: List[int] = field(default_factory=list)
     pg_enc: List[int] = field(default_factory=list)         # PGE_* class
+    # the chunk's PLAIN fixed-width value sections, end to end: the
+    # 4-aligned byte offset of the region (-1: no PLAIN page) and the
+    # dense lane of its first value. Slot k of the region is dense lane
+    # plain_dense0 + k; slots of other encodings' pages between two
+    # PLAIN pages are zero bytes (no lane selects them)
+    plain_base: int = -1
+    plain_dense0: int = 0
     pg_first: List[int] = field(default_factory=list)  # delta first_value
     vr: Optional[RunTable] = None  # dict-index / bool-bit runs
     dr: Optional[RunTable] = None  # delta miniblock runs (value=min_delta)
@@ -239,10 +255,23 @@ class ColumnDevicePlan:
     dict_arrays: List[np.ndarray] = field(default_factory=list)
     char_cap: int = 0
     n_dense: int = 0               # non-null value count
-    has_plain: bool = False
     has_delta: bool = False
     has_bss: bool = False
     encoding_values: Dict[str, int] = field(default_factory=dict)
+
+    @property
+    def has_plain(self) -> bool:
+        return self.plain_base >= 0
+
+    @property
+    def paged(self) -> bool:
+        """Whether a lane has to look its page up. Not when the pages
+        are dictionary pages followed by PLAIN fixed-width pages, which
+        is what every writer produces (a dictionary that overflows is
+        abandoned for the rest of the chunk): a lane is then PLAIN iff
+        it is not before ``plain_dense0``."""
+        tail = itertools.dropwhile(lambda e: e == PGE_DICT, self.pg_enc)
+        return any(e != PGE_PLAIN for e in tail)
 
 
 @dataclass
@@ -652,6 +681,8 @@ def _plan_column(raw: bytes, chunk, leaf, dt: T.DataType, n_rows: int,
     n_dict = 0
     all_valid_runs = True
     str_parts: List[Tuple[int, np.ndarray]] = []  # (dense_off, lengths)
+    plain_parts: List[np.ndarray] = []  # the PLAIN region, in dense order
+    plain_end = 0                       # dense lane behind its last slot
     pos = start
     while pos < end:
         hdr, body_off = parse_page_header(raw, pos)
@@ -711,7 +742,11 @@ def _plan_column(raw: bytes, chunk, leaf, dt: T.DataType, n_rows: int,
 
         if nv == 0:
             continue
-        page_off = packer.add(np.frombuffer(body, dtype=np.uint8))
+        page = np.frombuffer(body, dtype=np.uint8)
+        plain_fixed = enc == ENC_PLAIN and kind not in ("str", "bool")
+        # a PLAIN fixed-width page stages only its levels here: its
+        # values join the chunk's PLAIN region behind the last page
+        page_off = packer.add(page[:val_off] if plain_fixed else page)
 
         # definition levels -> validity runs (+ per-page non-null count)
         nn = nv
@@ -765,9 +800,19 @@ def _plan_column(raw: bytes, chunk, leaf, dt: T.DataType, n_rows: int,
             plan.pg_enc.append(PGE_DICT)  # value comes from vr
             plan.pg_plain_byte.append(-1)
         elif enc == ENC_PLAIN:
-            plan.has_plain = True
+            if val_off + nn * elem_bytes > len(body):
+                raise UnsupportedColumn("PLAIN page overrun")
+            if not plain_parts:
+                plan.plain_dense0 = dense
+            elif dense > plain_end:
+                # another encoding's pages since the last PLAIN page:
+                # their slots keep the region's stride, as zeros
+                plain_parts.append(np.zeros(
+                    (dense - plain_end) * elem_bytes, dtype=np.uint8))
+            plain_parts.append(page[val_off:val_off + nn * elem_bytes])
+            plain_end = dense + nn
             plan.pg_enc.append(PGE_PLAIN)
-            plan.pg_plain_byte.append(page_off + val_off)
+            plan.pg_plain_byte.append(-1)
         elif enc == ENC_RLE and kind == "bool":
             # v2 boolean pages: 4-byte length prefix then a hybrid
             # stream of width 1 — same device lane as PLAIN booleans
@@ -829,6 +874,8 @@ def _plan_column(raw: bytes, chunk, leaf, dt: T.DataType, n_rows: int,
             f"page rows {rows} != row-group rows {n_rows}")
     plan.n_dense = dense
     plan.pg_dense_start.append(dense)
+    if plain_parts:
+        plan.plain_base = packer.add_parts(plain_parts)
     if all_valid_runs or max_def == 0:
         plan.dl = None  # no nulls: validity is just the active mask
     if len(plan.vr) == 0:
@@ -992,6 +1039,8 @@ def _rebase_plan(plan: ColumnDevicePlan, base: int) -> None:
                 rt.bit_start[i] += base * 8
     plan.pg_plain_byte = [b + base if b >= 0 else b
                           for b in plan.pg_plain_byte]
+    if plan.has_plain:
+        plan.plain_base += base
 
 
 def _null_host_column(dt: T.DataType, n: int):

@@ -23,21 +23,30 @@ fuse them into ONE decode program per scan batch:
   themselves and shifts them together in unsigned 32-bit lanes; the
   staging buffer is not expanded to bytes for it (PERF.md §6, PR 33:
   on the chip a gather costs by the element gathered).
-- ``read_le`` / ``read_be_signed`` / ``read_be_limbs``: PLAIN
-  fixed-width and FIXED_LEN_BYTE_ARRAY (decimal) reinterpretation at
-  arbitrary byte offsets.
+- ``read_plain``: PLAIN fixed-width values (INT32/INT64/FLOAT/DOUBLE
+  little-endian; FIXED_LEN_BYTE_ARRAY decimals big-endian, 1..16
+  bytes) with no gather. The host guarantees what makes that
+  possible (``io/device_decode._plan_column``): a chunk's PLAIN value
+  sections lie end to end in the staging buffer, 4-aligned at the
+  start, in dense order, so value k is at byte ``base + k * W`` — a
+  contiguous window at a fixed stride. One ``dynamic_slice`` takes the
+  window, strided slices de-interleave it, a second ``dynamic_slice``
+  moves the values to their first dense lane (PERF.md §6, PR 35).
 
 All functions are shape-polymorphic trace-time helpers. The bit-packed
 readers (``read_packed``, ``read_packed64``, ``hybrid_lookup``,
 ``delta_lookup``) take the packed int32 staging ``words`` and int64
-BIT offsets into them; the byte readers take the byte array as an
-int32 array (one byte per element, the form ``bytes_of_words``
-produces from the staging words) and int64 byte offsets. All return
-int64 values. Callers mask invalid lanes afterwards; out-of-range
-offsets are clipped, never trapped.
+BIT offsets into them, ``read_plain`` the same ``words`` and the
+region's word index; the byte readers (``read_bss``,
+``gather_chars``) take the byte array as an int32 array (one byte per
+element, the form ``bytes_of_words`` produces from the staging words)
+and int64 byte offsets. All return int64 values. Callers mask invalid
+lanes afterwards; out-of-range offsets are clipped, never trapped.
 """
 
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -47,14 +56,6 @@ def bytes_of_words(words: jax.Array) -> jax.Array:
     """int32 staging words -> int32 byte array (little-endian order)."""
     shifts = jnp.arange(4, dtype=jnp.int32) * 8
     return ((words[:, None] >> shifts) & 0xFF).reshape(-1)
-
-
-def _gather_window(bytes_all: jax.Array, byte_off: jax.Array,
-                   width: int) -> jax.Array:
-    """(m, width) int64 window of bytes starting at byte_off (clipped)."""
-    nb = bytes_all.shape[0]
-    idx = byte_off[:, None] + jnp.arange(width, dtype=jnp.int64)
-    return bytes_all[jnp.clip(idx, 0, nb - 1)].astype(jnp.int64)
 
 
 def read_packed(words: jax.Array, bit_off: jax.Array,
@@ -266,44 +267,129 @@ def seg_excl_cumsum(contrib: jax.Array, seg_first_lane: jax.Array
     return excl - excl[seg_first_lane]
 
 
-def read_le(bytes_all: jax.Array, byte_off: jax.Array,
-            nbytes: int) -> jax.Array:
-    """PLAIN fixed-width reinterpret: little-endian nbytes -> int64
-    (sign bits land naturally for nbytes == 8; narrower widths are
-    returned zero-extended — cast to the narrow dtype to re-sign)."""
-    win = _gather_window(bytes_all, byte_off, nbytes)
-    k = jnp.arange(nbytes, dtype=jnp.int64) * 8
-    return jnp.sum(win << k, axis=1)
+def _plain_groups(cap: int, nbytes: int) -> tuple:
+    """``(g, gw, ng)``: the PLAIN region of ``nbytes``-wide values
+    repeats every ``g = 4 / gcd(nbytes, 4)`` values, which are
+    ``gw = nbytes / gcd(nbytes, 4)`` WHOLE words; ``ng`` such groups
+    cover ``cap`` values."""
+    d = math.gcd(nbytes, 4)
+    g = 4 // d
+    return g, nbytes // d, -(-cap // g)
 
 
-def _sign_extend(v: jax.Array, nbytes: int) -> jax.Array:
-    if nbytes >= 8:
-        return v
-    bits = 8 * nbytes
-    return v - ((v >> (bits - 1)) << bits)
+def plain_window_words(cap: int, nbytes: int) -> int:
+    """Words ``read_plain`` slices out of the staging buffer for
+    ``cap`` values of ``nbytes``: what its caller pads the buffer by."""
+    _g, gw, ng = _plain_groups(cap, nbytes)
+    return ng * gw
 
 
-def read_be_signed(bytes_all: jax.Array, byte_off: jax.Array,
-                   nbytes: int) -> jax.Array:
-    """FIXED_LEN_BYTE_ARRAY decimal: big-endian two's-complement of
-    nbytes (<= 8) -> signed int64 (the engine's DECIMAL64 storage)."""
-    win = _gather_window(bytes_all, byte_off, nbytes)
-    # descending shifts, byte 0 the most significant
-    k = (nbytes - 1 - jnp.arange(nbytes, dtype=jnp.int64)) * 8
-    return _sign_extend(jnp.sum(win << k, axis=1), nbytes)
+def _bswap32(x: jax.Array) -> jax.Array:
+    return ((x & 0xFF) << 24) | ((x & 0xFF00) << 8) \
+        | ((x >> 8) & 0xFF00) | (x >> 24)
 
 
-def read_be_limbs(bytes_all: jax.Array, byte_off: jax.Array,
-                  nbytes: int) -> tuple:
-    """FIXED_LEN_BYTE_ARRAY decimal128: big-endian two's-complement of
-    nbytes (9..16) -> (hi, lo) int64 limbs (transfer.py's dec128
-    layout: hi = value >> 64 arithmetic, lo = low 64 bits)."""
-    lo_bytes = 8
-    hi_bytes = nbytes - 8
-    hi = read_be_signed(bytes_all, byte_off, hi_bytes)
-    win = _gather_window(bytes_all, byte_off + hi_bytes, lo_bytes)
-    k = (lo_bytes - 1 - jnp.arange(lo_bytes, dtype=jnp.int64)) * 8
-    lo = jnp.sum(win << k, axis=1)
+def _plain_parts(le32, nbytes: int, big_endian: bool) -> list:
+    """A value's 32-bit parts, most significant first: the first int32
+    and sign-extended from the value's top byte, the others uint32.
+    ``le32(m)`` is the little-endian uint32 at byte ``m`` of the value
+    (what it holds past the value's last byte is shifted out)."""
+    def signed(x):
+        return jax.lax.bitcast_convert_type(x, jnp.int32)
+
+    if not big_endian:  # INT32 / INT64 / FLOAT / DOUBLE: whole words
+        top = nbytes - 4
+        return [signed(le32(top))] + [le32(m)
+                                      for m in range(top - 4, -1, -4)]
+    parts = [_bswap32(le32(m)) for m in range(nbytes % 4, nbytes, 4)]
+    if nbytes % 4:  # the 1..3 top bytes: arithmetic shift extends them
+        return [signed(_bswap32(le32(0))) >> (8 * (4 - nbytes % 4))] + parts
+    return [signed(parts[0])] + parts[1:]
+
+
+def _pair64(hi: jax.Array, lo: jax.Array) -> jax.Array:
+    """int64 of a signed or unsigned high part and a uint32 low part."""
+    return (hi.astype(jnp.int64) << 32) | lo.astype(jnp.int64)
+
+
+def read_plain(words: jax.Array, at: jax.Array, cap: int, nbytes: int,
+               big_endian: bool):
+    """PLAIN fixed-width values at their dense lanes, with no gather:
+    lane ``i >= d0`` of ``arange(cap)`` gets the ``nbytes``-wide value
+    at byte ``4 * b0 + (i - d0) * nbytes`` of the int32 staging
+    ``words``, where ``at = (b0, d0)`` is an int32 pair on the DEVICE
+    (the region's word index and the dense lane of its first value
+    differ by file; a static pair would compile a program a file).
+    The host lays a chunk's PLAIN value sections end to end from a
+    4-aligned byte (``io/device_decode._plan_column``), so the values
+    lie at a fixed stride: ONE ``dynamic_slice`` takes the window's
+    words, strided slices de-interleave them — ``g`` values are ``gw``
+    whole words (``_plain_groups``), so inside a group every word
+    index and every shift is static — and a second ``dynamic_slice``
+    of the front-padded parts moves value ``k`` to lane ``d0 + k``.
+    Lanes before ``d0`` read zero. All arithmetic runs in 32-bit
+    lanes; the parts are widened to int64 once, at the end.
+
+    ``big_endian=False`` reads PLAIN INT32/FLOAT (``nbytes`` 4,
+    sign-extended) and INT64/DOUBLE (8); ``big_endian=True`` a
+    FIXED_LEN_BYTE_ARRAY decimal, two's complement of 1..16 bytes.
+    Returns int64 for ``nbytes <= 8`` and the ``(hi, lo)`` int64 limbs
+    of transfer.py's dec128 layout above that.
+
+    ``dynamic_slice`` CLAMPS a start that would run its window past
+    the array, silently shifting every lane: the caller pads ``words``
+    with ``plain_window_words(cap, nbytes)`` words, so that no ``b0``
+    inside the buffer can. Lanes whose value would lie behind the
+    buffer read that padding; callers mask them (``validity``)."""
+    g, gw, ng = _plain_groups(cap, nbytes)
+    b0, d0 = at[0], at[1]
+    with jax.named_scope("window"):
+        win = jax.lax.bitcast_convert_type(
+            jax.lax.dynamic_slice(words, (b0,), (ng * gw,)), jnp.uint32)
+        cols = [jax.lax.slice(win, (c,), (c + (ng - 1) * gw + 1,), (gw,))
+                for c in range(gw)]
+
+    def value(r: int) -> list:
+        def le32(m: int) -> jax.Array:
+            c, p = divmod(r * nbytes + m, 4)
+            if not p:
+                return cols[c]
+            v = cols[c] >> (8 * p)
+            # the next word is the next GROUP's where c is the last:
+            # only bytes past this value's end would come from it
+            return v | (cols[c + 1] << (32 - 8 * p)) if c + 1 < gw else v
+        return _plain_parts(le32, nbytes, big_endian)
+
+    def interleaved(vals: list) -> jax.Array:
+        # value r of every group to lanes r, r + g, ...: each spread by
+        # interior padding, then ORed (a stack on a minor axis of g and
+        # a reshape runs as fast and compiles 3.5 times as long:
+        # PERF.md §6, PR 35)
+        out = None
+        for r, v in enumerate(vals):
+            v = jax.lax.bitcast_convert_type(v, jnp.int32)
+            if g > 1:
+                v = jax.lax.pad(v, jnp.int32(0), [(r, g - 1 - r, g - 1)])
+            out = v if out is None else out | v
+        return out[:cap]
+
+    per_r = [value(r) for r in range(g)]
+    with jax.named_scope("lanes"):
+        # part k of the g values of a group, interleaved again; then
+        # every part moved by d0 lanes at once
+        parts = jnp.stack([interleaved([v[k] for v in per_r])
+                           for k in range(len(per_r[0]))])
+        parts = jax.lax.dynamic_slice(
+            jnp.pad(parts, ((0, 0), (cap, 0))),
+            (jnp.zeros((), d0.dtype), cap - d0), parts.shape)
+    top = parts[0]
+    rest = [jax.lax.bitcast_convert_type(p, jnp.uint32) for p in parts[1:]]
+    if not rest:
+        return top.astype(jnp.int64)
+    if len(rest) == 1:
+        return _pair64(top, rest[0])
+    lo = _pair64(rest[-2], rest[-1])
+    hi = top.astype(jnp.int64) if len(rest) == 2 else _pair64(top, rest[0])
     return hi, lo
 
 

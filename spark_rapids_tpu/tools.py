@@ -1770,13 +1770,17 @@ def generate_observability_docs() -> str:
         "fused stage (`Filter`, `Project`) and the aggregate's steps",
         "(`agg_inputs`, `groupby_sort`, `groupby_reduce`, `compact`,",
         "`agg_result`) and the lanes of the Parquet page decode",
-        "(`srt_decode`: `decode_page_lookup`,",
-        "`decode_bits` with `/bytes` (staging words to bytes, for the",
-        "lanes that read bytes: PLAIN, BYTE_STREAM_SPLIT, strings),",
+        "(`srt_decode`: `decode_page_lookup` (only for a column whose",
+        "chunk needs its page table: not dictionary pages followed by PLAIN",
+        "ones), `decode_bits` with `/bytes` (staging words to bytes, for the",
+        "lanes that read bytes: BYTE_STREAM_SPLIT, strings),",
         "`/run_fields` (a run's fields to its lanes by prefix sum)",
         "and `/window` (the two aligned staging words a packed value",
         "lies in, gathered and shifted together) inside",
-        "it, `decode_dict`, `decode_plain`, `decode_chars`,",
+        "it, `decode_dict`, `decode_plain` with `/window` (one",
+        "contiguous window of the staging words, de-interleaved at the",
+        "value's fixed stride) and `/lanes` (the values moved to their",
+        "first dense lane) inside it, `decode_chars`,",
         "`decode_delta`, `decode_rows` — docs/scan.md §1) and the three",
         "steps of a conditional semi/anti join's rank loop",
         "(`srt_join_cond_mask`: `join_cond/gather`, `/eval`, `/reduce`);",

@@ -703,35 +703,6 @@ def test_hybrid_lookup_kernel_matches_oracle(width):
     assert np.array_equal(got.astype(np.int64) & 0xFFFFFFFF, vals)
 
 
-def test_fixed_width_kernels_match_oracle():
-    import jax.numpy as jnp
-
-    from spark_rapids_tpu.ops import rle as R
-    rng = np.random.default_rng(12)
-    raw = rng.integers(0, 256, 256).astype(np.uint8)
-    words = raw.view(np.int32)
-    bytes_all = R.bytes_of_words(jnp.asarray(words))
-    # little-endian int64/int32
-    offs = np.arange(0, 128, 8, dtype=np.int64)
-    got = np.asarray(R.read_le(bytes_all, jnp.asarray(offs), 8))
-    assert np.array_equal(got, raw[:128].view(np.int64))
-    # big-endian signed (decimal FLBA)
-    for w in (3, 7):
-        offs = np.arange(0, 10 * w, w, dtype=np.int64)
-        got = np.asarray(R.read_be_signed(bytes_all, jnp.asarray(offs), w))
-        want = [int.from_bytes(raw[o:o + w].tobytes(), "big", signed=True)
-                for o in offs]
-        assert got.tolist() == want, f"w={w}"
-    # big-endian limbs (decimal128 FLBA)
-    w = 13
-    offs = np.arange(0, 5 * w, w, dtype=np.int64)
-    hi, lo = R.read_be_limbs(bytes_all, jnp.asarray(offs), w)
-    for k, o in enumerate(offs):
-        full = int.from_bytes(raw[o:o + w].tobytes(), "big", signed=True)
-        assert int(hi[k]) == full >> 64
-        assert int(lo[k]) & ((1 << 64) - 1) == full & ((1 << 64) - 1)
-
-
 def test_dense_ranks_kernel():
     import jax.numpy as jnp
 
@@ -856,3 +827,224 @@ def test_read_bss_kernel():
     local = jnp.asarray(np.arange(n, dtype=np.int64))
     got = np.asarray(R.read_bss(ba, base, stride, local, 8))
     assert got.tolist() == vals.tolist()
+
+
+# -- the PLAIN region: staged end to end, read at a fixed stride -------------
+
+def _plan_file(path):
+    """The file's one row group through ``plan_unit_encoded``."""
+    from spark_rapids_tpu.io import device_decode as DD
+    from spark_rapids_tpu.io.arrow_convert import arrow_schema_to_sql
+    from spark_rapids_tpu.io.readers import ScanUnit
+    schema = arrow_schema_to_sql(pq.read_schema(path))
+    enc = DD.plan_unit_encoded(ScanUnit(path, os.path.getsize(path), [0]),
+                               schema)
+    assert enc is not None and not enc.fallbacks, enc and enc.fallbacks
+    return enc
+
+
+def _decode_encoded(enc):
+    from spark_rapids_tpu.columnar import transfer as TR
+    from spark_rapids_tpu.columnar.device import bucket_capacity
+    cap = bucket_capacity(enc.num_rows)
+    staged = TR.prepare_encoded_upload(enc, cap)
+    return staged, TR.finish_upload(staged).to_host().to_pydict()
+
+
+def _plain_column(kind, n, rng):
+    """(arrow array, bytes of one stored value as PLAIN writes it)."""
+    import decimal
+    import struct
+    if kind == "int64":
+        v = rng.integers(-(1 << 62), 1 << 62, n)
+        return pa.array(v, type=pa.int64()), \
+            lambda x: int(x).to_bytes(8, "little", signed=True)
+    if kind == "date32":
+        v = rng.integers(-30_000, 60_000, n).astype("int32")
+        return pa.array(v, type=pa.date32()), \
+            lambda x: int(x).to_bytes(4, "little", signed=True)
+    if kind == "float32":
+        v = rng.normal(size=n).astype("float32")
+        return pa.array(v), lambda x: struct.pack("<f", x)
+    if kind == "float64":
+        v = rng.normal(size=n)
+        return pa.array(v), lambda x: struct.pack("<d", x)
+    prec, width = {"decimal15_2": (15, 7), "decimal7_2": (7, 4),
+                   "decimal30_4": (30, 13)}[kind]
+    v = rng.integers(-10 ** min(prec, 18) + 1, 10 ** min(prec, 18), n)
+    scale = 4 if prec == 30 else 2
+    arr = pa.array([decimal.Decimal(int(x)).scaleb(-scale) for x in v],
+                   type=pa.decimal128(prec, scale))
+    return arr, lambda x: int(x).to_bytes(width, "big", signed=True)
+
+
+_PLAIN_KINDS = ["int64", "date32", "float32", "float64", "decimal15_2",
+                "decimal7_2", "decimal30_4"]
+# how the chunk comes to hold PLAIN pages, and what else it holds
+_PLAIN_CHUNKS = ["overflow_mid_chunk", "plain_from_first_page",
+                 "overflow_with_nulls", "overflow_v2_pages",
+                 "plain_v2_with_nulls"]
+
+
+@pytest.mark.parametrize("chunk", _PLAIN_CHUNKS)
+@pytest.mark.parametrize("kind", _PLAIN_KINDS)
+def test_plain_region_is_contiguous_aligned_and_in_dense_order(
+        tmp_path, kind, chunk):
+    """What ``rle.read_plain`` relies on, on written files: the bytes
+    from ``plain_base`` ARE the PLAIN pages' stored values, end to end
+    and in dense order, from a 4-aligned byte; the pages are dictionary
+    pages then PLAIN ones, so no page table rides; and the decoded
+    column is pyarrow's. 20,011 rows of tiny pages: a last page of odd
+    length, which at W = 7 and 13 ends off a word."""
+    n = 20_011
+    rng = np.random.default_rng(len(kind) * 31 + len(chunk))
+    arr, stored = _plain_column(kind, n, rng)
+    nulls = "nulls" in chunk
+    if nulls:
+        mask = rng.random(n) < 0.3
+        mask[:40] = True      # a page of nulls only, then mixed pages
+        arr = pa.array([None if m else x
+                        for m, x in zip(mask, arr.to_pylist())],
+                       type=arr.type)
+    opts = {"data_page_size": 4096, "compression": "snappy",
+            "data_page_version": "2.0" if "v2" in chunk else "1.0"}
+    if chunk.startswith("overflow"):
+        opts["dictionary_pagesize_limit"] = 12_000
+    else:
+        opts["use_dictionary"] = False
+    path = _write(tmp_path, pa.table({"c": arr}), **opts)
+    enc = _plan_file(path)
+    plan = enc.plans[0]
+    from spark_rapids_tpu.io.device_decode import PGE_DICT, PGE_PLAIN
+    n_dict = plan.pg_enc.count(PGE_DICT)
+    assert plan.pg_enc == [PGE_DICT] * n_dict \
+        + [PGE_PLAIN] * (len(plan.pg_enc) - n_dict)
+    assert bool(n_dict) == chunk.startswith("overflow")
+    assert plan.has_plain and not plan.paged
+    assert (plan.dl is not None) == nulls
+    d0 = plan.pg_dense_start[n_dict]
+    assert plan.plain_dense0 == d0 and plan.plain_base % 4 == 0
+    # stored (non-null) values from dense lane d0 on, as PLAIN writes them
+    dense = [x for x in pq.read_table(path).column("c").to_pylist()
+             if x is not None]
+    if kind.startswith("decimal"):
+        dense = [int(x.scaleb(arr.type.scale)) for x in dense]
+    elif kind == "date32":
+        import datetime
+        dense = [(x - datetime.date(1970, 1, 1)).days for x in dense]
+    want = b"".join(stored(x) for x in dense[d0:])
+    assert plan.n_dense == len(dense) and len(dense) > d0
+    raw = enc.words.tobytes()
+    assert raw[plan.plain_base:plan.plain_base + len(want)] == want
+    # nothing behind the region but its padding to a word
+    assert len(raw) == plan.plain_base + len(want) + (-len(want)) % 4
+    staged, got = _decode_encoded(enc)
+    assert [ent[5] for ent in staged[6]] == [0]    # npg: no page table
+    assert got["c"] == pq.read_table(path).column("c").to_pylist()
+
+
+def _thrift(fields):
+    """A compact-protocol struct of i32 fields and nested structs:
+    ``[(field id, int | list)]`` in ascending ids."""
+    out, last = bytearray(), 0
+    for fid, v in fields:
+        nested = isinstance(v, list)
+        out.append(((fid - last) << 4) | (12 if nested else 5))
+        last = fid
+        out += _thrift(v) if nested else _uvarint((v << 1) ^ (v >> 31))
+    return bytes(out) + b"\x00"
+
+
+def _page(ptype, body, header_field, header):
+    return _thrift([(1, ptype), (2, len(body)), (3, len(body)),
+                    (header_field, header)]) + body
+
+
+def test_plain_pages_around_a_dictionary_page_share_one_region():
+    """A chunk no writer produces, built by hand: dictionary, PLAIN,
+    dictionary, PLAIN pages of a required decimal(15,2). ONE device
+    path serves it: the region spans the dense lanes from the first
+    PLAIN value to the last with the dictionary page's slots as zeros,
+    the page table rides and says which lanes read it."""
+    import types
+    from spark_rapids_tpu.columnar.transfer import _Packer
+    from spark_rapids_tpu.io import device_decode as DD
+    from spark_rapids_tpu.sql import types as T
+    w = 7
+    dictionary = [10 ** 12 + 7, -5, 123_456_789_012_345, -(10 ** 14)]
+
+    def flba(x):
+        return int(x).to_bytes(w, "big", signed=True)
+
+    def dict_page(idx):      # bit width 2, one bit-packed run
+        groups = -(-len(idx) // 8)
+        bits = sum(v << (2 * i) for i, v in enumerate(idx))
+        body = b"\x02" + _uvarint((groups << 1) | 1) \
+            + bits.to_bytes(groups * 2, "little")
+        return _page(DD.PAGE_DATA, body, 5,
+                     [(1, len(idx)), (2, DD.ENC_RLE_DICTIONARY), (3, 3),
+                      (4, 3)])
+
+    def plain_page(vals):
+        return _page(DD.PAGE_DATA, b"".join(map(flba, vals)), 5,
+                     [(1, len(vals)), (2, DD.ENC_PLAIN), (3, 3), (4, 3)])
+
+    i1, p1 = [0, 1, 2, 3, 3, 2, 1, 0, 1, 1, 3], [7, -8, 2 ** 40 + 1]
+    i2, p2 = [2, 2, 0, 3, 1], [-(2 ** 50), 99, 0, -1, 5 * 10 ** 13]
+    raw = _page(DD.PAGE_DICTIONARY, b"".join(map(flba, dictionary)), 7,
+                [(1, len(dictionary)), (2, DD.ENC_PLAIN)]) \
+        + dict_page(i1) + plain_page(p1) + dict_page(i2) + plain_page(p2)
+    want = [dictionary[i] for i in i1] + p1 \
+        + [dictionary[i] for i in i2] + p2
+    dt = T.DecimalType(15, 2)
+    leaf = types.SimpleNamespace(
+        max_repetition_level=0, max_definition_level=0,
+        physical_type="FIXED_LEN_BYTE_ARRAY", length=w,
+        logical_type="Decimal(precision=15, scale=2)")
+    chunk = types.SimpleNamespace(compression="UNCOMPRESSED")
+    packer = _Packer()
+    packer.add(np.arange(5, dtype=np.uint8))   # the region is not first
+    plan = DD._plan_column(raw, chunk, leaf, dt, len(want), packer)
+    assert plan.pg_enc == [DD.PGE_DICT, DD.PGE_PLAIN] * 2
+    assert plan.paged and plan.plain_dense0 == len(i1)
+    assert plan.plain_base % 4 == 0
+    region = packer.words().tobytes()[plan.plain_base:]
+    assert region[:(len(want) - len(i1)) * w] == b"".join(
+        map(flba, p1)) + bytes(len(i2) * w) + b"".join(map(flba, p2))
+    enc = DD.EncodedBatch(T.StructType([T.StructField("c", dt, False)]),
+                          len(want), packer.words(), {0: plan}, {}, [])
+    staged, got = _decode_encoded(enc)
+    assert [ent[5] for ent in staged[6]] == [8]    # the page table rides
+    import decimal
+    assert got["c"] == [decimal.Decimal(v).scaleb(-2) for v in want]
+
+
+def test_one_decode_program_serves_files_that_overflow_at_other_lanes(
+        tmp_path):
+    """``plain_base`` and ``plain_dense0`` are runtime values: two
+    files of one shape whose dictionaries overflow at different lanes
+    (and whose regions start elsewhere) stage the same layout and run
+    the same compiled program."""
+    from spark_rapids_tpu.columnar import transfer as TR
+    staged = []
+    for seed, n_head in ((1, 9_000), (2, 11_000)):
+        rng = np.random.default_rng(seed)
+        head = rng.integers(0, 600, n_head)
+        tail = rng.integers(1 << 40, 1 << 41, 20_000 - n_head)
+        tbl = pa.table({"k": pa.array(np.concatenate([head, tail]),
+                                      type=pa.int64()),
+                        "x": pa.array(rng.normal(size=20_000))})
+        path = _write(tmp_path, tbl, name=f"f{seed}.parquet",
+                      data_page_size=4096, use_dictionary=["k"],
+                      dictionary_pagesize_limit=40_000)
+        enc = _plan_file(path)
+        st, got = _decode_encoded(enc)
+        assert got == tbl.to_pydict()
+        staged.append((enc.plans[0], st))
+    (plan_a, st_a), (plan_b, st_b) = staged
+    assert plan_a.plain_dense0 != plan_b.plain_dense0
+    assert plan_a.plain_base != plan_b.plain_base
+    assert st_a[6] == st_b[6] and st_a[4].shape == st_b[4].shape
+    assert TR._chain_fn(st_a[6], st_a[3], st_a[4].nbytes) \
+        is TR._chain_fn(st_b[6], st_b[3], st_b[4].nbytes)
+    assert TR._chain_fn(st_a[6], st_a[3], st_a[4].nbytes)._cache_size() == 1

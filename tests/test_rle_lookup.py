@@ -6,9 +6,15 @@ gives, and the decode program must hold no loop and no gather by run.
 Then the bit-packed read (ops/rle.read_packed): two aligned staging
 words a lane must give what Python integers over the bytes give, at
 every width and phase, and the decode program must gather two elements
-a lane a hybrid stream, not a byte window."""
+a lane a hybrid stream, not a byte window. Then the PLAIN read
+(ops/rle.read_plain): one contiguous window of the staging words,
+de-interleaved at the fixed stride and moved to its first dense lane,
+must give what Python integers over the bytes give, at every width,
+either byte order, any first lane and any place in the buffer, and the
+decode program must gather nothing for it."""
 
 import collections
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -287,6 +293,111 @@ def test_packed_reads_clip_offsets_outside_the_buffer(case, off, fn):
                 (case, width)
 
 
+# -- the PLAIN read: one contiguous window at a fixed stride -----------------
+
+_PLAIN_CAP = 163_840    # bucket_capacity(151_265)
+_PLAIN_NW = 16_384      # staging words: 65,536 bytes
+# (bytes a value, big-endian): PLAIN INT32/FLOAT and INT64/DOUBLE are
+# little-endian, a FIXED_LEN_BYTE_ARRAY decimal is big-endian at 1..16
+_PLAIN_SHAPES = [(4, False), (8, False)] + [(w, True) for w in range(1, 17)]
+
+
+@functools.lru_cache(maxsize=None)
+def _plain_reader(nbytes, big_endian):
+    """One program a shape: the region's place and first lane are
+    device values, as the decode body hands them over."""
+    pad = R.plain_window_words(_PLAIN_CAP, nbytes)
+    return jax.jit(lambda words, at: R.read_plain(
+        jnp.pad(words, (0, pad)), at, _PLAIN_CAP, nbytes, big_endian))
+
+
+def _plain_ints(got, lanes):
+    """Python integers of lanes: an int64, or (hi, lo) limbs."""
+    if isinstance(got, tuple):
+        hi = np.asarray(got[0])[lanes].tolist()
+        lo = np.asarray(got[1])[lanes].astype(np.uint64).tolist()
+        return [(h << 64) | l for h, l in zip(hi, lo)]
+    return np.asarray(got)[lanes].tolist()
+
+
+def _plain_want(raw, byte0, nbytes, big_endian, count):
+    order = "big" if big_endian else "little"
+    return [int.from_bytes(raw[byte0 + k * nbytes:byte0 + (k + 1) * nbytes],
+                           order, signed=True) for k in range(count)]
+
+
+@pytest.mark.parametrize("place", ["first_in_buffer", "middle",
+                                   "ends_on_last_byte"])
+@pytest.mark.parametrize("d0", [0, 1, 19_999, 151_264])
+@pytest.mark.parametrize("nbytes,big_endian", _PLAIN_SHAPES,
+                         ids=[f"{w}{'be' if b else 'le'}"
+                              for w, b in _PLAIN_SHAPES])
+def test_read_plain_matches_python_integers(nbytes, big_endian, d0, place):
+    """Lane i >= d0 holds the value at byte 4 * b0 + (i - d0) * W, as
+    a signed Python integer reads it, for every lane a row can use;
+    lanes before d0 hold zero. ``ends_on_last_byte`` is the clamp
+    hazard: the window of ``cap`` values starts inside the buffer and
+    runs far past it, and a clamped ``dynamic_slice`` would shift
+    every lane."""
+    rng = np.random.default_rng(nbytes * 7 + d0 % 5)
+    words = _staging("top_bit_set" if (nbytes + d0) % 2 else "random",
+                     _PLAIN_NW, rng)
+    raw = words.tobytes()
+    if place == "first_in_buffer":
+        b0 = 0
+    elif place == "middle":
+        b0 = 5_003
+    else:  # the region's last value ends on the buffer's last byte
+        count = 4 * 37
+        b0 = _PLAIN_NW - count * nbytes // 4
+    count = min((len(raw) - 4 * b0) // nbytes, _PLAIN_CAP - d0, 9_000)
+    assert count >= 4 * 37 and d0 + count <= _PLAIN_CAP
+    got = _plain_reader(nbytes, big_endian)(
+        jnp.asarray(words), jnp.asarray(np.array([b0, d0], np.int32)))
+    for g in got if isinstance(got, tuple) else (got,):
+        assert g.dtype == jnp.int64 and g.shape == (_PLAIN_CAP,)
+    assert _plain_ints(got, slice(d0, d0 + count)) == _plain_want(
+        raw, 4 * b0, nbytes, big_endian, count), (nbytes, d0, place)
+    assert not any(_plain_ints(got, slice(0, d0)))
+
+
+@pytest.mark.parametrize("nbytes,big_endian", _PLAIN_SHAPES,
+                         ids=[f"{w}{'be' if b else 'le'}"
+                              for w, b in _PLAIN_SHAPES])
+def test_read_plain_sign_extension_and_limbs(nbytes, big_endian):
+    """The extremes of every width: -1, the least and the greatest
+    value, and a one in the lowest bit — sign extension from the top
+    byte at every width under 8, the (hi, lo) limbs above it."""
+    least = bytes([0x80]) + bytes(nbytes - 1)
+    most = bytes([0x7F]) + bytes([0xFF] * (nbytes - 1))
+    one = bytes(nbytes - 1) + bytes([1])
+    vals = [bytes([0xFF] * nbytes), least, most, one] * 5
+    if not big_endian:
+        vals = [v[::-1] for v in vals]
+    raw = b"".join(vals)
+    raw += bytes(-len(raw) % 4)
+    words = np.zeros(_PLAIN_NW, dtype=np.int32)
+    words[:len(raw) // 4] = np.frombuffer(raw, dtype=np.int32)
+    got = _plain_reader(nbytes, big_endian)(
+        jnp.asarray(words), jnp.asarray(np.array([0, 3], np.int32)))
+    bits = 8 * nbytes
+    want = [-1, -(1 << (bits - 1)), (1 << (bits - 1)) - 1, 1] * 5
+    assert _plain_ints(got, slice(3, 23)) == want, nbytes
+    assert _plain_ints(got, slice(0, 3)) == [0, 0, 0]
+
+
+def test_read_plain_one_program_serves_any_region_and_first_lane():
+    """``at`` is a runtime value: files whose dictionaries overflow at
+    different lanes, and whose regions lie elsewhere, share a program."""
+    fn = _plain_reader(8, False)
+    words = jnp.asarray(_staging("random", _PLAIN_NW,
+                                 np.random.default_rng(8)))
+    fn(words, jnp.asarray(np.array([0, 0], np.int32)))
+    before = fn._cache_size()
+    fn(words, jnp.asarray(np.array([4_001, 151_264], np.int32)))
+    assert fn._cache_size() == before
+
+
 # -- structural guard: no loop in the decode program -------------------------
 
 _RUN_DTYPES = ("int64", "bool", "int64", "int64", "int64")
@@ -294,28 +405,37 @@ _RUN_DTYPES = ("int64", "bool", "int64", "int64", "int64")
 # the seven ("dev", ...) entries q1 gives at SF1 (cap 786,432; one decode
 # a partition): all RLE_DICTIONARY, extendedprice overflowing to PLAIN
 _Q1_CAP = 786_432
+# (no page table rides: npg 0, every chunk is dictionary pages then PLAIN)
 _Q1_LAYOUT = (
-    ("dev", "dec64", "int64", 7, 0, 64, 0, 2048, 0,
+    ("dev", "dec64", "int64", 7, 0, 0, 0, 2048, 0,
      (((64,), "int64"),), False, False, False, False),
-    ("dev", "dec64", "int64", 7, 0, 64, 0, 512, 0,
+    ("dev", "dec64", "int64", 7, 0, 0, 0, 512, 0,
      (((262144,), "int64"),), True, False, False, False),
-    ("dev", "dec64", "int64", 7, 0, 64, 0, 2048, 0,
+    ("dev", "dec64", "int64", 7, 0, 0, 0, 2048, 0,
      (((16,), "int64"),), False, False, False, False),
-    ("dev", "dec64", "int64", 7, 0, 64, 0, 2048, 0,
+    ("dev", "dec64", "int64", 7, 0, 0, 0, 2048, 0,
      (((16,), "int64"),), False, False, False, False),
-    ("dev", "str", "uint8", 0, 8, 64, 0, 2048, 0,
+    ("dev", "str", "uint8", 0, 8, 0, 0, 2048, 0,
      (((4, 8), "uint8"), ((4,), "int32")), False, False, False, False),
-    ("dev", "str", "uint8", 0, 8, 64, 0, 4096, 0,
+    ("dev", "str", "uint8", 0, 8, 0, 0, 4096, 0,
      (((2, 8), "uint8"), ((2,), "int32")), False, False, False, False),
-    ("dev", "int", "int32", 4, 0, 64, 0, 2048, 0,
+    ("dev", "int", "int32", 4, 0, 0, 0, 2048, 0,
      (((4096,), "int64"),), False, False, False, False),
 )
 
 _LAYOUTS = {
     "q1_sf1": (_Q1_LAYOUT, _Q1_CAP),
     "nullable_dict_and_plain": ((
-        ("dev", "int", "int64", 8, 0, 8, 16, 8, 0,
+        ("dev", "int", "int64", 8, 0, 0, 16, 8, 0,
          (((32,), "int64"),), True, False, False, False),), 1024),
+    # PLAIN pages with a dictionary page between them (no writer does
+    # it): the page table rides and says which lanes are PLAIN
+    "plain_pages_not_consecutive": ((
+        ("dev", "dec64", "int64", 7, 0, 8, 0, 8, 0,
+         (((32,), "int64"),), True, False, False, False),), 1024),
+    "plain_from_the_first_page": ((
+        ("dev", "f64", "float64", 8, 0, 0, 8, 0, 0, (),
+         True, False, False, False),), 1024),
     "string_with_lengths": ((
         ("dev", "str", "uint8", 0, 16, 8, 8, 8, 0,
          (((8, 16), "uint8"), ((8,), "int32")),
@@ -324,10 +444,10 @@ _LAYOUTS = {
         ("dev", "int", "int64", 8, 0, 8, 8, 0, 16, (),
          False, True, False, False),), 1024),
     "nullable_bool": ((
-        ("dev", "bool", "bool", 1, 0, 8, 8, 8, 0, (),
+        ("dev", "bool", "bool", 1, 0, 0, 8, 8, 0, (),
          False, False, False, False),), 1024),
     "dec128_plain_and_dict": ((
-        ("dev", "dec128", "int64", 16, 0, 8, 8, 8, 0,
+        ("dev", "dec128", "int64", 16, 0, 0, 8, 8, 0,
          (((8,), "int64"), ((8,), "int64")),
          True, False, False, False),), 1024),
     "byte_stream_split_f64": ((
@@ -335,7 +455,7 @@ _LAYOUTS = {
          True, False, True, False),), 1024),
     "host_column_beside_a_device_one": ((
         ("host", 2),
-        ("dev", "f32", "float32", 4, 0, 8, 8, 8, 0,
+        ("dev", "f32", "float32", 4, 0, 0, 8, 8, 0,
          (((16,), "int64"),), True, False, False, False)), 1024),
 }
 
@@ -352,9 +472,12 @@ def _abstract_extras(layout, cap):
             out.extend(arr((cap,), "int64") for _ in range(ent[1]))
             continue
         (_tag, _kind, _np_dt, _eb, _cc, npg, ndl, nvr, ndr, dict_shapes,
-         _has_plain, has_delta, _has_bss, has_slen) = ent
-        out.extend([arr((npg + 1,), "int64"), arr((npg,), "int64"),
-                    arr((npg,), "int32")])
+         has_plain, has_delta, _has_bss, has_slen) = ent
+        if npg:
+            out.extend([arr((npg + 1,), "int64"), arr((npg,), "int64"),
+                        arr((npg,), "int32")])
+        if has_plain:
+            out.append(arr((2,), "int32"))
         if has_delta:
             out.append(arr((npg,), "int64"))
         for n_runs in (ndl, nvr, ndr):
@@ -391,19 +514,24 @@ def test_guard_sees_the_search_it_guards_against():
 # field of a run is read through the run's index and a packed value is
 # read from two aligned staging words (two gathers of one element a
 # hybrid stream, four a DELTA stream, where the byte window was one
-# gather of five: q1's seven streams held 32). What is left beside
-# them: the dictionary reads, the PLAIN/BSS byte windows, the page
-# tables' ``dense_start[pg]`` / ``pg_enc[pg]`` / ``plain_byte[pg]`` /
-# ``pg_first[pg]`` and the row gather through ``j``.
+# gather of five: q1's seven streams held 32), a PLAIN value is read
+# with none (the byte windows were one to three a column) and a chunk
+# of dictionary pages followed by PLAIN ones carries no page table (its
+# ``dense_start[pg]`` / ``pg_enc[pg]`` / ``plain_byte[pg]`` went: q1's
+# seven columns held 16). What is left beside the packed reads: the
+# dictionary reads, the BSS and string byte windows, the page tables of
+# the layouts that still need one and the row gather through ``j``.
 _GATHERS = {
-    "q1_sf1": 39,
-    "nullable_dict_and_plain": 10,
+    "q1_sf1": 23,
+    "nullable_dict_and_plain": 6,
+    "plain_pages_not_consecutive": 5,
+    "plain_from_the_first_page": 3,
     "string_with_lengths": 13,
     "delta_binary_packed": 11,
     "nullable_bool": 5,
-    "dec128_plain_and_dict": 13,
-    "byte_stream_split_f64": 7,
-    "host_column_beside_a_device_one": 10,
+    "dec128_plain_and_dict": 8,
+    "byte_stream_split_f64": 5,
+    "host_column_beside_a_device_one": 6,
 }
 
 
@@ -453,3 +581,33 @@ def test_packed_read_gathers_two_elements_a_lane(name):
                for ent in layout if ent[0] == "dev")
     got = _gathered_elements(_decode_jaxpr(name), "decode_bits/window")
     assert got == want * cap, (name, got / cap, want)
+
+
+@pytest.mark.parametrize("name", sorted(_LAYOUTS))
+def test_plain_read_gathers_nothing(name):
+    """The static proof that a PLAIN value reaches its lane by
+    contiguous copies in every layout: no element is gathered under
+    ``decode_plain`` but BYTE_STREAM_SPLIT's, which shares the scope
+    (its bytes a value, and two page-table fields)."""
+    layout, cap = _LAYOUTS[name]
+    want = sum((ent[3] + 2) * bool(ent[12])
+               for ent in layout if ent[0] == "dev")
+    got = _gathered_elements(_decode_jaxpr(name), "decode_plain")
+    assert got == want * cap, (name, got / cap, want)
+
+
+@pytest.mark.parametrize("name", sorted(_LAYOUTS))
+def test_page_lookup_only_where_a_page_table_rides(name):
+    """A column of dictionary pages followed by PLAIN ones (``npg``
+    0) looks no page up: nothing runs under ``decode_page_lookup`` for
+    it. A layout with a page table gathers its two fields a lane."""
+    layout, cap = _LAYOUTS[name]
+    jaxpr = _decode_jaxpr(name)
+    paged = sum(bool(ent[5]) and ent[1] != "bool"
+                for ent in layout if ent[0] == "dev")
+    assert _gathered_elements(jaxpr, "decode_page_lookup") \
+        == 2 * paged * cap, name
+    if not paged:
+        assert not any("decode_page_lookup"
+                       in str(e.source_info.name_stack)
+                       for e in _eqns(jaxpr)), name
