@@ -302,11 +302,11 @@ class DeviceBatch:
     def column(self, i: int) -> AnyDeviceColumn:
         return self.columns[i]
 
-    def row_count(self) -> int:
+    def row_count(self, site: str = "rowCount") -> int:
         if self._num_rows is None:
             # the host blocks here until the device has the value:
-            # counted as deviceSyncTime (site=rowCount)
-            with _trace.device_sync("rowCount"):
+            # counted as deviceSyncTime (site= names the reader)
+            with _trace.device_sync(site):
                 if self._num_rows_dev is not None:
                     self._num_rows = int(np.asarray(self._num_rows_dev))
                 else:

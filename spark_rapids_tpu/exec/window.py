@@ -15,10 +15,30 @@ generalized to the whole supported frame set:
     last peer row — Spark's default frame),
   - bounded ROWS frames for sum/count/avg (prefix differences).
 
-Running min/max uses a segmented associative scan over (partition id,
-total-order rank, winner position) so values round-trip bit-exactly.
+Running min/max is a segmented doubling scan over (total-order rank
+words, winner position), and the winner's value is gathered, so values
+round-trip bit-exactly.
 Results are scattered back to ORIGINAL row order (the exec appends
 columns without permuting its input, matching CpuWindowExec).
+
+Decimal sources (PR 36): sum/min/max/count/first/last read a
+``DecimalType`` column in its 64-bit (p <= 18) or two-limb form, in the
+frames above. ``sum`` is Spark's ``decimal(min(38, p + 10), s)``: while
+that fits 18 digits the accumulator is the int64 it is stored in, past
+them the source is split into 32-bit digits, prefix-summed side by
+side (and differenced, for a bounded frame) in int64, and the digit sums
+are recombined with their carries once a lane (``_sum_limbs``) — exact, and
+null where the true sum passes the result's precision (non-ANSI).
+min/max compare a two-limb value by ``groupby.limb_words``, two more
+rank words of the same scan, and gather both limbs at the winner.
+``avg`` over a decimal (a division into ``decimal(p + 4, s + 4)``) and
+every aggregate over a string are refused by name.
+
+The program's steps carry ``jax.named_scope``s for ``tools trace``:
+``window/sort`` (the multi-key sort), ``window/layout`` (boundaries,
+``part_id``, partition and peer ends), ``window/sum`` (prefix sums and
+counts), ``window/extreme`` (running / sparse-table min and max, and the
+first / last non-null), ``window/unsort`` (back to input order).
 """
 
 from __future__ import annotations
@@ -29,6 +49,7 @@ import jax
 import jax.numpy as jnp
 
 from spark_rapids_tpu import metrics as M
+from spark_rapids_tpu import trace as TR
 from spark_rapids_tpu.columnar.device import (AnyDeviceColumn, DeviceBatch,
                                               DeviceColumn,
                                               DeviceStringColumn,
@@ -113,10 +134,13 @@ def is_device_window(window_exprs: List[E.Expression],
                 from spark_rapids_tpu import device_caps as DC
                 from spark_rapids_tpu.conf import ENABLE_FLOAT_AGG
                 src = agg.children[0]
-                if isinstance(src.data_type, (T.StringType, T.BinaryType,
-                                              T.DecimalType)):
+                if isinstance(src.data_type, (T.StringType, T.BinaryType)):
                     return (f"window aggregate over {src.data_type} "
                             "runs on CPU")
+                if isinstance(src.data_type, T.DecimalType) \
+                        and isinstance(agg, E.Average):
+                    return (f"window average over {src.data_type} (a "
+                            "decimal division) runs on CPU")
                 float_ok = bool(conf.get(ENABLE_FLOAT_AGG))
                 if isinstance(agg, (E.Sum, E.Average)) \
                         and T.is_floating(src.data_type) and not float_ok:
@@ -162,45 +186,62 @@ def _seg_running_extreme(part_id: jax.Array, words: List[jax.Array],
                          ) -> Tuple[jax.Array, jax.Array]:
     """Segmented running min/max over multi-word ranks (most-significant
     first; native dtypes — see groupby.rank_words). Returns (winner
-    position per row, has-winner flag)."""
+    position per row, has-winner flag).
+
+    A doubling scan: after step k a row holds the winner of the 2**k
+    rows of its partition that end at it, and takes the better of its own
+    and the one 2**k rows back; the loop ends once 2**k covers the
+    longest partition. Every step is one elementwise pass, and the
+    program is one loop body whatever the capacity (a
+    ``lax.associative_scan`` over the same operands compiles to more
+    than 100 MB of code at 786,432 lanes: docs/profiles/pr36)."""
     cap = part_id.shape[0]
     pos = jnp.arange(cap, dtype=jnp.int32)
-    n_words = len(words)
+    new_part = jnp.concatenate([jnp.ones(1, dtype=bool),
+                                part_id[1:] != part_id[:-1]])
+    start = jax.lax.cummax(jnp.where(new_part, pos, 0))
+    longest = jnp.max(pos - start) + 1
 
-    def combine(a, b):
-        a_id, a_valid, a_p = a[0], a[1], a[2]
-        b_id, b_valid, b_p = b[0], b[1], b[2]
-        aw = a[3:]
-        bw = b[3:]
-        same = b_id == a_id
-        a_live = a_valid & same
-        better = jnp.zeros_like(a_valid)
-        eq = jnp.ones_like(a_valid)
+    def step(state):
+        # b: the row d rows back (``roll`` wraps; ``pos - d >= start``
+        # keeps only a row of this partition)
+        d, a_valid, a_pos, aw = state
+        b_valid = jnp.roll(a_valid, d) & (pos - d >= start)
+        b_pos = jnp.roll(a_pos, d)
+        bw = [jnp.roll(w, d) for w in aw]
+        better = jnp.zeros(cap, dtype=bool)   # the earlier one strictly
+        eq = jnp.ones(cap, dtype=bool)
         for wa, wb in zip(aw, bw):
-            c = (wa < wb) if is_min else (wa > wb)
+            c = (wb < wa) if is_min else (wb > wa)
             better = better | (eq & c)
             eq = eq & (wa == wb)
-        take_a = a_live & ((~b_valid) | better)
-        out = [b_id, a_live | b_valid,
-               jnp.where(take_a, a_p, b_p)]
-        out += [jnp.where(take_a, wa, wb) for wa, wb in zip(aw, bw)]
-        return tuple(out)
+        take_b = b_valid & ((~a_valid) | better)
+        return (d * 2, a_valid | b_valid, jnp.where(take_b, b_pos, a_pos),
+                [jnp.where(take_b, wb, wa) for wa, wb in zip(aw, bw)])
 
-    res = jax.lax.associative_scan(
-        combine, tuple([part_id, valid, pos] + list(words)))
-    return res[2], res[1]
+    _, has, win, _ = jax.lax.while_loop(
+        lambda state: state[0] < longest, step,
+        (jnp.int32(1), valid, pos, list(words)))
+    return win, has
+
+
+def _rows(mask: jax.Array, x: jax.Array) -> jax.Array:
+    """A per-row mask shaped to broadcast over ``x``'s columns."""
+    return mask.reshape(mask.shape + (1,) * (x.ndim - 1))
 
 
 def _prefix_in_part(x: jax.Array, start_of_row: jax.Array) -> jax.Array:
-    """Inclusive prefix sum restarting at each partition boundary.
-    ``start_of_row[i]`` is the sorted position where row i's partition
-    begins. Floats use a segmented scan (no cross-partition
+    """Inclusive prefix sum restarting at each partition boundary, down
+    the rows of ``x`` (a vector, or a matrix of columns summed side by
+    side). ``start_of_row[i]`` is the sorted position where row i's
+    partition begins. Floats use a segmented scan (no cross-partition
     cancellation); ints use the cheaper global-cumsum difference."""
     if jnp.issubdtype(x.dtype, jnp.floating):
         return G.seg_running_sum(start_of_row, x)
-    prefix = jnp.cumsum(x)
-    base = jnp.where(start_of_row > 0,
-                     jnp.take(prefix, jnp.maximum(start_of_row - 1, 0)),
+    prefix = jnp.cumsum(x, axis=0)
+    base = jnp.where(_rows(start_of_row > 0, x),
+                     jnp.take(prefix, jnp.maximum(start_of_row - 1, 0),
+                              axis=0),
                      jnp.zeros((), x.dtype))
     return prefix - base
 
@@ -238,37 +279,40 @@ def _layout(part_keys: List[AnyDeviceColumn],
     # sort — no per-key gathers, which are HBM-bound on TPU)
     from spark_rapids_tpu.columnar.device import sort_with_payload
     all_keys = [~active] + part_subkeys + order_subkeys
-    sorted_keys, perm, _ = sort_with_payload(all_keys, [])
-    active_s = ~sorted_keys[0]
-    part_sorted = sorted_keys[1:1 + len(part_subkeys)]
-    order_sorted = sorted_keys[1 + len(part_subkeys):]
-    pos = jnp.arange(cap, dtype=jnp.int32)
+    with jax.named_scope("window/sort"):
+        sorted_keys, perm, _ = sort_with_payload(all_keys, [])
+    with jax.named_scope("window/layout"):
+        active_s = ~sorted_keys[0]
+        part_sorted = sorted_keys[1:1 + len(part_subkeys)]
+        order_sorted = sorted_keys[1 + len(part_subkeys):]
+        pos = jnp.arange(cap, dtype=jnp.int32)
 
-    def boundaries(keys) -> jax.Array:
-        new = jnp.zeros(cap, dtype=bool).at[0].set(True)
-        for ks in keys:
-            d = ks[1:] != ks[:-1]
-            new = new.at[1:].set(new[1:] | d)
-        return new.at[1:].set(new[1:] | (active_s[1:] != active_s[:-1]))
+        def boundaries(keys) -> jax.Array:
+            new = jnp.zeros(cap, dtype=bool).at[0].set(True)
+            for ks in keys:
+                d = ks[1:] != ks[:-1]
+                new = new.at[1:].set(new[1:] | d)
+            return new.at[1:].set(
+                new[1:] | (active_s[1:] != active_s[:-1]))
 
-    new_part = boundaries(part_sorted)
-    new_peer = new_part | boundaries(list(part_sorted)
-                                     + list(order_sorted))
-    part_id = jnp.cumsum(new_part.astype(jnp.int32)) - 1
-    peer_id = jnp.cumsum(new_peer.astype(jnp.int32)) - 1
-    # boundary latches, not segment ops (XLA scatters serialize on TPU):
-    # partition start = last boundary position at-or-before me (cummax),
-    # ends = next boundary position at-or-after me (reverse cummin)
-    start_of_row = jax.lax.cummax(jnp.where(new_part, pos, -1))
-    part_last_flag = jnp.concatenate(
-        [new_part[1:], jnp.ones(1, dtype=bool)])
-    end_of_row = jnp.flip(jax.lax.cummin(
-        jnp.flip(jnp.where(part_last_flag, pos, cap))))
-    peer_last_flag = jnp.concatenate(
-        [new_peer[1:], jnp.ones(1, dtype=bool)])
-    peer_last = jnp.flip(jax.lax.cummin(
-        jnp.flip(jnp.where(peer_last_flag, pos, cap))))
-    part_size = end_of_row - start_of_row + 1
+        new_part = boundaries(part_sorted)
+        new_peer = new_part | boundaries(list(part_sorted)
+                                         + list(order_sorted))
+        part_id = jnp.cumsum(new_part.astype(jnp.int32)) - 1
+        peer_id = jnp.cumsum(new_peer.astype(jnp.int32)) - 1
+        # boundary latches, not segment ops (XLA scatters serialize on TPU):
+        # partition start = last boundary position at-or-before me (cummax),
+        # ends = next boundary position at-or-after me (reverse cummin)
+        start_of_row = jax.lax.cummax(jnp.where(new_part, pos, -1))
+        part_last_flag = jnp.concatenate(
+            [new_part[1:], jnp.ones(1, dtype=bool)])
+        end_of_row = jnp.flip(jax.lax.cummin(
+            jnp.flip(jnp.where(part_last_flag, pos, cap))))
+        peer_last_flag = jnp.concatenate(
+            [new_peer[1:], jnp.ones(1, dtype=bool)])
+        peer_last = jnp.flip(jax.lax.cummin(
+            jnp.flip(jnp.where(peer_last_flag, pos, cap))))
+        part_size = end_of_row - start_of_row + 1
     return _SortedLayout(perm, active_s, part_id, peer_id, pos,
                          start_of_row, end_of_row, peer_last, new_peer,
                          part_size)
@@ -359,16 +403,74 @@ def _to_orig(inv_perm: jax.Array, arr: jax.Array) -> jax.Array:
     return jnp.take(arr, inv_perm, axis=0)
 
 
-def _winner_value(val: DeviceColumn, lay: _SortedLayout,
+def _value_arrays(val: AnyDeviceColumn) -> Tuple[jax.Array, ...]:
+    """A fixed-width column's value arrays: ``(data,)``, or both limbs
+    of a DECIMAL128 column."""
+    return val.arrays()[:-1]
+
+
+def _winner_value(val: AnyDeviceColumn, lay: _SortedLayout,
                   win_pos: jax.Array, has: jax.Array
-                  ) -> Tuple[jax.Array, jax.Array]:
+                  ) -> Tuple[Tuple[jax.Array, ...], jax.Array]:
     """Gather the value at sorted position ``win_pos`` (per sorted row)."""
     cap = lay.pos.shape[0]
     orig = jnp.take(lay.perm, jnp.clip(win_pos, 0, cap - 1))
-    data = jnp.take(val.data, orig)
     validity = has & lay.active_s
-    data = jnp.where(validity, data, jnp.zeros((), data.dtype))
-    return data, validity
+    return _gather_value(val, orig, validity), validity
+
+
+def _gather_value(val: AnyDeviceColumn, orig: jax.Array,
+                  validity: jax.Array) -> Tuple[jax.Array, ...]:
+    """``val``'s arrays at the ORIGINAL rows ``orig``, zeroed where the
+    result is null."""
+    return tuple(
+        jnp.where(validity, jnp.take(a, orig), jnp.zeros((), a.dtype))
+        for a in _value_arrays(val))
+
+
+def _sum_limbs(scan: Callable, val: AnyDeviceColumn, valid_s: jax.Array,
+               lay: _SortedLayout, precision: int
+               ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Framed sum of a decimal source into a two-limb accumulator:
+    ``(hi, lo, fits)``. The source is split into 32-bit digits (two of
+    an int64 source, four of a two-limb one; the top digit signed), the
+    columns of one matrix that ``scan`` sums over the frame in int64 — a
+    partition's sum of fewer than 2**31 rows of 32-bit digits stays
+    under 2**63, and a bounded frame's difference of two prefixes is
+    exact — and the digit sums are recombined with their carries once a
+    lane. ``fits`` is false where the true sum does not fit 128 bits or
+    ``precision``. (One prefix sum and one row gather for all digits: a
+    gather costs by the element it addresses, and a digit a vector 3.5
+    times as much on the chip, docs/profiles/pr36.)"""
+    from spark_rapids_tpu.ops import int128 as I
+    z = jnp.int64(0)
+    m32 = jnp.int64(0xFFFFFFFF)
+    sh = jnp.int64(32)
+    limbs = [jnp.where(valid_s, jnp.take(a, lay.perm), z)
+             for a in _value_arrays(val)]
+    if len(limbs) == 2:
+        hi, lo = limbs
+        digits = [lo & m32, (lo >> sh) & m32, hi & m32, hi >> sh]
+    else:
+        digits = [limbs[0] & m32, limbs[0] >> sh]
+    summed = scan(jnp.stack(digits, axis=1))
+    sums = [summed[:, k] for k in range(len(digits))]
+    # value = sum(sums[i] << 32 i): carry each digit sum's overflow of
+    # 32 bits into the next (arithmetic shifts: the top one is signed)
+    words = []
+    carry = jnp.zeros_like(sums[0])
+    for total in sums:
+        t = total + carry
+        words.append(t & m32)
+        carry = t >> sh
+    while len(words) < 4:  # sign-extend an int64 source's two digits
+        words.append(carry & m32)
+        carry = carry >> sh
+    rlo = words[0] | (words[1] << sh)
+    rhi = words[2] | (words[3] << sh)
+    # bits 128 and up must repeat the sign bit
+    fits = carry == (rhi >> jnp.int64(63))
+    return rhi, rlo, fits & I.fits_precision(jnp, rhi, rlo, precision)
 
 
 def _frame_bounds(lay: _SortedLayout, frame: E.WindowFrame, cap: int
@@ -471,15 +573,26 @@ def _frame_bounds(lay: _SortedLayout, frame: E.WindowFrame, cap: int
 
 
 def _agg_window(agg: E.AggregateFunction, frame: E.WindowFrame,
-                val: Optional[DeviceColumn], lay: _SortedLayout,
-                out_type: T.DataType) -> Tuple[jax.Array, jax.Array]:
-    """(data, validity) in sorted space for one windowed aggregate."""
+                val: Optional[AnyDeviceColumn], lay: _SortedLayout,
+                out_type: T.DataType
+                ) -> Tuple[Tuple[jax.Array, ...], jax.Array]:
+    """(value arrays, validity) in sorted space for one windowed
+    aggregate: one array, or both limbs of a DECIMAL128 result."""
+    scope = ("window/sum" if isinstance(agg, (E.Count, E.Sum, E.Average))
+             else "window/extreme")
+    with jax.named_scope(scope):
+        return _agg_window_body(agg, frame, val, lay, out_type)
+
+
+def _agg_window_body(agg: E.AggregateFunction, frame: E.WindowFrame,
+                     val: Optional[AnyDeviceColumn], lay: _SortedLayout,
+                     out_type: T.DataType
+                     ) -> Tuple[Tuple[jax.Array, ...], jax.Array]:
+    from spark_rapids_tpu.columnar.device import DeviceDecimal128Column
     cap = lay.pos.shape[0]
     if val is not None:
-        data_s = jnp.take(val.data, lay.perm)
         valid_s = jnp.take(val.validity, lay.perm) & lay.active_s
     else:  # Count(*) — every active row counts
-        data_s = jnp.ones(cap, dtype=jnp.int64)
         valid_s = lay.active_s
     ones = jnp.where(valid_s, jnp.int64(1), jnp.int64(0))
 
@@ -487,24 +600,24 @@ def _agg_window(agg: E.AggregateFunction, frame: E.WindowFrame,
         """Inclusive running value; RANGE frames read the peer-group end."""
         pp = _prefix_in_part(x, lay.start_of_row)
         if frame.frame_type == "range":
-            return jnp.take(pp, lay.peer_last)
+            return jnp.take(pp, lay.peer_last, axis=0)
         return pp
 
     def whole(x):
         # running total read at the partition's END row (scatter-free)
         pp = _prefix_in_part(x, lay.start_of_row)
-        return jnp.take(pp, lay.end_of_row)
+        return jnp.take(pp, lay.end_of_row, axis=0)
 
     def bounded(x):
         pp = _prefix_in_part(x, lay.start_of_row)
         lo, hi = _frame_bounds(lay, frame, cap)
-        nonempty = hi >= lo
-        hi_v = jnp.take(pp, jnp.clip(hi, 0, cap - 1))
+        hi_v = jnp.take(pp, jnp.clip(hi, 0, cap - 1), axis=0)
         lo_base = jnp.where(
-            lo > lay.start_of_row,
-            jnp.take(pp, jnp.clip(lo - 1, 0, cap - 1)),
+            _rows(lo > lay.start_of_row, x),
+            jnp.take(pp, jnp.clip(lo - 1, 0, cap - 1), axis=0),
             jnp.zeros((), x.dtype))
-        return jnp.where(nonempty, hi_v - lo_base, jnp.zeros((), x.dtype))
+        return jnp.where(_rows(hi >= lo, x), hi_v - lo_base,
+                         jnp.zeros((), x.dtype))
 
     if frame.is_unbounded_whole:
         scan = whole
@@ -514,11 +627,22 @@ def _agg_window(agg: E.AggregateFunction, frame: E.WindowFrame,
         scan = bounded
 
     if isinstance(agg, E.Count):
-        return scan(ones), lay.active_s
+        return (scan(ones),), lay.active_s
+
+    if isinstance(agg, E.Sum) and T.is_limb_decimal(out_type):
+        rhi, rlo, fits = _sum_limbs(scan, val, valid_s, lay,
+                                    out_type.precision)
+        validity = (scan(ones) > 0) & lay.active_s & fits
+        z = jnp.int64(0)
+        return (jnp.where(validity, rhi, z),
+                jnp.where(validity, rlo, z)), validity
 
     if isinstance(agg, (E.Sum, E.Average)):
+        # a decimal sum stored in 64 bits (p + 10 <= 18) cannot pass its
+        # precision: 10**p a row, under 2**31 < 10**10 rows
         acc_dt = (jnp.float64 if isinstance(agg, E.Average)
                   else storage_jnp_dtype(out_type))
+        data_s = jnp.take(val.data, lay.perm)
         x = jnp.where(valid_s, data_s.astype(acc_dt),
                       jnp.zeros((), acc_dt))
         cnt = scan(ones)
@@ -528,11 +652,17 @@ def _agg_window(agg: E.AggregateFunction, frame: E.WindowFrame,
             d = s / jnp.maximum(cnt, 1).astype(jnp.float64)
         else:
             d = s
-        return jnp.where(validity, d, jnp.zeros((), d.dtype)), validity
+        return (jnp.where(validity, d, jnp.zeros((), d.dtype)),), validity
 
     if isinstance(agg, (E.Min, E.Max)):
         is_min = isinstance(agg, E.Min)
-        words = G.rank_words(DeviceColumn(val.dtype, data_s, valid_s))
+        sorted_arrs = [jnp.take(a, lay.perm) for a in _value_arrays(val)]
+        if isinstance(val, DeviceDecimal128Column):
+            words = G.limb_words(DeviceDecimal128Column(
+                val.dtype, *sorted_arrs, valid_s))
+        else:
+            words = G.rank_words(DeviceColumn(val.dtype, *sorted_arrs,
+                                              valid_s))
         bounded_frame = not (frame.is_unbounded_whole or frame.is_running)
         if bounded_frame:
             lo, hi = _frame_bounds(lay, frame, cap)
@@ -562,9 +692,8 @@ def _agg_window(agg: E.AggregateFunction, frame: E.WindowFrame,
                 tgt = (lay.peer_last if frame.frame_type == "range"
                        else lay.pos)
             orig = jnp.take(lay.perm, tgt)
-            d = jnp.take(val.data, orig)
             v = jnp.take(val.validity, orig) & lay.active_s
-            return jnp.where(v, d, jnp.zeros((), d.dtype)), v
+            return _gather_value(val, orig, v), v
         # ignore_nulls: running min/max over the position of valid rows
         posrank = (lay.pos + 1).astype(jnp.uint64)
         win, has = _seg_running_extreme(lay.part_id, [posrank],
@@ -723,14 +852,19 @@ def _build_window_fn(part_bound: Tuple[E.Expression, ...],
                              & lay.active_s,
                              order_specs[0].ascending,
                              order_specs[0].nulls_first)
-        inv = jnp.argsort(lay.perm)  # original row -> sorted pos
+        with jax.named_scope("window/unsort"):
+            inv = jnp.argsort(lay.perm)  # original row -> sorted pos
+
+        def to_orig(arrs, v):
+            with jax.named_scope("window/unsort"):
+                return (tuple(_to_orig(inv, a) for a in arrs),
+                        _to_orig(inv, v))
         outs = []
         for item in items:
             kind = item[0]
             if kind == "rank":
                 d, v = _ranking(item[1], lay)
-                outs.append(((_to_orig(inv, d),),
-                             _to_orig(inv, v)))
+                outs.append(to_orig((d,), v))
             elif kind == "offset":
                 _k, func, src_i, dflt_i = item
                 val = X.dev_eval(all_exprs[src_i], ctx)
@@ -745,15 +879,13 @@ def _build_window_fn(part_bound: Tuple[E.Expression, ...],
                     else:
                         dflt = (dc.data, dc.validity)
                 arrs, v = _offset_fn(func, val, dflt, lay)
-                outs.append((tuple(_to_orig(inv, a) for a in arrs),
-                             _to_orig(inv, v)))
+                outs.append(to_orig(arrs, v))
             else:  # agg
                 _k, agg, frame, src_i, out_type = item
                 val = (X.dev_eval(all_exprs[src_i], ctx)
                        if src_i is not None else None)
-                d, v = _agg_window(agg, frame, val, lay, out_type)
-                outs.append(((_to_orig(inv, d),),
-                             _to_orig(inv, v)))
+                arrs, v = _agg_window(agg, frame, val, lay, out_type)
+                outs.append(to_orig(arrs, v))
         return outs
     return named_jit("srt_window", fn)
 
@@ -850,7 +982,8 @@ class TpuWindowExec(TpuExec):
                 tuple(items), all_exprs))
         lit_vals = X.literal_values(list(all_exprs))
         self.metrics.create(M.DISPATCH_COUNT, M.ESSENTIAL).add(1)
-        with self.metrics.timed(M.OP_TIME), G.nan_scope(salt[0]):
+        TR.first_dispatch(self.metrics, fn)
+        with G.nan_scope(salt[0]):
             outs = fn(batch.columns, batch.active, lit_vals)
         new_cols: List[AnyDeviceColumn] = list(batch.columns)
         for (arrs, validity), dt in zip(outs, out_types):
@@ -858,8 +991,31 @@ class TpuWindowExec(TpuExec):
         return DeviceBatch(self.schema, new_cols, batch.active,
                            batch._num_rows)
 
+    def _decimal_aggs(self) -> int:
+        """Window aggregates of this exec that read a decimal source."""
+        n = 0
+        for alias in self.window_exprs:
+            func = alias.child.func
+            if isinstance(func, E.AggregateExpression) \
+                    and func.func.children and isinstance(
+                        func.func.children[0].data_type, T.DecimalType):
+                n += 1
+        return n
+
+    def _run_whole(self, handles: List) -> DeviceBatch:
+        """One program over the concatenation of ``handles``' batches
+        (released here): the host's part of it is ``windowTime``."""
+        with self.metrics.timed(M.WINDOW_TIME):
+            parts = [h.get() for h in handles]
+            whole = parts[0] if len(parts) == 1 else concat_device(parts)
+            for h in handles:
+                h.close()
+            return self._run_batch(whole)
+
     def device_partitions(self) -> List[DevicePartitionThunk]:
         goal = self.conf.batch_size_rows
+        self.metrics.create(M.WINDOW_DECIMAL_AGG_COUNT, M.ESSENTIAL).add(
+            self._decimal_aggs())
 
         def make(thunk: DevicePartitionThunk) -> DevicePartitionThunk:
             def run() -> Iterator[DeviceBatch]:
@@ -882,13 +1038,14 @@ class TpuWindowExec(TpuExec):
                     handles.append(self.register_spillable(store, b))
                 if not handles:
                     return
-                total = sum(h.rows for h in handles)
+                # the one value this exec reads back: a batch's row
+                # count, where its producer attached none
+                total = sum(h.row_count(site="windowRows")
+                            for h in handles)
+                self.metrics.create(M.WINDOW_ROWS, M.ESSENTIAL).add(total)
                 if total <= goal or len(handles) == 1 or not part_bound:
                     # small partition (or global window): one program
-                    whole = concat_device([h.get() for h in handles])
-                    for h in handles:
-                        h.close()
-                    yield self._run_batch(whole)
+                    yield self._run_whole(handles)
                     return
                 # KEY-BATCHING (GpuKeyBatchingIterator.scala:35 role):
                 # chunk the stream so every partition-key GROUP lands
@@ -896,27 +1053,23 @@ class TpuWindowExec(TpuExec):
                 # batch-row goal and inputs are spillable handles, so
                 # the partition never has to fit HBM at once
                 n_chunks = max(1, (total + goal - 1) // goal)
-                pids_per_batch = _key_chunk_ids(keycols, actives, goal,
-                                                n_chunks)
-                keycols.clear()
                 buckets: List[List] = [[] for _ in range(n_chunks)]
-                for h, pids, act in zip(handles, pids_per_batch, actives):
-                    b, pids = realign_spilled_pids(h, pids, act)
-                    parts = split_by_pid(b, pids, n_chunks)
-                    h.close()
-                    for pid, part in enumerate(parts):
-                        if part is not None:
-                            buckets[pid].append(
-                                self.register_spillable(store, part))
-                for pid in range(n_chunks):
-                    parts = [h.get() for h in buckets[pid]]
-                    if not parts:
-                        continue
-                    whole = parts[0] if len(parts) == 1 \
-                        else concat_device(parts)
-                    for h in buckets[pid]:
+                with self.metrics.timed(M.WINDOW_TIME):
+                    pids_per_batch = _key_chunk_ids(keycols, actives, goal,
+                                                    n_chunks)
+                    keycols.clear()
+                    for h, pids, act in zip(handles, pids_per_batch,
+                                            actives):
+                        b, pids = realign_spilled_pids(h, pids, act)
+                        parts = split_by_pid(b, pids, n_chunks)
                         h.close()
-                    yield self._run_batch(whole)
+                        for pid, part in enumerate(parts):
+                            if part is not None:
+                                buckets[pid].append(
+                                    self.register_spillable(store, part))
+                for bucket in buckets:
+                    if bucket:
+                        yield self._run_whole(bucket)
             return run
         return [make(t) for t in device_channel(self.child)]
 

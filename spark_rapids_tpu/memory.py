@@ -173,19 +173,23 @@ class SpillableBatch:
         """The device batch, re-promoted through the tiers if spilled."""
         return self._store._access(self._id)
 
-    @property
-    def rows(self) -> int:
+    def row_count(self, site: str = "rowCount") -> int:
         """Row count; cached when the producer attached one, resolved
-        (one D2H sync, or free from the host tier) otherwise."""
+        (one D2H sync booked to ``deviceSync site=``, or free from the
+        host tier) otherwise."""
         st = self._state
         if st.rows is None:
             if st.tier == TIER_DEVICE:
-                st.rows = st.device.row_count()
+                st.rows = st.device.row_count(site)
             elif st.tier == TIER_HOST:
                 st.rows = st.host.num_rows
             else:
-                st.rows = self._store._access(self._id).row_count()
+                st.rows = self._store._access(self._id).row_count(site)
         return st.rows
+
+    @property
+    def rows(self) -> int:
+        return self.row_count()
 
     @property
     def capacity_hint(self) -> Optional[int]:

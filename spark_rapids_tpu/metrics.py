@@ -85,6 +85,10 @@ JOIN_DEMOTED_COUNT = "joinDemotedCount"  # shuffled joins run as broadcast
 JOIN_CONDITIONAL_COUNT = "joinConditionalCount"  # conditional mask joins run
 JOIN_CONDITION_PAIRS = "joinConditionPairs"  # candidate pairs evaluated
 JOIN_CONDITION_TIME = "joinConditionTime"    # host wall around those joins
+# what the window execs of a plan did (exec/window.py)
+WINDOW_TIME = "windowTime"    # host wall: concat, key batching, enqueue
+WINDOW_ROWS = "windowRows"    # rows handed to the window programs
+WINDOW_DECIMAL_AGG_COUNT = "windowDecimalAggCount"  # decimal-source aggs
 # what the analysis rules of a statement did (sql/session.py)
 DECORRELATED_SUBQUERY_COUNT = "decorrelatedSubqueryCount"
 
@@ -171,6 +175,17 @@ METRIC_DESCRIPTIONS: Dict[str, str] = {
     JOIN_CONDITION_TIME: "host wall around the conditional semi/anti "
                          "joins: count program, mask program, the "
                          "pairs' read (ns; inside joinTime)",
+    WINDOW_TIME: "host wall around the window execs' work (ns): "
+                 "concatenating a partition's batches, key batching "
+                 "over batchSizeRows, enqueueing the window program — "
+                 "not device time",
+    WINDOW_ROWS: "rows the window execs were handed, from the row "
+                 "counts of their input batches (read back under "
+                 "deviceSync site=windowRows only where the producer "
+                 "attached none)",
+    WINDOW_DECIMAL_AGG_COUNT: "window aggregates over a decimal source "
+                              "run on the device, once per window exec "
+                              "executed",
     DECORRELATED_SUBQUERY_COUNT: "correlated [NOT] EXISTS subqueries "
                                  "the analysis rule turned into left "
                                  "semi or left anti joins, once per "

@@ -45,6 +45,7 @@ from spark_rapids_tpu.columnar.device import (AnyDeviceColumn, DeviceBatch,
                                               take_columns)
 from spark_rapids_tpu.ops import exprs as X
 from spark_rapids_tpu.ops import groupby as G
+from spark_rapids_tpu.ops.rle import run_index
 from spark_rapids_tpu.sql import expressions as E
 from spark_rapids_tpu.sql import types as T
 
@@ -258,9 +259,21 @@ def _build_gather_fn(out_cap: int, join_type: str) -> Callable:
         cap_l = m.shape[0]
         cap_r = order_r.shape[0]
         s = jnp.arange(out_cap, dtype=jnp.int64)
-        li = jnp.clip(
-            jnp.searchsorted(offsets, s, side="right") - 1, 0, cap_l - 1
-        ).astype(jnp.int32)
+        if right_outer:
+            # the stream row of output lane s, as below, by one scatter
+            # and one prefix sum (the page decode's spelling, PR 27).
+            # The search's loop gathers `offsets` out_cap lanes at a
+            # time, eighteen times over; on the chip that gather's
+            # seconds follow where the runtime put `offsets`, not what
+            # is in it: 0.29-0.41 s of query 51's full outer join, in
+            # two groups, query to query on the same data (PERF.md §6,
+            # PR 36). Inner and left joins keep the search until a
+            # perf_opt pairs them on every cell (ROADMAP S17.1).
+            li = run_index(offsets, out_cap)
+        else:
+            li = jnp.clip(
+                jnp.searchsorted(offsets, s, side="right") - 1, 0,
+                cap_l - 1).astype(jnp.int32)
         k = s - jnp.take(offsets, li)
         in_pairs = s < total_pairs
         has_match = jnp.take(m, li) > 0

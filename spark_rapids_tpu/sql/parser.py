@@ -106,6 +106,10 @@ class _Parser:
         # what a subquery's unresolved name is looked up in to call it
         # an outer reference rather than misspelt
         self._outer: List = []
+        # common table expressions in scope, by lower-cased name: the
+        # plan each stands for (a WITH's own dict while its statement
+        # is parsed; the enclosing one again after it)
+        self._ctes: dict = {}
 
     # -- token helpers -----------------------------------------------------
 
@@ -139,9 +143,11 @@ class _Parser:
     # -- query -------------------------------------------------------------
 
     def query(self):
-        """select_core (UNION [ALL] select_core)* [ORDER BY] [LIMIT] —
-        a trailing ORDER BY/LIMIT binds to the WHOLE union (SQL spec),
-        not the last branch."""
+        """[WITH name AS (query) [, ...]] select_core (UNION [ALL]
+        select_core)* [ORDER BY] [LIMIT] — a trailing ORDER BY/LIMIT
+        binds to the WHOLE union (SQL spec), not the last branch."""
+        if self.at_kw("with"):
+            return self._with_query()
         df = self.select_stmt()
         while self.kw("union"):
             all_ = self.kw("all")
@@ -156,6 +162,41 @@ class _Parser:
             assert kind == "num", f"LIMIT expects a number, got {val!r}"
             df = df.limit(int(val))
         return df
+
+    def _with_query(self):
+        """``WITH a AS (query), b AS (query) query``: a name is in scope
+        for the definitions after it and for the statement, shadows a
+        catalog view of its name, and each reference is planned as the
+        derived table it stands for (``relation``)."""
+        self.expect("with")
+        if self.at_kw("recursive") and self.peek(1)[0] == "id" \
+                and self.peek(1)[1].lower() != "as":
+            raise NotImplementedError(
+                "WITH RECURSIVE is not supported: a common table "
+                "expression may read the ones before it, not itself")
+        enclosing = self._ctes
+        self._ctes = dict(enclosing)
+        try:
+            while True:
+                kind, name = self.next()
+                if kind != "id":
+                    raise ValueError(
+                        f"expected a name after WITH, got {name!r}")
+                if self.peek()[1] == "(":
+                    raise NotImplementedError(
+                        f"WITH {name} (column list) AS ... is not "
+                        "supported: name the columns in the common "
+                        "table expression's select list")
+                self.expect("as")
+                self.expect("(")
+                self._ctes[name.lower()] = self.query().plan
+                self.expect(")")
+                if self.peek()[1] != ",":
+                    break
+                self.next()
+            return self.query()
+        finally:
+            self._ctes = enclosing
 
     def select_stmt(self):
         self.expect("select")
@@ -273,7 +314,14 @@ class _Parser:
             return df.alias(alias) if alias else df
         kind, name = self.next()
         assert kind == "id", f"expected table name, got {name!r}"
-        df = self.session.table(name)
+        cte = self._ctes.get(name.lower())
+        if cte is not None:
+            # a second reference shares the first's expression ids, as
+            # a view read twice does: the join re-aliases its right side
+            from spark_rapids_tpu.sql.dataframe import DataFrame
+            df = DataFrame(L.SubqueryAlias(name, cte), self.session)
+        else:
+            df = self.session.table(name)
         alias = self._relation_alias()
         return df.alias(alias) if alias else df
 
@@ -292,13 +340,29 @@ class _Parser:
                  having: Optional[Column]):
         from spark_rapids_tpu.sql.dataframe import DataFrame
 
+        def window_args(w: E.WindowExpression) -> List[E.Expression]:
+            """What a window expression reads of its input: its
+            function's arguments, partition keys and order keys — not
+            the function itself, which the Window node evaluates."""
+            f = w.func
+            if isinstance(f, E.AggregateExpression):
+                f = f.func
+            return list(f.children) + w.children[1:]
+
         def has_group_agg(e: E.Expression) -> bool:
-            """Aggregate NOT under an OVER clause (window aggs project)."""
+            """An aggregate the GROUP BY evaluates: one outside any OVER
+            clause, or inside the arguments or keys of one
+            (``sum(sum(x)) over (...)``). A window's own aggregate
+            function projects."""
             if isinstance(e, E.WindowExpression):
-                return False
+                return any(has_group_agg(c) for c in window_args(e))
             if isinstance(e, E.AggregateExpression):
                 return True
             return any(has_group_agg(c) for c in e.children)
+
+        def has_window(e: E.Expression) -> bool:
+            return bool(e.collect(
+                lambda x: isinstance(x, E.WindowExpression)))
 
         resolved: List[Tuple[Optional[E.Expression], Optional[str]]] = []
         has_agg = False
@@ -311,6 +375,10 @@ class _Parser:
                 has_agg = True
             resolved.append((e, name))
         having_e = df._resolve(having) if having is not None else None
+        if having_e is not None and has_window(having_e):
+            raise NotImplementedError(
+                "a window function in HAVING is not supported: compute "
+                "it in a derived table and filter on its column")
         if having_e is not None and has_group_agg(having_e):
             has_agg = True
 
@@ -326,6 +394,10 @@ class _Parser:
 
         # Aggregate + Project (Spark analyzer shape)
         group_exprs = [df._resolve(g) for g in (group or [])]
+        if any(has_window(g) for g in group_exprs):
+            raise NotImplementedError(
+                "a window function in GROUP BY is not supported: compute "
+                "it in a derived table and group by its column")
         grouping: List[E.Expression] = []
         group_attr_by_repr = {}
         for g in group_exprs:
@@ -340,18 +412,32 @@ class _Parser:
 
         def extract(e: E.Expression) -> E.Expression:
             """Replace agg subtrees (and grouping-expr matches) with
-            attribute refs into the Aggregate's output."""
+            attribute refs into the Aggregate's output. Beneath an OVER
+            the same holds for what the window reads (``window_args``);
+            its own function stays, for the Window node above the
+            Aggregate (Spark's Aggregate -> Window -> Project)."""
             rg = group_attr_by_repr.get(repr(e))
             if rg is not None:
                 return rg
+            if isinstance(e, E.AggregateExpression):
+                alias = E.Alias(e, f"_a{len(agg_aliases)}")
+                agg_aliases.append(alias)
+                return alias.to_attribute()
+            if isinstance(e, E.WindowExpression):
+                f = e.func
+                if isinstance(f, E.AggregateExpression):
+                    f = f.with_children([rebuilt(f.func)])
+                else:
+                    f = rebuilt(f)
+                return e.with_children(
+                    [f] + [extract(c) for c in e.children[1:]])
+            return rebuilt(e)
 
-            def rule(x):
-                if isinstance(x, E.AggregateExpression):
-                    alias = E.Alias(x, f"_a{len(agg_aliases)}")
-                    agg_aliases.append(alias)
-                    return alias.to_attribute()
-                return None
-            return e.transform(rule)
+        def rebuilt(e: E.Expression) -> E.Expression:
+            kids = [extract(c) for c in e.children]
+            if all(k is c for k, c in zip(kids, e.children)):
+                return e
+            return e.with_children(kids)
 
         out_items: List[E.Expression] = []
         for e, name in resolved:

@@ -117,6 +117,13 @@ class CpuWindowExec(P.PhysicalPlan):
             else:
                 src = agg.children[0]
                 if isinstance(agg, E.Average):
+                    if isinstance(src.data_type, T.DecimalType):
+                        # it was a float mean stored as the unscaled
+                        # value: no answer is better than that one
+                        raise NotImplementedError(
+                            f"window average over {src.data_type} is not "
+                            "supported: it is a decimal division into "
+                            "decimal(p + 4, s + 4)")
                     src = E.Cast(src, T.DoubleT)
                 vals = E.bind_references(src, child_out).eval(batch)
         elif isinstance(func, E.Lag):
@@ -213,8 +220,14 @@ class CpuWindowExec(P.PhysicalPlan):
                         dt: T.DataType,
                         order_vals: Optional[HostColumn] = None,
                         asc: bool = True) -> Tuple[np.ndarray, np.ndarray]:
+        from spark_rapids_tpu.ops import int128 as I
         m = len(sorted_rows)
         v = vals.data[sorted_rows]
+        if isinstance(vals.dtype, T.DecimalType):
+            # exact Python integers whatever the width: a decimal's sum
+            # is ten digits wider than its source and passes 64 bits
+            v = (I.to_pyints(v[:, 0], v[:, 1])
+                 if T.is_limb_decimal(vals.dtype) else v.astype(object))
         ok = vals.validity[sorted_rows].astype(bool)
         # frame [lo_i, hi_i] inclusive bounds per sorted position
         pos = np.arange(m)
@@ -287,7 +300,8 @@ class CpuWindowExec(P.PhysicalPlan):
             hi = pos + ((1 << 62) if frame.upper is None else frame.upper)
             lo = np.clip(lo, 0, m)
             hi = np.clip(hi, -1, m - 1)
-        out = np.zeros(m, dtype=T.numpy_dtype(dt))
+        limb_out = T.is_limb_decimal(dt)
+        out = np.zeros(m, dtype=object if limb_out else T.numpy_dtype(dt))
         valid = np.zeros(m, dtype=bool)
         for i in range(m):
             l, h = int(lo[i]), int(hi[i])
@@ -307,7 +321,11 @@ class CpuWindowExec(P.PhysicalPlan):
             if len(sl) == 0:
                 continue
             if isinstance(agg, E.Sum):
-                out[i], valid[i] = sl.sum(), True
+                total = sl.sum()
+                if isinstance(dt, T.DecimalType) \
+                        and abs(total) >= 10 ** dt.precision:
+                    continue  # past the result's precision: null (non-ANSI)
+                out[i], valid[i] = total, True
             elif isinstance(agg, E.Min):
                 # Spark total order: NaN is greatest, so min skips NaNs
                 if np.issubdtype(sl.dtype, np.floating):
@@ -332,6 +350,8 @@ class CpuWindowExec(P.PhysicalPlan):
                 out[i], valid[i] = sl[-1], True
             else:
                 raise NotImplementedError(type(agg).__name__)
+        if limb_out:
+            return np.stack(I.from_pyints(out), axis=1), valid
         return out, valid
 
     def simple_string(self):

@@ -1521,7 +1521,7 @@ def generate_supported_ops() -> str:
             f"| {sig_str(rule.checks.inputs)} | {note} |")
     lines += [
         "",
-        "## SQL dialect: relation lists and subqueries",
+        "## SQL dialect: relation lists, subqueries, WITH and windows",
         "",
         "`FROM a, b JOIN c ON ..., d` is a comma-separated list of "
         "relations, each with its own JOIN chain (JOIN binds tighter "
@@ -1586,8 +1586,56 @@ def generate_supported_ops() -> str:
         "left join runs on CPU`); under `test.forceDevice` an error. "
         "Inner, cross, left semi and left anti joins evaluate theirs "
         "on the device |",
+        "| `WITH a AS (query), b AS (query) query` | a common table "
+        "expression is in scope for the ones after it and for the "
+        "statement (a derived table's own `WITH` for that derived "
+        "table), shadows a catalog view of its name, and every "
+        "reference is planned as the derived table it stands for: two "
+        "references are two subplans, the second re-aliased under "
+        "fresh ids by the join as a view read twice is. The plan "
+        "cache and the fingerprints see the inlined plan |",
+        "| `WITH RECURSIVE ...`, `WITH a (x, y) AS ...` | refused: "
+        "`NotImplementedError` by name |",
+        "| a window function in the select list of a grouped query "
+        "(`sum(sum(x)) OVER (PARTITION BY k ORDER BY d ROWS BETWEEN "
+        "UNBOUNDED PRECEDING AND CURRENT ROW)` under `GROUP BY k, d`) "
+        "| Spark's Aggregate -> Window -> Project: the aggregates "
+        "inside the window's arguments, partition keys and order keys "
+        "are evaluated by the GROUP BY (grouping expressions matched) "
+        "as those outside one, the window's own function by a Window "
+        "node above it, after any HAVING |",
+        "| a window function in HAVING or GROUP BY | refused: "
+        "`NotImplementedError` by name |",
         "| a token the grammar has no place for | `ValueError` naming "
         "the token |",
+        "",
+        "## Window aggregates by source type",
+        "",
+        "`TpuWindowExec` (`exec/window.py`, the program `srt_window`) "
+        "runs ranking and offset functions over any device type; its "
+        "aggregates by the type of what they aggregate. Frames: the "
+        "whole partition, running (ROWS or RANGE to the current row), "
+        "bounded ROWS and value-bounded RANGE for sum / count / avg / "
+        "min / max; first / last take the whole and the running "
+        "frames. What is not here runs on the CPU engine and says so "
+        "in the fallback report (under `test.forceDevice`: an error).",
+        "",
+        "| Source | On the device | Refused by name |",
+        "|---|---|---|",
+        "| BYTE, SHORT, INT, LONG, DATE, TIMESTAMP, BOOLEAN | sum, "
+        "count, avg, min, max, first, last | DISTINCT aggregates |",
+        "| FLOAT, DOUBLE | count, min, max, first, last; sum and avg "
+        "under `spark.rapids.sql.variableFloatAgg.enabled` | |",
+        "| DECIMAL, 64-bit (p <= 18) and two-limb (p <= 38) | sum "
+        "(`decimal(min(38, p + 10), s)`, accumulated in 64 bits while "
+        "that fits 18 digits and in two limbs past them; null where "
+        "the sum passes the result's precision, non-ANSI), count, min, "
+        "max, first, last (the source's type; nulls skipped, a frame "
+        "without a value gives null) | avg (`window average over "
+        "DecimalType ... runs on CPU`: a division into `decimal(p + 4, "
+        "s + 4)`) |",
+        "| STRING, BINARY | lag / lead only | every aggregate "
+        "(`window aggregate over string runs on CPU`) |",
         "",
         "## Parquet device decode (encoding matrix)",
         "",

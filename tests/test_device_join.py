@@ -140,6 +140,25 @@ def test_join_duplicate_heavy_keys():
         fn, expect_execs=["TpuBroadcastHashJoin"])
 
 
+@pytest.mark.parametrize("jt", ["left", "right", "full"])
+def test_outer_join_many_to_many_with_unmatched_rows(jt):
+    """The pair-expanding gather of an outer join (every output lane
+    finds its stream row: by ``ops/rle.run_index`` for right and full,
+    by the search for left): keys that match many to many, keys only
+    one side has, null keys on both sides, and stream rows without a
+    match between rows that have several."""
+    def fn(s):
+        l = s.createDataFrame(
+            {"k": [1] * 7 + [None, 5, 2, 2, 9, None, 2, 3] + [4] * 5,
+             "a": list(range(20))}, "k int, a int")
+        r = s.createDataFrame(
+            {"k2": [2] * 6 + [7, None, 1, 1, 1, 8] + [4] * 4 + [None],
+             "b": list(range(17))}, "k2 int, b int")
+        return l.join(r.repartition(2), F.col("k") == F.col("k2"), jt)
+    assert_tpu_and_cpu_equal_collect(
+        fn, expect_execs=["TpuShuffledHashJoin"])
+
+
 def _fact_and_dimension(spark, dup, m=300, n=3000):
     """A 3,000-row fact against a 300-row dimension on 64-bit keys: a
     tenth of the foreign keys null, some past the dimension's last

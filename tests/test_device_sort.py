@@ -98,6 +98,38 @@ def test_topn_fusion():
         expect_execs=["TpuTopN"])
 
 
+@pytest.mark.parametrize("n, limit", [(200_000, 100), (500, 100),
+                                      (500, 7), (90, 1000)])
+def test_topn_yields_its_limits_own_bucket(n, limit):
+    """A TopN's kept rows are a prefix of its sorted input; the program
+    emits them at the limit's capacity bucket (never more than the
+    input's), so that a collect does not fetch the input's capacity for
+    a hundred rows."""
+    import numpy as np
+    from spark_rapids_tpu.columnar.device import bucket_capacity
+    from spark_rapids_tpu.exec.sort import TpuTopNExec
+    from spark_rapids_tpu.sql.session import TpuSparkSession
+    spark = TpuSparkSession({"spark.rapids.sql.enabled": "true"})
+    try:
+        rng = np.random.default_rng(n)
+        df = spark.createDataFrame(
+            {"a": rng.permutation(n).tolist(),
+             "b": rng.integers(0, 1 << 40, n).tolist(),
+             "c": [f"s{i % 13}" for i in range(n)]},
+            "a long, b long, c string", num_partitions=1)
+        node = spark.plan_physical(df.orderBy("a").limit(limit).plan)
+        while not isinstance(node, TpuTopNExec):
+            node = node.children[0]
+        (thunk,) = node.device_partitions()
+        (out,) = list(thunk())
+        assert out.row_count() == min(n, limit)
+        assert out.capacity == min(bucket_capacity(n),
+                                   bucket_capacity(limit))
+        assert out.to_host().to_pydict()["a"] == list(range(min(n, limit)))
+    finally:
+        spark.stop()
+
+
 def test_sort_after_filter_keeps_masked_rows_out():
     assert_tpu_and_cpu_equal_collect(
         lambda s: _df(s, [("a", IntegerGen()), ("b", IntegerGen())])

@@ -272,3 +272,170 @@ def test_lag_lead_decimal128_on_device():
             F.lead("d", 2).over(w).alias("ld"),
             F.lag("d", 1, Decimal("0.55")).over(w).alias("lgd"))
     assert_tpu_and_cpu_equal_collect(q)
+
+
+# ---------------------------------------------------------------------------
+# Window aggregates over decimal sources (PR 36): 64-bit and two-limb
+# sources, 128-bit accumulators; the CPU engine is the oracle
+# ---------------------------------------------------------------------------
+
+_DEC_TYPES = {"decimal(7,2)": 7, "decimal(17,2)": 17, "decimal(27,2)": 27}
+_DEC_FRAMES = {
+    "whole": lambda w: w.rowsBetween(Window.unboundedPreceding,
+                                     Window.unboundedFollowing),
+    "running": lambda w: w.rowsBetween(Window.unboundedPreceding, 0),
+    "bounded": lambda w: w.rowsBetween(-2, 1),
+    # empty at a partition's last row, and in every one-row partition
+    "following": lambda w: w.rowsBetween(1, 3),
+}
+_DEC_AGGS = {
+    "sum_count": lambda: [F.sum("v"), F.count("v"), F.count("*")],
+    "min_max": lambda: [F.min("v"), F.max("v")],
+    "first_last": lambda: [F.first("v"), F.last("v"),
+                           F.first("v", ignorenulls=True),
+                           F.last("v", ignorenulls=True)],
+}
+
+
+def _decimal_rows(precision: int, n: int = 300, seed: int = 36):
+    """Partitions of 1 to some 40 rows (keys 90.. have one row each),
+    a unique order key, values over the whole of the type with both
+    signs, a fifth of them null and one partition null throughout."""
+    import random
+    from decimal import Decimal
+    rng = random.Random(seed * 100 + precision)
+    k = [90 + i if i < 6 else rng.randrange(8) for i in range(n)]
+    o = list(range(n))
+    rng.shuffle(o)
+    top = 10 ** precision - 1
+    v = [None if (rng.random() < 0.2 or key == 3)
+         else Decimal(rng.randint(-top, top)).scaleb(-2) for key in k]
+    return {"k": k, "o": o, "v": v}
+
+
+# bounded frames run first/last on the CPU engine, for every source type
+_DEC_CASES = [(t, f, a) for t in sorted(_DEC_TYPES)
+              for f in sorted(_DEC_FRAMES) for a in sorted(_DEC_AGGS)
+              if not (a == "first_last" and f in ("bounded", "following"))]
+
+
+@pytest.mark.parametrize("dec_type,frame,aggs", _DEC_CASES)
+def test_decimal_window_aggregates(dec_type, frame, aggs):
+    rows = _decimal_rows(_DEC_TYPES[dec_type])
+    w = _DEC_FRAMES[frame](_w())
+
+    def q(spark):
+        df = spark.createDataFrame(rows, f"k int, o int, v {dec_type}",
+                                   num_partitions=2)
+        return df.select("k", "o", "v", *[
+            a.over(w).alias(f"a{i}")
+            for i, a in enumerate(_DEC_AGGS[aggs]())])
+    assert_tpu_and_cpu_equal_collect(
+        q, conf={"spark.rapids.sql.test.forceDevice": "true"},
+        expect_execs=["TpuWindow"])
+
+
+@pytest.mark.parametrize("dec_type,sum_type", [
+    ("decimal(7,2)", "decimal(17,2)"), ("decimal(8,0)", "decimal(18,0)"),
+    ("decimal(9,0)", "decimal(19,0)"), ("decimal(17,2)", "decimal(27,2)"),
+    ("decimal(27,2)", "decimal(37,2)"), ("decimal(30,4)", "decimal(38,4)")])
+def test_decimal_window_result_types(dec_type, sum_type):
+    """sum is decimal(min(38, p + 10), s); min/max/first/last keep the
+    source's type; count is a long."""
+    from spark_rapids_tpu.sql.session import TpuSparkSession
+    spark = TpuSparkSession({})
+    try:
+        df = spark.createDataFrame({"k": [1], "o": [1], "v": [None]},
+                                   f"k int, o int, v {dec_type}")
+        out = df.select(F.sum("v").over(_w()).alias("s"),
+                        F.max("v").over(_w()).alias("m"),
+                        F.first("v").over(_w()).alias("f"),
+                        F.count("v").over(_w()).alias("c"))
+        got = [f.data_type.simple_string for f in out.schema.fields]
+        assert got == [sum_type, dec_type, dec_type, "bigint"]
+    finally:
+        spark.stop()
+
+
+@pytest.mark.parametrize("frame", ["whole", "running", "bounded"])
+def test_decimal_window_sum_overflows_to_null(frame):
+    """Past decimal(38, s) a sum is null (non-ANSI), also where the true
+    value no longer fits 128 bits and the limbs wrap to a small number:
+    three times 9e37 is 2.7e38, -0.7e38 mod 2**128."""
+    from decimal import Decimal
+    big = Decimal(9 * 10 ** 37)
+    rows = {"k": [1, 1, 1, 1, 2, 2, 2, 2, 3],
+            "o": list(range(9)),
+            "v": [big, big, big, -big, -big, -big, -big, big, big]}
+    w = _DEC_FRAMES[frame](_w())
+    from spark_rapids_tpu.sql.session import TpuSparkSession
+
+    def q(spark):
+        return spark.createDataFrame(rows, "k int, o int, v decimal(38,0)") \
+            .select("k", "o", F.sum("v").over(w).alias("s"))
+    assert_tpu_and_cpu_equal_collect(
+        q, conf={"spark.rapids.sql.test.forceDevice": "true"},
+        expect_execs=["TpuWindow"])
+    spark = TpuSparkSession({"spark.rapids.sql.enabled": "false"})
+    try:
+        got = {r[1]: r[2] for r in q(spark).collect()}
+    finally:
+        spark.stop()
+    want = {"whole": [2 * big, 2 * big, 2 * big, 2 * big, -2 * big,
+                      -2 * big, -2 * big, -2 * big, big],
+            "running": [big, 2 * big, 3 * big, 2 * big, -big, -2 * big,
+                        -3 * big, -2 * big, big],
+            "bounded": [2 * big, 3 * big, 2 * big, big, -2 * big, -3 * big,
+                        -2 * big, -big, big]}[frame]
+    limit = Decimal(10 ** 38)
+    assert [got[i] for i in range(9)] == [
+        None if abs(v) >= limit else v for v in want]
+
+
+def test_decimal_window_key_batching_over_budget():
+    """Over batchSizeRows the key batching runs: every partition lands
+    whole in one chunk, and a chunk's 128-bit sums see only its rows."""
+    rows = _decimal_rows(17, n=2000)
+
+    def q(spark):
+        df = spark.createDataFrame(rows, "k int, o int, v decimal(17,2)",
+                                   num_partitions=3)
+        w = Window.partitionBy("k").orderBy("o")
+        return df.select("k", "o", F.sum("v").over(w).alias("s"),
+                         F.max("v").over(w).alias("m"),
+                         F.sum(F.col("v").cast("decimal(27,2)")).over(w)
+                         .alias("s2"))
+    assert_tpu_and_cpu_equal_collect(
+        q, conf={"spark.rapids.sql.batchSizeRows": "256",
+                 "spark.rapids.sql.test.forceDevice": "true",
+                 "spark.rapids.memory.tpu.poolSize": str(1 << 16)},
+        expect_execs=["TpuWindow"])
+
+
+@pytest.mark.parametrize("make,reason", [
+    (lambda: F.avg("v"), "window average over DecimalType"),
+    (lambda: F.max("s"), "window aggregate over string"),
+    (lambda: F.count("s"), "window aggregate over string")],
+    ids=["avg_decimal", "max_string", "count_string"])
+def test_window_aggregates_refused_by_name(make, reason):
+    """What stays off the device says so in the fallback report (and
+    the CPU engine, which has no decimal division for a window's
+    average either, refuses that one by name too)."""
+    from decimal import Decimal
+    from spark_rapids_tpu.sql.session import TpuSparkSession
+    spark = TpuSparkSession({"spark.rapids.sql.enabled": "true"})
+    try:
+        df = spark.createDataFrame(
+            {"k": [1, 1, 2], "o": [1, 2, 3],
+             "v": [Decimal("1.50"), None, Decimal("2.25")],
+             "s": ["a", "b", None]}, "k int, o int, v decimal(7,2), s string")
+        out = df.select("k", make().over(_w()).alias("a"))
+        if "average" in reason:
+            with pytest.raises(NotImplementedError, match=reason):
+                out.collect()
+        else:
+            out.collect()
+        report = spark.last_rewrite_report.format()
+        assert reason in report, report
+    finally:
+        spark.stop()
