@@ -220,7 +220,11 @@ BATCH_SIZE_BYTES = conf("spark.rapids.sql.batchSizeBytes").doc(
 
 BATCH_SIZE_ROWS = conf("spark.rapids.sql.batchSizeRows").doc(
     "Target row capacity of a device columnar batch. Static XLA shapes are "
-    "derived by bucketing row counts up to this ceiling.").integer(1 << 20)
+    "derived by bucketing row counts up to this ceiling. A join probes its "
+    "build side with max(batchSizeRows, the build side's capacity) stream "
+    "rows at a time: every probe sorts the build side's lanes with the "
+    "chunk's, so a chunk is never smaller than what it is sorted with "
+    "(joinStreamChunks counts the probes).").integer(1 << 20)
 
 MAX_READER_BATCH_SIZE_ROWS = conf(
     "spark.rapids.sql.reader.batchSizeRows").doc(

@@ -14,7 +14,7 @@ filters inside cudf's join, a complexity this design doesn't need yet).
 from __future__ import annotations
 
 import threading
-from typing import Iterator, List, Optional
+from typing import Callable, Iterator, List, Optional
 
 from spark_rapids_tpu import metrics as M
 from spark_rapids_tpu.columnar.device import DeviceBatch, concat_device
@@ -63,6 +63,25 @@ def is_device_join(join_type: str, left_keys: List[E.Expression],
             return (f"mismatched join key types {lk.data_type} vs "
                     f"{rk.data_type} run on CPU")
     return None
+
+
+def stream_chunks(handles: List, goal: int) -> List[List]:
+    """Consecutive stream handles grouped into the chunks one probe
+    takes each: a chunk closes before the handle whose ``rows`` would
+    take it past ``goal``, and a handle over the goal by itself is a
+    chunk of its own. Order kept, every handle once; no handle at all is
+    one empty chunk, since the join still runs once against its build
+    side."""
+    chunks: List[List] = [[]]
+    rows = 0
+    for h in handles:
+        n = h.rows
+        if chunks[-1] and rows + n > goal:
+            chunks.append([])
+            rows = 0
+        chunks[-1].append(h)
+        rows += n
+    return chunks
 
 
 class TpuShuffledHashJoinExec(TpuExec):
@@ -433,10 +452,8 @@ class TpuShuffledHashJoinExec(TpuExec):
         """Broadcast-style execution: the resident build side is shared
         by every stream partition, and each stream partition keeps the
         shuffled path's discipline — batches register as spillable and
-        join goal-rows at a time (skew safety). Shared by
-        TpuBroadcastHashJoinExec and the AQE runtime flip."""
-        goal = self.conf.batch_size_rows
-        chunkable = self.join_type in self._LEFT_STREAM_TYPES
+        join a chunk at a time (``_join_stream``: skew safety). Shared
+        by TpuBroadcastHashJoinExec and the AQE runtime flip."""
         self._book_build(rwhole)
         # one sizing probe for the WHOLE broadcast: unique build keys
         # (the dimension-table norm) certify every stream chunk for the
@@ -470,29 +487,7 @@ class TpuShuffledHashJoinExec(TpuExec):
                 store = get_device_store(self.conf)
                 lhandles = [self.register_spillable(store, b)
                             for b in lt() if b._num_rows != 0]
-                total_l = sum(h.rows for h in lhandles)
-                if not chunkable or total_l <= goal:
-                    lb = [h.get() for h in lhandles]
-                    for h in lhandles:
-                        h.close()
-                    yield from self._join_one(lb, [rwhole],
-                                              fk_hint=fk_hint())
-                    return
-                i = 0
-                while i < len(lhandles):
-                    chunk = [lhandles[i]]
-                    rows = lhandles[i].rows
-                    i += 1
-                    while i < len(lhandles) and \
-                            rows + lhandles[i].rows <= goal:
-                        rows += lhandles[i].rows
-                        chunk.append(lhandles[i])
-                        i += 1
-                    lb = [h.get() for h in chunk]
-                    for h in chunk:
-                        h.close()
-                    yield from self._join_one(lb, [rwhole],
-                                              fk_hint=fk_hint())
+                yield from self._join_stream(lhandles, rwhole, fk_hint)
             return run
         return [make(t) for t in device_channel(left_src)]
 
@@ -658,45 +653,44 @@ class TpuShuffledHashJoinExec(TpuExec):
         """One co-partition's join: the stream side arrives as
         spillable handles, the build side as device batches (shared by
         the in-memory path and each out-of-core bucket)."""
-        goal = self.conf.batch_size_rows
-        total_l = sum(h.rows for h in lhandles)
-        chunkable = (self.join_type in self._LEFT_STREAM_TYPES
-                     or self.join_type in self._CHUNKED_OUTER)
-        # build side concatenated once, whether the stream side joins
-        # whole or goal-rows at a time
         rwhole = (concat_device(rb) if len(rb) > 1 else
                   rb[0] if rb else
                   DeviceBatch.empty(self.right.schema))
         self._book_build(rwhole)
-        if not chunkable or total_l <= goal:
-            lb = [h.get() for h in lhandles]
-            for h in lhandles:
-                h.close()
-            yield from self._join_one(lb, [rwhole])
-            return
-        # chunked stream: left handles re-promoted and joined
-        # goal-rows at a time
-        chunk_type = self._CHUNKED_OUTER.get(self.join_type)
+        yield from self._join_stream(lhandles, rwhole)
+
+    def _join_stream(self, lhandles: List, rwhole: DeviceBatch,
+                     fk_hint: Callable[[], bool] = lambda: False
+                     ) -> Iterator[DeviceBatch]:
+        """One stream partition's handles against one build side, a
+        chunk at a time, for every join type. Every probe sorts its
+        chunk's lanes together with the build side's (ops/join.py
+        _key_plan), so a chunk smaller than the build side spends most
+        of its probe sorting the build side again: a chunk may grow to
+        ``batchSizeRows`` or to the build side's capacity (a shape: no
+        sync), whichever is larger. A build side over ``batchSizeRows``
+        is then never probed by less than itself, and its join sorts at
+        most twice the lanes it would joined whole. A right/full outer
+        join in several chunks joins each as inner/leftouter while the
+        matched-right mask accumulates on the device, and emits the
+        unmatched right rows once at the end."""
+        chunks = stream_chunks(
+            lhandles, max(self.conf.batch_size_rows, rwhole.capacity))
+        chunk_type = (self._CHUNKED_OUTER.get(self.join_type)
+                      if len(chunks) > 1 else None)
         matched_any = None
         if chunk_type is not None:
             lk = P.bind_list(self.left_keys, self.left.output)
             rk = P.bind_list(self.right_keys, self.right.output)
             pair_schema = self._pair_schema()
-        i = 0
-        while i < len(lhandles):
-            chunk = [lhandles[i]]
-            rows = lhandles[i].rows
-            i += 1
-            while i < len(lhandles) and \
-                    rows + lhandles[i].rows <= goal:
-                rows += lhandles[i].rows
-                chunk.append(lhandles[i])
-                i += 1
+        for chunk in chunks:
+            # left handles re-promoted only for their own chunk
             lb = [h.get() for h in chunk]
             for h in chunk:
                 h.close()
+            self.metrics.create(M.JOIN_STREAM_CHUNKS, M.ESSENTIAL).add(1)
             if chunk_type is None:
-                yield from self._join_one(lb, [rwhole])
+                yield from self._join_one(lb, [rwhole], fk_hint=fk_hint())
             else:
                 out, matched = self._join_one_matched(
                     lb, rwhole, chunk_type, lk, rk, pair_schema)
@@ -789,7 +783,7 @@ class TpuBroadcastHashJoinExec(TpuShuffledHashJoinExec):
             rbatches.extend(b for b in t() if b._num_rows != 0)
         # concat the build side ONCE (a TpuBroadcastExchangeExec child
         # already yields its single cached batch); every stream
-        # partition shares it, with the common goal-row chunking
+        # partition shares it, chunked by _join_stream
         rwhole = (concat_device(rbatches) if len(rbatches) > 1 else
                   rbatches[0] if rbatches else
                   DeviceBatch.empty(self.right.schema))

@@ -81,6 +81,7 @@ AGG_GROUP_COUNT = "aggGroupCount"    # groups into the final aggregates
 JOIN_BUILD_ROWS = "joinBuildRows"    # build-side rows, once per build
 JOIN_OUTPUT_ROWS = "joinOutputRows"  # joined rows, where the count is known
 JOIN_DEMOTED_COUNT = "joinDemotedCount"  # shuffled joins run as broadcast
+JOIN_STREAM_CHUNKS = "joinStreamChunks"  # stream chunks probed (whole: 1)
 # semi/anti joins under a residual condition (a decorrelated EXISTS)
 JOIN_CONDITIONAL_COUNT = "joinConditionalCount"  # conditional mask joins run
 JOIN_CONDITION_PAIRS = "joinConditionPairs"  # candidate pairs evaluated
@@ -164,6 +165,10 @@ METRIC_DESCRIPTIONS: Dict[str, str] = {
     JOIN_DEMOTED_COUNT: "shuffled hash joins that adaptive execution ran "
                         "as broadcast joins because the materialized "
                         "build side was under the threshold",
+    JOIN_STREAM_CHUNKS: "stream chunks the joins probed their build sides "
+                        "with, one per probe: a stream partition joined "
+                        "whole adds 1, one joined max(batchSizeRows, "
+                        "build capacity) rows at a time adds 1 a chunk",
     JOIN_CONDITIONAL_COUNT: "left semi and left anti joins run under a "
                             "residual condition (a decorrelated [NOT] "
                             "EXISTS), once per stream chunk joined",

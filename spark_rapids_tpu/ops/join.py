@@ -117,14 +117,16 @@ def _group_extents(words: List[jax.Array], valid: jax.Array, cap: int):
     (active_s, order, start, end): per-sorted-position group extents.
     Shared by _key_plan and build_key_max_multiplicity."""
     from spark_rapids_tpu.columnar.device import sort_with_payload
-    sorted_all, order, _p = sort_with_payload([~valid] + words, [])
-    active_s = ~sorted_all[0]
-    boundary, is_end = G._boundaries_from_words(sorted_all[1:], active_s,
-                                                cap)
-    pos = jnp.arange(cap, dtype=jnp.int32)
-    start = jax.lax.cummax(jnp.where(boundary, pos, -1))
-    end = jnp.flip(jax.lax.cummin(
-        jnp.flip(jnp.where(is_end, pos, cap))))
+    with jax.named_scope("join_plan/sort"):
+        sorted_all, order, _p = sort_with_payload([~valid] + words, [])
+    with jax.named_scope("join_plan/extents"):
+        active_s = ~sorted_all[0]
+        boundary, is_end = G._boundaries_from_words(sorted_all[1:],
+                                                    active_s, cap)
+        pos = jnp.arange(cap, dtype=jnp.int32)
+        start = jax.lax.cummax(jnp.where(boundary, pos, -1))
+        end = jnp.flip(jax.lax.cummin(
+            jnp.flip(jnp.where(is_end, pos, cap))))
     return active_s, order, start, end
 
 
@@ -139,54 +141,59 @@ def _key_plan(lkeys: Sequence[E.Expression], rkeys: Sequence[E.Expression],
     entirely), the two prefix sums ride one 2-lane cumsum, and all
     back-to-original-row gathers ride one fused lane gather."""
     ns = list(null_safe) or [False] * len(lkeys)
-    kl = [X.dev_eval(e, ctx_l) for e in lkeys]
-    kr = [X.dev_eval(e, ctx_r) for e in rkeys]
-    valid_l = active_l
-    for c, nsf in zip(kl, ns):
-        if not nsf:  # <=> keys keep null rows in the match set
-            valid_l = valid_l & c.validity
-    valid_r = active_r
-    for c, nsf in zip(kr, ns):
-        if not nsf:
-            valid_r = valid_r & c.validity
-    cap_l = active_l.shape[0]
-    cap_r = active_r.shape[0]
-    cap_c = cap_l + cap_r
-    combined = _concat_key_columns(kl, kr)
-    valid_c = jnp.concatenate([valid_l, valid_r])
-    active_s, order, start, end = _group_extents(
-        _key_words(combined, ns), valid_c, cap_c)
-    pos_c = jnp.arange(cap_c, dtype=jnp.int32)
-    is_left_s = order < cap_l
-    left_valid_s = is_left_s & active_s
-    right_valid_s = (~is_left_s) & active_s
-    # both prefix sums in ONE 2-lane cumsum
-    pref = jnp.cumsum(jnp.stack(
-        [left_valid_s.astype(jnp.int64), right_valid_s.astype(jnp.int64)],
-        axis=1), axis=0)
-    before = jnp.where((start > 0)[:, None],
-                       jnp.take(pref, jnp.maximum(start - 1, 0), axis=0),
-                       jnp.int64(0))
-    at_end = jnp.take(pref, jnp.clip(end, 0, cap_c - 1), axis=0)
-    cnt_l_s = at_end[:, 0] - before[:, 0]
-    cnt_r_s = at_end[:, 1] - before[:, 1]
-    base_r_s = before[:, 1]
-    # original combined row -> sorted pos (one stable sort pass), then
-    # ONE fused gather brings every per-sorted-row stat back to
-    # original row order
-    _o, inv = jax.lax.sort((order, pos_c), num_keys=1, is_stable=True)
-    from spark_rapids_tpu.ops.lanes import fused_take
-    g = fused_take([cnt_r_s, base_r_s, cnt_l_s], inv)
-    m = jnp.where(valid_l, g[0][:cap_l], jnp.int64(0))
-    base = jnp.where(valid_l, g[1][:cap_l], jnp.int64(0))
-    cnt_l_at_r = jnp.where(valid_r, g[2][cap_l:], jnp.int64(0))
-    # order_r[j] = original right index of the j-th valid right row in
-    # key-sorted order (base/cnt index into this)
-    rkey_sorted = jnp.where(right_valid_s, pos_c, jnp.int32(cap_c))
-    _k2, ord2 = jax.lax.sort((rkey_sorted, pos_c), num_keys=1,
-                             is_stable=True)
-    order_r = jnp.clip(jnp.take(order, ord2[:cap_r]) - cap_l, 0,
-                       cap_r - 1)
+    with jax.named_scope("join_plan/keys"):
+        kl = [X.dev_eval(e, ctx_l) for e in lkeys]
+        kr = [X.dev_eval(e, ctx_r) for e in rkeys]
+        valid_l = active_l
+        for c, nsf in zip(kl, ns):
+            if not nsf:  # <=> keys keep null rows in the match set
+                valid_l = valid_l & c.validity
+        valid_r = active_r
+        for c, nsf in zip(kr, ns):
+            if not nsf:
+                valid_r = valid_r & c.validity
+        cap_l = active_l.shape[0]
+        cap_r = active_r.shape[0]
+        cap_c = cap_l + cap_r
+        combined = _concat_key_columns(kl, kr)
+        valid_c = jnp.concatenate([valid_l, valid_r])
+        words = _key_words(combined, ns)
+    # scopes join_plan/sort and /extents
+    active_s, order, start, end = _group_extents(words, valid_c, cap_c)
+    with jax.named_scope("join_plan/extents"):
+        pos_c = jnp.arange(cap_c, dtype=jnp.int32)
+        is_left_s = order < cap_l
+        left_valid_s = is_left_s & active_s
+        right_valid_s = (~is_left_s) & active_s
+        # both prefix sums in ONE 2-lane cumsum
+        pref = jnp.cumsum(jnp.stack(
+            [left_valid_s.astype(jnp.int64),
+             right_valid_s.astype(jnp.int64)], axis=1), axis=0)
+        before = jnp.where((start > 0)[:, None],
+                           jnp.take(pref, jnp.maximum(start - 1, 0), axis=0),
+                           jnp.int64(0))
+        at_end = jnp.take(pref, jnp.clip(end, 0, cap_c - 1), axis=0)
+        cnt_l_s = at_end[:, 0] - before[:, 0]
+        cnt_r_s = at_end[:, 1] - before[:, 1]
+        base_r_s = before[:, 1]
+    with jax.named_scope("join_plan/inverse"):
+        # original combined row -> sorted pos (one stable sort pass), then
+        # ONE fused gather brings every per-sorted-row stat back to
+        # original row order
+        _o, inv = jax.lax.sort((order, pos_c), num_keys=1, is_stable=True)
+        from spark_rapids_tpu.ops.lanes import fused_take
+        g = fused_take([cnt_r_s, base_r_s, cnt_l_s], inv)
+        m = jnp.where(valid_l, g[0][:cap_l], jnp.int64(0))
+        base = jnp.where(valid_l, g[1][:cap_l], jnp.int64(0))
+        cnt_l_at_r = jnp.where(valid_r, g[2][cap_l:], jnp.int64(0))
+    with jax.named_scope("join_plan/right_order"):
+        # order_r[j] = original right index of the j-th valid right row in
+        # key-sorted order (base/cnt index into this)
+        rkey_sorted = jnp.where(right_valid_s, pos_c, jnp.int32(cap_c))
+        _k2, ord2 = jax.lax.sort((rkey_sorted, pos_c), num_keys=1,
+                                 is_stable=True)
+        order_r = jnp.clip(jnp.take(order, ord2[:cap_r]) - cap_l, 0,
+                           cap_r - 1)
     return kl, kr, valid_l, valid_r, m, base, order_r, cnt_l_at_r
 
 

@@ -1831,7 +1831,14 @@ def generate_observability_docs() -> str:
         "first dense lane) inside it, `decode_chars`,",
         "`decode_delta`, `decode_rows` — docs/scan.md §1) and the three",
         "steps of a conditional semi/anti join's rank loop",
-        "(`srt_join_cond_mask`: `join_cond/gather`, `/eval`, `/reduce`);",
+        "(`srt_join_cond_mask`: `join_cond/gather`, `/eval`, `/reduce`)",
+        "and the steps of a join's key plan (`srt_join_probe`,",
+        "`srt_join_mask`; `srt_join_build` runs the middle two:",
+        "`join_plan/keys`, `/sort` (both sides' key words sorted",
+        "together), `/extents` (each key's run and its counts by prefix",
+        "sum), `/inverse` (the counts back to input order by a second",
+        "sort) and `/right_order` (the build rows in key order by a",
+        "third));",
         "scopes are",
         "op_name metadata and change no compiled code. The tpu-lint",
         "`jit-direct` rule treats",

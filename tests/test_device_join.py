@@ -210,6 +210,15 @@ def test_join_then_agg_pipeline_on_device():
         fn, expect_execs=["TpuBroadcastHashJoin", "TpuHashAggregate"])
 
 
+def _stream_chunks_moved(run):
+    """``joinStreamChunks`` booked while ``run()`` ran (process-wide
+    totals: they outlive the sessions ``run`` stops)."""
+    from spark_rapids_tpu.telemetry.prometheus import aggregator
+    before = aggregator().scrape()[0].get("joinStreamChunks", 0)
+    run()
+    return aggregator().scrape()[0].get("joinStreamChunks", 0) - before
+
+
 @pytest.mark.parametrize("jt", ["right", "full"])
 def test_chunked_outer_join_skewed_partition(jt):
     """Right/full outer over a skewed stream partition with a tiny batch
@@ -218,26 +227,29 @@ def test_chunked_outer_join_skewed_partition(jt):
     the unmatched right rows emit once at the end (JoinGatherer.scala:55
     chunked-gather role; fixes the round-4 single-batch limitation)."""
     def fn(s):
-        # one fat partition (skew) so the chunker has real work
+        # one fat partition (skew) of ten batches, nearly eight times
+        # the 512-lane build side, so the chunker has real work: a
+        # chunk may grow to the build side's capacity and no further
         l = s.createDataFrame(
             gen_batch([("k", SmallIntGen()), ("a", IntegerGen())],
                       4000, 11),
-            num_partitions=1)
+            num_partitions=10).repartition(1)
         r = s.createDataFrame(
             gen_batch([("k2", SmallIntGen()), ("b", LongGen()),
                        ("sname", StringGen())], 400, 12),
             num_partitions=1).repartition(1)
         return l.join(r, F.col("k") == F.col("k2"), jt)
-    assert_tpu_and_cpu_equal_collect(
+    chunks = _stream_chunks_moved(lambda: assert_tpu_and_cpu_equal_collect(
         fn,
         conf={
-            # chunk the 4000-row stream side into ~8 chunks, and keep
-            # the spill store small enough that handles demote
+            # ten chunks of 400 rows, and the spill store small enough
+            # that handles demote
             "spark.rapids.sql.batchSizeRows": "512",
             "spark.rapids.memory.tpu.poolSize": str(256 << 10),
             "spark.rapids.sql.autoBroadcastJoinThreshold": "-1",
         },
-        expect_execs=["TpuShuffledHashJoin"])
+        expect_execs=["TpuShuffledHashJoin"]))
+    assert chunks >= 3, chunks
 
 
 def test_broadcast_exchange_reuse_builds_once():
@@ -387,7 +399,12 @@ def test_conditional_semi_and_anti_join_on_device(jt, path, monkeypatch):
     from spark_rapids_tpu.exec import join as J
     from spark_rapids_tpu.sql.session import TpuSparkSession
     from spark_rapids_tpu.telemetry.prometheus import aggregator
-    left, right = _cond_sides()
+    # the chunked paths stream 2,000 rows in ONE partition of sixteen
+    # batches against the 512-lane build side: at batchSizeRows 128 a
+    # chunk grows to the build side's capacity, four batches, and the
+    # masks of four chunks must add up to the whole's
+    chunked = "chunked" in path
+    left, right = _cond_sides(n_left=2000 if chunked else 700)
     conf, exec_name = _COND_PATHS[path]
     conf = dict(conf, **{"spark.rapids.sql.enabled": "true",
                          "spark.rapids.sql.test.forceDevice": "true"})
@@ -405,7 +422,10 @@ def test_conditional_semi_and_anti_join_on_device(jt, path, monkeypatch):
     spark = TpuSparkSession(conf)
     try:
         spark.start_capture()
-        l = spark.createDataFrame(left, "k long, a long", num_partitions=3)
+        l = spark.createDataFrame(left, "k long, a long",
+                                  num_partitions=16 if chunked else 3)
+        if chunked:
+            l = l.repartition(1)
         r = spark.createDataFrame(right, "k2 long, b long", num_partitions=2)
         before = dict(aggregator().scrape()[0])
         rows = l.join(r, (l["k"] == r["k2"]) & (l["a"] != r["b"]),
@@ -422,11 +442,14 @@ def test_conditional_semi_and_anti_join_on_device(jt, path, monkeypatch):
     assert sorted(map(tuple, rows), key=key) == sorted(
         _brute_force(left, right, jt), key=key)
     moved = {k: after.get(k, 0) - before.get(k, 0) for k in (
-        "joinConditionalCount", "joinConditionPairs", "splitRetryCount")}
+        "joinConditionalCount", "joinConditionPairs", "splitRetryCount",
+        "joinStreamChunks")}
     pairs = sum(1 for k in left["k"] for k2 in right["k2"]
                 if k is not None and k == k2)
     assert moved["joinConditionPairs"] == pairs
-    assert moved["joinConditionalCount"] >= (3 if "chunked" in path else 1)
+    assert moved["joinConditionalCount"] >= (3 if chunked else 1)
+    # every chunk probed is a conditional one: this query has one join
+    assert moved["joinStreamChunks"] == moved["joinConditionalCount"]
     assert moved["splitRetryCount"] == (1 if path == "split_retry" else 0)
 
 
@@ -444,3 +467,115 @@ def test_conditional_outer_join_says_what_runs_on_the_device():
         why = is_device_join(jt, [a], [b], cond, conf)
         assert f"conditional {jt} join runs on CPU" in why
         assert "left semi and left anti" in why
+
+
+# -- how many stream rows one probe takes (exec/join.py stream_chunks) ----
+
+class _Handle:
+    def __init__(self, rows):
+        self.rows = rows
+
+
+def _chunks_of_goal_rows(rows, goal):
+    """The loop both join paths ran before they shared ``stream_chunks``,
+    kept as the reference: a chunk takes handles while they fit."""
+    out, i = [], 0
+    while i < len(rows):
+        chunk, total = [i], rows[i]
+        i += 1
+        while i < len(rows) and total + rows[i] <= goal:
+            total += rows[i]
+            chunk.append(i)
+            i += 1
+        out.append(chunk)
+    return out
+
+
+_Q21_BATCHES = [786432, 786432, 786432, 786432, 644000]  # 3.79 M late lines
+
+_CHUNKER_CASES = {
+    # name: (handle rows, batchSizeRows, build capacity, chunks expected)
+    "build_under_goal_fits_three": ([300] * 10, 1024, 512, 4),
+    "build_under_goal_uneven": ([700, 100, 900, 50, 60, 1000, 5], 1024, 256,
+                                3),
+    "build_at_goal": ([400] * 10, 512, 512, 10),
+    "q21_semi_join": (_Q21_BATCHES, 1 << 20, 6291456, 1),
+    "q21_anti_join": ([3670000], 1 << 20, 4194304, 1),
+    "q21_at_the_batch_goal": (_Q21_BATCHES, 1 << 20, 1 << 20, 5),
+    "stream_3.5_builds": ([128] * 14, 128, 512, 4),
+    "stream_3.5_builds_odd_batches": ([100] * 18, 128, 512, 4),
+    "one_handle_over_the_goal": ([100, 5000, 100], 128, 512, 3),
+    "stream_under_build": ([110] * 4, 128, 512, 1),
+    "no_handle": ([], 128, 512, 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CHUNKER_CASES))
+def test_stream_chunks(case):
+    from spark_rapids_tpu.exec.join import stream_chunks
+    rows, batch_rows, build_cap, expected = _CHUNKER_CASES[case]
+    goal = max(batch_rows, build_cap)
+    handles = [_Handle(n) for n in rows]
+    chunks = stream_chunks(handles, goal)
+    # order kept, every handle once
+    assert [h for c in chunks for h in c] == handles
+    assert len(chunks) == expected
+    if not handles:
+        assert chunks == [[]]  # the join still runs once
+        return
+    assert all(chunks)
+    for c in chunks:
+        # none over the goal unless a single handle is
+        assert sum(h.rows for h in c) <= goal or len(c) == 1
+    for a, b in zip(chunks, chunks[1:]):
+        # greedy: the next handle did not fit
+        assert sum(h.rows for h in a) + b[0].rows > goal
+    if build_cap <= batch_rows:
+        # a build side under batchSizeRows keeps the chunks it had
+        assert [[handles.index(h) for h in c] for c in chunks] == \
+            _chunks_of_goal_rows(rows, batch_rows)
+    else:
+        # a larger build side only ever merges them (and, by the goal
+        # above, into no chunk larger than itself)
+        assert len(chunks) <= len(_chunks_of_goal_rows(rows, batch_rows))
+
+
+_STREAMED_JOINS = {
+    "inner": ("inner", False), "left": ("left", False),
+    "leftsemi": ("leftsemi", False), "leftanti": ("leftanti", False),
+    "leftsemi_residual": ("leftsemi", True),
+    "leftanti_residual": ("leftanti", True), "full": ("full", False),
+}
+
+
+@pytest.mark.parametrize("family", sorted(_STREAMED_JOINS))
+def test_stream_chunk_is_never_smaller_than_the_build_side(family,
+                                                           monkeypatch):
+    """2,200 stream rows in one partition of twenty batches against a
+    512-lane build side at batchSizeRows 128: a chunk grows to the build
+    side's capacity (four batches of 110 rows), so the join probes five
+    times and not twenty; the rows are the CPU engine's and
+    ``joinStreamChunks`` is the chunker's own count."""
+    from spark_rapids_tpu.exec import join as J
+    jt, residual = _STREAMED_JOINS[family]
+    left, right = _cond_sides(seed=37, n_left=2200, n_right=500)
+    real, calls = J.stream_chunks, []
+
+    def recording(handles, goal):
+        out = real(handles, goal)
+        calls.append(([h.rows for h in handles], goal, len(out)))
+        return out
+    monkeypatch.setattr(J, "stream_chunks", recording)
+
+    def fn(s):
+        l = s.createDataFrame(left, "k long, a long",
+                              num_partitions=20).repartition(1)
+        r = s.createDataFrame(right, "k2 long, b long", num_partitions=1)
+        on = l["k"] == r["k2"]
+        return l.join(r, on & (l["a"] != r["b"]) if residual else on, jt)
+    moved = _stream_chunks_moved(lambda: assert_tpu_and_cpu_equal_collect(
+        fn, conf={"spark.rapids.sql.batchSizeRows": "128",
+                  "spark.rapids.sql.test.forceDevice": "true"},
+        expect_execs=["HashJoin " + jt]))
+    assert calls == [([110] * 20, 512, 5)], calls
+    assert moved == 5
