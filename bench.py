@@ -1858,28 +1858,6 @@ def run_adaptive(clean_wall: float) -> dict:
     return out
 
 
-def run_bench_diff(current: dict) -> dict:
-    """Regression tracking: diff THIS run's output against the newest
-    BENCH_r0*.json in the repo (docs/observability.md 'Live
-    telemetry'); the machine verdict rides in the bench JSON so the
-    round trajectory is an enforced curve, not loose files."""
-    from spark_rapids_tpu.telemetry.bench_diff import (bench_diff,
-                                                      latest_bench_file)
-    prev = latest_bench_file(os.path.dirname(os.path.abspath(__file__)))
-    if prev is None:
-        return {"skipped": True, "reason": "no previous BENCH_r*.json"}
-    report = bench_diff(prev, current)
-    return {
-        "skipped": False,
-        "baseline": os.path.basename(prev),
-        "verdict": report["verdict"],
-        "regressed": report["regressed"],
-        "improved": report["improved"],
-        "compared": len(report["checks"]),
-        "notComparable": len(report["missing"]),
-    }
-
-
 def run_leg(failed: list, label: str, fn, *args) -> dict:
     """Fault-isolated detail leg: a leg that raises must not discard
     the measured primary results, so its failure rides in the JSON —
@@ -2068,10 +2046,6 @@ def main():
             },
         },
     }
-    # regression verdict vs the previous round rides IN the output
-    # (fault-isolated: a differ failure must not discard the results)
-    telemetry_leg["benchDiff"] = run_leg(failed_legs, "bench-diff",
-                                         run_bench_diff, result)
     result["detail"]["failedLegs"] = failed_legs
     print(json.dumps(result))
     if failed_legs:

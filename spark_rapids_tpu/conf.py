@@ -792,9 +792,8 @@ SERVE_TUNING_GUARD_WINDOW = conf(
 SERVE_TUNING_REVERT_THRESHOLD = conf(
     "spark.rapids.sql.serve.tuning.revertThreshold").doc(
     "Relative p50/p99 regression past which the guardrail reverts an "
-    "applied action — the same relative-change discipline tools "
-    "bench-diff gates on ((baseline - candidate) / baseline for "
-    "lower-is-better metrics; docs/tuning.md).").double(0.25)
+    "applied action ((baseline - observed) / baseline, lower being "
+    "better; docs/tuning.md).").double(0.25)
 
 SERVE_TUNING_MAX_PREWARM = conf(
     "spark.rapids.sql.serve.tuning.maxPrewarm").doc(

@@ -7,7 +7,9 @@ XLA owns the HBM allocator on TPU, so initialization here is:
 - enable the persistent XLA compilation cache (compiled programs survive
   process restarts — the analogue of CUDA's on-disk kernel cache);
 - discover device/backend facts used for memory accounting (HBM bytes)
-  and capability gating (device_caps probes exactness separately).
+  and capability gating (device_caps probes exactness separately);
+- register the process's one ``gc.callbacks`` hook
+  (``trace.install_gc_hook``: gcPauseTime, gcCollections.<gen>).
 
 Cache placement: where ``JAX_COMPILATION_CACHE_DIR`` is set (or the
 caller configured ``jax_compilation_cache_dir`` itself), JAX already
@@ -51,6 +53,8 @@ def initialize() -> None:
                 jax.config.update("jax_compilation_cache_dir", cache_dir)
             jax.config.update(
                 "jax_persistent_cache_min_compile_time_secs", 0.5)
+        from spark_rapids_tpu import trace
+        trace.install_gc_hook()
         _INITIALIZED = True
 
 

@@ -171,7 +171,6 @@ class TpuRowToColumnarExec(TpuExec):
         def run() -> Iterator[DeviceBatch]:
             import queue as _q
             import threading
-            import time as _time
 
             from spark_rapids_tpu import trace as _trace
             from spark_rapids_tpu.io.device_decode import EncodedBatch
@@ -179,6 +178,7 @@ class TpuRowToColumnarExec(TpuExec):
             q: "_q.Queue" = _q.Queue(maxsize=depth)
             stop = threading.Event()
             scope = _trace.scope_of(metrics)
+            chip = device.id if device is not None else None
 
             def put_bounded(item) -> bool:
                 while not stop.is_set():
@@ -199,10 +199,8 @@ class TpuRowToColumnarExec(TpuExec):
                         # decodeTime>wall audit applies to these
                         # threads too), mirrored as a scanPrefetch span
                         m = metrics.create("scanPrefetchTime")
-                        with _trace.span(
-                                "scanPrefetch", scope=scope,
-                                chip=(device.id if device is not None
-                                      else None)):
+                        with _trace.span("scanPrefetch", scope=scope,
+                                         chip=chip):
                             m.enter_wall()
                             try:
                                 prep = self._prepare(payload, metrics)
@@ -259,16 +257,31 @@ class TpuRowToColumnarExec(TpuExec):
             ring: List = []
 
             def get_item():
-                # cancellation-aware ring pull: a cancelled query must
-                # not park on the prefetch queue (the raise runs the
-                # finally below, which stops and joins the producer)
+                try:
+                    return q.get_nowait()
+                except _q.Empty:
+                    pass
+                # the ring is empty: a scanWait span, and one more of
+                # the query's open streams waiting; a cancelled query
+                # must not park here (the raise runs the finally below,
+                # which stops and joins the producer)
                 from spark_rapids_tpu.lifecycle import checkpoint
-                while True:
-                    try:
-                        return q.get(timeout=0.05)
-                    except _q.Empty:
-                        checkpoint("prefetch")
+                if scope is not None:
+                    scope.scan_streams(waiting=1)
+                try:
+                    with _trace.span("scanWait", scope=scope, chip=chip):
+                        while True:
+                            try:
+                                return q.get(timeout=0.05)
+                            except _q.Empty:
+                                checkpoint("prefetch")
+                finally:
+                    if scope is not None:
+                        scope.scan_streams(waiting=-1)
 
+            # open from the first pull until its last batch is handed on
+            if scope is not None:
+                scope.scan_streams(opened=1)
             try:
                 while True:
                     item = get_item()
@@ -298,6 +311,8 @@ class TpuRowToColumnarExec(TpuExec):
                 while ring:
                     yield from self._complete_ahead(ring.pop(0), metrics)
             finally:
+                if scope is not None:
+                    scope.scan_streams(opened=-1)
                 stop.set()
                 try:
                     while True:

@@ -665,7 +665,7 @@ class TpuShuffleExchangeExec(TpuExec):
         # (other served queries' collectives proceed while this one
         # waits out memory pressure); the timed scope sits inside the
         # mutex so queue-wait never inflates partitionTime (the
-        # slow-query triggers and bench-diff read that metric)
+        # slow-query triggers read that metric)
         def _locked_exchange():
             with collective_section(self.conf), \
                     self.metrics.timed(M.PARTITION_TIME):

@@ -31,12 +31,15 @@ host decode, so results stay bit-for-bit identical to the host path.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from spark_rapids_tpu import metrics as M
+from spark_rapids_tpu import trace as _trace
 from spark_rapids_tpu.sql import types as T
 
 # Parquet enums (format/parquet.thrift)
@@ -654,13 +657,105 @@ def _decode_dict_page(body: bytes, nvals: int, dt: T.DataType,
 _ALL_FEATS = (True, True, True)  # (byteArray, delta, byteStreamSplit)
 
 
+class _HostSteps:
+    """The three host steps of a unit's plan, each a span nested in
+    ``FileScan.deviceDecodeTime`` (``tools trace`` puts an idle instant
+    down to the deepest span, so it names the step) and an interval-
+    union timer on ``metrics``, as ``timed_wall`` keeps one:
+    ``scanRead`` (the footer, each column chunk's seek + read),
+    ``scanInflate`` (a chunk's pages decompressed; the parse timer
+    pauses for it) and ``scanParse`` (the rest of a chunk's plan): a
+    few spans a column chunk."""
+
+    __slots__ = ("metrics", "_read", "_inflate", "_parse", "_read_bytes",
+                 "_inflated", "_pages")
+
+    def __init__(self, metrics):
+        self.metrics = metrics
+        c = metrics.create
+        self._read, self._inflate, self._parse = (
+            c(M.SCAN_READ_TIME), c(M.SCAN_INFLATE_TIME),
+            c(M.SCAN_PARSE_TIME))
+        self._read_bytes, self._inflated, self._pages = (
+            c(M.SCAN_READ_BYTES), c(M.SCAN_INFLATED_BYTES),
+            c(M.SCAN_PAGES))
+
+    @contextlib.contextmanager
+    def _step(self, kind: str, timer, **attrs):
+        with _trace.span(kind, metrics=self.metrics, **attrs) as sp:
+            timer.enter_wall()
+            try:
+                yield sp
+            finally:
+                timer.exit_wall()
+
+    def footer(self, path: str):
+        import pyarrow.parquet as pq
+        with self._step("scanRead", self._read, what="footer") as sp:
+            pf = pq.ParquetFile(path)
+            sp.annotate(bytes=pf.metadata.serialized_size)
+        return pf
+
+    def read(self, f, start: int, size: int) -> bytes:
+        with self._step("scanRead", self._read, what="chunk", bytes=size):
+            f.seek(start)
+            raw = f.read(size)
+        self._read_bytes.add(len(raw))
+        return raw
+
+    def parse(self):
+        """``with steps.parse():`` around one column chunk's plan."""
+        return self._step("scanParse", self._parse)
+
+    def pages(self, n: int) -> None:
+        self._pages.add(n)
+
+    def inflate(self, codec, raw: bytes,
+                pages: List[Tuple[Dict[int, Any], int, int]]
+                ) -> List[Tuple[Dict[int, Any], bytes]]:
+        """A chunk's pages ``(header, body start, body end)`` in ``raw``
+        with their bodies cut out and decompressed, as ONE step (a span
+        a page would be thousands a query)."""
+        usize = sum(h.get(2, 0) for h, _o, _e in pages)
+        self._parse.exit_wall()
+        try:
+            with self._step("scanInflate", self._inflate, bytes=usize,
+                            pages=len(pages)):
+                out = [(h, _inflate_body(codec, h, raw[o:e]))
+                       for h, o, e in pages]
+        finally:
+            self._parse.enter_wall()
+        self._inflated.add(sum(len(b) for _h, b in out))
+        return out
+
+
+def _inflate_body(codec, hdr: Dict[int, Any], body: bytes) -> bytes:
+    """One page's body as the parse reads it: decompressed where the
+    writer compressed it (a v2 page keeps its levels uncompressed in
+    front of its values)."""
+    ptype, usize = hdr.get(1), hdr.get(2, 0)
+    if ptype in (PAGE_DICTIONARY, PAGE_DATA):
+        return codec.decompress(body, usize).to_pybytes()
+    if ptype == PAGE_DATA_V2:
+        dph = hdr.get(8) or {}
+        if dph.get(7, True):
+            skip = dph.get(5, 0) + dph.get(6, 0)
+            return body[:skip] + codec.decompress(
+                body[skip:], usize - skip).to_pybytes()
+    return body
+
+
 def _plan_column(raw: bytes, chunk, leaf, dt: T.DataType, n_rows: int,
-                 packer, feats: Tuple[bool, bool, bool] = _ALL_FEATS
-                 ) -> ColumnDevicePlan:
+                 packer, feats: Tuple[bool, bool, bool] = _ALL_FEATS,
+                 steps: Optional[_HostSteps] = None) -> ColumnDevicePlan:
     """Walk one column chunk's pages, appending decompressed page bytes
     to ``packer`` and building the device plan. ``feats`` are the
     per-encoding enables (deviceDecode.byteArray/delta/byteStreamSplit
-    confs) — a disabled encoding falls back per column."""
+    confs) — a disabled encoding falls back per column; ``steps``
+    (the unit's, else a throwaway registry's) inflates the pages and
+    counts them."""
+    if steps is None:
+        steps = _HostSteps(M.MetricRegistry())
     _check_supported(dt, leaf)
     codec_name = _HOST_CODECS.get(chunk.compression, "?")
     if codec_name == "?":
@@ -683,13 +778,17 @@ def _plan_column(raw: bytes, chunk, leaf, dt: T.DataType, n_rows: int,
     str_parts: List[Tuple[int, np.ndarray]] = []  # (dense_off, lengths)
     plain_parts: List[np.ndarray] = []  # the PLAIN region, in dense order
     plain_end = 0                       # dense lane behind its last slot
+    spans: List[Tuple[Dict[int, Any], int, int]] = []  # (hdr, body)
     pos = start
     while pos < end:
         hdr, body_off = parse_page_header(raw, pos)
+        pos = body_off + hdr.get(3, 0)
+        spans.append((hdr, body_off, pos))
+    steps.pages(len(spans))
+    pages = (steps.inflate(codec, raw, spans) if codec is not None
+             else [(h, raw[o:e]) for h, o, e in spans])
+    for hdr, body in pages:
         ptype = hdr.get(1)
-        usize, csize = hdr.get(2, 0), hdr.get(3, 0)
-        body = raw[body_off:body_off + csize]
-        pos = body_off + csize
         if ptype == PAGE_INDEX:
             continue
         if ptype == PAGE_DICTIONARY:
@@ -697,8 +796,6 @@ def _plan_column(raw: bytes, chunk, leaf, dt: T.DataType, n_rows: int,
             if dph.get(2, ENC_PLAIN) not in (ENC_PLAIN,
                                              ENC_PLAIN_DICTIONARY):
                 raise UnsupportedColumn("non-PLAIN dictionary page")
-            if codec is not None:
-                body = codec.decompress(body, usize).to_pybytes()
             n_dict = dph.get(1, 0)
             plan.dict_arrays, plan.char_cap = _decode_dict_page(
                 body, n_dict, dt, kind, leaf)
@@ -711,8 +808,6 @@ def _plan_column(raw: bytes, chunk, leaf, dt: T.DataType, n_rows: int,
             enc = dph.get(2, ENC_PLAIN)
             if max_def and dph.get(3, ENC_RLE) != ENC_RLE:
                 raise UnsupportedColumn("non-RLE definition levels")
-            if codec is not None:
-                body = codec.decompress(body, usize).to_pybytes()
             val_off = 0
             def_section = None
             if max_def:
@@ -729,12 +824,6 @@ def _plan_column(raw: bytes, chunk, leaf, dt: T.DataType, n_rows: int,
             dl_len = dph.get(5, 0)
             if rep_len:
                 raise UnsupportedColumn("v2 repetition levels")
-            levels = body[:dl_len]
-            values = body[dl_len:]
-            if dph.get(7, True) and codec is not None:
-                values = codec.decompress(
-                    values, usize - dl_len).to_pybytes()
-            body = levels + values
             def_section = (0, dl_len) if max_def else None
             val_off = dl_len
         else:
@@ -921,20 +1010,22 @@ def _feats_from_conf(conf) -> Tuple[bool, bool, bool]:
             bool(conf.get(PARQUET_DEVICE_DECODE_BSS)))
 
 
-def plan_unit_encoded(unit, data_schema: T.StructType, conf=None
-                      ) -> Optional[EncodedBatch]:
+def plan_unit_encoded(unit, data_schema: T.StructType, conf=None,
+                      metrics=None) -> Optional[EncodedBatch]:
     """Build the device-decode staging for one parquet ScanUnit (one
     row group). Columns whose chunk cannot be device-decoded fall back
     to the pyarrow host decode individually; returns None when nothing
-    can be device-decoded (caller uses the plain host path)."""
-    import pyarrow.parquet as pq
+    can be device-decoded (caller uses the plain host path). ``metrics``
+    (the scan's registry) times the read, inflate and parse steps."""
     from spark_rapids_tpu.columnar.transfer import _Packer
     from spark_rapids_tpu.io.arrow_convert import arrow_column_to_host
     feats = _feats_from_conf(conf)
 
     if not unit.row_groups or len(unit.row_groups) != 1:
         return None
-    pf = pq.ParquetFile(unit.path)
+    steps = _HostSteps(metrics if metrics is not None
+                       else M.MetricRegistry())
+    pf = steps.footer(unit.path)
     meta = pf.metadata
     rg = unit.row_groups[0]
     rgm = meta.row_group(rg)
@@ -957,8 +1048,7 @@ def plan_unit_encoded(unit, data_schema: T.StructType, conf=None
             start = chunk.data_page_offset
             if chunk.dictionary_page_offset is not None:
                 start = min(start, chunk.dictionary_page_offset)
-            f.seek(start)
-            return f.read(chunk.total_compressed_size)
+            return steps.read(f, start, chunk.total_compressed_size)
 
         packer = _Packer()
         plans: Dict[int, ColumnDevicePlan] = {}
@@ -978,9 +1068,10 @@ def plan_unit_encoded(unit, data_schema: T.StructType, conf=None
                 # must not leave this column's already-appended pages
                 # as dead bytes in every uploaded batch
                 sub = _Packer()
-                plan = _plan_column(raw, chunk, leaf,
-                                    fld.data_type, n_rows, sub, feats)
-                _rebase_plan(plan, packer.off)
+                with steps.parse():
+                    plan = _plan_column(raw, chunk, leaf, fld.data_type,
+                                        n_rows, sub, feats, steps)
+                    _rebase_plan(plan, packer.off)
                 packer.parts.extend(sub.parts)
                 packer.off += sub.off
                 plans[fi] = plan

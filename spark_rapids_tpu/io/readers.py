@@ -28,6 +28,7 @@ from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from typing import Any, Dict, Iterator, List, Optional, Sequence
 
+from spark_rapids_tpu import trace as TR
 from spark_rapids_tpu.columnar.host import HostBatch
 from spark_rapids_tpu.conf import (MAX_READER_BATCH_SIZE_ROWS,
                                    MULTITHREADED_READ_NUM_THREADS,
@@ -665,7 +666,7 @@ class CpuFileScanExec(P.PhysicalPlan):
                     # failure after retries fails the query either way
                     enc = R.io_with_retry(
                         lambda: DD.plan_unit_encoded(
-                            u, data_schema, self.conf),
+                            u, data_schema, self.conf, metrics),
                         self.conf, metrics, path=u.path)
                 except OSError:
                     raise  # exhausted retries: a real reader failure
@@ -723,6 +724,12 @@ class CpuFileScanExec(P.PhysicalPlan):
                     n_threads = int(
                         self.conf.get(MULTITHREADED_READ_NUM_THREADS))
                     pool = _shared_pool(n_threads)
+                    scope = TR.scope_of(metrics)
+
+                    def decode_scoped(u: ScanUnit) -> List:
+                        # the pool threads' spans carry the query's q=
+                        with TR.attach(scope):
+                            return decode_unit(u)
                     # sliding prefetch window: decoded-and-converted
                     # HostBatches are several times their arrow size, so
                     # bound in-flight units instead of materializing the
@@ -730,7 +737,7 @@ class CpuFileScanExec(P.PhysicalPlan):
                     from collections import deque
                     from itertools import islice
                     it = iter(units)
-                    futures = deque(pool.submit(decode_unit, u)
+                    futures = deque(pool.submit(decode_scoped, u)
                                     for u in islice(it, n_threads + 2))
                     done = iter(units)
                     try:
@@ -739,7 +746,7 @@ class CpuFileScanExec(P.PhysicalPlan):
                             nxt = next(it, None)
                             if nxt is not None:
                                 futures.append(
-                                    pool.submit(decode_unit, nxt))
+                                    pool.submit(decode_scoped, nxt))
                             _set_file(next(done).path)
                             for hb in f.result():
                                 yield hb

@@ -74,6 +74,16 @@ PLANNED_OOC_ESCALATIONS = "plannedOutOfCoreEscalations"  # re-plans
 PLAN_TIME = "planTime"                    # parse + rewrite, calling thread
 FIRST_DISPATCH_TIME = "firstDispatchTime"  # query begin -> first enqueue
 DEVICE_SYNC_TIME = "deviceSyncTime"       # blocked reading device values
+SCAN_STARVED_TIME = "scanStarvedTime"     # every open scan stream waiting
+# the interpreter's collections, process-wide (trace.install_gc_hook)
+GC_PAUSE_TIME = "gcPauseTime"             # and gcCollections.<gen>
+# the host steps of a unit's device-decode plan (io/device_decode.py)
+SCAN_READ_TIME = "scanReadTime"
+SCAN_READ_BYTES = "scanReadBytes"
+SCAN_INFLATE_TIME = "scanInflateTime"
+SCAN_INFLATED_BYTES = "scanInflatedBytes"
+SCAN_PARSE_TIME = "scanParseTime"
+SCAN_PAGES = "scanPages"
 # what the aggregates and joins of a plan did (exec/agg.py, exec/join.py):
 # each is added where the number already exists, never by a device sync
 AGG_MERGE_COUNT = "aggMergeCount"    # partial results concatenated + merged
@@ -204,6 +214,26 @@ METRIC_DESCRIPTIONS: Dict[str, str] = {
                       "device value back (row counts, fetches, "
                       "aggregate counts, join sizes); summed over task "
                       "threads",
+    SCAN_STARVED_TIME: "ns in which every open scan stream of a query "
+                       "waited on an empty prefetch ring at once: host "
+                       "starvation of the scan streams, including time "
+                       "the device is busy with queued work (not device "
+                       "idle)",
+    GC_PAUSE_TIME: "ns the interpreter spent in its garbage "
+                   "collections, every generation, process-wide",
+    SCAN_READ_TIME: "device-decode plans reading files: footers and "
+                    "column chunks' seek + read (ns; interval union "
+                    "over the scan's threads)",
+    SCAN_READ_BYTES: "column-chunk bytes the device-decode plans read "
+                     "(footers not counted)",
+    SCAN_INFLATE_TIME: "device-decode plans decompressing pages, "
+                       "dictionary pages included (ns; interval union)",
+    SCAN_INFLATED_BYTES: "bytes the page decompression put out",
+    SCAN_PARSE_TIME: "the rest of the device-decode plans' column "
+                     "chunks: page headers, level/run tables, "
+                     "dictionary pages, string lengths, DELTA runs "
+                     "(ns; interval union, decompression left out)",
+    SCAN_PAGES: "pages whose headers the device-decode plans parsed",
     # ad-hoc keys registered inline by individual operators
     "pipelineDrainTime": "wall where the partial agg drained the async "
                          "upstream pipeline (interval union)",
@@ -256,6 +286,8 @@ METRIC_PREFIX_DESCRIPTIONS: Dict[str, str] = {
     "deviceDecodedValues.": "values decoded on device per encoding",
     "hostDecodedValues.": "values host-decoded (fallback columns) per "
                           "encoding",
+    "gcCollections.": "the interpreter's collections of generation "
+                      "<gen>, process-wide",
 }
 
 

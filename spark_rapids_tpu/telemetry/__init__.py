@@ -22,10 +22,6 @@ after the fact. Four coordinated pieces:
   format, fed by a registry-delta aggregator whose counters stay
   monotone across plan lifetimes; ``tools top`` renders a live
   per-tenant terminal view over the same stats;
-- **regression tracking** (bench_diff.py): ``tools bench-diff`` diffs
-  two bench JSON outputs (headline walls + detail legs) against
-  configurable thresholds with a machine-readable verdict and a
-  nonzero exit on regression;
 - **query history** (history.py): the persistent, bounded JSONL store
   of one record per finished query — the cross-run memory behind
   server warm-start (watchdog p99 + quarantine streaks survive

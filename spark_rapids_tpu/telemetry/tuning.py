@@ -31,8 +31,8 @@ Prometheus families), INSPECTABLE (``tools tuning``; pin/revert by
 epoch), and GUARDED: each applied action remembers the pre-action
 p50/p99 baseline, and once ``serve.tuning.guardWindowQueries``
 post-action finished records exist for its scope the controller diffs
-observed p50/p99 against that baseline with the same relative-change
-discipline ``tools bench-diff`` gates on — a regression past
+observed p50/p99 against that baseline as a relative change
+``(baseline - observed) / baseline`` — a regression past
 ``serve.tuning.revertThreshold`` auto-reverts the action and logs a
 ``revert`` record. ``site:tuning:N`` in the fault grammar injects a
 deliberately harmful synthetic action at the Nth tick so the
@@ -555,7 +555,7 @@ class TuningController:
                              ) -> None:
         """Judge each applied, unpinned action once its guard window
         filled: relative change = (baseline - observed) / baseline for
-        p50 and p99 (lower-is-better, the bench-diff discipline); a
+        p50 and p99 (lower is better); a
         change below -revertThreshold on either reverts, otherwise the
         action graduates to accepted."""
         from spark_rapids_tpu.lifecycle import percentile

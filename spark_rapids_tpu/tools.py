@@ -719,16 +719,31 @@ def device_occupancy(ops: Dict[str, List],
     return out
 
 
+WAIT_SPANS = ("scanWait", "semaphoreWait")  # a thread waiting on another
+
+
+def _owner_rank(s: dict) -> Tuple:
+    """Which covering span owns an idle instant: a work span over a
+    wait span (a consumer that began waiting after its producer's
+    ``scanParse`` began must not take the gap from it) over the query's
+    root; within a rank the deepest, the one that started last (ties:
+    the shorter)."""
+    name = s["name"]
+    rank = 0 if name == ROOT_SPAN else 1 if name in WAIT_SPANS else 2
+    return rank, s["t0"], -s["t1"]
+
+
 def attribute_gaps(gaps: List[Tuple[float, float]], spans: List[dict],
                    min_gap_us: float = GAP_MIN_US) -> Dict:
     """Put every idle gap of at least ``min_gap_us`` down to what the
-    host was doing in it. Each instant of a gap belongs to the DEEPEST
-    engine span covering it on any host thread — the one that started
-    last (ties: the shorter) — or to ``no_span``; a gap two spans share
-    is split between them at the boundary. Returns the totals by span
-    kind and by query id (exact, from the pieces), the gaps themselves
-    with the kind that covers most of each, and the idle time in gaps
-    too short to attribute."""
+    host was doing in it. Each instant of a gap belongs to one engine
+    span covering it on any host thread (``_owner_rank``: the deepest
+    work span, else the deepest wait, else the query's root) or to
+    ``no_span``; a gap two spans share is split between them at the
+    boundary. Returns the totals by span kind and by query id (exact,
+    from the pieces), the gaps themselves with the kind that covers
+    most of each, and the idle time in gaps too short to
+    attribute."""
     by_kind: Dict[str, float] = {}
     by_q: Dict = {}
     labelled: List[Dict] = []
@@ -746,7 +761,7 @@ def attribute_gaps(gaps: List[Tuple[float, float]], spans: List[dict],
             mid = (a + b) / 2
             cover = [s for s in over if s["t0"] <= mid < s["t1"]]
             if cover:
-                s = max(cover, key=lambda s: (s["t0"], -s["t1"]))
+                s = max(cover, key=_owner_rank)
                 key = (s["name"], s.get("args", {}).get("q"))
             else:
                 key = (NO_SPAN, None)
@@ -938,7 +953,7 @@ def _main(argv: List[str]) -> int:
     ap.add_argument("command",
                     choices=["qualify", "profile", "docs", "trace",
                              "hotspots", "serve", "serve-client",
-                             "lint", "top", "bench-diff", "soak",
+                             "lint", "top", "soak",
                              "history", "doctor", "tuning"])
     ap.add_argument("sql", nargs="?", help="SQL text to analyze (live "
                     "mode; omit when using --log), the trace "
@@ -946,13 +961,8 @@ def _main(argv: List[str]) -> int:
                     "a profile-*.json file/directory for the "
                     "profile command (spark.rapids.sql.profile.dir "
                     "output), the server port for `top`, the "
-                    "BASELINE bench JSON for `bench-diff`, the "
                     "history directory for `history`, or the "
                     "queryId/signature selector for `doctor`")
-    ap.add_argument("paths", nargs="*",
-                    help="bench-diff: the CANDIDATE bench JSON, or a "
-                    "directory holding BENCH_r*.json files (the "
-                    "newest round is the candidate)")
     ap.add_argument("--view", action="append", default=[],
                     help="name=path parquet view registrations")
     ap.add_argument("--log", help="offline mode: event-log file or "
@@ -1050,9 +1060,6 @@ def _main(argv: List[str]) -> int:
     ap.add_argument("--data", default=None,
                     help="soak: existing data directory (default: "
                     "generate into a temp dir)")
-    ap.add_argument("--threshold", type=float, default=None,
-                    help="bench-diff: relative regression threshold "
-                    "for gating checks (default 0.10)")
     # intermixed: `serve-client --port N "SELECT ..."` must parse (the
     # plain parser cannot allocate a positional after optionals)
     args = ap.parse_intermixed_args(argv)
@@ -1085,9 +1092,6 @@ def _main(argv: List[str]) -> int:
         return run_top(port, host=host or args.host or "127.0.0.1",
                        interval=args.interval,
                        iterations=args.iterations, once=args.once)
-
-    if args.command == "bench-diff":
-        return _bench_diff_main(args, ap)
 
     if args.command == "history":
         return _history_main(args, ap)
@@ -1363,41 +1367,6 @@ def _tuning_main(args, ap) -> int:
         return 0
     print(format_tuning(state))
     return 0
-
-
-def _bench_diff_main(args, ap) -> int:
-    """`tools bench-diff <a> <b|dir>`: exit 0 when no gating check
-    regressed, 1 on regression, 2 on unusable inputs
-    (docs/observability.md 'Live telemetry')."""
-    import json as _json
-    import os
-
-    from spark_rapids_tpu.telemetry.bench_diff import (
-        DEFAULT_THRESHOLD, bench_diff, format_diff, latest_bench_file)
-    if not args.sql or not args.paths:
-        ap.error("bench-diff requires <baseline.json> "
-                 "<candidate.json | dir>")
-    a, b = args.sql, args.paths[0]
-    if os.path.isdir(b):
-        picked = latest_bench_file(b, exclude=a)
-        if picked is None:
-            print(f"no BENCH_r*.json files in {b}")
-            return 2
-        b = picked
-    for p in (a, b):
-        if not os.path.exists(p):
-            print(f"no such bench file: {p}")
-            return 2
-    try:
-        report = bench_diff(
-            a, b, threshold=(args.threshold if args.threshold is not None
-                             else DEFAULT_THRESHOLD))
-    except ValueError as e:
-        print(f"bench-diff: {e}")
-        return 2
-    print(_json.dumps(report, indent=2) if args.json
-          else format_diff(report))
-    return 1 if report["verdict"] == "regression" else 0
 
 
 def _serve_main(args) -> int:
@@ -1730,9 +1699,19 @@ def generate_observability_docs() -> str:
         "  `TpuHashAggregateExec.dispatch` (mode);",
         "- the scan pipeline (docs/scan.md) adds `scanPrefetch` (the",
         "  producer thread's read+pack of one staged batch, mirrored",
-        "  into the interval-union `scanPrefetchTime` metric) and",
+        "  into the interval-union `scanPrefetchTime` metric),",
         "  `uploadAhead` (the async raw-chunk device_put issued ahead",
-        "  of the consuming stage, with the target chip);",
+        "  of the consuming stage, with the target chip) and",
+        "  `scanWait` (a consumer blocked on an empty prefetch ring);",
+        "  inside `FileScan.deviceDecodeTime` a unit's plan is three",
+        "  steps, each a span and an interval-union timer on the scan's",
+        "  registry: `scanRead` (`what=footer|chunk`, `bytes=`;",
+        "  `scanReadTime`, `scanReadBytes`), `scanParse` (one a column",
+        "  chunk: page headers, level/run tables, dictionary pages,",
+        "  string lengths, DELTA runs; `scanParseTime`, `scanPages`) and,",
+        "  nested in it, `scanInflate` (the chunk's pages decompressed;",
+        "  `scanInflateTime`, `scanInflatedBytes`; the parse timer",
+        "  pauses for it);",
         "- JIT compiles are `compile` spans (attr `cache` = which LRU",
         "  missed); a thread that blocks on ANOTHER thread's",
         "  in-progress compile of the same key (single-flight) emits a",
@@ -1845,7 +1824,7 @@ def generate_observability_docs() -> str:
         "`named_jit` as `jax.jit`. Dispatch spans, `compile` spans and",
         "the `firstDispatch` instant carry `program=<name>`.",
         "",
-        "### Three intervals no operator timer covers",
+        "### Intervals no operator timer covers",
         "",
         "Always-on timers of one process-wide registry (owner `Query`;",
         "two clock reads each), each mirrored to a span kind:",
@@ -1873,6 +1852,17 @@ def generate_observability_docs() -> str:
         " of a conditional semi/anti join, read from the count program"
         " once the mask program is enqueued), `exchangeSplit`, `iciSizes`,"
         " `ansiError` | thread-ns blocked, summed over task threads |",
+        "| `scanStarvedTime` | `scanWait` (`chip=`; only a pull that"
+        " found the ring empty) | `TpuRowToColumnarExec`'s consumer of a"
+        " scan stream (`exec/base.py`); `QueryScope.scan_streams` counts"
+        " the query's open streams (first pull to last batch handed on)"
+        " and those waiting | ns in which every open stream of the query"
+        " waited at once: host starvation of the scan streams, including"
+        " time the device is busy with queued work (not device idle) |",
+        "| `gcPauseTime`, `gcCollections.<gen>` | `gcPause` (`gen=`,"
+        " generations 1 and 2 only) | one `gc.callbacks` hook registered"
+        " at engine start (`trace.install_gc_hook`) | the interpreter's"
+        " collections on any thread, process-wide |",
         "",
         "## Configuration",
         "",
@@ -1936,14 +1926,19 @@ def generate_observability_docs() -> str:
         "the queries' extent), **idle by host span** (each gap of 1 ms",
         "or more goes, instant by instant, to the deepest engine span",
         "covering it on any host thread — the one that started last —",
-        "or to `no_span`; totals by kind and by `q`), and **device",
-        "time by program and named scope**.",
+        "or to `no_span`; a wait span, `scanWait` or `semaphoreWait`,",
+        "owns an instant only where no work span covers it, and the",
+        "`srt.query` root only where nothing else does; totals by kind",
+        "and by `q`), and **device time by program and named scope**.",
         "",
         "How to find why the device was idle: (1) record a profile"
         " around the queries (`jax.profiler.trace(dir)`, or the"
         " benchmark's `--trace 1`); (2) `tools trace <dir>`; (3) read"
         " `idle by the host span`: `plan`/`scanPrefetch` before"
-        " `firstDispatch` is start-up, `deviceSync site=...` is the"
+        " `firstDispatch` is start-up, `scanRead`/`scanInflate`/"
+        "`scanParse` name the step of a unit's decode plan,"
+        " `scanWait` a consumer with nothing to hand on, `gcPause` the"
+        " interpreter collecting, `deviceSync site=...` is the"
         " host waiting for a result it needs before it can enqueue"
         " more, `no_span` is host code nobody has instrumented; (4)"
         " `longest gaps` gives each gap's time, owner and `q`; (5)"
@@ -2214,19 +2209,6 @@ def generate_observability_docs() -> str:
         lines.append(f"| `{vname}` | {vdesc} |")
     lines += [
         "",
-        "### Regression tracking (`tools bench-diff`)",
-        "",
-        "`tools bench-diff <baseline.json> <candidate.json|dir>` diffs",
-        "two bench outputs — headline rows/s, device walls, decode",
-        "overlap, serving QPS, tracing/profiling/ring",
-        "overheads — against a relative `--threshold` (default 10%),",
-        "prints a verdict table (`--json` for machines), and exits 1",
-        "when a gating check regressed; bench.py runs it against the",
-        "previous BENCH_r0*.json every round (`detail.telemetry.",
-        "benchDiff`). Informational checks (CPU-engine wall, retry",
-        "counters, the `detail.tuning.*` feedback-control legs) report",
-        "but never gate.",
-        "",
         "### Self-tuning (`tools tuning`)",
         "",
         "`spark.rapids.sql.serve.tuning.enabled` closes the",
@@ -2375,8 +2357,7 @@ def generate_tuning_docs() -> str:
         "  finished records exist for the scope (cache-served and",
         "  control-plane records excluded), the controller computes",
         "  `change = (baseline - observed) / baseline` for p50 and",
-        "  p99 — the same relative-change discipline `tools",
-        "  bench-diff` gates on;",
+        "  p99, lower being better;",
         "- `change < -serve.tuning.revertThreshold` on either",
         "  percentile auto-reverts: the knob's old value is restored",
         "  and a `revert` record lands with the observed window as",

@@ -160,6 +160,26 @@ SPAN_CATALOG: Dict[str, str] = {
     "cacheEntryDrop": "the device pool dropped a cache-tier entry "
                       "under pressure instead of spilling a live "
                       "query's batch (docs/caching.md)",
+    "scanRead": "the device-decode plan reading a unit's file: "
+                "what=footer (the footer opened and parsed) or "
+                "what=chunk (one column chunk's seek + read); bytes= "
+                "(mirrors scanReadTime; nested in "
+                "FileScan.deviceDecodeTime)",
+    "scanInflate": "one column chunk's pages decompressed, its "
+                   "dictionary page included; bytes= out, pages= "
+                   "(mirrors scanInflateTime; nested in scanParse)",
+    "scanParse": "the rest of one column chunk's device-decode plan: "
+                 "page headers, level/run tables, dictionary pages, "
+                 "string lengths, DELTA runs (mirrors scanParseTime, "
+                 "which leaves its scanInflate spans out)",
+    "scanWait": "a scan stream's consumer blocked on an empty prefetch "
+                "ring (chip=); while every open stream of the query "
+                "waits at once the query books scanStarvedTime (host "
+                "starvation, not device idle: the device may still run "
+                "queued work)",
+    "gcPause": "a collection of the interpreter's generation 1 or 2 "
+               "(gen=), on the thread that triggered it (mirrors "
+               "gcPauseTime, which counts generation 0 too)",
 }
 
 INSTANT_CATALOG: Dict[str, str] = {
@@ -232,6 +252,19 @@ KIND_READERS: Dict[str, str] = {
     "resultCacheHit": _GENERIC + " of served queries (a hit's whole "
                       "wall)",
     "cacheEntryDrop": _GENERIC + " (cache-tier pressure)",
+    "scanRead": "mirrors scanReadTime -> benchmark `scan_read_host_s`; "
+                "idle-gap owner inside FileScan.deviceDecodeTime",
+    "scanInflate": "mirrors scanInflateTime -> benchmark "
+                   "`scan_inflate_host_s`; idle-gap owner inside "
+                   "scanParse",
+    "scanParse": "mirrors scanParseTime -> benchmark "
+                 "`scan_parse_host_s`; idle-gap owner inside "
+                 "FileScan.deviceDecodeTime",
+    "scanWait": "its starved part is scanStarvedTime -> benchmark "
+                "`scan_starved_s`; idle-gap owner only where no work "
+                "span covers the instant",
+    "gcPause": "mirrors gcPauseTime -> benchmark `gc_pause_s`; idle-gap "
+               "owner (a collection inside a gap)",
     "firstDispatch": "mirrors firstDispatchTime -> benchmark "
                      "`first_dispatch_s`; marks the end of the first "
                      "device gap",
@@ -257,6 +290,7 @@ KIND_READERS: Dict[str, str] = {
 
 _QSEQ = itertools.count(1)      # next() is atomic under the GIL
 _FIRST_LOCK = threading.Lock()  # QueryScope.first_dispatch
+_SCAN_LOCK = threading.Lock()   # QueryScope.scan_streams
 _QTLS = threading.local()
 
 
@@ -278,10 +312,14 @@ class QueryScope:
     ``pending`` marks a scope opened ahead of execution (``session.sql``
     for the parse, the server before admission): the next
     ``execute_plan`` on that plan or thread adopts it instead of
-    nesting under it."""
+    nesting under it.
+
+    It also counts the query's open scan streams and those of them
+    waiting on an empty prefetch ring (``scan_streams``)."""
 
     __slots__ = ("q", "tenant", "t_begin", "parent", "pending",
-                 "running", "_dispatched")
+                 "running", "_dispatched", "_streams", "_waiting",
+                 "_starved_at")
 
     def __init__(self, tenant: Optional[str] = None,
                  pending: bool = False):
@@ -296,6 +334,9 @@ class QueryScope:
         self.pending = pending
         self.running = False
         self._dispatched = False
+        self._streams = 0
+        self._waiting = 0
+        self._starved_at: Optional[int] = None
 
     def first_dispatch(self, program: Optional[str]) -> None:
         """Called at every enqueue of a device program; the first one
@@ -312,6 +353,28 @@ class QueryScope:
             M.FIRST_DISPATCH_TIME, M.ESSENTIAL).add(
             time.perf_counter_ns() - self.t_begin)
         instant("firstDispatch", scope=self, program=program)
+
+    def scan_streams(self, opened: int = 0, waiting: int = 0) -> None:
+        """One change to the query's scan streams: ``opened`` is +1 at a
+        stream consumer's first pull and -1 once it has handed on its
+        last batch, ``waiting`` +1 when a pull finds the prefetch ring
+        empty and -1 when the item comes. While every open stream waits
+        at once no stream has a batch it could hand the device: that
+        interval is booked to scanStarvedTime."""
+        now = time.perf_counter_ns()
+        with _SCAN_LOCK:
+            self._streams += opened
+            self._waiting += waiting
+            starved = 0 < self._streams == self._waiting
+            since = self._starved_at
+            if starved and since is None:
+                self._starved_at = now
+            if starved or since is None:
+                return
+            self._starved_at = None
+        from spark_rapids_tpu import metrics as M
+        M.query_registry().create(M.SCAN_STARVED_TIME, M.ESSENTIAL).add(
+            now - since)
 
 
 def current_scope() -> Optional[QueryScope]:
@@ -642,6 +705,12 @@ class span:
         self.t0 = time.perf_counter_ns()
         return self
 
+    def annotate(self, **attrs) -> None:
+        """Attributes known only inside the interval (a footer's size
+        once it is read), on the annotation and the host record."""
+        self.attrs.update(attrs)
+        self._ann.set_metadata(**attrs)
+
     def __exit__(self, *exc):
         t1 = self.t1 = time.perf_counter_ns()
         self._ann.__exit__(*exc)
@@ -697,6 +766,61 @@ def chip_of(batch) -> Optional[int]:
         return d.id if d is not None else None
     except Exception:
         return None
+
+
+# ---------------------------------------------------------------------------
+# The interpreter's collections (one process-wide gc.callbacks hook)
+# ---------------------------------------------------------------------------
+
+# (t0, annotation or None, scope) of the collection under way: CPython
+# runs one collection at a time, its callbacks on the collecting thread
+_GC_OPEN: list = []
+_GC_TIMERS: list = []   # [gcPauseTime, gcCollections.0, .1, .2]
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook: every collection books gcPauseTime and
+    gcCollections.<gen>; one of generation 1 or 2 is also a
+    ``gcPause`` span (gen=) under the collecting thread's query. The
+    metrics are created at install: a registry's create() takes a lock
+    the interrupted thread may hold."""
+    gen = info["generation"]
+    if phase == "start":
+        sc = getattr(_QTLS, "scope", None)
+        ann = None
+        if gen:
+            ann = annotation("gcPause", sc, None, {"gen": gen})
+            ann.__enter__()
+        _GC_OPEN[:] = (time.perf_counter_ns(), ann, sc)
+        return
+    if not _GC_OPEN:
+        return  # installed mid-collection
+    t0, ann, sc = _GC_OPEN
+    del _GC_OPEN[:]
+    t1 = time.perf_counter_ns()
+    _GC_TIMERS[0].add(t1 - t0)
+    _GC_TIMERS[1 + min(gen, 2)].add(1)
+    if ann is not None:
+        ann.__exit__(None, None, None)
+        qt = _ACTIVE
+        if qt is not None:
+            record(qt, "gcPause", t0, t1, sc, attrs={"gen": gen})
+
+
+def install_gc_hook() -> None:
+    """Register ``_on_gc`` once per process (engine start:
+    ``device_manager.initialize``); its totals live in the query
+    registry, which the telemetry aggregator folds."""
+    import gc
+    from spark_rapids_tpu import metrics as M
+    with _LOCK:
+        if _on_gc in gc.callbacks:
+            return
+        reg = M.query_registry()
+        _GC_TIMERS[:] = [reg.create(M.GC_PAUSE_TIME, M.ESSENTIAL)] + [
+            reg.create(f"gcCollections.{g}", M.ESSENTIAL)
+            for g in range(3)]
+        gc.callbacks.append(_on_gc)
 
 
 # ---------------------------------------------------------------------------

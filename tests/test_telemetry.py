@@ -4,8 +4,7 @@ dumps loading in `tools trace`), the trigger engine (forced slow-query
 bundle round trip under the server, per-trigger rate limiting, HBM /
 queue / retry-storm units), the Prometheus endpoint (exposition
 parseability, describe_metric coverage, monotone counters across
-registry GC, the protocol verb + HTTP twin), `tools top`,
-`tools bench-diff` (injected regression flags + exit contract), the
+registry GC, the protocol verb + HTTP twin), `tools top`, the
 empty-trace-dir CLI contract, the stats-under-concurrent-mutation
 satellite, and lint fixtures for the span-kind / prom-family rules."""
 
@@ -554,67 +553,6 @@ def test_tools_top_format_and_live_poll(data_dir, oracle):
         assert run_top(srv.port, interval=0.1, iterations=1) == 0
     finally:
         srv.shutdown()
-
-
-# ---------------------------------------------------------------------------
-# bench-diff
-# ---------------------------------------------------------------------------
-
-def _bench_doc(value=1.0e6, wall=5.0, qps=3.0):
-    return {"metric": "tpch_q1_sf1_parquet", "value": value,
-            "detail": {"device_wall_s": wall,
-                       "tpcds_q3": {"device_wall_s": 2.0},
-                       "serving": {"concurrency": {"c4": {"qps": qps}}},
-                       "telemetry": {"ringOverhead": 1.01}}}
-
-
-def test_bench_diff_flags_injected_regression(tmp_path):
-    from spark_rapids_tpu.telemetry.bench_diff import (bench_diff,
-                                                       format_diff)
-    # >= 10% wall regression on the candidate side
-    report = bench_diff(_bench_doc(), _bench_doc(value=0.88e6,
-                                                 wall=5.8))
-    assert report["verdict"] == "regression"
-    assert "value" in report["regressed"]
-    assert "detail.device_wall_s" in report["regressed"]
-    assert "REGRESSED" in format_diff(report)
-    # identical runs: ok, and an IMPROVEMENT is not a regression
-    assert bench_diff(_bench_doc(), _bench_doc())["verdict"] == "ok"
-    assert bench_diff(_bench_doc(),
-                      _bench_doc(value=2e6))["verdict"] == "ok"
-    # informational checks never gate: worse CPU wall alone stays ok
-    a = _bench_doc()
-    a["detail"]["cpu_engine_wall_s"] = 10.0
-    b = _bench_doc()
-    b["detail"]["cpu_engine_wall_s"] = 20.0
-    assert bench_diff(a, b)["verdict"] == "ok"
-
-
-def test_bench_diff_cli_exit_contract(tmp_path, capsys):
-    from spark_rapids_tpu.tools import _main
-    a, b = tmp_path / "a.json", tmp_path / "BENCH_r07.json"
-    with open(a, "w") as f:
-        json.dump(_bench_doc(), f)
-    with open(b, "w") as f:
-        json.dump(_bench_doc(value=0.8e6, wall=6.5), f)
-    assert _main(["bench-diff", str(a), str(b)]) == 1
-    assert "REGRESSION" in capsys.readouterr().out
-    assert _main(["bench-diff", str(a), str(a)]) == 0
-    capsys.readouterr()  # drop the ok-run table
-    # --json is machine-readable
-    assert _main(["bench-diff", "--json", str(a), str(b)]) == 1
-    doc = json.loads(capsys.readouterr().out)
-    assert doc["verdict"] == "regression"
-    # directory candidate: the newest BENCH_r*.json in it
-    assert _main(["bench-diff", str(a), str(tmp_path)]) == 1
-    # missing files: exit 2, clean message
-    assert _main(["bench-diff", str(a),
-                  str(tmp_path / "nope.json")]) == 2
-    # harness-wrapper shape (BENCH_r0*.json): parsed field unwraps
-    wrapped = tmp_path / "BENCH_r08.json"
-    with open(wrapped, "w") as f:
-        json.dump({"n": 8, "rc": 0, "parsed": _bench_doc()}, f)
-    assert _main(["bench-diff", str(a), str(wrapped)]) == 0
 
 
 # ---------------------------------------------------------------------------
