@@ -809,16 +809,18 @@ def take_columns(columns: Sequence[AnyDeviceColumn], idx: jax.Array,
 
 
 @jax.named_scope("compact")
-def _compact_body(active: jax.Array, flat):
-    """Stable compaction (active rows to the front): ONE 2-operand sort
-    pass for the permutation + ONE fused lane-matrix gather for all
-    arrays. (A searchsorted-based variant was tried in round 5: XLA
+def _compact_body(active: jax.Array, flat, cap: Optional[int] = None):
+    """Stable compaction (active rows to the front) into ``cap`` lanes,
+    the input's by default: ONE 2-operand sort pass for the permutation
+    + ONE fused lane-matrix gather, for all arrays, of its first ``cap``
+    entries only. (A searchsorted-based variant was tried in round 5: XLA
     lowers searchsorted to ~log2(cap) gather iterations on this backend,
     costing more than the sort pass it saved.)"""
     from spark_rapids_tpu.ops.lanes import fused_take
-    cap = active.shape[0]
-    pos = jnp.arange(cap, dtype=jnp.int32)
+    pos = jnp.arange(active.shape[0], dtype=jnp.int32)
     _k, idx = jax.lax.sort((~active, pos), num_keys=1, is_stable=True)
+    if cap is not None:
+        pos, idx = pos[:cap], idx[:cap]
     new_active = pos < jnp.sum(active)
     outs = []
     for g in fused_take(list(flat), idx):
@@ -892,7 +894,7 @@ def _shrink_impl(batch: DeviceBatch, n: int, compact_first: bool
     if fn is None:
         def _fn(active, *arrs):
             if compact_first:
-                active, arrs = _compact_body(active, arrs)
+                return _compact_body(active, arrs, cap)
             return active[:cap], tuple(
                 (a[:cap] if a.ndim == 1 else a[:cap, :]) for a in arrs)
         fn = _SHRINK_CACHE.put(key, named_jit("srt_shrink", _fn))

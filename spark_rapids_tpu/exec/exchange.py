@@ -263,7 +263,7 @@ class TpuBroadcastExchangeExec(TpuExec):
         return self.child.output
 
     def materialize_device(self):
-        from spark_rapids_tpu.columnar.device import concat_device
+        from spark_rapids_tpu.exec.join import assemble_build
         from spark_rapids_tpu.resource import get_semaphore
         # consumers touch the device with the broadcast batch: take the
         # permit BEFORE the build lock — a permit-holder blocked on the
@@ -287,10 +287,8 @@ class TpuBroadcastExchangeExec(TpuExec):
                         release_current_thread
                     release_current_thread()
                     raise
-                self._built = (
-                    concat_device(batches) if len(batches) > 1 else
-                    batches[0] if batches else
-                    DeviceBatch.empty(self.child.schema))
+                self._built = assemble_build(batches, self.child.schema,
+                                             self.metrics)
             return self._built
 
     def device_partitions(self) -> List[DevicePartitionThunk]:

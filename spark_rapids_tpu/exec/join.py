@@ -17,7 +17,8 @@ import threading
 from typing import Callable, Iterator, List, Optional
 
 from spark_rapids_tpu import metrics as M
-from spark_rapids_tpu.columnar.device import DeviceBatch, concat_device
+from spark_rapids_tpu.columnar.device import (DeviceBatch, bucket_capacity,
+                                              concat_device, shrink_to_bucket)
 from spark_rapids_tpu.conf import TpuConf
 from spark_rapids_tpu.exec.base import (DevicePartitionThunk, TpuExec,
                                         device_channel)
@@ -82,6 +83,33 @@ def stream_chunks(handles: List, goal: int) -> List[List]:
         chunks[-1].append(h)
         rows += n
     return chunks
+
+
+def assemble_build(batches: List[DeviceBatch], schema: T.StructType,
+                   metrics: M.MetricRegistry) -> DeviceBatch:
+    """A join's build side as one batch, held at its rows and not its
+    lanes wherever that costs no device sync: every probe sorts the build
+    side's lanes with its chunk's (ops/join.py _key_plan), empty ones
+    too. Several batches concatenate (``concat_device`` compacts them to
+    their rows' bucket). One batch whose active rows are already counted
+    on the host (``_num_rows``: its producer's count, or one a handle
+    read, as the AQE demotion does) is compacted stably to
+    ``bucket_capacity(rows)`` when that is at most half its capacity, so
+    the rows keep their order and the joins' outputs theirs. Any other
+    batch is returned as it is: its count is never read for this."""
+    if len(batches) > 1:
+        return concat_device(batches)
+    if not batches:
+        return DeviceBatch.empty(schema)
+    b = batches[0]
+    n = b._num_rows
+    if n is None or bucket_capacity(max(1, n)) > b.capacity // 2:
+        return b
+    out = shrink_to_bucket(b)  # the count is cached: no sync
+    metrics.create(M.JOIN_BUILD_COMPACTIONS, M.ESSENTIAL).add(1)
+    metrics.create(M.JOIN_BUILD_LANES_DROPPED, M.ESSENTIAL).add(
+        b.capacity - out.capacity)
+    return out
 
 
 class TpuShuffledHashJoinExec(TpuExec):
@@ -153,15 +181,11 @@ class TpuShuffledHashJoinExec(TpuExec):
     def _pair_attrs(self):
         return list(self.left.output) + list(self.right.output)
 
-    def _join_one(self, lbatches: List[DeviceBatch],
-                  rbatches: List[DeviceBatch],
+    def _join_one(self, lbatches: List[DeviceBatch], rwhole: DeviceBatch,
                   fk_hint: bool = False) -> Iterator[DeviceBatch]:
         lschema = self.left.schema
-        rschema = self.right.schema
         lwhole = (concat_device(lbatches) if len(lbatches) > 1 else
                   lbatches[0] if lbatches else DeviceBatch.empty(lschema))
-        rwhole = (concat_device(rbatches) if len(rbatches) > 1 else
-                  rbatches[0] if rbatches else DeviceBatch.empty(rschema))
         rwhole = self._align_build(lwhole, rwhole)
         lk = P.bind_list(self.left_keys, self.left.output)
         rk = P.bind_list(self.right_keys, self.right.output)
@@ -308,10 +332,8 @@ class TpuShuffledHashJoinExec(TpuExec):
                 self.metrics.create("subplanCacheHits",
                                     M.ESSENTIAL).add(1)
             else:
-                rbatches = [h.get() for h in handles]
-                rwhole = (concat_device(rbatches) if len(rbatches) > 1
-                          else rbatches[0] if rbatches else
-                          DeviceBatch.empty(self.right.schema))
+                rwhole = assemble_build([h.get() for h in handles],
+                                        self.right.schema, self.metrics)
                 # the build executed during the exchange's stat
                 # materialization above, so the pre-EXECUTION capture
                 # (session TLS; a superset of this subtree's inputs) is
@@ -538,7 +560,10 @@ class TpuShuffledHashJoinExec(TpuExec):
                             store, lhandles, rhandles,
                             n * max(1, co_parts), oracle, depth=0)
                         return
-                yield from self._join_items(store, lhandles, rb)
+                rwhole = assemble_build(rb, self.right.schema,
+                                        self.metrics)
+                self._book_build(rwhole)
+                yield from self._join_stream(lhandles, rwhole)
             return run
         return make(lt, rt)
 
@@ -615,9 +640,9 @@ class TpuShuffledHashJoinExec(TpuExec):
                 yield from self._ooc_join(store, lhs, rhs, modulus * 2,
                                           oracle, depth + 1)
                 continue
-            def mat(rhs=rhs) -> List[DeviceBatch]:
-                bs = [h.get() for h in rhs]
-                return [concat_device(bs)] if len(bs) > 1 else bs
+            def mat(rhs=rhs) -> DeviceBatch:
+                return assemble_build([h.get() for h in rhs],
+                                      self.right.schema, self.metrics)
 
             if depth >= oracle.max_recursion:
                 # recursion exhausted: the OOM-retry protocol is the
@@ -646,18 +671,8 @@ class TpuShuffledHashJoinExec(TpuExec):
                     continue
             for h in rhs:
                 h.close()
-            yield from self._join_items(store, lhs, rwhole)
-
-    def _join_items(self, store, lhandles: List,
-                    rb: List[DeviceBatch]) -> Iterator[DeviceBatch]:
-        """One co-partition's join: the stream side arrives as
-        spillable handles, the build side as device batches (shared by
-        the in-memory path and each out-of-core bucket)."""
-        rwhole = (concat_device(rb) if len(rb) > 1 else
-                  rb[0] if rb else
-                  DeviceBatch.empty(self.right.schema))
-        self._book_build(rwhole)
-        yield from self._join_stream(lhandles, rwhole)
+            self._book_build(rwhole)
+            yield from self._join_stream(lhs, rwhole)
 
     def _join_stream(self, lhandles: List, rwhole: DeviceBatch,
                      fk_hint: Callable[[], bool] = lambda: False
@@ -690,7 +705,7 @@ class TpuShuffledHashJoinExec(TpuExec):
                 h.close()
             self.metrics.create(M.JOIN_STREAM_CHUNKS, M.ESSENTIAL).add(1)
             if chunk_type is None:
-                yield from self._join_one(lb, [rwhole], fk_hint=fk_hint())
+                yield from self._join_one(lb, rwhole, fk_hint=fk_hint())
             else:
                 out, matched = self._join_one_matched(
                     lb, rwhole, chunk_type, lk, rk, pair_schema)
@@ -781,12 +796,10 @@ class TpuBroadcastHashJoinExec(TpuShuffledHashJoinExec):
         rbatches: List[DeviceBatch] = []
         for t in device_channel(self.right):
             rbatches.extend(b for b in t() if b._num_rows != 0)
-        # concat the build side ONCE (a TpuBroadcastExchangeExec child
-        # already yields its single cached batch); every stream
+        # assemble the build side ONCE (a TpuBroadcastExchangeExec child
+        # already yields its single assembled batch); every stream
         # partition shares it, chunked by _join_stream
-        rwhole = (concat_device(rbatches) if len(rbatches) > 1 else
-                  rbatches[0] if rbatches else
-                  DeviceBatch.empty(self.right.schema))
+        rwhole = assemble_build(rbatches, self.right.schema, self.metrics)
         self._subplan_cache_put(probe, captured, rwhole)
         return self._broadcast_stream_thunks(self.left, rwhole)
 

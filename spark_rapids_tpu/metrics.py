@@ -92,6 +92,8 @@ JOIN_BUILD_ROWS = "joinBuildRows"    # build-side rows, once per build
 JOIN_OUTPUT_ROWS = "joinOutputRows"  # joined rows, where the count is known
 JOIN_DEMOTED_COUNT = "joinDemotedCount"  # shuffled joins run as broadcast
 JOIN_STREAM_CHUNKS = "joinStreamChunks"  # stream chunks probed (whole: 1)
+JOIN_BUILD_COMPACTIONS = "joinBuildCompactions"  # builds held at their rows
+JOIN_BUILD_LANES_DROPPED = "joinBuildLanesDropped"  # lanes those dropped
 # semi/anti joins under a residual condition (a decorrelated EXISTS)
 JOIN_CONDITIONAL_COUNT = "joinConditionalCount"  # conditional mask joins run
 JOIN_CONDITION_PAIRS = "joinConditionPairs"  # candidate pairs evaluated
@@ -179,6 +181,13 @@ METRIC_DESCRIPTIONS: Dict[str, str] = {
                         "with, one per probe: a stream partition joined "
                         "whole adds 1, one joined max(batchSizeRows, "
                         "build capacity) rows at a time adds 1 a chunk",
+    JOIN_BUILD_COMPACTIONS: "join build sides held at their rows' capacity "
+                            "bucket: one batch whose row count was already "
+                            "on the host, compacted where that bucket is at "
+                            "most half its capacity",
+    JOIN_BUILD_LANES_DROPPED: "lanes those compactions took off the join "
+                              "build sides (capacity before minus capacity "
+                              "after, summed)",
     JOIN_CONDITIONAL_COUNT: "left semi and left anti joins run under a "
                             "residual condition (a decorrelated [NOT] "
                             "EXISTS), once per stream chunk joined",

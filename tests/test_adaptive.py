@@ -25,6 +25,7 @@ from spark_rapids_tpu.sql.session import TpuSparkSession
 from tests.datagen import (IntegerGen, KeyStringGen, LongGen, SmallIntGen,
                            gen_batch)
 from tests.harness import _rows, _sort_key
+from tests.test_device_join import _STREAMED_JOINS, _one_build_batch_join
 
 
 @pytest.fixture(autouse=True)
@@ -250,6 +251,45 @@ def test_broadcast_demotion_fires_and_matches():
     assert m_on.get("aqeBroadcastFlip", 0) >= 1, m_on
     assert m_on.get("aqeReplans", 0) >= 1
     assert m_off.get("aqeBroadcastFlip", 0) == 0
+
+
+@pytest.mark.parametrize("family", sorted(_STREAMED_JOINS))
+def test_demoted_sparse_build_is_held_at_its_rows(family, monkeypatch):
+    """A shuffled join whose build side is ONE batch of 4,096 lanes with
+    40 rows active after a filter: its bytes are over the threshold, so
+    the demotion reads the active rows to decide, and the build side it
+    then broadcasts is compacted to those rows' bucket (64 lanes): the
+    stream is probed max(batchSizeRows, 64) rows at a time. A full outer
+    join is never demoted and nothing reads its count, so its build side
+    stays as it is. Adaptive on, off and the CPU oracle agree."""
+    from spark_rapids_tpu.exec import join as J
+    from spark_rapids_tpu.sql import functions as F
+    jt = _STREAMED_JOINS[family][0]
+    fn = _one_build_batch_join(family, 4096, 40,
+                               lambda r, n: r.filter(F.col("k2") < n))
+    real, goals = J.stream_chunks, []
+
+    def recording(handles, goal):
+        goals.append(goal)
+        return real(handles, goal)
+    monkeypatch.setattr(J, "stream_chunks", recording)
+    conf = {"spark.rapids.sql.batchSizeRows": "128",
+            # 40 rows of two longs are under it, 4,096 lanes are not
+            "spark.rapids.sql.autoBroadcastJoinThreshold": "1000"}
+    cpu, _ = _collect(fn, {**conf, "spark.rapids.sql.enabled": "false"})
+    off, m_off = _collect(fn, {**conf, "spark.rapids.sql.enabled": "true",
+                               "spark.rapids.sql.adaptive.enabled":
+                               "false"})
+    goals.clear()
+    on, m_on = _collect(fn, {**conf, "spark.rapids.sql.enabled": "true"})
+    assert on == off == cpu
+    assert m_off.get("joinBuildCompactions", 0) == 0
+    demoted = jt != "full"
+    assert m_on.get("joinDemotedCount", 0) == int(demoted), m_on
+    assert m_on.get("joinBuildCompactions", 0) == int(demoted), m_on
+    assert m_on.get("joinBuildLanesDropped", 0) == (
+        4096 - 64 if demoted else 0)
+    assert goals == [128 if demoted else 4096], goals
 
 
 def test_coalesce_merges_undersized_partitions():

@@ -579,3 +579,203 @@ def test_stream_chunk_is_never_smaller_than_the_build_side(family,
         expect_execs=["HashJoin " + jt]))
     assert calls == [([110] * 20, 512, 5)], calls
     assert moved == 5
+
+
+# -- a build side held at its rows, not its lanes (exec/join.py
+# assemble_build) ---------------------------------------------------------
+
+_BUILD_COUNTERS = ("joinBuildCompactions", "joinBuildLanesDropped")
+
+
+def _counters_moved(run, keys=_BUILD_COUNTERS):
+    """The process-wide counters ``keys`` booked while ``run()`` ran."""
+    from spark_rapids_tpu.telemetry.prometheus import aggregator
+    before = dict(aggregator().scrape()[0])
+    run()
+    after = aggregator().scrape()[0]
+    return {k: after.get(k, 0) - before.get(k, 0) for k in keys}
+
+
+def _one_build_batch_join(family, build_rows, kept, narrow):
+    """2,200 stream rows in one partition of twenty batches against a
+    build side of ONE batch of ``build_rows`` rows of which ``kept``
+    stay active: ``narrow(frame, kept)`` keeps them (a limit, whose
+    count is on the host; a filter, whose count is not)."""
+    jt, residual = _STREAMED_JOINS[family]
+    rng = np.random.default_rng(39)
+    left = {"k": [int(v) for v in rng.integers(0, 2 * kept, 2200)],
+            "a": [int(v) for v in rng.integers(0, 6, 2200)]}
+    right = {"k2": list(range(build_rows)),
+             "b": [int(v) for v in rng.integers(0, 6, build_rows)]}
+
+    def fn(s):
+        l = s.createDataFrame(left, "k long, a long",
+                              num_partitions=20).repartition(1)
+        r = narrow(s.createDataFrame(right, "k2 long, b long",
+                                     num_partitions=1), kept)
+        on = l["k"] == r["k2"]
+        return l.join(r, on & (l["a"] != r["b"]) if residual else on, jt)
+    return fn
+
+
+def _recording_chunker(monkeypatch):
+    """Every ``stream_chunks`` call as (handle rows, goal, chunks)."""
+    from spark_rapids_tpu.exec import join as J
+    real, calls = J.stream_chunks, []
+
+    def recording(handles, goal):
+        out = real(handles, goal)
+        calls.append(([h.rows for h in handles], goal, len(out)))
+        return out
+    monkeypatch.setattr(J, "stream_chunks", recording)
+    return calls
+
+
+_BUILD_SHAPES = {
+    # name: (build rows, rows kept, the chunk goal, lanes dropped)
+    "sparse_40_of_4096": (4096, 40, 128, 4096 - 64),
+    "dense_500_of_512": (512, 500, 512, 0),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(_BUILD_SHAPES))
+@pytest.mark.parametrize("family", sorted(_STREAMED_JOINS))
+def test_build_side_is_held_at_its_rows(family, shape, monkeypatch):
+    """One build batch whose count is on the host (a limit's): a sparse
+    one (40 rows in 4,096 lanes) is compacted to its rows' bucket, 64
+    lanes, so a probe takes max(batchSizeRows, 64) stream rows; a dense
+    one (500 in 512) is left as it is. Broadcast (six families) and
+    shuffled (full) alike, the rows are the CPU engine's."""
+    build_rows, kept, goal, dropped = _BUILD_SHAPES[shape]
+    calls = _recording_chunker(monkeypatch)
+    fn = _one_build_batch_join(family, build_rows, kept,
+                               lambda r, n: r.limit(n))
+    moved = _counters_moved(lambda: assert_tpu_and_cpu_equal_collect(
+        fn, conf={"spark.rapids.sql.batchSizeRows": "128",
+                  "spark.rapids.sql.test.forceDevice": "true"},
+        expect_execs=["HashJoin " + _STREAMED_JOINS[family][0]]))
+    chunks = -(-20 // (goal // 110))
+    assert calls == [([110] * 20, goal, chunks)], calls
+    assert moved == {"joinBuildCompactions": int(dropped > 0),
+                     "joinBuildLanesDropped": dropped}, moved
+
+
+def _device_sync_spans(trace_dir) -> int:
+    import glob
+    import os
+
+    from spark_rapids_tpu import trace as TR
+    return sum(sum(1 for s in TR.load_trace(f)["spans"]
+                   if s["name"] == "deviceSync")
+               for f in glob.glob(os.path.join(str(trace_dir),
+                                               "trace-*.json")))
+
+
+@pytest.mark.parametrize("family", sorted(_STREAMED_JOINS))
+def test_build_side_whose_count_is_not_on_the_host_stays(family, tmp_path,
+                                                         monkeypatch):
+    """A filter leaves 40 of 4,096 lanes active and nobody reads the
+    count: the build side is probed as it is (a chunk goal of 4,096),
+    nothing is compacted, and the query records as many ``deviceSync``
+    spans as with the build side assembled the way it was before."""
+    from spark_rapids_tpu import trace as TR
+    from spark_rapids_tpu.columnar.device import DeviceBatch, concat_device
+    from spark_rapids_tpu.exec import join as J
+    from spark_rapids_tpu.sql.session import TpuSparkSession
+    calls = _recording_chunker(monkeypatch)
+    fn = _one_build_batch_join(family, 4096, 40,
+                               lambda r, n: r.filter(F.col("k2") < n))
+
+    def run(tag):
+        TR.reset_tracing()
+        s = TpuSparkSession({
+            "spark.rapids.sql.enabled": "true",
+            "spark.rapids.sql.test.forceDevice": "true",
+            "spark.rapids.sql.batchSizeRows": "128",
+            "spark.rapids.sql.trace.enabled": "true",
+            "spark.rapids.sql.trace.dir": str(tmp_path / tag)})
+        try:
+            rows = sorted(map(tuple, fn(s).collect()),
+                          key=lambda r: tuple((v is None, v) for v in r))
+        finally:
+            s.stop()
+        TR.reset_tracing()
+        return rows, _device_sync_spans(tmp_path / tag)
+
+    out = []
+    moved = _counters_moved(lambda: out.append(run("helper")))
+    assert calls == [([110] * 20, 4096, 1)], calls
+    assert moved == {"joinBuildCompactions": 0, "joinBuildLanesDropped": 0}
+    rows, syncs = out[0]
+
+    def as_before(batches, schema, metrics):
+        return (concat_device(batches) if len(batches) > 1 else
+                batches[0] if batches else DeviceBatch.empty(schema))
+    monkeypatch.setattr(J, "assemble_build", as_before)
+    assert run("before") == (rows, syncs) and syncs > 0, syncs
+
+
+def _long_batch(keys, active=None, counted=True):
+    """A one-column int64 batch over ``keys``; ``active`` masks lanes and
+    ``counted`` says whether its count rides with it."""
+    from spark_rapids_tpu.columnar.device import DeviceBatch
+    schema = T.StructType([T.StructField("k", T.LongT)])
+    b = DeviceBatch.from_host(HostBatch(
+        schema, [HostColumn.all_valid(np.asarray(keys, np.int64),
+                                      T.LongT)], len(keys)),
+        capacity=len(keys))
+    if active is not None:
+        import jax.numpy as jnp
+        b = DeviceBatch(schema, b.columns, jnp.asarray(active),
+                        int(np.sum(active)) if counted else None)
+    return b
+
+
+def _active_keys(b):
+    return [int(v) for v in b.to_host().to_pydict()["k"]]
+
+
+def test_assemble_build_from_none_one_and_several_batches(monkeypatch):
+    from spark_rapids_tpu import metrics as M
+    from spark_rapids_tpu.columnar.device import DeviceBatch
+    from spark_rapids_tpu.exec.join import assemble_build
+    schema = T.StructType([T.StructField("k", T.LongT)])
+    reg = M.MetricRegistry()
+
+    def counters():
+        snap = reg.snapshot()
+        return [snap.get(k, 0) for k in _BUILD_COUNTERS]
+
+    # none: an empty build side of the schema
+    empty = assemble_build([], schema, reg)
+    assert empty.schema == schema and empty.row_count() == 0
+    # one, sparse, counted: compacted in order to its rows' bucket
+    keys = np.arange(4096) * 7
+    act = np.zeros(4096, bool)
+    act[[5, 900, 901, 4000, 17, 2222]] = True
+    b = _long_batch(keys, act)
+    out = assemble_build([b], schema, reg)
+    assert out.capacity == 64 and out._num_rows == 6
+    assert _active_keys(out) == [int(k) for k in keys[act]]
+    assert counters() == [1, 4096 - 64]
+    # one, dense (100 rows need 112 lanes, over half of 128): the batch
+    # itself
+    dense = _long_batch(keys[:128], np.arange(128) < 100)
+    assert assemble_build([dense], schema, reg) is dense
+    # one, uncounted: the batch itself, and its count is never read
+    blind = _long_batch(keys, act, counted=False)
+
+    def no_read(self, site="rowCount"):
+        raise AssertionError("the build side's count was read")
+    monkeypatch.setattr(DeviceBatch, "row_count", no_read)
+    assert assemble_build([blind], schema, reg) is blind
+    assert counters() == [1, 4096 - 64]
+    monkeypatch.undo()
+    # several: concatenated, compacted as concat_device does, in order
+    parts = [_long_batch(keys[:256], act[:256]),
+             _long_batch(keys[:2048], act[:2048], counted=False)]
+    whole = assemble_build(parts, schema, reg)
+    assert _active_keys(whole) == [int(k) for k in keys[:256][act[:256]]] \
+        + [int(k) for k in keys[:2048][act[:2048]]]
+    assert whole.capacity == 64
+    assert counters() == [1, 4096 - 64]
