@@ -82,10 +82,21 @@ class _Packer:
             self.off += pad
         return start
 
-    def words(self) -> np.ndarray:
-        if not self.parts:
-            return np.zeros(1, dtype=np.int32)
-        return np.concatenate(self.parts).view(np.int32)
+    def words(self, bucketed: bool = False) -> np.ndarray:
+        """The regions end to end as int32 words; ``bucketed`` lays them
+        into a zeroed buffer of ``bucket_capacity`` words, what an
+        encoded upload sends (exact sizes would compile a decode program
+        a scan unit)."""
+        if not bucketed:
+            if not self.parts:
+                return np.zeros(1, dtype=np.int32)
+            return np.concatenate(self.parts).view(np.int32)
+        from spark_rapids_tpu.columnar.device import bucket_capacity
+        out = np.zeros(bucket_capacity(max(1, self.off // 4)),
+                       dtype=np.int32)
+        if self.parts:
+            np.concatenate(self.parts, out=out.view(np.uint8)[:self.off])
+        return out
 
 
 def _encode_strings(data: np.ndarray, validity: np.ndarray, n: int,
@@ -616,15 +627,18 @@ def prepare_encoded_upload(enc, cap: int, metrics=None):
                        plan.has_delta, plan.has_bss, has_slen))
         arity = 3 if plan.kind in ("str", "dec128") else 2
         spec.append((dt, arity))
-    # bucket the page buffer so same-shaped row groups share one
-    # decode program (exact sizes would compile per unit)
-    from spark_rapids_tpu.columnar.device import bucket_capacity
-    words = enc.words
-    nw = bucket_capacity(len(words))
-    if nw > len(words):
-        words = np.concatenate([words,
-                                np.zeros(nw - len(words), np.int32)])
-    return ("encoded", enc.schema, n, cap, words, extras,
+    if metrics is not None:
+        # ent[1], ent[4]: kind and char_cap of a "dev" entry
+        chars = sum(cap * ent[4] for ent in layout
+                    if ent[0] == "dev" and ent[1] == "str")
+        if chars:
+            from spark_rapids_tpu import metrics as M
+            metrics.create(M.DEVICE_DECODE_STRING_BYTES,
+                           M.ESSENTIAL).add(chars)
+    # the scan's plans lay the page buffer out at its bucket
+    # (``_Packer.words(bucketed=True)``), so that same-shaped row groups
+    # share one decode program
+    return ("encoded", enc.schema, n, cap, enc.words, extras,
             tuple(layout), tuple(spec), metrics)
 
 

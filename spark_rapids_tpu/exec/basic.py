@@ -116,11 +116,12 @@ class TpuFilterExec(TpuExec):
                     with metrics.timed(M.OP_TIME):
                         if needs_part:
                             out = X.run_filter(
-                                bound, b, part_ctx=(pid_d, row_start))
+                                bound, b, part_ctx=(pid_d, row_start),
+                                metrics=metrics)
                             row_start = _advance_rows(row_start,
                                                       b.active)
                         else:
-                            out = X.run_filter(bound, b)
+                            out = X.run_filter(bound, b, metrics=metrics)
                     metrics.create(M.DISPATCH_COUNT, M.ESSENTIAL).add(1)
                     from spark_rapids_tpu.parallel.mesh import \
                         record_chip_dispatch

@@ -160,6 +160,8 @@ class TpuFusedStageExec(TpuExec):
         stage_lits = X.stage_literal_values(steps)  # constant per stage
         schema = self.schema
         has_filter = any(k == "filter" for k, _ in steps)
+        pkey = ("stage", skey)
+        has_like = X.steps_have_like(steps)
         window_n = max(1, int(self.conf.get(STAGE_FUSION_MAX_IN_FLIGHT)))
         metrics = self.metrics
         stage_label = "+".join(op.simple_string().split()[0]
@@ -179,6 +181,8 @@ class TpuFusedStageExec(TpuExec):
             from spark_rapids_tpu.parallel.mesh import record_chip_dispatch
             record_chip_dispatch(metrics, b)
             chip = TR.chip_of(b)  # None (no device query) when untraced
+            shapes = X.input_shapes(b.columns, b.active) if has_like \
+                else None
             fn, was_miss = _STAGE_CACHE.get_or_build(
                 (skey, donate), lambda: X.build_stage_fn(steps, donate))
             mirror_to_metrics(_STAGE_CACHE, metrics, was_miss)
@@ -207,6 +211,8 @@ class TpuFusedStageExec(TpuExec):
             else:
                 self._fan_back(elapsed)
             metrics.create(M.DISPATCH_COUNT, M.ESSENTIAL).add(1)
+            if has_like:
+                X.book_like_char_bytes(metrics, pkey, lambda: shapes)
             metrics.create(M.NUM_OUTPUT_BATCHES, M.ESSENTIAL).add(1)
             self._fan_back_batches()
             X._raise_if_errors(err)

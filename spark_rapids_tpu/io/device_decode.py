@@ -32,9 +32,11 @@ host decode, so results stay bit-for-bit identical to the host path.
 from __future__ import annotations
 
 import contextlib
+import functools
 import itertools
+import struct
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -456,41 +458,119 @@ def _popcount_regions(page: bytes, regions: List[Tuple[int, int]]) -> int:
     return total
 
 
+_U32 = struct.Struct("<I")
+
+
 def _plain_str_lengths(body: bytes, pos: int, end: int,
                        nn: int) -> np.ndarray:
-    """Per-value byte lengths of a PLAIN byte-array page (4-byte LE
-    length prefixes interleaved with the bytes). The value starts form
-    a sequential chain (start[i+1] = start[i] + 4 + len[i]); resolved
-    with vectorized pointer doubling over a byte-position jump table —
-    O(page_bytes * log n) numpy work, no per-value Python loop."""
-    if nn <= 0:
-        return np.zeros(0, dtype=np.int64)
-    buf = np.frombuffer(body, dtype=np.uint8, offset=pos,
-                        count=end - pos).astype(np.int64)
-    B = buf.shape[0]
-    if B < 4:
+    """Per-value byte lengths of a PLAIN byte-array run (4-byte LE
+    length prefixes interleaved with the bytes): a dictionary page, or a
+    data page of a chunk planned without its file (``_plan_column``'s
+    ``arrow_values`` is None). The value starts form a sequential chain
+    (start[i+1] = start[i] + 4 + len[i]), walked once from the first: a
+    few hundred ns a value, where pointer doubling over every byte
+    position cost O(bytes x log n) of numpy, 13 times the walk on a page
+    of TPC-H comments."""
+    unpack = _U32.unpack_from
+    lengths = [0] * max(nn, 0)
+    try:
+        for i in range(nn):
+            n = unpack(body, pos)[0]
+            lengths[i] = n
+            pos += 4 + n
+    except struct.error:
         raise UnsupportedColumn("truncated PLAIN byte-array page")
-    le = (buf[:-3] | (buf[1:-2] << 8) | (buf[2:-1] << 16)
-          | (buf[3:] << 24))        # u32 length at every byte position
-    limit = B - 3
-    nxt = np.arange(limit, dtype=np.int64) + 4 + le
-    np.clip(nxt, 0, limit - 1, out=nxt)   # keep the table in-domain
-    starts = np.empty(nn, dtype=np.int64)
-    starts[0] = 0
-    filled = 1
-    jump = nxt                            # jumps exactly `filled` values
-    while filled < nn:
-        take = min(filled, nn - filled)
-        starts[filled:filled + take] = jump[starts[:take]]
-        filled += take
-        if filled < nn:
-            jump = jump[jump]
-    lengths = le[starts]
-    if nn >= 2 and not (np.diff(starts) > 0).all():
-        raise UnsupportedColumn("corrupt PLAIN byte-array chain")
-    if int(starts[-1]) + 4 + int(lengths[-1]) > B:
+    if pos > end:
         raise UnsupportedColumn("PLAIN byte-array page overruns body")
-    return lengths
+    return np.array(lengths, dtype=np.int64)
+
+
+ArrowValues = Tuple[np.ndarray, np.ndarray, Optional[np.ndarray]]
+
+
+def _arrow_values(pf, row_group: int, name: str) -> Optional[ArrowValues]:
+    """A string or binary column chunk as pyarrow decodes it: its non-null
+    values' bytes end to end, their byte lengths in order, and the bytes
+    of its validity bitmap (None where no value is null). One call in
+    C++, outside the interpreter's lock: the chunk's pages are inflated
+    and walked there, not page by page in Python, where each page's
+    decompression gives the lock up and waits for it again behind the
+    scan's other threads. None where the array is one this layout cannot
+    carry: a bitmap that does not start on a byte, or a null slot that
+    holds bytes."""
+    import pyarrow as pa
+    col = pf.read_row_group(row_group, columns=[name]).column(0)
+    arr = col.chunk(0) if col.num_chunks == 1 else col.combine_chunks()
+    n = len(arr)
+    bufs = arr.buffers()
+    wide = pa.types.is_large_string(arr.type) or \
+        pa.types.is_large_binary(arr.type)
+    offs = np.frombuffer(bufs[1], dtype=np.int64 if wide else np.int32)[
+        arr.offset:arr.offset + n + 1].astype(np.int64)
+    data = (np.frombuffer(bufs[2], dtype=np.uint8) if bufs[2] is not None
+            else np.zeros(0, dtype=np.uint8))
+    lens = np.diff(offs)
+    validity = None
+    if arr.null_count:
+        if arr.offset % 8:
+            return None
+        valid = arr.is_valid().to_numpy(zero_copy_only=False)
+        if lens[~valid].any():
+            return None
+        lens = lens[valid]
+        validity = np.frombuffer(bufs[0], dtype=np.uint8)[
+            arr.offset // 8:(arr.offset + n + 7) // 8]
+    return data[offs[0]:offs[-1]], lens, validity
+
+
+def _page_values(hdr: Dict[int, Any]) -> Tuple[int, int]:
+    """A data page's (level count, value encoding) from its header;
+    (0, -1) for any other page."""
+    dph = hdr.get(5) if hdr.get(1) == PAGE_DATA else (
+        hdr.get(8) if hdr.get(1) == PAGE_DATA_V2 else None)
+    if not dph:
+        return 0, -1
+    return dph.get(1, 0), dph.get(2 if hdr.get(1) == PAGE_DATA else 4,
+                                  ENC_PLAIN)
+
+
+def _plan_from_arrow(plan: ColumnDevicePlan, got: ArrowValues,
+                     spans: List[Tuple[Dict[int, Any], int, int]],
+                     packer) -> None:
+    """Fill ``plan`` from ``_arrow_values``: the non-null values as ONE
+    concatenated-bytes page (the lane DELTA_LENGTH_BYTE_ARRAY pages take)
+    and, where a value is null, the validity bitmap as one bit-packed run
+    of definition levels. The pages' own headers still say which
+    encoding held how many values."""
+    from spark_rapids_tpu.columnar.device import bucket_char_cap
+    vals, lens, validity = got
+    dense = int(lens.shape[0])
+    if validity is not None:
+        plan.dl = RunTable()
+        plan.dl.add(0, True, 0, packer.add(validity) * 8, 1)
+        valid = np.unpackbits(validity, bitorder="little").astype(np.int64)
+        seen = np.concatenate([[0], np.cumsum(valid)])
+    else:
+        plan.dl = None
+    rows = 0
+    for hdr, _o, _e in spans:
+        nv, enc = _page_values(hdr)
+        if nv:
+            nn = nv if validity is None else int(seen[rows + nv]
+                                                 - seen[rows])
+            ename = _ENC_NAMES.get(enc, str(enc))
+            plan.encoding_values[ename] = \
+                plan.encoding_values.get(ename, 0) + nn
+            rows += nv
+    plan.pg_dense_start = [0, dense]
+    plan.pg_first = [0]
+    plan.pg_enc = [PGE_DL_STR]
+    plan.pg_plain_byte = [packer.add(vals)]
+    plan.n_dense = dense
+    plan.vr = plan.dr = None
+    plan.str_lens = np.zeros(max(1, dense), dtype=np.int32)
+    plan.str_lens[:dense] = lens
+    plan.char_cap = bucket_char_cap(max(1, int(lens.max(initial=1))))
 
 
 def _parse_delta_header(page: bytes, pos: int) -> Tuple[int, int, int,
@@ -635,21 +715,17 @@ def _decode_dict_page(body: bytes, nvals: int, dt: T.DataType,
         return [hi, lo.view(np.int64)], 0
     if kind == "str":
         from spark_rapids_tpu.columnar.device import bucket_char_cap
-        vals: List[bytes] = []
-        pos = 0
-        max_len = 1
-        for _ in range(nvals):
-            ln = int.from_bytes(body[pos:pos + 4], "little")
-            pos += 4
-            vals.append(body[pos:pos + ln])
-            pos += ln
-            max_len = max(max_len, ln)
-        char_cap = bucket_char_cap(max_len)
+        lens = _plain_str_lengths(body, 0, len(body), nvals)
+        char_cap = bucket_char_cap(max(1, int(lens.max(initial=1))))
         chars = np.zeros((max(nvals, 1), char_cap), dtype=np.uint8)
         lengths = np.zeros(max(nvals, 1), dtype=np.int32)
-        for i, v in enumerate(vals):
-            chars[i, :len(v)] = np.frombuffer(v, dtype=np.uint8)
-            lengths[i] = len(v)
+        lengths[:nvals] = lens
+        # byte t of the values (prefixes left out) is in row r at column
+        # t - (bytes of the rows before r), and in the page at t + 4(r+1)
+        row = np.repeat(np.arange(nvals, dtype=np.int64), lens)
+        t = np.arange(row.shape[0], dtype=np.int64)
+        chars[row, t - (np.cumsum(lens) - lens)[row]] = np.frombuffer(
+            body, dtype=np.uint8)[t + 4 * (row + 1)]
         return [chars, lengths], char_cap
     raise UnsupportedColumn(f"dictionary for kind {kind}")
 
@@ -664,18 +740,19 @@ class _HostSteps:
     union timer on ``metrics``, as ``timed_wall`` keeps one:
     ``scanRead`` (the footer, each column chunk's seek + read),
     ``scanInflate`` (a chunk's pages decompressed; the parse timer
-    pauses for it) and ``scanParse`` (the rest of a chunk's plan): a
-    few spans a column chunk."""
+    pauses for it) and ``scanParse`` (the rest of a chunk's plan), with
+    ``scanStrLengths`` (one byte-array page's value lengths) inside it,
+    a part of the parse: a few spans a column chunk."""
 
-    __slots__ = ("metrics", "_read", "_inflate", "_parse", "_read_bytes",
-                 "_inflated", "_pages")
+    __slots__ = ("metrics", "_read", "_inflate", "_parse", "_str_lengths",
+                 "_read_bytes", "_inflated", "_pages")
 
     def __init__(self, metrics):
         self.metrics = metrics
         c = metrics.create
-        self._read, self._inflate, self._parse = (
+        self._read, self._inflate, self._parse, self._str_lengths = (
             c(M.SCAN_READ_TIME), c(M.SCAN_INFLATE_TIME),
-            c(M.SCAN_PARSE_TIME))
+            c(M.SCAN_PARSE_TIME), c(M.SCAN_STR_LENGTHS_TIME))
         self._read_bytes, self._inflated, self._pages = (
             c(M.SCAN_READ_BYTES), c(M.SCAN_INFLATED_BYTES),
             c(M.SCAN_PAGES))
@@ -728,6 +805,13 @@ class _HostSteps:
         self._inflated.add(sum(len(b) for _h, b in out))
         return out
 
+    def str_lengths(self, values: int, nbytes: int):
+        """``with steps.str_lengths(...):`` around one PLAIN or
+        DELTA_LENGTH byte-array page's value lengths, or around a string
+        chunk's decode by pyarrow (``_arrow_values``)."""
+        return self._step("scanStrLengths", self._str_lengths,
+                          values=values, bytes=nbytes)
+
 
 def _inflate_body(codec, hdr: Dict[int, Any], body: bytes) -> bytes:
     """One page's body as the parse reads it: decompressed where the
@@ -747,13 +831,20 @@ def _inflate_body(codec, hdr: Dict[int, Any], body: bytes) -> bytes:
 
 def _plan_column(raw: bytes, chunk, leaf, dt: T.DataType, n_rows: int,
                  packer, feats: Tuple[bool, bool, bool] = _ALL_FEATS,
-                 steps: Optional[_HostSteps] = None) -> ColumnDevicePlan:
+                 steps: Optional[_HostSteps] = None,
+                 arrow_values: Optional[
+                     Callable[[], Optional[ArrowValues]]] = None
+                 ) -> ColumnDevicePlan:
     """Walk one column chunk's pages, appending decompressed page bytes
     to ``packer`` and building the device plan. ``feats`` are the
     per-encoding enables (deviceDecode.byteArray/delta/byteStreamSplit
     confs) — a disabled encoding falls back per column; ``steps``
     (the unit's, else a throwaway registry's) inflates the pages and
-    counts them."""
+    counts them. A string chunk with a PLAIN page, whose values a
+    deployment's writer leaves unencoded once its dictionary overflows
+    (long distinct text), is planned from ``arrow_values`` (the chunk
+    decoded by pyarrow, ``_arrow_values``) where that is given and takes
+    it: no page of it is inflated or walked here."""
     if steps is None:
         steps = _HostSteps(M.MetricRegistry())
     _check_supported(dt, leaf)
@@ -785,6 +876,15 @@ def _plan_column(raw: bytes, chunk, leaf, dt: T.DataType, n_rows: int,
         pos = body_off + hdr.get(3, 0)
         spans.append((hdr, body_off, pos))
     steps.pages(len(spans))
+    if kind == "str" and arrow_values is not None and feat_bytearray \
+            and max_def <= 1 \
+            and any(_page_values(h)[1] == ENC_PLAIN for h, _o, _e in spans):
+        with steps.str_lengths(n_rows,
+                               sum(h.get(2, 0) for h, _o, _e in spans)):
+            got = arrow_values()
+        if got is not None:
+            _plan_from_arrow(plan, got, spans, packer)
+            return plan
     pages = (steps.inflate(codec, raw, spans) if codec is not None
              else [(h, raw[o:e]) for h, o, e in spans])
     for hdr, body in pages:
@@ -879,7 +979,8 @@ def _plan_column(raw: bytes, chunk, leaf, dt: T.DataType, n_rows: int,
             if not feat_bytearray:
                 raise UnsupportedColumn(
                     "PLAIN byte array (deviceDecode.byteArray disabled)")
-            lens = _plain_str_lengths(body, val_off, len(body), nn)
+            with steps.str_lengths(nn, len(body) - val_off):
+                lens = _plain_str_lengths(body, val_off, len(body), nn)
             str_parts.append((dense, lens))
             plan.pg_enc.append(PGE_PLAIN_STR)
             plan.pg_plain_byte.append(page_off + val_off)
@@ -927,8 +1028,9 @@ def _plan_column(raw: bytes, chunk, leaf, dt: T.DataType, n_rows: int,
             if not (feat_bytearray and feat_delta):
                 raise UnsupportedColumn(
                     "DELTA_LENGTH_BYTE_ARRAY (deviceDecode disabled)")
-            lens, bytes_pos = _delta_decode_host(body, val_off,
-                                                 len(body))
+            with steps.str_lengths(nn, len(body) - val_off):
+                lens, bytes_pos = _delta_decode_host(body, val_off,
+                                                     len(body))
             if lens.shape[0] != nn:
                 raise UnsupportedColumn(
                     f"delta-length count {lens.shape[0]} != {nn}")
@@ -1069,8 +1171,10 @@ def plan_unit_encoded(unit, data_schema: T.StructType, conf=None,
                 # as dead bytes in every uploaded batch
                 sub = _Packer()
                 with steps.parse():
-                    plan = _plan_column(raw, chunk, leaf, fld.data_type,
-                                        n_rows, sub, feats, steps)
+                    plan = _plan_column(
+                        raw, chunk, leaf, fld.data_type, n_rows, sub, feats,
+                        steps, functools.partial(_arrow_values, pf, rg,
+                                                 fld.name))
                     _rebase_plan(plan, packer.off)
                 packer.parts.extend(sub.parts)
                 packer.off += sub.off
@@ -1113,8 +1217,8 @@ def plan_unit_encoded(unit, data_schema: T.StructType, conf=None,
             else:
                 from spark_rapids_tpu.columnar.host import HostColumn
                 host_cols[fi] = _null_host_column(fld.data_type, n_rows)
-    return EncodedBatch(data_schema, n_rows, packer.words(), plans,
-                        host_cols, fallbacks, unit.path,
+    return EncodedBatch(data_schema, n_rows, packer.words(bucketed=True),
+                        plans, host_cols, fallbacks, unit.path,
                         fallback_encodings=fallback_encodings)
 
 

@@ -84,6 +84,11 @@ SCAN_INFLATE_TIME = "scanInflateTime"
 SCAN_INFLATED_BYTES = "scanInflatedBytes"
 SCAN_PARSE_TIME = "scanParseTime"
 SCAN_PAGES = "scanPages"
+SCAN_STR_LENGTHS_TIME = "scanStrLengthsTime"  # a part of the parse
+# what the device decode and the fused stages made of strings, booked on
+# the host from static shapes (columnar/transfer.py, exec/fused.py)
+DEVICE_DECODE_STRING_BYTES = "deviceDecodeStringBytes"
+LIKE_CHAR_BYTES = "likeCharBytes"
 # what the aggregates and joins of a plan did (exec/agg.py, exec/join.py):
 # each is added where the number already exists, never by a device sync
 AGG_MERGE_COUNT = "aggMergeCount"    # partial results concatenated + merged
@@ -104,6 +109,7 @@ WINDOW_ROWS = "windowRows"    # rows handed to the window programs
 WINDOW_DECIMAL_AGG_COUNT = "windowDecimalAggCount"  # decimal-source aggs
 # what the analysis rules of a statement did (sql/session.py)
 DECORRELATED_SUBQUERY_COUNT = "decorrelatedSubqueryCount"
+JOIN_CONDITION_PUSHED_COUNT = "joinConditionPushedCount"
 
 
 # ---------------------------------------------------------------------------
@@ -214,6 +220,10 @@ METRIC_DESCRIPTIONS: Dict[str, str] = {
                                  "the analysis rule turned into left "
                                  "semi or left anti joins, once per "
                                  "statement planned",
+    JOIN_CONDITION_PUSHED_COUNT: "one-sided ON conjuncts the analysis "
+                                 "rule moved beneath their join as a "
+                                 "filter on the side they read, once per "
+                                 "statement planned",
     PLAN_TIME: "host planning wall on the calling thread (ns): SQL "
                "parse, analysis, overrides, plan cache, fingerprints, up "
                "to execute_collect — once per query",
@@ -243,6 +253,17 @@ METRIC_DESCRIPTIONS: Dict[str, str] = {
                      "dictionary pages, string lengths, DELTA runs "
                      "(ns; interval union, decompression left out)",
     SCAN_PAGES: "pages whose headers the device-decode plans parsed",
+    SCAN_STR_LENGTHS_TIME: "device-decode plans finding the value "
+                           "lengths of PLAIN and DELTA_LENGTH byte-array "
+                           "pages, or decoding a string chunk with a "
+                           "PLAIN page by pyarrow (ns; interval union; a "
+                           "part of scanParseTime)",
+    DEVICE_DECODE_STRING_BYTES: "lanes x char_cap of the string columns "
+                                "the device decode materialises, booked "
+                                "from each staged batch's plan",
+    LIKE_CHAR_BYTES: "capacity x char_cap of every LIKE a dispatched "
+                     "filter or fused stage evaluates, from the "
+                     "program's static shapes (no device read)",
     # ad-hoc keys registered inline by individual operators
     "pipelineDrainTime": "wall where the partial agg drained the async "
                          "upstream pipeline (interval union)",

@@ -18,7 +18,9 @@ jax.jit's own signature cache handles the (capacity, dtype) axis.
 
 from __future__ import annotations
 
+import contextlib
 import functools
+import threading
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -1195,6 +1197,45 @@ def _h_monotonic_id(e: E.MonotonicallyIncreasingID,
                         jnp.ones(ctx.capacity, dtype=jnp.bool_))
 
 
+# capacity x char_cap of the LIKEs a filter or stage program evaluates:
+# tallied in ``_LIKE_TRACE.bytes`` while the program traces
+# (``_like_tally``), kept by the program's key and input shapes, booked on
+# the host at each dispatch (``book_like_char_bytes``): no device read
+_LIKE_TRACE = threading.local()
+_PROGRAM_LIKE_BYTES: Dict[Tuple, Dict[Tuple, int]] = {}
+
+
+def input_shapes(cols, active) -> Tuple:
+    return tuple(x.shape for x in jax.tree_util.tree_leaves((cols, active)))
+
+
+@contextlib.contextmanager
+def _like_tally(pkey: Tuple, cols, active):
+    """Around a program's body while it traces over ``(cols, active)``:
+    keeps what its LIKEs read under ``pkey``, where it has any."""
+    outer, _LIKE_TRACE.bytes = getattr(_LIKE_TRACE, "bytes", None), [0]
+    try:
+        yield
+        if _LIKE_TRACE.bytes[0]:
+            # tpu-lint: disable=trace-purity(a static fact of the trace, shapes and char_cap, kept for the host to book; no value flows into the program)
+            _PROGRAM_LIKE_BYTES.setdefault(pkey, {})[
+                input_shapes(cols, active)] = _LIKE_TRACE.bytes[0]
+    finally:
+        # tpu-lint: disable=trace-purity(restores the enclosing tally; no value flows into the program)
+        _LIKE_TRACE.bytes = outer
+
+
+def book_like_char_bytes(metrics, pkey: Tuple, shapes) -> None:
+    """``likeCharBytes`` of one dispatch of program ``pkey`` over inputs
+    whose shapes ``shapes()`` gives, called only where the program has a
+    LIKE."""
+    per_shape = _PROGRAM_LIKE_BYTES.get(pkey)
+    if per_shape:
+        from spark_rapids_tpu import metrics as M
+        metrics.create(M.LIKE_CHAR_BYTES, M.ESSENTIAL).add(
+            per_shape.get(shapes(), 0))
+
+
 def _like_chunks(pattern: str):
     """LIKE pattern -> list of literal byte chunks split at ``%``
     (escape ``\\``). The gate rejects ``_`` before this runs."""
@@ -1265,58 +1306,73 @@ def _h_like(e: E.Like, ctx: Ctx) -> DeviceColumn:
     validity = lc.validity
     n = lc.lengths
     cap = ctx.capacity
+    counted = getattr(_LIKE_TRACE, "bytes", None)
+    if counted is not None:
+        counted[0] += cap * lc.char_cap
     if len(chunks) == 1:  # no %: exact match
         seg = chunks[0]
-        ok = (n == len(seg)) & _match_chunk_at(
-            lc, seg, jnp.zeros(cap, dtype=jnp.int32)) \
-            if seg else (n == 0)
+        with jax.named_scope("like/prefix"):
+            ok = (n == len(seg)) & _match_chunk_at(
+                lc, seg, jnp.zeros(cap, dtype=jnp.int32)) \
+                if seg else (n == 0)
         return _normalized(T.BooleanT, ok, validity)
     first, *mid, last = chunks
     ok = jnp.ones(cap, dtype=bool)
     pos = jnp.zeros(cap, dtype=jnp.int32)
     if first:
-        ok = ok & _match_chunk_at(lc, first,
-                                  jnp.zeros(cap, dtype=jnp.int32))
+        with jax.named_scope("like/prefix"):
+            ok = ok & _match_chunk_at(lc, first,
+                                      jnp.zeros(cap, dtype=jnp.int32))
         pos = jnp.full(cap, len(first), dtype=jnp.int32)
     for seg in mid:
         if not seg:
             continue
-        m = len(seg)
-        seg_a = jnp.asarray(np.frombuffer(seg, dtype=np.uint8))
-        n_off = max(lc.char_cap - m + 1, 0)
-        # earliest occurrence at offset >= pos (greedy, like regex .*)
-        if n_off == 0:
-            found = jnp.full(cap, -1, dtype=jnp.int32)
-        elif n_off * m <= 8192:
-            # one static-index gather evaluates every offset at once
-            offs = jnp.arange(n_off, dtype=jnp.int32)
-            win_idx = (offs[:, None]
-                       + jnp.arange(m, dtype=jnp.int32)[None, :]).reshape(-1)
-            windows = lc.chars[:, win_idx].reshape(cap, n_off, m)
-            match = (windows == seg_a[None, None, :]).all(axis=2)
-            eligible = match & (offs[None, :] >= pos[:, None]) \
-                & (offs[None, :] + m <= n[:, None])
-            has = eligible.any(axis=1)
-            first = jnp.argmax(eligible, axis=1).astype(jnp.int32)
-            found = jnp.where(has, first, jnp.int32(-1))
-        else:
-            # wide char matrices: a fori_loop keeps the program small
-            # (the unrolled/vectorized forms blow compile time / HBM)
-            def body(o, found, _seg=seg_a, _m=m, _pos=pos, _n=n):
-                window = jax.lax.dynamic_slice_in_dim(
-                    lc.chars, o, _m, axis=1)
-                match = (window == _seg[None, :]).all(axis=1) \
-                    & (o + _m <= _n) & (o >= _pos)
-                return jnp.where((found < 0) & match,
-                                 o.astype(jnp.int32), found)
-            found = jax.lax.fori_loop(
-                0, n_off, body, jnp.full(cap, -1, dtype=jnp.int32))
-        ok = ok & (found >= 0)
-        pos = jnp.where(found >= 0, found + m, pos)
+        with jax.named_scope("like/search"):
+            ok, pos = _like_search(lc, seg, ok, pos)
     if last:
-        off = n - len(last)
-        ok = ok & (off >= pos) & _match_chunk_at(lc, last, off)
+        with jax.named_scope("like/suffix"):
+            off = n - len(last)
+            ok = ok & (off >= pos) & _match_chunk_at(lc, last, off)
     return _normalized(T.BooleanT, ok, validity)
+
+
+def _like_search(lc: DeviceStringColumn, seg: bytes, ok: jax.Array,
+                 pos: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """One middle ``%seg%`` chunk: its earliest occurrence at an offset
+    >= ``pos`` (greedy, like regex ``.*``); ``(ok, pos)`` after it."""
+    n = lc.lengths
+    cap = lc.chars.shape[0]
+    m = len(seg)
+    seg_a = jnp.asarray(np.frombuffer(seg, dtype=np.uint8))
+    n_off = max(lc.char_cap - m + 1, 0)
+    if n_off == 0:
+        found = jnp.full(cap, -1, dtype=jnp.int32)
+    elif n_off * m <= 8192:
+        # one static-index gather evaluates every offset at once
+        offs = jnp.arange(n_off, dtype=jnp.int32)
+        win_idx = (offs[:, None]
+                   + jnp.arange(m, dtype=jnp.int32)[None, :]).reshape(-1)
+        windows = lc.chars[:, win_idx].reshape(cap, n_off, m)
+        match = (windows == seg_a[None, None, :]).all(axis=2)
+        eligible = match & (offs[None, :] >= pos[:, None]) \
+            & (offs[None, :] + m <= n[:, None])
+        has = eligible.any(axis=1)
+        first = jnp.argmax(eligible, axis=1).astype(jnp.int32)
+        found = jnp.where(has, first, jnp.int32(-1))
+    else:
+        # wide char matrices: a fori_loop keeps the program small
+        # (the unrolled/vectorized forms blow compile time / HBM)
+        def body(o, found):
+            window = jax.lax.dynamic_slice_in_dim(lc.chars, o, m, axis=1)
+            match = (window == seg_a[None, :]).all(axis=1) \
+                & (o + m <= n) & (o >= pos)
+            return jnp.where((found < 0) & match,
+                             o.astype(jnp.int32), found)
+        found = jax.lax.fori_loop(
+            0, n_off, body, jnp.full(cap, -1, dtype=jnp.int32))
+    ok = ok & (found >= 0)
+    pos = jnp.where(found >= 0, found + m, pos)
+    return ok, pos
 
 
 # ---------------------------------------------------------------------------
@@ -1684,17 +1740,20 @@ _FILTER_CACHE = JitCache("filter")
 
 
 def run_filter(cond: E.Expression, batch: DeviceBatch,
-               part_ctx=None) -> DeviceBatch:
+               part_ctx=None, metrics=None) -> DeviceBatch:
     """Filter = mask update only; no data movement (compaction is explicit
-    and happens at shuffle/concat boundaries)."""
+    and happens at shuffle/concat boundaries). ``metrics`` (the exec's)
+    books ``likeCharBytes``."""
     key = (expr_key(cond), part_ctx is not None)
+    pkey = ("filter",) + key
     fn = _FILTER_CACHE.get(key)
     if fn is None:
         def _fn(cols, active, lit_vals, part_vals=None):
             ctx = Ctx(cols, active.shape[0], (cond,), lit_vals)
             ctx.part_vals = part_vals
             ctx.active_hint = active
-            p = dev_eval(cond, ctx)
+            with _like_tally(pkey, cols, active):
+                p = dev_eval(cond, ctx)
             err = (jnp.any(jnp.stack([jnp.any(f & active)
                                       for f, _m in ctx.errors]))
                    if ctx.errors else None)
@@ -1706,6 +1765,9 @@ def run_filter(cond: E.Expression, batch: DeviceBatch,
     else:
         new_active, err = fn(batch.columns, batch.active,
                              literal_values([cond]))
+    if metrics is not None:
+        book_like_char_bytes(metrics, pkey, lambda: input_shapes(
+            batch.columns, batch.active))
     _raise_if_errors(err)
     return DeviceBatch(batch.schema, batch.columns, new_active, None)
 
@@ -1768,6 +1830,11 @@ def trace_stage_steps(steps: StageSteps, cols, active, lits_per_step):
     return cols, active, errors
 
 
+def steps_have_like(steps: StageSteps) -> bool:
+    return any(e.collect(lambda x: isinstance(x, E.Like))
+               for _kind, exprs in steps for e in exprs)
+
+
 def build_stage_fn(steps: StageSteps, donate: bool = False) -> Callable:
     """Compile a fused chain into one jitted program:
     ``fn(cols, active, lits_per_step) -> (out_cols, out_active, err)``.
@@ -1776,10 +1843,12 @@ def build_stage_fn(steps: StageSteps, donate: bool = False) -> Callable:
     of being held live across the op boundary (callers must guarantee
     sole ownership of the inputs — see TpuFusedStageExec)."""
     steps_t = tuple(steps)
+    pkey = ("stage", stage_structural_key(steps_t))
 
     def fn(cols, active, lits_per_step):
-        cols, active, errors = trace_stage_steps(steps_t, cols, active,
-                                                 lits_per_step)
+        with _like_tally(pkey, cols, active):
+            cols, active, errors = trace_stage_steps(steps_t, cols, active,
+                                                     lits_per_step)
         err = (jnp.any(jnp.stack([jnp.any(f) for f in errors]))
                if errors else None)
         return cols, active, err

@@ -260,22 +260,23 @@ def _build_fast_gather_fn(join_type: str) -> Callable:
 
 def _build_gather_fn(out_cap: int, join_type: str) -> Callable:
     right_outer = join_type in ("right", "rightouter", "full", "fullouter")
+    outer = right_outer or join_type in ("left", "leftouter")
 
     def fn(cols_l, cols_r, total_pairs, n_extra, m, offsets, base,
            order_r):
         cap_l = m.shape[0]
         cap_r = order_r.shape[0]
         s = jnp.arange(out_cap, dtype=jnp.int64)
-        if right_outer:
+        if outer:
             # the stream row of output lane s, as below, by one scatter
             # and one prefix sum (the page decode's spelling, PR 27).
             # The search's loop gathers `offsets` out_cap lanes at a
             # time, eighteen times over; on the chip that gather's
             # seconds follow where the runtime put `offsets`, not what
             # is in it: 0.29-0.41 s of query 51's full outer join, in
-            # two groups, query to query on the same data (PERF.md §6,
-            # PR 36). Inner and left joins keep the search until a
-            # perf_opt pairs them on every cell (ROADMAP S17.1).
+            # two groups, query to query on the same data (PERF.md §6).
+            # Inner joins keep the search until a perf_opt pairs them on
+            # every cell (ROADMAP S17.1).
             li = run_index(offsets, out_cap)
         else:
             li = jnp.clip(

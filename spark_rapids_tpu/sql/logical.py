@@ -489,10 +489,69 @@ def outer_references(p: LogicalPlan) -> List[AttributeReference]:
     return out
 
 
+# the sides (0 left, 1 right) to which a join type's one-sided ON
+# conjuncts may move: a conjunct of a side whose rows survive unmatched
+# decides matching, not survival, and stays (Catalyst's
+# PushPredicateThroughJoin); a full outer join keeps every conjunct
+_PUSHABLE_SIDES = {"inner": (0, 1), "cross": (0, 1), "left": (1,),
+                   "leftouter": (1,), "leftsemi": (1,), "leftanti": (1,),
+                   "right": (0,), "rightouter": (0,)}
+
+
+def _one_sided_conjuncts(j: "Join") -> List[tuple]:
+    """``(conjunct, side)`` for each conjunct of ``j``'s condition that
+    reads one side it may move to alone: a column of that side and none
+    of the other's, no subquery, nothing whose value depends on where
+    it runs."""
+    from spark_rapids_tpu.sql.expressions import (MonotonicallyIncreasingID,
+                                                  OuterReference,
+                                                  SparkPartitionID)
+    sides = _PUSHABLE_SIDES.get(j.join_type, ())
+    if j.condition is None or not sides:
+        return []
+    ids = [_ids(c.output) for c in j.children]
+    out = []
+    for c in split_conjuncts(j.condition):
+        refs = _ids(c.references())
+        if not refs or _has_subquery_predicate(c) or c.collect(
+                lambda x: isinstance(x, (OuterReference, SparkPartitionID,
+                                         MonotonicallyIncreasingID))):
+            continue
+        for s in sides:
+            if refs <= ids[s] and not refs & ids[1 - s]:
+                out.append((c, s))
+                break
+    return out
+
+
+def _push_join_conjuncts(j: "Join") -> LogicalPlan:
+    """``j`` with its one-sided ON conjuncts as filters beneath it, on
+    the side each reads; counted as ``joinConditionPushedCount``."""
+    import copy
+
+    from spark_rapids_tpu import metrics as M
+    pushed = _one_sided_conjuncts(j)
+    if not pushed:
+        return j
+    q = copy.copy(j)
+    q.children = list(j.children)
+    for s in (0, 1):
+        conds = [c for c, side in pushed if side == s]
+        if conds:
+            q.children[s] = Filter(_conjunction(conds), j.children[s])
+    q.condition = _conjunction([c for c in split_conjuncts(j.condition)
+                                if not any(c is d for d, _s in pushed)])
+    M.query_registry().create(M.JOIN_CONDITION_PUSHED_COUNT,
+                              M.ESSENTIAL).add(len(pushed))
+    return q
+
+
 def needs_rewrite(p: LogicalPlan) -> bool:
     """Whether ``rewrite_joins_and_subqueries`` has anything to do: the
     one walk a statement with none of its forms pays."""
     if isinstance(p, Filter) and _is_comma_join(p.child):
+        return True
+    if isinstance(p, Join) and _one_sided_conjuncts(p):
         return True
     if any(_has_subquery_predicate(e) for e in node_expressions(p)):
         return True
@@ -500,7 +559,7 @@ def needs_rewrite(p: LogicalPlan) -> bool:
 
 
 def rewrite_joins_and_subqueries(plan: LogicalPlan) -> LogicalPlan:
-    """Three rules, copy-on-write (a DataFrame's plan is planned again
+    """Four rules, copy-on-write (a DataFrame's plan is planned again
     by every action):
 
     - a ``WHERE`` over a comma list of relations (cross joins without a
@@ -520,6 +579,11 @@ def rewrite_joins_and_subqueries(plan: LogicalPlan) -> LogicalPlan:
       left semi (``NOT EXISTS``: a plain left anti) join, its equalities
       the keys and the rest the residual, placed like the ``IN``'s; the
       filter's other conjuncts stay beneath that join.
+    - a join's ON conjunct that reads one side alone becomes a filter
+      beneath the join on that side (``_push_join_conjuncts``), where the
+      join type allows: the right side of a left outer, left semi or
+      left anti join, the left of a right outer join, either of an inner
+      join, neither of a full outer join.
 
     ``NOT IN (subquery)`` needs Spark's null-aware anti join and a
     subquery predicate anywhere but such a conjunct an existence join:
@@ -538,6 +602,8 @@ def rewrite_joins_and_subqueries(plan: LogicalPlan) -> LogicalPlan:
             p = _join_comma_list(p)
         if isinstance(p, Filter):
             p = _subqueries_to_semi_joins(p)
+    elif isinstance(p, Join):
+        p = _push_join_conjuncts(p)
     for e in node_expressions(p):
         for x in e.collect(lambda x: isinstance(x, (InSubquery, Exists))):
             if isinstance(x, Exists):

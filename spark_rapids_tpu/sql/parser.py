@@ -311,6 +311,8 @@ class _Parser:
             df = self.query()
             self.expect(")")
             alias = self._relation_alias()
+            if alias and self.peek()[1] == "(":
+                df = self._column_aliases(df, alias)
             return df.alias(alias) if alias else df
         kind, name = self.next()
         assert kind == "id", f"expected table name, got {name!r}"
@@ -324,6 +326,25 @@ class _Parser:
             df = self.session.table(name)
         alias = self._relation_alias()
         return df.alias(alias) if alias else df
+
+    def _column_aliases(self, df, alias: str):
+        """``AS name (c1, ..., cn)`` after a derived table: its output
+        columns renamed in order."""
+        from spark_rapids_tpu.sql.dataframe import DataFrame
+        self.expect("(")
+        names = [self.next()[1]]
+        while self.peek()[1] == ",":
+            self.next()
+            names.append(self.next()[1])
+        self.expect(")")
+        output = df.plan.output
+        if len(names) != len(output):
+            raise ValueError(
+                f"the column alias list of {alias} names {len(names)} "
+                f"columns, and its subquery gives {len(output)}")
+        return DataFrame(L.Project(
+            [a.renamed(n) for a, n in zip(output, names)], df.plan),
+            self.session)
 
     def _relation_alias(self) -> Optional[str]:
         if self.kw("as"):

@@ -1711,7 +1711,10 @@ def generate_observability_docs() -> str:
         "  string lengths, DELTA runs; `scanParseTime`, `scanPages`) and,",
         "  nested in it, `scanInflate` (the chunk's pages decompressed;",
         "  `scanInflateTime`, `scanInflatedBytes`; the parse timer",
-        "  pauses for it);",
+        "  pauses for it) and `scanStrLengths` (one PLAIN or",
+        "  DELTA_LENGTH byte-array page's value lengths, or one string",
+        "  chunk with a PLAIN page decoded by pyarrow, `values=`,",
+        "  `bytes=`; `scanStrLengthsTime`, a part of the parse);",
         "- JIT compiles are `compile` spans (attr `cache` = which LRU",
         "  missed); a thread that blocks on ANOTHER thread's",
         "  in-progress compile of the same key (single-flight) emits a",

@@ -168,6 +168,12 @@ SPAN_CATALOG: Dict[str, str] = {
     "scanInflate": "one column chunk's pages decompressed, its "
                    "dictionary page included; bytes= out, pages= "
                    "(mirrors scanInflateTime; nested in scanParse)",
+    "scanStrLengths": "one PLAIN or DELTA_LENGTH byte-array page's "
+                      "value lengths found on the host, or one string "
+                      "chunk with a PLAIN page decoded by pyarrow (its "
+                      "values and their lengths); values=, bytes= "
+                      "(mirrors scanStrLengthsTime; nested in "
+                      "scanParse, which counts it too)",
     "scanParse": "the rest of one column chunk's device-decode plan: "
                  "page headers, level/run tables, dictionary pages, "
                  "string lengths, DELTA runs (mirrors scanParseTime, "
@@ -257,6 +263,9 @@ KIND_READERS: Dict[str, str] = {
     "scanInflate": "mirrors scanInflateTime -> benchmark "
                    "`scan_inflate_host_s`; idle-gap owner inside "
                    "scanParse",
+    "scanStrLengths": "mirrors scanStrLengthsTime -> benchmark "
+                      "`string_lengths_host_s`; idle-gap owner inside "
+                      "scanParse",
     "scanParse": "mirrors scanParseTime -> benchmark "
                  "`scan_parse_host_s`; idle-gap owner inside "
                  "FileScan.deviceDecodeTime",

@@ -343,6 +343,43 @@ def test_binary_plain_device_decode(tmp_path):
                              "ORDER BY k")
 
 
+@pytest.mark.parametrize("case", ["all_null", "all_empty", "utf8",
+                                  "binary", "declined"])
+def test_plain_string_chunk_from_pyarrow_decode(tmp_path, monkeypatch,
+                                                case):
+    # a string chunk with PLAIN pages is planned from pyarrow's decode of
+    # it as one concatenated-bytes page; where that declines the chunk,
+    # its pages are inflated and walked as before. Either way the device
+    # decodes it bit-equal to the host
+    from spark_rapids_tpu.io import device_decode as DD
+    n = 3000
+    vals = {"all_null": [None] * n,
+            "all_empty": [""] * n,
+            "utf8": [None if i % 9 == 4 else "é€" * (i % 7) + f"ü{i}"
+                     for i in range(n)],
+            "binary": [None if i % 5 == 1 else bytes([i % 256]) * (i % 13)
+                       for i in range(n)],
+            "declined": [f"row-{i}-" + "z" * (i % 31) for i in range(n)],
+            }[case]
+    typ = pa.binary() if case == "binary" else pa.string()
+    path = _write(tmp_path, pa.table({"s": pa.array(vals, type=typ),
+                                      "i": pa.array(np.arange(n))}),
+                  use_dictionary=False, data_page_size=2048)
+    if case == "declined":
+        monkeypatch.setattr(DD, "_arrow_values", lambda *a: None)
+    m = _assert_parity(path)
+    assert m.get("deviceDecodedValues.PLAIN", 0) == n + sum(
+        v is not None for v in vals), m
+    from spark_rapids_tpu.io.readers import ScanUnit
+    from spark_rapids_tpu.sql import types as T
+    schema = T.StructType([T.StructField(
+        "s", T.BinaryT if case == "binary" else T.StringT, True)])
+    plan = DD.plan_unit_encoded(ScanUnit(path, os.path.getsize(path), [0]),
+                                schema).plans[0]
+    assert (plan.pg_enc == [DD.PGE_DL_STR]) == (case != "declined")
+    assert (plan.dl is None) == (case in ("all_empty", "declined"))
+
+
 def test_delta_length_byte_array(tmp_path):
     n = 5000
     vals = ["" if i % 13 == 0 else
@@ -936,8 +973,12 @@ def test_plain_region_is_contiguous_aligned_and_in_dense_order(
     assert plan.n_dense == len(dense) and len(dense) > d0
     raw = enc.words.tobytes()
     assert raw[plan.plain_base:plan.plain_base + len(want)] == want
-    # nothing behind the region but its padding to a word
-    assert len(raw) == plan.plain_base + len(want) + (-len(want)) % 4
+    # nothing behind the region but its padding to a word, then zero
+    # words up to the bucket the upload sends
+    from spark_rapids_tpu.columnar.device import bucket_capacity
+    end = plan.plain_base + len(want) + (-len(want)) % 4
+    assert not raw[plan.plain_base + len(want):].strip(b"\0")
+    assert len(raw) == 4 * bucket_capacity(end // 4)
     staged, got = _decode_encoded(enc)
     assert [ent[5] for ent in staged[6]] == [0]    # npg: no page table
     assert got["c"] == pq.read_table(path).column("c").to_pylist()
