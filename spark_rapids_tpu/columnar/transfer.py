@@ -628,13 +628,21 @@ def prepare_encoded_upload(enc, cap: int, metrics=None):
         arity = 3 if plan.kind in ("str", "dec128") else 2
         spec.append((dt, arity))
     if metrics is not None:
-        # ent[1], ent[4]: kind and char_cap of a "dev" entry
+        from spark_rapids_tpu import metrics as M
+        from spark_rapids_tpu.ops.rle import char_window_words
+
+        # ent[1], ent[4], ent[13]: kind, char_cap and has_slen of a
+        # "dev" entry
         chars = sum(cap * ent[4] for ent in layout
                     if ent[0] == "dev" and ent[1] == "str")
         if chars:
-            from spark_rapids_tpu import metrics as M
             metrics.create(M.DEVICE_DECODE_STRING_BYTES,
                            M.ESSENTIAL).add(chars)
+        char_words = sum(cap * char_window_words(ent[4]) for ent in layout
+                         if ent[0] == "dev" and ent[13])
+        if char_words:
+            metrics.create(M.DEVICE_DECODE_STRING_WORDS,
+                           M.ESSENTIAL).add(char_words)
     # the scan's plans lay the page buffer out at its bucket
     # (``_Packer.words(bucketed=True)``), so that same-shaped row groups
     # share one decode program
@@ -655,7 +663,9 @@ def _encoded_decode_body(layout: Tuple, cap: int, words, n_arr, extras):
     prefix sum: fields by prefix sum, no gather through a run's
     index), and each stored value is decoded exactly once. A
     bit-packed value is read from the int32 staging ``words``
-    themselves — the two aligned words it lies in, ``rle.read_packed``.
+    themselves — the two aligned words it lies in, ``rle.read_packed``
+    — and so is a string value: the window of ``char_cap/4 + 1`` words
+    its bytes lie in, ``rle.gather_char_words``.
     A PLAIN fixed-width value is read from them too, with no gather:
     the host lays a chunk's PLAIN value sections end to end from a
     4-aligned byte, in dense order (``device_decode._plan_column``),
@@ -668,9 +678,9 @@ def _encoded_decode_body(layout: Tuple, cap: int, words, n_arr, extras):
     lane). Where a chunk's pages are dictionary pages followed by
     PLAIN ones — what writers produce — no page table rides at all
     (``npg == 0``, a static fact of the layout): a lane is PLAIN iff
-    it is not before that first lane. Only BYTE_STREAM_SPLIT and
-    string pages read bytes and expand the buffer to them, lazily; a
-    layout with neither never does. Rows reach their values by ONE gather
+    it is not before that first lane. Only BYTE_STREAM_SPLIT pages
+    read bytes and expand the buffer to them, lazily; a layout without
+    them never does. Rows reach their values by ONE gather
     through ``j`` (row -> dense rank) at the end, and a column without
     definition levels (``ndl == 0``, a static fact of the layout)
     skips it: there every active row IS its own dense lane, and rows
@@ -791,7 +801,8 @@ def _encoded_decode_body(layout: Tuple, cap: int, words, n_arr, extras):
                 # value's footprint counts exactly once — offsets are a
                 # per-page segmented prefix-sum over the byte
                 # footprints (PLAIN values add their 4-byte length
-                # prefix), then one gather builds the char matrix
+                # prefix), then one gather of each value's staging
+                # words builds the char matrix
                 with jax.named_scope("decode_chars"):
                     lp = jnp.where(enc_pg == PGE_PLAIN_STR, 4, 0) \
                         .astype(jnp.int64)
@@ -802,8 +813,8 @@ def _encoded_decode_body(layout: Tuple, cap: int, words, n_arr, extras):
                     rel = R.seg_excl_cumsum(
                         contrib, jnp.clip(pg_start, 0, cap - 1))
                     plens = slen.astype(jnp.int32)
-                    pchars = R.gather_chars(
-                        get_bytes(), plain_byte[pg] + rel + lp, plens,
+                    pchars = R.gather_char_words(
+                        words, plain_byte[pg] + rel + lp, plens,
                         char_cap)
             else:
                 pchars = jnp.zeros((cap, char_cap), dtype=jnp.uint8)

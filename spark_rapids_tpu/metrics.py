@@ -88,6 +88,7 @@ SCAN_STR_LENGTHS_TIME = "scanStrLengthsTime"  # a part of the parse
 # what the device decode and the fused stages made of strings, booked on
 # the host from static shapes (columnar/transfer.py, exec/fused.py)
 DEVICE_DECODE_STRING_BYTES = "deviceDecodeStringBytes"
+DEVICE_DECODE_STRING_WORDS = "deviceDecodeStringWords"
 LIKE_CHAR_BYTES = "likeCharBytes"
 # what the aggregates and joins of a plan did (exec/agg.py, exec/join.py):
 # each is added where the number already exists, never by a device sync
@@ -261,6 +262,11 @@ METRIC_DESCRIPTIONS: Dict[str, str] = {
     DEVICE_DECODE_STRING_BYTES: "lanes x char_cap of the string columns "
                                 "the device decode materialises, booked "
                                 "from each staged batch's plan",
+    DEVICE_DECODE_STRING_WORDS: "staging words the device decode "
+                                "gathers for the PLAIN and DELTA_LENGTH "
+                                "string values of each staged batch: "
+                                "lanes x (char_cap/4 + 1) a column, "
+                                "booked from its plan",
     LIKE_CHAR_BYTES: "capacity x char_cap of every LIKE a dispatched "
                      "filter or fused stage evaluates, from the "
                      "program's static shapes (no device read)",

@@ -32,16 +32,24 @@ fuse them into ONE decode program per scan batch:
   contiguous window at a fixed stride. One ``dynamic_slice`` takes the
   window, strided slices de-interleave it, a second ``dynamic_slice``
   moves the values to their first dense lane (PERF.md §6, PR 35).
+- ``gather_char_words``: a string value's bytes lie end to end from an
+  arbitrary byte, so a value of at most ``char_cap`` bytes lies in
+  ``char_cap/4 + 1`` consecutive staging words: a row gathers that one
+  window of words, shifts it into place by the value's phase and
+  reinterprets the words as bytes, in place of one gathered byte a
+  lane (PERF.md §6).
 
 All functions are shape-polymorphic trace-time helpers. The bit-packed
 readers (``read_packed``, ``read_packed64``, ``hybrid_lookup``,
 ``delta_lookup``) take the packed int32 staging ``words`` and int64
 BIT offsets into them, ``read_plain`` the same ``words`` and the
-region's word index; the byte readers (``read_bss``,
-``gather_chars``) take the byte array as an int32 array (one byte per
-element, the form ``bytes_of_words`` produces from the staging words)
-and int64 byte offsets. All return int64 values. Callers mask invalid
-lanes afterwards; out-of-range offsets are clipped, never trapped.
+region's word index, ``gather_char_words`` the same ``words`` and
+int64 BYTE offsets; only ``read_bss`` takes the byte array, an int32
+array of one byte per element (the form ``bytes_of_words`` produces
+from the staging words), and int64 byte offsets. All return int64
+values but ``gather_char_words``, whose uint8 character matrix is
+masked to each value's length. Callers mask invalid lanes afterwards;
+out-of-range offsets are clipped, never trapped.
 """
 
 from __future__ import annotations
@@ -241,17 +249,54 @@ def read_bss(bytes_all: jax.Array, base: jax.Array, stride: jax.Array,
     return jnp.sum(win << (k * 8), axis=1)
 
 
-def gather_chars(bytes_all: jax.Array, starts: jax.Array,
-                 lengths: jax.Array, char_cap: int) -> jax.Array:
-    """Variable bytes -> (n, char_cap) uint8 matrix: row i gathers
-    lengths[i] bytes at starts[i], zero-padded (the SURVEY offset+bytes
-    string model's gather half; offsets come from a segmented
-    prefix-sum over the lengths)."""
-    nb = bytes_all.shape[0]
-    idx = starts[:, None] + jnp.arange(char_cap, dtype=jnp.int64)
+def char_window_words(char_cap: int) -> int:
+    """Words a string value of at most ``char_cap`` bytes lies in,
+    counted from the word of its first byte: ``gather_char_words``
+    gathers this many a row."""
+    return char_cap // 4 + 1
+
+
+def gather_char_words(words: jax.Array, starts: jax.Array,
+                      lengths: jax.Array, char_cap: int) -> jax.Array:
+    """Variable bytes -> (n, char_cap) uint8 matrix: row i holds the
+    lengths[i] bytes at byte starts[i] of the int32 staging ``words``,
+    zero-padded (the SURVEY offset+bytes string model's gather half;
+    offsets come from a segmented prefix-sum over the lengths).
+
+    A value of L <= char_cap bytes at byte b lies in the
+    ``char_window_words(char_cap)`` words from ``b >> 2`` on, so a row
+    gathers that window's words (a quarter of the elements of one
+    gather a byte lane, and the buffer is never expanded to bytes),
+    shifts each pair of neighbours together by the row's phase
+    ``8 * (b & 3)`` in unsigned 32-bit lanes (the staging words are
+    int32: an arithmetic shift would smear the sign of a byte >= 0x80
+    into the next), and reinterprets the words as their bytes. On the
+    chip the element-wise gather of the window, rows minor, is the
+    fastest spelling: 63 ms for Q13's u8[393,216, 80] against 280 ms a
+    byte lane; ``lax.gather`` of one contiguous slice a row takes
+    530 ms and the transposed form 72 ms (PERF.md §6).
+
+    The word index goes down to int32 once, exact for staging buffers
+    up to 8 GiB. Each word of a window is clipped to the buffer on its
+    own, so the buffer needs no padding: a value lies inside the
+    buffer, and a word past its end feeds only the lanes past the
+    value's length, which are masked (a window clamped as a whole, as
+    ``dynamic_slice`` or a sliced gather clamps it, would shift every
+    byte of its row). Rows whose start lies outside the buffer read
+    garbage the caller masks."""
+    n = starts.shape[0]
+    nw = char_window_words(char_cap)
+    w0 = (starts >> 2).astype(jnp.int32)
+    s = ((starts & 3) * 8).astype(jnp.uint32)[:, None]
+    win = jax.lax.bitcast_convert_type(words, jnp.uint32)[
+        w0[:, None] + jnp.arange(nw, dtype=jnp.int32)]
+    # win[:, k + 1] << (32 - s) as two shifts: 32 (phase 0) is not
+    # defined, 24 then 8 is, and leaves the 0 that phase wants
+    aligned = (win[:, :-1] >> s) | ((win[:, 1:] << (24 - s)) << 8)
+    chars = jax.lax.bitcast_convert_type(aligned, jnp.uint8) \
+        .reshape(n, char_cap)
     mask = jnp.arange(char_cap, dtype=jnp.int32) < lengths[:, None]
-    g = bytes_all[jnp.clip(idx, 0, nb - 1)]
-    return jnp.where(mask, g, 0).astype(jnp.uint8)
+    return jnp.where(mask, chars, jnp.uint8(0))
 
 
 def seg_excl_cumsum(contrib: jax.Array, seg_first_lane: jax.Array

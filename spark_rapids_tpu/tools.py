@@ -1803,14 +1803,17 @@ def generate_observability_docs() -> str:
         "(`srt_decode`: `decode_page_lookup` (only for a column whose",
         "chunk needs its page table: not dictionary pages followed by PLAIN",
         "ones), `decode_bits` with `/bytes` (staging words to bytes, for the",
-        "lanes that read bytes: BYTE_STREAM_SPLIT, strings),",
+        "lanes that read bytes: BYTE_STREAM_SPLIT alone),",
         "`/run_fields` (a run's fields to its lanes by prefix sum)",
         "and `/window` (the two aligned staging words a packed value",
         "lies in, gathered and shifted together) inside",
         "it, `decode_dict`, `decode_plain` with `/window` (one",
         "contiguous window of the staging words, de-interleaved at the",
         "value's fixed stride) and `/lanes` (the values moved to their",
-        "first dense lane) inside it, `decode_chars`,",
+        "first dense lane) inside it, `decode_chars` (a string value's",
+        "window of `char_cap/4 + 1` staging words gathered, shifted into",
+        "place by its phase and read as its bytes; `deviceDecodeStringWords`",
+        "counts the words),",
         "`decode_delta`, `decode_rows` — docs/scan.md §1) and the three",
         "steps of a conditional semi/anti join's rank loop",
         "(`srt_join_cond_mask`: `join_cond/gather`, `/eval`, `/reduce`)",

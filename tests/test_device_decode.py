@@ -380,6 +380,53 @@ def test_plain_string_chunk_from_pyarrow_decode(tmp_path, monkeypatch,
     assert (plan.dl is None) == (case in ("all_empty", "declined"))
 
 
+def test_plain_long_strings_with_high_bytes_parity(tmp_path):
+    # a no-dictionary PLAIN string chunk of 19-78-byte values (Q13's
+    # comments) holding bytes >= 0x80 at every phase: each value's
+    # window of staging words gathers bit-equal to the host's decode
+    rng = np.random.default_rng(43)
+    n = 3000
+    chars = np.array(list("abcdefgh ,.é€üß"))
+    vals = []
+    for i in range(n):
+        s = "".join(rng.choice(chars, int(rng.integers(8, 40))))
+        s = s.encode()[:int(rng.integers(19, 79))].decode(errors="ignore")
+        vals.append((s + "x" * 19)[:max(19, len(s))])
+    raw = [rng.integers(0, 256, int(rng.integers(19, 79)),
+                        dtype=np.uint8).tobytes() for _ in range(n)]
+    path = _write(tmp_path, pa.table({
+        "s": pa.array(vals), "b": pa.array(raw, type=pa.binary())}),
+        use_dictionary=False)
+    m = _assert_parity(path)
+    assert m.get("deviceDecodedValues.PLAIN", 0) == 2 * n, m
+
+
+@pytest.mark.parametrize("dictionary", [False, True])
+def test_string_words_counter(tmp_path, dictionary):
+    # deviceDecodeStringWords books lanes x (char_cap/4 + 1) for every
+    # string column whose values are gathered from the staging words,
+    # and nothing for a chunk that is dictionary-coded throughout
+    from spark_rapids_tpu.columnar.device import (bucket_capacity,
+                                                  bucket_char_cap)
+    n = 2000
+    short = [f"s{i % 40 if dictionary else i}" for i in range(n)]
+    long = [f"long-{i % 7 if dictionary else i}-" + "q" * (i % 50)
+            for i in range(n)]
+    path = _write(tmp_path, pa.table({"a": pa.array(short),
+                                      "b": pa.array(long)}),
+                  use_dictionary=dictionary)
+    m = _assert_parity(path)
+    assert m.get("deviceDecodedValues.RLE_DICTIONARY", 0) == \
+        (2 * n if dictionary else 0), m
+    cap = bucket_capacity(n)
+    want = 0 if dictionary else sum(
+        cap * (bucket_char_cap(max(map(len, col))) // 4 + 1)
+        for col in (short, long))
+    assert m.get("deviceDecodeStringWords", 0) == want, m
+    assert m.get("deviceDecodeStringBytes", 0) == sum(
+        cap * bucket_char_cap(max(map(len, col))) for col in (short, long))
+
+
 def test_delta_length_byte_array(tmp_path):
     n = 5000
     vals = ["" if i % 13 == 0 else
@@ -834,10 +881,9 @@ def test_gather_chars_and_seg_cumsum_kernels():
 
     from spark_rapids_tpu.ops import rle as R
     data = b"heyworldabc!"
-    ba = _bytes_arr(data)
     starts = jnp.asarray(np.array([0, 3, 8], dtype=np.int64))
     lens = jnp.asarray(np.array([3, 5, 4], dtype=np.int32))
-    out = np.asarray(R.gather_chars(ba, starts, lens, 8))
+    out = np.asarray(R.gather_char_words(_words_arr(data), starts, lens, 8))
     assert bytes(out[0][:3]) == b"hey" and out[0][3:].tolist() == [0] * 5
     assert bytes(out[1][:5]) == b"world"
     assert bytes(out[2][:4]) == b"abc!"
@@ -847,6 +893,46 @@ def test_gather_chars_and_seg_cumsum_kernels():
     seg = jnp.asarray(np.array([0, 0, 0, 3, 3, 3], dtype=np.int64))
     got = np.asarray(R.seg_excl_cumsum(contrib, seg))
     assert got.tolist() == [0, 2, 5, 0, 10, 30]
+
+
+@pytest.mark.parametrize("char_cap", [8, 24, 80, 256])
+@pytest.mark.parametrize("phase", [0, 1, 2, 3])
+def test_gather_char_words_matches_numpy(char_cap, phase):
+    # values end to end from a byte of the given phase, as a
+    # concatenated-bytes page lays them: every length from 0 to char_cap
+    # that matters, bytes 0x80-0xFF at each of a word's four positions
+    # (an arithmetic shift of the int32 words would smear their sign),
+    # and the last value, one byte in the buffer's last word, whose
+    # window reaches char_cap/4 words past the buffer (clipped word by
+    # word; a window clamped as a whole would shift the value)
+    import jax.numpy as jnp
+
+    from spark_rapids_tpu.ops import rle as R
+    rng = np.random.default_rng(42 * char_cap + phase)
+    lens = [0, 1, char_cap - 1, char_cap, 0, 3, char_cap // 2]
+    lens += rng.integers(0, char_cap + 1, 40).tolist()
+    lens += [char_cap - 1, char_cap, 1]
+    vals = []
+    for k, n in enumerate(lens):
+        hi = rng.integers(0x80, 0x100, n, dtype=np.uint8)
+        lo = rng.integers(0, 0x80, n, dtype=np.uint8)
+        # value k sets the top bit on every (k % 5)-th byte, or on all
+        vals.append(np.where(np.arange(n) % 5 == k % 5 if k % 6 else True,
+                             hi, lo).astype(np.uint8).tobytes())
+    base = 4 * 3 + phase
+    payload = bytes(rng.integers(0, 256, base, dtype=np.uint8)) \
+        + b"".join(vals)
+    starts = base + np.concatenate([[0], np.cumsum(lens[:-1])])
+    assert (len(payload) - 1) // 4 == (starts[-1] + lens[-1] - 1) // 4
+    want = np.zeros((len(lens), char_cap), dtype=np.uint8)
+    for i, v in enumerate(vals):
+        want[i, :len(v)] = np.frombuffer(v, dtype=np.uint8)
+    got = np.asarray(R.gather_char_words(
+        _words_arr(payload),
+        jnp.asarray(starts.astype(np.int64)),
+        jnp.asarray(np.array(lens, dtype=np.int32)), char_cap))
+    assert got.dtype == np.uint8 and got.shape == want.shape
+    assert np.array_equal(got, want)
 
 
 def test_read_bss_kernel():
