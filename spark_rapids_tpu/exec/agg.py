@@ -192,6 +192,9 @@ from spark_rapids_tpu.jit_cache import (JitCache, mirror_to_metrics,
                                         program_of)
 
 _AGG_FN_CACHE = JitCache("agg")
+# (program cache key, input shapes) -> lanes x group-key words, kept
+# while the program traces and booked as ``aggKeyWords`` at each dispatch
+_AGG_KEY_WORDS: Dict[Tuple, int] = {}
 
 # tpu-lint: disable=jit-direct(single fixed count-stack program — one executable, bounded by construction)
 _stack_counts = named_jit("srt_agg_stack_counts",
@@ -275,7 +278,7 @@ class TpuHashAggregateExec(TpuExec):
                   slot_srcs: List[E.Expression],
                   prims: List[Tuple[str, T.DataType]],
                   has_nans: bool, prelude_steps=None,
-                  donate: bool = False) -> Callable:
+                  donate: bool = False, words_key=None) -> Callable:
         aliases = self._agg_aliases()
         slot_counts = [len(self.slots[a.expr_id]) for a in aliases]
         grouping = self.grouping
@@ -293,6 +296,7 @@ class TpuHashAggregateExec(TpuExec):
         def fn(cols, active, lit_vals):
             from spark_rapids_tpu.columnar.device import (flatten_columns,
                                                           rebuild_columns)
+            shapes = X.input_shapes(cols, active)
             if prelude_steps:
                 # fused filter/project prelude: the chain's mask update
                 # and projections trace INLINE ahead of the key/slot
@@ -304,6 +308,9 @@ class TpuHashAggregateExec(TpuExec):
             ctx = X.Ctx(cols, cap, all_exprs, lit_vals)
             with jax.named_scope("agg_inputs"):
                 key_cols = [X.dev_eval(e, ctx) for e in key_bound]
+            # tpu-lint: disable=trace-purity(a static fact of the trace, lanes and key widths, kept for the host to book; no value flows into the program)
+            _AGG_KEY_WORDS[words_key, shapes] = cap * G.key_word_count(
+                key_cols, has_nans)
             # dedupe slot sources (sum(x) + avg(x) share x): each unique
             # expression is evaluated, sorted, and lane-packed ONCE
             uniq_srcs: List[E.Expression] = []
@@ -470,14 +477,16 @@ class TpuHashAggregateExec(TpuExec):
         chip = TR.chip_of(batch)  # None (no device query) when untraced
         import time as _time
 
+        cache_key = struct + (donate,)
         fn, was_miss = _AGG_FN_CACHE.get_or_build(
-            struct + (donate,),
+            cache_key,
             lambda: self._build_fn(mode, key_bound, slot_srcs, prims,
                                    has_nans=salt[0],
                                    prelude_steps=prelude_steps,
-                                   donate=donate))
+                                   donate=donate, words_key=cache_key))
         mirror_to_metrics(_AGG_FN_CACHE, self.metrics, was_miss)
         TR.first_dispatch(self.metrics, fn)
+        in_shapes = X.input_shapes(batch.columns, batch.active)
         with TR.annotation(
                 "TpuHashAggregateExec.dispatch", TR.scope_of(self.metrics),
                 attrs={"mode": mode, "program": program_of(fn)}):
@@ -485,6 +494,9 @@ class TpuHashAggregateExec(TpuExec):
             t0 = _time.perf_counter_ns()
             outs = fn(batch.columns, batch.active, lit_vals)
         elapsed = _time.perf_counter_ns() - t0
+        key_words = _AGG_KEY_WORDS.get((cache_key, in_shapes))
+        if key_words:
+            self.metrics.create(M.AGG_KEY_WORDS, M.ESSENTIAL).add(key_words)
         if mode in ("partial", "merge", "merge_partial"):
             out_cols, out_active, cnt = outs
         else:

@@ -9,7 +9,7 @@ is the static-shape discipline SURVEY.md section 7(a) calls for.
 
 from __future__ import annotations
 
-from typing import Iterator, List
+from typing import Callable, Iterator, List
 
 import jax.numpy as jnp
 
@@ -25,7 +25,7 @@ from spark_rapids_tpu.sql import types as T
 
 import jax
 
-from spark_rapids_tpu.jit_cache import named_jit
+from spark_rapids_tpu.jit_cache import JitCache, named_jit
 
 # row counters are DEVICE int64 scalars created via T.device_long —
 # a bare jnp.int64 would silently truncate to int32 without x64 and
@@ -282,10 +282,114 @@ class TpuGlobalLimitExec(TpuLocalLimitExec):
         return f"TpuGlobalLimit {self.n}"
 
 
+_EXPAND_CACHE = JitCache("expand")
+
+
+def _expand_entry_key(e: E.Expression) -> tuple:
+    """What one entry of a projection is to the expand program: a null
+    (the key a set leaves out), a constant, or an expression. A literal
+    (the grouping id) is a constant of the program, its value in the
+    key: the sets' literals are the same in every query of a plan, and
+    passing them as scalars would upload one a set and batch."""
+    if isinstance(e, E.Literal):
+        if e.value is None:
+            return ("null",)
+        return ("const", repr(e.value), repr(e.data_type))
+    return ("expr", X.expr_key(e))
+
+
+def _expand_exprs(projections) -> List[E.Expression]:
+    """The entries the expand program evaluates, in one order for the
+    program's literals and the dispatch's."""
+    return [e for proj in projections for e in proj
+            if _expand_entry_key(e)[0] == "expr"]
+
+
+def _expand_slots(projections) -> List[List[object]]:
+    """Where each entry's column comes from: None for a null, else the
+    slot it is evaluated into. A constant, or an expression with no
+    runtime literal, reads the same wherever it appears and shares one
+    slot; an expression with a runtime literal has a slot of its own,
+    since ``expr_key`` leaves literal values out."""
+    slots = []
+    for si, proj in enumerate(projections):
+        row = []
+        for j, e in enumerate(proj):
+            key = _expand_entry_key(e)
+            if key[0] == "null":
+                row.append(None)
+            elif key[0] == "expr" and any(X.collect_literals([e])):
+                row.append((si, j))
+            else:
+                row.append(key)
+        slots.append(row)
+    return slots
+
+
+def _build_expand(projections, out_cap: int) -> Callable:
+    """ONE program for every grouping set of a batch. A batch whose rows
+    fit a smaller capacity bucket is first compacted to ``out_cap`` lanes
+    (``expand/compact``: the sets would repeat its empty lanes nine
+    times). Each slot is then evaluated once (``expand/project``), a key
+    a set leaves out is that column with every row nulled, and the sets
+    are written one after another (``expand/write``): set ``s`` at lanes
+    ``[s * out_cap, (s + 1) * out_cap)``, the rows' activity repeated."""
+    from spark_rapids_tpu.columnar.device import (_compact_body,
+                                                  flatten_columns, mask_col,
+                                                  rebuild_columns)
+    width = len(projections[0])
+    exprs = _expand_exprs(projections)
+    slots = _expand_slots(projections)
+
+    def fn(cols, active, lit_vals):
+        if out_cap < active.shape[0]:
+            with jax.named_scope("expand/compact"):
+                flat, spec = flatten_columns(cols)
+                active, flat = _compact_body(active, flat, out_cap)
+                cols = rebuild_columns(spec, flat)
+        ctx = X.Ctx(cols, out_cap, exprs, lit_vals)
+        ctx.active_hint = active
+        with jax.named_scope("expand/project"):
+            evaluated = {}
+            for proj, row in zip(projections, slots):
+                for e, slot in zip(proj, row):
+                    if slot is not None and slot not in evaluated:
+                        evaluated[slot] = mask_col(X.dev_eval(e, ctx),
+                                                   active)
+            template = [next((evaluated[row[j]] for row in slots
+                              if row[j] is not None), None)
+                        for j in range(width)]
+            none = jnp.zeros_like(active)
+            sets = []
+            for proj, row in zip(projections, slots):
+                out = []
+                for j, (e, slot) in enumerate(zip(proj, row)):
+                    if slot is not None:
+                        out.append(evaluated[slot])
+                    elif template[j] is not None:
+                        out.append(mask_col(template[j], none))
+                    else:
+                        raise X.DeviceUnsupported(
+                            f"expand entry {e!r} has no column to null")
+                sets.append(flatten_columns(out))
+        with jax.named_scope("expand/write"):
+            spec = sets[0][1]
+            flat = [jnp.concatenate([f[i] for f, _ in sets], axis=0)
+                    for i in range(len(sets[0][0]))]
+            return (rebuild_columns(spec, flat),
+                    jnp.concatenate([active] * len(projections)))
+    return named_jit("srt_expand", fn)
+
+
 class TpuExpandExec(TpuExec):
-    """Grouping-sets expansion (GpuExpandExec.scala twin): each input
-    batch is projected once per grouping set and the results concat on
-    device (one fused program per projection + the jitted concat)."""
+    """Grouping-sets expansion (GpuExpandExec.scala twin): ONE program a
+    batch writes every set's projection into one output batch of
+    ``sets x bucket(rows)`` lanes (``_build_expand``). The batch's row
+    count is read first (``deviceSync site=expandRows``, where its
+    producer attached none): it sizes the output, so a join's sparse
+    output is not expanded with its empty lanes. The host books
+    ``expandTime`` around the enqueue (the count's wait is
+    ``deviceSyncTime``'s) and ``expandRows``, sets x the rows."""
 
     def __init__(self, projections: List[List[E.Expression]],
                  output, child: TpuExec, conf: TpuConf):
@@ -303,22 +407,35 @@ class TpuExpandExec(TpuExec):
         return self._output
 
     def device_partitions(self) -> List[DevicePartitionThunk]:
-        from spark_rapids_tpu.columnar.device import concat_device
+        from spark_rapids_tpu import trace as TR
         bound = [P.bind_list(proj, self.child.output)
                  for proj in self.projections]
+        key = tuple(tuple(_expand_entry_key(e) for e in proj)
+                    for proj in bound)
+        lit_exprs = _expand_exprs(bound)
         schema = self.schema
         metrics = self.metrics
+        n_sets = len(bound)
 
         def make(thunk: DevicePartitionThunk) -> DevicePartitionThunk:
             def run() -> Iterator[DeviceBatch]:
                 for b in thunk():
-                    outs = []
-                    for proj in bound:
-                        with metrics.timed(M.OP_TIME):
-                            cols = X.run_project(proj, b)
-                        outs.append(b.with_columns(schema, cols))
-                    if outs:
-                        yield concat_device(outs)
+                    rows = b.row_count(site="expandRows")
+                    if rows == 0:
+                        continue
+                    with metrics.timed(M.EXPAND_TIME):
+                        out_cap = min(b.capacity, bucket_capacity(rows))
+                        fn = _EXPAND_CACHE.get((key, out_cap))
+                        if fn is None:
+                            fn = _EXPAND_CACHE.put(
+                                (key, out_cap), _build_expand(bound, out_cap))
+                        TR.first_dispatch(metrics, fn)
+                        cols, active = fn(b.columns, b.active,
+                                          X.literal_values(lit_exprs))
+                    metrics.create(M.EXPAND_ROWS, M.ESSENTIAL).add(
+                        n_sets * rows)
+                    metrics.create(M.DISPATCH_COUNT, M.ESSENTIAL).add(1)
+                    yield DeviceBatch(schema, cols, active, n_sets * rows)
             return run
         return [make(t) for t in device_channel(self.child)]
 

@@ -108,6 +108,11 @@ JOIN_CONDITION_TIME = "joinConditionTime"    # host wall around those joins
 WINDOW_TIME = "windowTime"    # host wall: concat, key batching, enqueue
 WINDOW_ROWS = "windowRows"    # rows handed to the window programs
 WINDOW_DECIMAL_AGG_COUNT = "windowDecimalAggCount"  # decimal-source aggs
+# what the grouping-sets expansion and the aggregates' keys cost
+# (exec/basic.py, exec/agg.py), from row counts and static shapes
+EXPAND_TIME = "expandTime"    # host wall enqueueing the expand program
+EXPAND_ROWS = "expandRows"    # rows out of the grouping-sets expansion
+AGG_KEY_WORDS = "aggKeyWords"  # lanes x group-key words an aggregate sorts
 # what the analysis rules of a statement did (sql/session.py)
 DECORRELATED_SUBQUERY_COUNT = "decorrelatedSubqueryCount"
 JOIN_CONDITION_PUSHED_COUNT = "joinConditionPushedCount"
@@ -217,6 +222,17 @@ METRIC_DESCRIPTIONS: Dict[str, str] = {
     WINDOW_DECIMAL_AGG_COUNT: "window aggregates over a decimal source "
                               "run on the device, once per window exec "
                               "executed",
+    EXPAND_TIME: "host wall around the grouping-sets expansion (ns): "
+                 "building and enqueueing the one program that writes "
+                 "every set of a batch — not device time, and not the "
+                 "wait for the batch's row count (deviceSyncTime)",
+    EXPAND_ROWS: "rows out of the grouping-sets expansion: sets x each "
+                 "input batch's row count (read under deviceSync "
+                 "site=expandRows where the producer attached none)",
+    AGG_KEY_WORDS: "lanes x group-key words each dispatched aggregate "
+                   "pass sorts or gathers (a string key: validity, "
+                   "length and char_cap/8 words), from the program's "
+                   "static shapes (no device read)",
     DECORRELATED_SUBQUERY_COUNT: "correlated [NOT] EXISTS subqueries "
                                  "the analysis rule turned into left "
                                  "semi or left anti joins, once per "

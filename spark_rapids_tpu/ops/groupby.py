@@ -178,6 +178,29 @@ def grouping_subkeys(col: AnyDeviceColumn,
     return [col.validity] + rank_words(col, has_nans)
 
 
+def _value_word_count(col: AnyDeviceColumn, has_nans: bool) -> int:
+    """``len(value_words(col))`` from the column's static shapes."""
+    from spark_rapids_tpu.columnar.device import (DeviceDecimal128Column,
+                                                  DeviceStructColumn)
+    if isinstance(col, DeviceStringColumn):
+        return -(-col.chars.shape[1] // 8) + 1
+    if isinstance(col, DeviceDecimal128Column):
+        return 2
+    if isinstance(col, DeviceStructColumn):
+        return sum(1 + _value_word_count(f, has_nans) for f in col.fields)
+    floating = jnp.issubdtype(col.data.dtype, jnp.floating)
+    return 2 if floating and has_nans else 1
+
+
+def key_word_count(key_cols: Sequence[AnyDeviceColumn],
+                   has_nans: bool) -> int:
+    """The group-key words a row sorts or gathers:
+    ``len(grouping_subkeys(c))`` summed over the keys, from their static
+    shapes alone (a string key: validity, length and ``char_cap/8``
+    packed words)."""
+    return sum(1 + _value_word_count(c, has_nans) for c in key_cols)
+
+
 def word_sentinel(dtype, is_min: bool):
     """A value no real candidate beats: the loser for this word dtype."""
     if dtype == jnp.bool_:

@@ -389,68 +389,19 @@ class GroupedData:
         self.sets_mode = sets_mode  # None | "rollup" | "cube"
 
     def _expand_sets(self, agg_cols) -> DataFrame:
-        """rollup/cube -> Aggregate over Expand with a grouping-id column
-        (Spark's ResolveGroupingAnalytics shape; device twin:
-        GpuExpandExec). The gid keeps 'key absent from this set' groups
-        apart from genuine null-key groups."""
+        """rollup/cube -> Aggregate over Expand (``GroupingSets``). The
+        aggregate columns resolve against the child, so an aggregate of
+        a key reads its original values; a key outside an aggregate
+        function is its nulled copy."""
         df = self.df
-        # 1. make every key an attribute (pre-project aliased exprs)
-        base_items = list(df.plan.output)
-        key_attrs: List[E.AttributeReference] = []
-        need_proj = False
-        for g in self.grouping:
-            if isinstance(g, E.AttributeReference):
-                key_attrs.append(g)
-            else:
-                alias = g if isinstance(g, E.Alias) else \
-                    E.Alias(g, _auto_name(g))
-                base_items.append(alias)
-                key_attrs.append(alias.to_attribute())
-                need_proj = True
-        plan = (L.Project(base_items, df.plan) if need_proj else df.plan)
-        child_out = list(plan.output)
-        # 2. grouping sets
-        n = len(key_attrs)
-        if self.sets_mode == "rollup":
-            sets = [frozenset(range(k)) for k in range(n, -1, -1)]
-        else:  # cube
-            sets = [frozenset(i for i in range(n) if mask & (1 << i))
-                    for mask in range((1 << n) - 1, -1, -1)]
-        # 3. expanded output: child cols + one fresh attr per key + gid
-        out_keys = [E.AttributeReference(a.name, a.data_type, True)
-                    for a in key_attrs]
-        gid = E.AttributeReference("spark_grouping_id", T.LongT, False)
-        expand_out = child_out + out_keys + [gid]
-        projections: List[List[E.Expression]] = []
-        for si, s in enumerate(sets):
-            proj: List[E.Expression] = list(child_out)
-            for i, a in enumerate(key_attrs):
-                proj.append(a if i in s
-                            else E.Literal(None, a.data_type))
-            proj.append(E.Literal(si, T.LongT))
-            projections.append(proj)
-        expanded = DataFrame(
-            L.Expand(projections, expand_out, plan), df.session)
-        # 4. aggregate over (expanded keys, gid); gid stays internal.
-        # Aggregates referencing a grouping column resolve to the
-        # EXPANDED (nulled) key, like Spark — so resolve against the
-        # non-key child columns + the fresh key attrs only.
-        key_ids = {a.expr_id for a in key_attrs}
-        resolve_attrs = [a for a in child_out
-                         if a.expr_id not in key_ids] + out_keys
-        case_sensitive = df.session.conf.get(
-            "spark.sql.caseSensitive", False)
-        aggs: List[E.Expression] = list(out_keys)
+        sets = GroupingSets(df.plan, self.grouping, self.sets_mode)
+        aggs: List[E.Expression] = list(sets.keys)
         for c in agg_cols:
-            e = _coerce_resolved(L.resolve(
-                c.expr if isinstance(c, Column) else c,
-                resolve_attrs, bool(case_sensitive)))
+            e = sets.substitute(df._resolve(c))
             if not isinstance(e, (E.Alias, E.AttributeReference)):
                 e = E.Alias(e, _auto_name(e))
             aggs.append(e)
-        return DataFrame(
-            L.Aggregate(out_keys + [gid], aggs, expanded.plan),
-            df.session)
+        return DataFrame(sets.aggregate(aggs), df.session)
 
     def agg(self, *cols) -> DataFrame:
         if self.sets_mode is not None:
@@ -561,6 +512,98 @@ class PivotedData:
     def max(self, *cols) -> DataFrame:
         from spark_rapids_tpu.sql import functions as F
         return self._simple(F.max, *cols)
+
+
+class GroupingSets:
+    """``GROUP BY ROLLUP(...)`` / ``CUBE(...)`` in the shape Spark's
+    analyzer gives it (ResolveGroupingAnalytics; device twin
+    GpuExpandExec): an Expand that emits, once per grouping set, the
+    columns the aggregates read, every key with those outside the set
+    nulled, and a grouping id; over it an Aggregate on the nulled keys
+    and the id. A key outside an aggregate function means its nulled
+    copy (``keys``); inside one it means the original value, so a
+    rolled-up row aggregates all it covers. The id stays internal: it
+    keeps a genuine null key's group apart from a rolled-up one.
+
+    The Expand carries only what the aggregates read (Spark's column
+    pruning of the Expand's child): its projections are those columns,
+    the keys and the id, not the whole child."""
+
+    def __init__(self, child: L.LogicalPlan, key_exprs: List[E.Expression],
+                 mode: str):
+        self.child = child
+        # what the Expand's child projects for each key: the column, or
+        # an alias of the expression
+        self.key_items: List[E.Expression] = []
+        key_attrs: List[E.AttributeReference] = []
+        for g in key_exprs:
+            if isinstance(g, E.AttributeReference):
+                self.key_items.append(g)
+                key_attrs.append(g)
+            else:
+                alias = g if isinstance(g, E.Alias) else \
+                    E.Alias(g, _auto_name(g))
+                self.key_items.append(alias)
+                key_attrs.append(alias.to_attribute())
+        self.key_exprs = [g.child if isinstance(g, E.Alias) else g
+                          for g in key_exprs]
+        self.key_attrs = key_attrs
+        self.keys = [E.AttributeReference(a.name, a.data_type, True)
+                     for a in key_attrs]
+        self.gid = E.AttributeReference("spark_grouping_id", T.LongT, False)
+        n = len(key_attrs)
+        if mode == "rollup":
+            self.sets = [frozenset(range(k)) for k in range(n, -1, -1)]
+        elif mode == "cube":
+            self.sets = [frozenset(i for i in range(n) if mask >> i & 1)
+                         for mask in range((1 << n) - 1, -1, -1)]
+        else:
+            raise ValueError(f"unknown grouping-sets mode {mode!r}")
+
+    def substitute(self, e: E.Expression) -> E.Expression:
+        """``e`` with each key subtree outside an aggregate function
+        replaced by its nulled copy (Spark's constructAggregateExprs)."""
+        by_repr = {repr(g): k for g, k in zip(self.key_exprs, self.keys)}
+        for a, k in zip(self.key_attrs, self.keys):
+            by_repr.setdefault(repr(a), k)
+
+        def walk(x: E.Expression) -> E.Expression:
+            if isinstance(x, E.AggregateExpression):
+                return x
+            hit = by_repr.get(repr(x))
+            if hit is not None:
+                return hit
+            kids = [walk(c) for c in x.children]
+            if all(k is c for k, c in zip(kids, x.children)):
+                return x
+            return x.with_children(kids)
+        return walk(e)
+
+    def aggregate(self, aggregates: List[E.Expression]) -> L.Aggregate:
+        """The Aggregate over the Expand; ``aggregates`` already read the
+        nulled keys where they mean them (``substitute``)."""
+        produced = {a.expr_id for a in self.keys} | {self.gid.expr_id}
+        carried: List[E.AttributeReference] = []
+        seen = set()
+        for e in aggregates:
+            for a in e.references():
+                if a.expr_id not in produced and a.expr_id not in seen:
+                    seen.add(a.expr_id)
+                    carried.append(a)
+        items: List[E.Expression] = list(carried)
+        for it in self.key_items:
+            if it.expr_id not in seen:
+                seen.add(it.expr_id)
+                items.append(it)
+        projections = [
+            list(carried)
+            + [a if i in s else E.Literal(None, a.data_type)
+               for i, a in enumerate(self.key_attrs)]
+            + [E.Literal(si, T.LongT)]
+            for si, s in enumerate(self.sets)]
+        expand = L.Expand(projections, carried + self.keys + [self.gid],
+                          L.Project(items, self.child))
+        return L.Aggregate(self.keys + [self.gid], aggregates, expand)
 
 
 def _auto_name(e: E.Expression) -> str:

@@ -3,9 +3,9 @@ the reference, which inherits it for free; this build supplies its own).
 
 Hand-written tokenizer + recursive-descent parser covering the dialect
 the engine executes: SELECT [DISTINCT] ... FROM (tables, subqueries,
-joins) WHERE / GROUP BY / HAVING / ORDER BY / LIMIT, UNION ALL, CASE,
-CAST, IN/LIKE/BETWEEN/IS NULL, window functions with OVER, and the
-engine's function library. Expressions are built through the Column API
+joins) WHERE / GROUP BY [ROLLUP | CUBE] / HAVING / ORDER BY / LIMIT,
+UNION ALL, CASE, CAST, IN/LIKE/BETWEEN/IS NULL, window functions with
+OVER, and the engine's function library. Expressions are built through the Column API
 (spark_rapids_tpu.sql.functions) so SQL gets exactly the same coercion
 rules as the DataFrame surface.
 
@@ -226,20 +226,42 @@ class _Parser:
             if self.kw("where"):
                 df = df.filter(self._mark_outer_references(self.expr(), df))
             group: Optional[List[Column]] = None
+            sets_mode: Optional[str] = None
             if self.kw("group", "by"):
+                sets_mode = self._grouping_sets_mode()
+                if sets_mode:
+                    self.expect("(")
                 group = [self.expr()]
                 while self.peek()[1] == ",":
                     self.next()
                     group.append(self.expr())
+                if sets_mode:
+                    self.expect(")")
             having = self.expr() if self.kw("having") else None
         finally:
             self._outer.pop()
-        df = self._project(df, items, group, having)
+        df = self._project(df, items, group, having, sets_mode)
         # DISTINCT applies to the projected rows (ORDER BY/LIMIT are
         # parsed by query(), after any UNION branches)
         if distinct:
             df = df.distinct()
         return df
+
+    def _grouping_sets_mode(self) -> Optional[str]:
+        """``ROLLUP(`` or ``CUBE(`` after GROUP BY: the mode of the
+        grouping sets it spells; ``GROUPING SETS`` is refused by name."""
+        kind, val = self.peek()
+        if kind != "id":
+            return None
+        low = val.lower()
+        if low in ("rollup", "cube") and self.peek(1)[1] == "(":
+            self.next()
+            return low
+        if low == "grouping" and self.peek(1)[1].lower() == "sets":
+            raise NotImplementedError(
+                "GROUP BY GROUPING SETS (...) is not supported: write "
+                "ROLLUP(...) or CUBE(...), or a UNION ALL of GROUP BYs")
+        return None
 
     def _opt_alias(self) -> Optional[str]:
         if self.kw("as"):
@@ -358,8 +380,8 @@ class _Parser:
     # -- aggregation shaping ----------------------------------------------
 
     def _project(self, df, items, group: Optional[List[Column]],
-                 having: Optional[Column]):
-        from spark_rapids_tpu.sql.dataframe import DataFrame
+                 having: Optional[Column], sets_mode: Optional[str] = None):
+        from spark_rapids_tpu.sql.dataframe import DataFrame, GroupingSets
 
         def window_args(w: E.WindowExpression) -> List[E.Expression]:
             """What a window expression reads of its input: its
@@ -419,16 +441,18 @@ class _Parser:
             raise NotImplementedError(
                 "a window function in GROUP BY is not supported: compute "
                 "it in a derived table and group by its column")
-        grouping: List[E.Expression] = []
-        group_attr_by_repr = {}
-        for g in group_exprs:
-            if isinstance(g, E.AttributeReference):
-                grouping.append(g)
-                group_attr_by_repr[repr(g)] = g
-            else:
-                alias = E.Alias(g, f"_g{len(grouping)}")
-                grouping.append(alias)
-                group_attr_by_repr[repr(g)] = alias.to_attribute()
+        grouping: List[E.Expression] = [
+            g if isinstance(g, E.AttributeReference)
+            else E.Alias(g, f"_g{i}") for i, g in enumerate(group_exprs)]
+        sets = None
+        if sets_mode:
+            # a key outside an aggregate function reads its nulled copy;
+            # inside one, the original values (GroupingSets)
+            sets = GroupingSets(df.plan, grouping, sets_mode)
+            grouping = list(sets.keys)
+        group_attr_by_repr = {
+            repr(g): E.named_output(k)
+            for g, k in zip(group_exprs, grouping)}
         agg_aliases: List[E.Expression] = []
 
         def extract(e: E.Expression) -> E.Expression:
@@ -471,8 +495,11 @@ class _Parser:
             out_items.append(r)
         having_r = extract(having_e) if having_e is not None else None
 
-        plan = L.Aggregate(list(grouping),
-                           list(grouping) + agg_aliases, df.plan)
+        if sets is not None:
+            plan = sets.aggregate(list(grouping) + agg_aliases)
+        else:
+            plan = L.Aggregate(list(grouping),
+                               list(grouping) + agg_aliases, df.plan)
         out = DataFrame(plan, df.session)
         if having_r is not None:
             out = DataFrame(L.Filter(having_r, out.plan), out.session)
@@ -872,6 +899,10 @@ class _Parser:
         if low in ("lcase",):
             low = "lower"
         fn = _FUNCTIONS.get(low)
+        if fn is None and low in ("grouping", "grouping_id"):
+            raise NotImplementedError(
+                f"{low}() is not supported: the grouping id of ROLLUP / "
+                "CUBE stays internal to the aggregate")
         if fn is None:
             raise ValueError(f"unknown SQL function {low!r}")
         c = fn(*args)

@@ -1787,7 +1787,7 @@ def generate_observability_docs() -> str:
         "`_gather_fast_<type>` / `_mask` / `_extras`, `srt_decode`,",
         "`srt_upload_decode`, `srt_fetch_pack`,",
         "`srt_concat`, `srt_shrink`, `srt_compact`, `srt_project`,",
-        "`srt_filter`, `srt_window`, `srt_generate`,",
+        "`srt_filter`, `srt_window`, `srt_generate`, `srt_expand`,",
         "`srt_exchange_pid` / `_range_keys` / `_range_rank` /",
         "`_split_sort` / `_extract` / `_round_robin`, `srt_ici_exchange`,",
         "`srt_ici_sizes`, `srt_mesh_agg_step`. **The rule:** a name is a",
@@ -1823,7 +1823,16 @@ def generate_observability_docs() -> str:
         "together), `/extents` (each key's run and its counts by prefix",
         "sum), `/inverse` (the counts back to input order by a second",
         "sort) and `/right_order` (the build rows in key order by a",
-        "third));",
+        "third)) and the grouping-sets expansion's (`srt_expand`:",
+        "`expand/compact` (a batch whose rows fit a smaller capacity",
+        "bucket moved to the front of it), `expand/project` (each",
+        "distinct expression of the sets evaluated once, a key a set",
+        "leaves out nulled) and `expand/write` (the sets written one",
+        "after another into one output batch); `expandTime` and",
+        "`expandRows` time and count it on the host, `aggKeyWords` the",
+        "group-key words the aggregate passes after it sort or gather",
+        "(the benchmark's `expand_host_s`, `expand_rows` and",
+        "`agg_key_words` read the three);",
         "scopes are",
         "op_name metadata and change no compiled code. The tpu-lint",
         "`jit-direct` rule treats",
@@ -1857,7 +1866,9 @@ def generate_observability_docs() -> str:
         " `joinSize`, `joinBuild`, `joinCondPairs` (the candidate pairs"
         " of a conditional semi/anti join, read from the count program"
         " once the mask program is enqueued), `exchangeSplit`, `iciSizes`,"
-        " `ansiError` | thread-ns blocked, summed over task threads |",
+        " `ansiError`, `expandRows` (a batch's row count, which sizes the"
+        " grouping-sets expansion's output) | thread-ns blocked, summed"
+        " over task threads |",
         "| `scanStarvedTime` | `scanWait` (`chip=`; only a pull that"
         " found the ring empty) | `TpuRowToColumnarExec`'s consumer of a"
         " scan stream (`exec/base.py`); `QueryScope.scan_streams` counts"
