@@ -116,6 +116,7 @@ AGG_KEY_WORDS = "aggKeyWords"  # lanes x group-key words an aggregate sorts
 # what the analysis rules of a statement did (sql/session.py)
 DECORRELATED_SUBQUERY_COUNT = "decorrelatedSubqueryCount"
 JOIN_CONDITION_PUSHED_COUNT = "joinConditionPushedCount"
+CONSTANT_FOLD_COUNT = "constantFoldCount"
 
 
 # ---------------------------------------------------------------------------
@@ -241,6 +242,9 @@ METRIC_DESCRIPTIONS: Dict[str, str] = {
                                  "rule moved beneath their join as a "
                                  "filter on the side they read, once per "
                                  "statement planned",
+    CONSTANT_FOLD_COUNT: "literal-only expression subtrees the analysis "
+                         "rule folded into one literal on the host, once "
+                         "per statement planned",
     PLAN_TIME: "host planning wall on the calling thread (ns): SQL "
                "parse, analysis, overrides, plan cache, fingerprints, up "
                "to execute_collect — once per query",

@@ -121,11 +121,9 @@ def expr_key(e: E.Expression) -> Tuple:
 # ---------------------------------------------------------------------------
 
 def _is_traced_literal(e: E.Literal) -> bool:
-    """Numeric non-null literals become runtime scalar inputs (limb
-    decimals stay trace-time constants: their unscaled value exceeds an
-    int64 scalar)."""
+    """Numeric, date and timestamp non-null literals become runtime
+    scalar inputs (a limb decimal its two int64 limbs, ``[hi, lo]``)."""
     return (e.value is not None
-            and not T.is_limb_decimal(e.data_type)
             and not isinstance(e.data_type, (T.StringType, T.BinaryType,
                                              T.BooleanType, T.NullType)))
 
@@ -163,12 +161,19 @@ def collect_literals(exprs: Sequence[E.Expression]
     return lits, derived
 
 
-def literal_values(exprs: Sequence[E.Expression]) -> List[jax.Array]:
+def _literal_input(e: E.Literal) -> jax.Array:
     from spark_rapids_tpu.columnar.host import _to_storage
+    v = _to_storage(e.value, e.data_type)
+    if T.is_limb_decimal(e.data_type):
+        from spark_rapids_tpu.ops import int128 as I
+        hi, lo = I.from_pyints([v])
+        return jnp.asarray([hi[0], lo[0]], dtype=jnp.int64)
+    return jnp.asarray(v, dtype=storage_jnp_dtype(e.data_type))
+
+
+def literal_values(exprs: Sequence[E.Expression]) -> List[jax.Array]:
     lits, derived = collect_literals(exprs)
-    vals = [jnp.asarray(_to_storage(l.value, l.data_type),
-                        dtype=storage_jnp_dtype(l.data_type))
-            for l in lits]
+    vals = [_literal_input(l) for l in lits]
     for node in derived:
         vals.extend(jnp.asarray(v) for v in _DERIVED[type(node)](node))
     return vals
@@ -498,13 +503,12 @@ def _h_literal(e: E.Literal, ctx: Ctx) -> AnyDeviceColumn:
                             jnp.zeros(cap, dtype=bool))
     if T.is_limb_decimal(dt):
         from spark_rapids_tpu.columnar.device import DeviceDecimal128Column
-        from spark_rapids_tpu.columnar.host import _to_storage
-        from spark_rapids_tpu.ops import int128 as I
-        hi, lo = I.from_pyints([_to_storage(e.value, dt)])
+        limbs = ctx.literal_scalar(e)
+        if limbs is None:
+            limbs = _literal_input(e)
         return DeviceDecimal128Column(
-            dt, jnp.full(cap, int(hi[0]), dtype=jnp.int64),
-            jnp.full(cap, int(lo[0]), dtype=jnp.int64),
-            jnp.ones(cap, dtype=bool))
+            dt, jnp.broadcast_to(limbs[0], (cap,)),
+            jnp.broadcast_to(limbs[1], (cap,)), jnp.ones(cap, dtype=bool))
     if isinstance(dt, (T.StringType, T.BinaryType)):
         raw = (e.value.encode("utf-8") if isinstance(e.value, str)
                else bytes(e.value))

@@ -75,6 +75,9 @@ def plan_signature(plan, conf) -> str:
     order (``expr_id`` attributes, wherever they appear), so two parses
     of the same SQL text agree; everything else — literals, data types,
     file paths, node parameters — is kept verbatim."""
+    import datetime
+    import decimal
+
     from spark_rapids_tpu.sql import expressions as E
     from spark_rapids_tpu.sql import types as T
     from spark_rapids_tpu.sql.logical import LogicalPlan
@@ -83,7 +86,10 @@ def plan_signature(plan, conf) -> str:
     parts: List[str] = []
 
     def enc_val(v) -> str:
-        if isinstance(v, (int, float, bool, bytes, type(None))):
+        # a date, timestamp or decimal literal's value, as a folded
+        # DATE '...' or CAST(... AS DECIMAL) holds it, is one too
+        if isinstance(v, (int, float, bool, bytes, type(None),
+                          datetime.date, decimal.Decimal)):
             return repr(v)
         if isinstance(v, str):
             return repr(v)

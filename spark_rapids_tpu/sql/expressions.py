@@ -3014,7 +3014,12 @@ def cast_host_column(c: HostColumn, to: T.DataType, ansi: bool = False
         return _cast_to_string(c)
     # string -> *
     if isinstance(frm, T.StringType):
-        return _cast_from_string(c, to, ansi)
+        out = _cast_from_string(c, to)
+        # Spark's ANSI cast raises where a malformed string reads null
+        if ansi and (c.validity & ~out.validity).any():
+            raise ValueError(f"invalid input syntax for type "
+                             f"{to.simple_string} in ANSI mode")
+        return out
     # date/timestamp conversions
     if isinstance(frm, T.DateType) and isinstance(to, T.TimestampType):
         data = c.data.astype(np.int64) * 86_400_000_000
@@ -3138,8 +3143,7 @@ def _format_decimal(unscaled: int, scale: int) -> str:
     return f"{sign}{s[:-scale]}.{s[-scale:]}"
 
 
-def _cast_from_string(c: HostColumn, to: T.DataType, ansi: bool
-                      ) -> HostColumn:
+def _cast_from_string(c: HostColumn, to: T.DataType) -> HostColumn:
     n = len(c)
     validity = c.validity.copy()
     np_dt = T.numpy_dtype(to)

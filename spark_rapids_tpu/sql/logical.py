@@ -7,12 +7,15 @@ expression in a plan refers to AttributeReferences with unique ids.
 
 from __future__ import annotations
 
-from typing import Any, List, Optional, Sequence
+from typing import Any, List, Optional, Sequence, Tuple
 
 from spark_rapids_tpu.sql import types as T
 from spark_rapids_tpu.sql.expressions import (
-    AggregateExpression, Alias, AttributeReference, Cast, Expression,
-    Literal, SortOrder, UnresolvedAttribute, named_output)
+    AggregateExpression, AggregateFunction, Alias, AttributeReference, Cast,
+    Exists, Expression, InputFileName, InSubquery, Literal,
+    MonotonicallyIncreasingID, PandasUDF, PythonUDF, ScalarSubquery,
+    SortOrder, SparkPartitionID, UnresolvedAttribute, WindowExpression,
+    WindowFunction, named_output)
 
 
 class LogicalPlan:
@@ -466,7 +469,6 @@ def _is_comma_join(p: LogicalPlan) -> bool:
 
 
 def _has_subquery_predicate(e: Expression) -> bool:
-    from spark_rapids_tpu.sql.expressions import Exists, InSubquery
     return bool(e.collect(lambda x: isinstance(x, (InSubquery, Exists))))
 
 
@@ -503,9 +505,7 @@ def _one_sided_conjuncts(j: "Join") -> List[tuple]:
     reads one side it may move to alone: a column of that side and none
     of the other's, no subquery, nothing whose value depends on where
     it runs."""
-    from spark_rapids_tpu.sql.expressions import (MonotonicallyIncreasingID,
-                                                  OuterReference,
-                                                  SparkPartitionID)
+    from spark_rapids_tpu.sql.expressions import OuterReference
     sides = _PUSHABLE_SIDES.get(j.join_type, ())
     if j.condition is None or not sides:
         return []
@@ -591,7 +591,7 @@ def rewrite_joins_and_subqueries(plan: LogicalPlan) -> LogicalPlan:
 
     Returns ``plan`` itself when none has anything to do."""
     import copy
-    from spark_rapids_tpu.sql.expressions import Exists, InSubquery, Not
+    from spark_rapids_tpu.sql.expressions import Not
     p = plan
     new_children = [rewrite_joins_and_subqueries(c) for c in p.children]
     if new_children != p.children:
@@ -681,8 +681,7 @@ def _subqueries_to_semi_joins(f: Filter) -> LogicalPlan:
     from spark_rapids_tpu import metrics as M
     from spark_rapids_tpu import trace as TR
     from spark_rapids_tpu.sql.dataframe import _coerce_resolved
-    from spark_rapids_tpu.sql.expressions import (EqualTo, Exists,
-                                                  InSubquery, Not)
+    from spark_rapids_tpu.sql.expressions import EqualTo, Not
     plain, refused, joins = [], [], []
     for c in split_conjuncts(f.condition):
         x = c.children[0] if isinstance(c, Not) else c
@@ -841,3 +840,108 @@ def _semi_join_below(p: LogicalPlan, refs: set, sub: LogicalPlan,
                                                  join_type)
                 return q
     return Join(p, sub, join_type, cond)
+
+
+def fold_constants(plan: LogicalPlan) -> Tuple[LogicalPlan, int]:
+    """Catalyst's ConstantFolding, copy-on-write: every maximal subtree
+    of the plan's expressions whose leaves are all literals becomes one
+    ``Literal`` of its own type, the value its own host ``eval`` gives on
+    one row (``DATE '1998-09-02'``, a ``Cast`` of a string, is then one
+    date, and a device program reads it as a traced scalar instead of
+    parsing the string on every lane). A subtree is left as it is where
+    a node of it is an aggregate or a window, a subquery, a UDF, one that
+    reads where it runs, a generator, an ``Alias`` or a ``SortOrder``
+    (their literal-only children fold), where its type is a nested one,
+    or where its evaluation raises (an ANSI cast error surfaces at run
+    time, as before). A null result folds to a typed null. A subtree
+    shared by two nodes folds once and stays shared. Returns the plan
+    (``plan`` itself when nothing folds) and the number of subtrees
+    folded, which is counted as ``constantFoldCount``."""
+    import copy
+
+    from spark_rapids_tpu import metrics as M
+    memo: dict = {}
+    folded = 0
+
+    def pure(e: Expression) -> bool:
+        """Whether ``e``'s leaves are all literals and no node of it
+        may not fold (memoised: a parent asks of each child once)."""
+        key = id(e)
+        if key not in memo:
+            memo[key] = (not isinstance(e, _UNFOLDABLE)
+                         and not getattr(e, "is_generator", False)
+                         and (all(pure(c) for c in e.children)
+                              if e.children else isinstance(e, Literal)))
+        return memo[key]
+
+    done: dict = {}
+
+    def fold(e: Expression) -> Expression:
+        nonlocal folded
+        key = id(e)
+        if key in done:
+            return done[key]
+        out = _literal_of(e) if pure(e) and e.children else None
+        if out is not None:
+            folded += 1
+        else:
+            kids = [fold(c) for c in e.children]
+            out = e if all(k is c for k, c in zip(kids, e.children)) \
+                else e.with_children(kids)
+        done[key] = out
+        return out
+
+    def walk(p: LogicalPlan) -> LogicalPlan:
+        kids = [walk(c) for c in p.children]
+        repl = {}
+        for attr in EXPR_ATTRS:
+            v = getattr(p, attr, None)
+            if isinstance(v, Expression):
+                w = fold(v)
+                if w is not v:
+                    repl[attr] = w
+            elif isinstance(v, list):
+                w = [fold(x) if isinstance(x, Expression) else x for x in v]
+                if any(a is not b for a, b in zip(w, v)):
+                    repl[attr] = w
+        if not repl and all(k is c for k, c in zip(kids, p.children)):
+            return p
+        q = copy.copy(p)
+        q.children = kids
+        for attr, w in repl.items():
+            setattr(q, attr, w)
+        return q
+
+    out = walk(plan)
+    if folded:
+        M.query_registry().create(M.CONSTANT_FOLD_COUNT,
+                                  M.ESSENTIAL).add(folded)
+    return out, folded
+
+
+# what fold_constants never folds through: evaluated over rows, by a plan
+# or by Python, reading where it runs, or naming or ordering its child
+_UNFOLDABLE = (Alias, SortOrder, AggregateFunction, AggregateExpression,
+               WindowExpression, WindowFunction, ScalarSubquery,
+               InSubquery, Exists, PythonUDF, PandasUDF, SparkPartitionID,
+               MonotonicallyIncreasingID, InputFileName)
+
+
+def _literal_of(e: Expression) -> Optional[Literal]:
+    """The literal a literal-only ``e`` evaluates to on one row (the CPU
+    engine's own answer), or None when its type is nested or its
+    evaluation raises. A timestamp keeps its storage integer (a
+    ``datetime`` converts back through a float)."""
+    from spark_rapids_tpu.columnar.host import HostBatch
+    try:
+        dt = e.data_type
+        if not isinstance(dt, (T.AtomicType, T.NumericType, T.NullType)):
+            return None
+        col = e.eval(HostBatch(T.StructType([]), [], 1))
+    except Exception:  # noqa: BLE001 - whatever it raises, it raises
+        return None    # when the query runs, as it did unfolded
+    if len(col) != 1:
+        return None
+    if isinstance(dt, T.TimestampType) and col.validity[0]:
+        return Literal(int(col.data[0]), dt)
+    return Literal(col.to_pylist()[0], dt)

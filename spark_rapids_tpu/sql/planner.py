@@ -488,31 +488,22 @@ _PUSH_OPS = {E.EqualTo: "eq", E.LessThan: "lt", E.LessThanOrEqual: "le",
 _PUSH_SWAP = {"lt": "gt", "le": "ge", "gt": "lt", "ge": "le", "eq": "eq"}
 
 
-def _fold_literal(e: E.Expression):
-    """Storage value of a literal-only subtree (e.g. Cast('1998-09-02'
-    as date)), or None when it references columns or fails to fold."""
-    def has_attr(x) -> bool:
-        if isinstance(x, (E.AttributeReference, E.BoundReference)):
-            return True
-        return any(has_attr(c) for c in x.children)
-    if has_attr(e):
+def _literal_value(e: E.Expression):
+    """Storage value of a non-null literal (``logical.fold_constants``
+    has made each literal-only subtree one), or None. Read from the
+    literal's own column, so a float literal keeps its type's width."""
+    from spark_rapids_tpu.columnar.host import HostBatch
+    if not isinstance(e, E.Literal) or e.value is None \
+            or T.is_limb_decimal(e.data_type):
         return None
-    try:
-        from spark_rapids_tpu.columnar.host import HostBatch
-        col = e.eval(HostBatch(T.StructType([]), [], 1))
-        if not col.validity[0]:
-            return None
-        v = col.data[0]
-        if hasattr(v, "item"):
-            v = v.item()
-        return v if isinstance(v, (int, float, str)) else None
-    except Exception:
-        return None
+    v = e.eval(HostBatch(T.StructType([]), [], 1)).data[0]
+    v = v.item() if hasattr(v, "item") else v
+    return v if isinstance(v, (int, float, str)) else None
 
 
 def _pushable_predicates(condition: E.Expression) -> List[tuple]:
     """(column, op, storage-value) conjuncts a parquet footer can rule
-    on: plain attribute vs foldable literal comparisons, IsNull and
+    on: plain attribute vs literal comparisons, IsNull and
     IsNotNull (ParquetFilters.createFilter's pushable subset)."""
     out: List[tuple] = []
     for conj in split_conjuncts(condition):
@@ -529,11 +520,11 @@ def _pushable_predicates(condition: E.Expression) -> List[tuple]:
             continue
         left, right = conj.left, conj.right
         if isinstance(left, E.AttributeReference):
-            v = _fold_literal(right)
+            v = _literal_value(right)
             if v is not None:
                 out.append((left.name, op, v))
         elif isinstance(right, E.AttributeReference):
-            v = _fold_literal(left)
+            v = _literal_value(left)
             if v is not None:
                 out.append((right.name, _PUSH_SWAP[op], v))
     return out

@@ -198,6 +198,9 @@ class TpuSparkSession:
             from spark_rapids_tpu import trace as TR
             with TR.span("plan", phase="subquery"):
                 plan = L.rewrite_joins_and_subqueries(plan)
+        # literal-only subtrees -> one literal each, on the host, once
+        # (DATE '1998-09-02' is a Cast of a string until then)
+        plan, folded = L.fold_constants(plan)
         plan = materialize_scalar_subqueries(
             plan, self if execute_subqueries else None)
         plan = udf_compiler.rewrite_plan(plan, self.conf_obj)
@@ -250,6 +253,8 @@ class TpuSparkSession:
             self._tls.rewrite_report = report
         if self._capture_enabled:
             self._plan_capture.append(physical)
+        # set last: a scalar subquery planned above sets its own
+        self._tls.constant_folds = folded
         return physical
 
     def _rewrite_fresh(self, plan):
@@ -365,6 +370,8 @@ class TpuSparkSession:
                 physical = self.plan_physical(plan)
                 plan_span.attrs["cacheHit"] = getattr(
                     self._tls, "plan_cache_hit", None)
+                plan_span.attrs["folded"] = getattr(
+                    self._tls, "constant_folds", None)
                 sig = getattr(self._tls, "plan_signature", None)
                 if quar_thr > 0 and sig is not None \
                         and LC.is_quarantined(sig):

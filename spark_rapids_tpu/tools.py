@@ -1846,7 +1846,8 @@ def generate_observability_docs() -> str:
         "",
         "| timer | span | where | what |",
         "|---|---|---|---|",
-        "| `planTime` | `plan` (`phase=parse` / `rewrite`, `cacheHit=`;"
+        "| `planTime` | `plan` (`phase=parse` / `rewrite`, `cacheHit=`,"
+        " `folded=`;"
         " inside `rewrite`, untimed, `phase=subquery` when a comma list,"
         " an `IN (subquery)` or an `EXISTS` is rewritten into joins, and"
         " inside that `phase=decorrelate`, one per correlated"

@@ -135,7 +135,8 @@ SPAN_CATALOG: Dict[str, str] = {
                  "to end (q=, tenant=, parent= for a scalar subquery)",
     "plan": "host planning on the calling thread (mirrors planTime): "
             "phase=parse in session.sql, phase=rewrite in execute_plan "
-            "up to execute_collect (cacheHit= plan-cache outcome); "
+            "up to execute_collect (cacheHit= plan-cache outcome, "
+            "folded= literal-only subtrees folded into literals); "
             "nested in it and untimed, phase=subquery: a comma list, "
             "an IN (subquery) or an EXISTS rewritten into joins "
             "(sql/logical.py), and inside that phase=decorrelate: one "
